@@ -2,13 +2,12 @@
 reference ecosystem's text-classification recipe: encoder + pooled [CLS]
 head, AdamW with linear warmup, padded batches with attention masks).
 
-python examples/finetune_ernie.py --platform cpu --steps 10 --hidden 64 \
+JAX_PLATFORMS=cpu python examples/finetune_ernie.py --steps 10 --hidden 64 \
     --layers 2 --heads 2
 """
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import argparse
 import time
@@ -18,7 +17,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 import paddle_tpu as paddle
 from paddle_tpu.models import ernie
@@ -26,7 +24,6 @@ from paddle_tpu.models import ernie
 
 def main():
     p = argparse.ArgumentParser()
-    add_platform_arg(p)
     p.add_argument('--steps', type=int, default=30)
     p.add_argument('--batch', type=int, default=8)
     p.add_argument('--seq', type=int, default=64)
@@ -36,7 +33,6 @@ def main():
     p.add_argument('--classes', type=int, default=2)
     p.add_argument('--lr', type=float, default=3e-4)
     args = p.parse_args()
-    apply_platform(args)
 
     cfg = ernie.ErnieConfig(vocab_size=1024, hidden_size=args.hidden,
                             num_layers=args.layers, num_heads=args.heads,

@@ -12,13 +12,11 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import numpy as np
 
 import jax
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
@@ -26,7 +24,6 @@ from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
 def main():
     p = argparse.ArgumentParser()
-    add_platform_arg(p)
     p.add_argument('--ckpt', default=None, help='state_dict path (.pdparams)')
     p.add_argument('--tokens', type=int, default=64)
     p.add_argument('--temperature', type=float, default=0.8)
@@ -45,7 +42,6 @@ def main():
                         'GenerationEngine and print tokens as each decode '
                         'iteration emits them')
     args = p.parse_args()
-    apply_platform(args)
     if args.hidden < 64 or args.hidden % 64:
         p.error('--hidden must be a positive multiple of 64 (head_dim=64)')
 

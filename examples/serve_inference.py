@@ -1,18 +1,15 @@
 """Export a model with jit.save and serve it with the inference Predictor.
 
-python examples/serve_inference.py [--platform cpu]
+python examples/serve_inference.py
 """
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import argparse
 import tempfile
 
 import numpy as np
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -21,10 +18,6 @@ from paddle_tpu.vision.models import mobilenet_v2
 
 
 def main():
-    p = argparse.ArgumentParser()
-    add_platform_arg(p)
-    apply_platform(p.parse_args())
-
     net = mobilenet_v2(num_classes=10, scale=0.25)
     net.eval()
     d = tempfile.mkdtemp()

@@ -1,6 +1,6 @@
 """PP-YOLOE detection training + serving export on synthetic boxes.
 
-python examples/train_detection.py --platform cpu --steps 5
+JAX_PLATFORMS=cpu python examples/train_detection.py --steps 5
 
 Trains the anchor-free PPYOLOE (TAL assignment + VFL/GIoU/DFL,
 vision/detection.py) on a synthetic box dataset, then exports the decode +
@@ -9,14 +9,12 @@ static-NMS serving graph through jit.save -> Predictor and ONNX.
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import argparse
 import tempfile
 
 import numpy as np
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 
 def synth_batch(rng, batch, size, num_classes, max_boxes=4):
@@ -41,14 +39,12 @@ def synth_batch(rng, batch, size, num_classes, max_boxes=4):
 
 def main():
     p = argparse.ArgumentParser()
-    add_platform_arg(p)
     p.add_argument('--steps', type=int, default=20)
     p.add_argument('--batch', type=int, default=2)
     p.add_argument('--size', type=int, default=64)
     p.add_argument('--classes', type=int, default=4)
     p.add_argument('--lr', type=float, default=2e-3)
     args = p.parse_args()
-    apply_platform(args)
 
     import paddle_tpu as paddle
     from paddle_tpu import inference

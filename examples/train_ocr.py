@@ -1,6 +1,6 @@
 """SVTR-lite text recognition with CTC on synthetic glyph strips.
 
-python examples/train_ocr.py --platform cpu --steps 10
+JAX_PLATFORMS=cpu python examples/train_ocr.py --steps 10
 
 Renders digit-like bar glyphs into 32xW strips and trains
 models.SVTRLite (local/global token mixing, CTC head) to read them.
@@ -8,13 +8,11 @@ models.SVTRLite (local/global token mixing, CTC head) to read them.
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import argparse
 
 import numpy as np
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 
 def synth_strip(rng, n_chars, n_classes, char_w=16):
@@ -32,14 +30,12 @@ def synth_strip(rng, n_chars, n_classes, char_w=16):
 
 def main():
     p = argparse.ArgumentParser()
-    add_platform_arg(p)
     p.add_argument('--steps', type=int, default=30)
     p.add_argument('--batch', type=int, default=4)
     p.add_argument('--chars', type=int, default=4)
     p.add_argument('--classes', type=int, default=12)
     p.add_argument('--lr', type=float, default=2e-3)
     args = p.parse_args()
-    apply_platform(args)
 
     import paddle_tpu as paddle
     from paddle_tpu.models import SVTRLite

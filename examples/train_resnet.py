@@ -5,13 +5,11 @@ python examples/train_resnet.py --arch resnet18 --epochs 2
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import argparse
 
 import jax
 
-from _common import add_platform_arg, apply_platform  # noqa: E402
 
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
@@ -21,13 +19,11 @@ from paddle_tpu.vision.datasets import Cifar10
 
 def main():
     p = argparse.ArgumentParser()
-    add_platform_arg(p)
     p.add_argument('--arch', default='resnet18')
     p.add_argument('--epochs', type=int, default=2)
     p.add_argument('--batch', type=int, default=64)
     p.add_argument('--lr', type=float, default=1e-3)
     args = p.parse_args()
-    apply_platform(args)
 
     tf = T.Compose([T.RandomHorizontalFlip(),
                     T.Normalize([125., 123., 114.], [63., 62., 67.],

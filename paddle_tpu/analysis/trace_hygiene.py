@@ -64,7 +64,8 @@ _TRACE_WRAPPERS = {
     'jax.lax.fori_loop', 'jax.lax.map', 'jax.lax.switch',
     'jax.lax.associative_scan', 'lax.scan', 'lax.cond', 'lax.while_loop',
     'lax.fori_loop', 'lax.map', 'lax.switch',
-    'shard_map', 'jax.experimental.shard_map.shard_map',
+    'shard_map', 'jax.shard_map',
+    'jit_train_step',      # parallel/train_jit.py: jit that donates its state
 }
 _JIT_NAMES = {'jax.jit', 'jit', 'pjit', 'jax.pjit'}
 

@@ -178,9 +178,7 @@ def recv(tensor, src=0, group=None, use_calc_stream=True):
 
 def barrier(group=None):
     inject('collective.entry')
-    for d in jax.devices():
-        pass
-    jax.effects_barrier() if hasattr(jax, 'effects_barrier') else None
+    jax.effects_barrier()
 
 
 def new_group(ranks=None, backend=None):

@@ -2,15 +2,20 @@
 (paddle.distributed.launch CLI spawning one proc per device + elastic).
 
 TPU-native: one process per HOST (JAX single-controller per host drives all
-local chips). The launcher execs the training script once per host via the
-same env-var contract as the reference (PADDLE_TRAINER_ID / TRAINERS_NUM /
-MASTER), plus an elastic watchdog with TWO failure detectors:
+local chips; a chip belongs to one process at a time). The launcher itself
+never initialises a jax backend, so it holds no chip. It execs the script once
+per host via the same env-var contract as the reference (PADDLE_TRAINER_ID
+/ TRAINERS_NUM / MASTER). ``--nproc_per_node N`` with N > 1 is the
+multi-process EMULATION layout and needs ``JAX_PLATFORMS=cpu``: on a host
+with TPU chips every child would open all of them and all but one would
+fail or hang, so the launcher refuses instead. Plus an elastic watchdog
+with TWO failure detectors:
  - exit watch: restart on nonzero child exit (up to --max_restarts);
  - liveness watch: the framework touches a heartbeat file every train step
    (hapi.Model train steps call ``touch_heartbeat``; custom loops may call
    it directly). If the file goes stale for longer than
-   --heartbeat_timeout the child is presumed hung (e.g. a dead device
-   tunnel blocking inside a collective — exit codes never fire for those),
+   --heartbeat_timeout the child is presumed hung (e.g. blocked inside a
+   collective whose peer died — exit codes never fire for those),
    SIGTERM'd, then SIGKILL'd, and restarted. Resume comes from the latest
    checkpoint the script wrote (orbax/hapi save).
 On a pod slice, run this on every host (GKE/xmanager provide the env).
@@ -168,6 +173,30 @@ def _run_group(cmd, envs, hb_paths, hb_timeout, stop_check=None):
     return 0, False, None
 
 
+def _local_tpu_chips():
+    """TPU chips this host exposes, counted from their device nodes (the
+    launcher must not ask jax: that would take the chips from the
+    children)."""
+    import glob
+    return len(glob.glob('/dev/accel[0-9]*')
+               + glob.glob('/dev/vfio/[0-9]*'))
+
+
+def _refuse_shared_chips(nproc):
+    """N > 1 local processes are fine on the CPU platform; on a TPU host
+    they would all open the same chips."""
+    if nproc <= 1 or os.environ.get('JAX_PLATFORMS', '').lower() == 'cpu':
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        sys.exit(
+            f'[launch] refusing --nproc_per_node {nproc}: this host has '
+            f'{chips} TPU chip(s) and a chip belongs to one process — '
+            f'every child would open all of them and hang. On a TPU host '
+            f'run ONE process (the default) that drives all local chips; '
+            f'for multi-process emulation set JAX_PLATFORMS=cpu.')
+
+
 def _build_envs(args, nproc, nnodes, node_rank):
     total = nnodes * nproc
     master = args.master
@@ -199,6 +228,7 @@ def main(argv=None):
         nproc = len([d for d in args.device_list.split(',') if d != ''])
     else:
         nproc = 1
+    _refuse_shared_chips(nproc)
     hb_paths = [None] * nproc
     if args.heartbeat_timeout > 0:
         base = args.log_dir or '/tmp'

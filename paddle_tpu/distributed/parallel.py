@@ -23,11 +23,7 @@ def init_parallel_env():
     rank = int(os.environ.get('PADDLE_TRAINER_ID', '0'))
     # probe the distributed-client state WITHOUT jax.process_count(): that
     # would initialize the XLA backend, after which initialize() is illegal
-    try:
-        from jax._src import distributed as _dist
-        already = _dist.global_state.client is not None
-    except Exception:   # pragma: no cover — private-API drift
-        already = False
+    already = jax.distributed.is_initialized()
     if coord and nprocs > 1 and not already:
         port = os.environ.get('MASTER_PORT', '8476')
         jax.distributed.initialize(f'{coord}:{port}', num_processes=nprocs,

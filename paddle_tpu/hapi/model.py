@@ -122,9 +122,8 @@ class Model:
         if strategy is not None:
             self._strategy = strategy
             self._partitioner = strategy.to_partition_rules()
-        if os.environ.get('PADDLE_TPU_COMPILE_CACHE'):
-            from .. import warmup as _warmup_mod
-            _warmup_mod.ensure_persistent_cache()
+        from .. import warmup as _warmup_mod
+        _warmup_mod.ensure_persistent_cache()
         if warmup is not None:
             self.prebuild_warmup(warmup)
 

@@ -94,9 +94,7 @@ def graph_send_recv(x, src_index, dst_index, pool_type='sum', out_size=None):
         gathered = jnp.take(v, jnp.asarray(si).astype(jnp.int32), axis=0)
         seg = jnp.asarray(di).astype(jnp.int32)
         if pool_type == 'sum':
-            return jax.ops.segment_sum(gathered, seg, num_segments=n) \
-                if hasattr(jax.ops, 'segment_sum') else \
-                jnp.zeros((n,) + v.shape[1:], v.dtype).at[seg].add(gathered)
+            return jax.ops.segment_sum(gathered, seg, num_segments=n)
         if pool_type == 'mean':
             s = jnp.zeros((n,) + v.shape[1:], v.dtype).at[seg].add(gathered)
             c = jnp.zeros((n,), v.dtype).at[seg].add(1.0)

@@ -3,7 +3,10 @@
 Python builds a producer callback (collate into a flat byte buffer); C++
 threads run it concurrently and keep an ordered ring of ready batches. For
 pure-C++ producers (pt_lm_window_producer) the whole pipeline runs without
-the GIL. Auto-builds the .so with make on first use.
+the GIL. The .so is never committed: the first loader of a process runs
+``make`` (a no-op when the library is newer than its source), so the
+library is always built from native/dataloader.cpp on the machine that
+runs it.
 """
 import ctypes
 import os
@@ -28,13 +31,16 @@ def get_lib():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            # build-once-under-lock is intentional: concurrent callers must
-            # block until the shared library exists, and no device work can
-            # be in flight before the first loader is constructed
-            # pt-lint: disable=lock-blocking-call
-            subprocess.run(['make', '-C', _NATIVE_DIR], check=True,
-                           capture_output=True)
+        # build-once-under-lock is intentional: concurrent callers must
+        # block until the shared library exists, and no device work can
+        # be in flight before the first loader is constructed
+        # pt-lint: disable=lock-blocking-call
+        build = subprocess.run(['make', '-C', _NATIVE_DIR],
+                               capture_output=True, text=True)
+        if build.returncode != 0:
+            raise RuntimeError(
+                f'building {_LIB_PATH} failed (needs make and a C++17 '
+                f'compiler):\n{build.stdout}{build.stderr}')
         lib = ctypes.CDLL(_LIB_PATH)
         lib.pt_pool_create.restype = ctypes.c_void_p
         lib.pt_pool_create.argtypes = [ctypes.c_int, ctypes.c_int,

@@ -27,13 +27,10 @@ class TracedLayer:
 
 
 def _trace_state_clean():
-    """True when no jax trace (jit/grad/vmap/export) is active. Private-API
-    fast path with a tracer-scan-free conservative fallback."""
-    try:
-        from jax._src.core import trace_state_clean
-        return trace_state_clean()
-    except Exception:   # pragma: no cover — jax internals moved
-        return True
+    """True when no jax trace (jit/grad/vmap/export) is active (private
+    API, checked against jax 0.9.0)."""
+    from jax._src.core import trace_state_clean
+    return trace_state_clean()
 
 
 def _hashable(v):

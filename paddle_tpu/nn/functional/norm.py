@@ -109,13 +109,9 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None,
                     # the same cancellation hazard out of it
                     var = jnp.maximum(ex2 - jnp.square(mean), 0.0)
                 except NameError:
-                    bound = {}
-                    try:
-                        from jax._src.core import get_axis_env
-                        bound = dict(get_axis_env().axis_sizes)
-                    except Exception:   # pragma: no cover — jax internals
-                        pass
-                    if bound:
+                    # private API, checked against jax 0.9.0
+                    from jax._src.core import get_axis_env
+                    if get_axis_env().axis_sizes:
                         # we ARE inside a mapped context but this axis name
                         # is not bound there — a typo'd mesh_axis must be
                         # loud, not silently-local statistics

@@ -50,7 +50,8 @@ ENV_PEAK_FLOPS_INT8 = 'PADDLE_TPU_PEAK_FLOPS_INT8'
 
 # (peak_flops/s, peak_HBM_bytes/s) by device-kind substring, checked in
 # order. FLOPs numbers match bench.py's PEAK_FLOPS; 'cpu' is nominal so
-# ratios stay comparable across runs, not a physical claim.
+# ratios stay comparable across runs, not a physical claim. A kind that
+# matches no row is an error, never a default.
 PEAKS = (
     ('v6e', (918e12, 1.64e12)),
     ('v5p', (459e12, 2.76e12)),
@@ -58,7 +59,6 @@ PEAKS = (
     ('v4', (275e12, 1.2e12)),
     ('cpu', (1e12, 100e9)),
 )
-_DEFAULT_PEAKS = (197e12, 0.82e12)      # unknown accelerator: v5e numbers
 
 # Per-precision peak FLOPs by device-kind substring: an fp8/int8 step
 # measured against the bf16 peak would report a flattering MFU on parts
@@ -103,32 +103,31 @@ def _device_kind():
     # obs-overhead budget, and the device set never changes in-process
     global _kind_cache
     if _kind_cache is None:
-        try:
-            import jax
-            _kind_cache = jax.devices()[0].device_kind.lower()
-        except Exception:
-            _kind_cache = 'unknown'
+        import jax
+        _kind_cache = jax.devices()[0].device_kind.lower()
     return _kind_cache
 
 
 def peaks(kind=None, precision=None):
     """-> ``(peak_flops_per_s, peak_bw_bytes_per_s, source)`` for a device
-    kind (default: device 0). Env overrides win over the table; source is
-    'env', 'table', or 'default'. ``precision`` ('fp8'/'float8',
+    kind (default: device 0); a kind the table does not know raises
+    ValueError. Env overrides win over the table; source is 'env' or
+    'table'. ``precision`` ('fp8'/'float8',
     'int8'/'int8_wo') swaps in that precision's peak FLOPs where the part
     has one (``PRECISION_PEAKS``; ``PADDLE_TPU_PEAK_FLOPS_FP8``/``_INT8``
     env overrides win) so MFU denominators stay honest per precision."""
     env_f = os.environ.get(ENV_PEAK_FLOPS)
     env_b = os.environ.get(ENV_PEAK_BW)
-    kind = (kind or _device_kind()).lower()
-    flops = bw = None
-    source = 'default'
-    for sub, (f, b) in PEAKS:
+    # a v5e chip reports its device_kind as 'TPU v5 lite'
+    kind = (kind or _device_kind()).lower().replace('v5 lite', 'v5e')
+    for sub, (flops, bw) in PEAKS:
         if sub in kind:
-            flops, bw, source = f, b, 'table'
             break
-    if flops is None:
-        flops, bw = _DEFAULT_PEAKS
+    else:
+        raise ValueError(
+            f'no peak FLOP/s and bytes/s known for device kind {kind!r}: '
+            f'add a row to observability.perf.PEAKS with its source')
+    source = 'table'
     if env_f:
         flops, source = float(env_f), 'env'
     if env_b:

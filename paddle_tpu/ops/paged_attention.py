@@ -37,14 +37,12 @@ import numpy as _np
 # set_interpret() is seen live.
 import importlib
 _fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import mesh_kernel
 from .paged_kv import gather_virtual
 from .weight_only import dequantize_kv, is_weight_only
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:   # pragma: no cover - gated by _fa._HAS_PALLAS
-    pl = pltpu = None
 
 _NEG_INF = _fa._NEG_INF
 _EPS = _fa._EPS
@@ -57,7 +55,7 @@ def paged_attention_available(q, pages):
     [N, page_size, H_kv, D] (pass the bank's ``['int8']`` plane for int8
     pools). Interpret mode (ops/flash_attention.set_interpret) counts as
     available so CPU tests exercise the kernel."""
-    if not _fa._HAS_PALLAS or not _fa._platform_ok():
+    if not _fa._platform_ok():
         return False
     b, t, h, d = (int(x) for x in q.shape)
     n, ps, h_kv = (int(x) for x in pages.shape[:3])
@@ -166,82 +164,73 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
                     / jnp.maximum(l_ref[:, :1], _EPS)).astype(o_ref.dtype)
 
 
-def _kernel_call(q, page_table, pos, kernel, args, in_specs):
+def _kernel_call(kernel_fn, q, page_table, pos, pools):
+    """One paged decode kernel over (under a mesh) the block each device
+    holds: slots split over 'dp', heads over 'mp' — the pool's own layout
+    (ops/paged_kv.POOL_LOGICAL_AXES), so no page moves between devices.
+    pools: planes in pool layout — pages [N, page_size, H_kv, D] and, for
+    int8 banks, their scales [N, page_size, H_kv]."""
     b, t, h, d = q.shape
+    ps, h_kv = (int(x) for x in pools[0].shape[1:3])
     p_max = int(page_table.shape[1])
-    bh = b * h
-    qt = q.transpose(0, 2, 1, 3).reshape(bh, t, d)
-    qt = _fa._pad_seq(qt, _TQ)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh, p_max),
-        in_specs=[pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0))]
-        + in_specs,
-        out_specs=pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((_TQ, d), jnp.float32),        # acc
-            pltpu.VMEM((_TQ, _LANES), jnp.float32),   # m (lane-broadcast)
-            pltpu.VMEM((_TQ, _LANES), jnp.float32),   # l
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, _TQ, d), q.dtype),
-        interpret=_fa._INTERPRET,
-    )(page_table.reshape(-1).astype(jnp.int32),
-      jnp.asarray(pos, jnp.int32).reshape(-1), qt, *args)
-    out = out[:, :t]
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    def core(q, page_table, pos, *pools):
+        b, _, h, _ = q.shape                  # this device's slots / heads
+        g = h // pools[0].shape[2]
+        bh = b * h
+        qt = _fa._pad_seq(q.transpose(0, 2, 1, 3).reshape(bh, t, d), _TQ)
+        # a page lands as one block of the [N, H_kv, ...] transpose; the
+        # page id comes straight out of the prefetched table
+        page = lambda i, p, pt, _pos: (pt[(i // h) * p_max + p],
+                                       (i % h) // g, 0, 0)
+        # -> pages [N, H_kv, ps, D], scales [N, H_kv, 1, ps]
+        planes = [x.transpose(0, 2, 1, 3) if x.ndim == 4
+                  else x.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
+                  for x in pools]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, p_max),
+            in_specs=[pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0))]
+            + [pl.BlockSpec((1, 1) + x.shape[2:], page) for x in planes],
+            out_specs=pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((_TQ, d), jnp.float32),        # acc
+                pltpu.VMEM((_TQ, _LANES), jnp.float32),   # m (lane-bcast)
+                pltpu.VMEM((_TQ, _LANES), jnp.float32),   # l
+            ],
+        )
+        out = pl.pallas_call(
+            functools.partial(kernel_fn, scale=1.0 / math.sqrt(d), ps=ps,
+                              tq=t, p_max=p_max, h=h),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, _TQ, d), q.dtype),
+            interpret=_fa._INTERPRET,
+        )(page_table.reshape(-1), pos, qt, *planes)
+        return out[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
+
+    return mesh_kernel.sharded_call(
+        core,
+        (q, page_table.astype(jnp.int32),
+         jnp.asarray(pos, jnp.int32).reshape(-1), *pools),
+        (_fa._BSHD, ('batch', None), ('batch',),
+         *((None, None, 'heads') + (None,) * (x.ndim - 3) for x in pools)),
+        _fa._BSHD, batch=b, heads=(h, h_kv))
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, pos):
     """Pallas paged decode. q: [B,T,H,D]; pages [N, page_size, H_kv, D];
     page_table [B, P_max] i32; pos [B] i32 -> [B,T,H,D]."""
-    b, t, h, d = q.shape
-    n, ps, h_kv, _ = (int(x) for x in k_pages.shape)
-    p_max = int(page_table.shape[1])
-    g = h // h_kv
-    # pages land as (1, 1, ps, d) blocks of the [N, H_kv, ps, D] transpose;
-    # the page id comes straight out of the prefetched table
-    page_spec = pl.BlockSpec(
-        (1, 1, ps, d),
-        lambda i, p, pt, _pos: (pt[(i // h) * p_max + p], (i % h) // g, 0, 0))
-    kt = k_pages.transpose(0, 2, 1, 3)
-    vt = v_pages.transpose(0, 2, 1, 3)
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=1.0 / math.sqrt(d), ps=ps, tq=t,
-        p_max=p_max, h=h)
-    return _kernel_call(q, page_table, pos, kernel, [kt, vt],
-                        [page_spec, page_spec])
+    return _kernel_call(_paged_decode_kernel, q, page_table, pos,
+                        [k_pages, v_pages])
 
 
 def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos):
     """``paged_flash_decode`` over int8 page pools: banks are
     ``{'int8': [N, page_size, H_kv, D] int8, 'scale': [N, page_size,
     H_kv] f32}`` (ops/paged_kv.paged_write rows)."""
-    b, t, h, d = q.shape
-    n, ps, h_kv, _ = (int(x) for x in k_bank['int8'].shape)
-    p_max = int(page_table.shape[1])
-    g = h // h_kv
-    page_spec = pl.BlockSpec(
-        (1, 1, ps, d),
-        lambda i, p, pt, _pos: (pt[(i // h) * p_max + p], (i % h) // g, 0, 0))
-    scale_spec = pl.BlockSpec(
-        (1, 1, 1, ps),
-        lambda i, p, pt, _pos: (pt[(i // h) * p_max + p], (i % h) // g, 0, 0))
-
-    def flat(bank):
-        pages = bank['int8'].transpose(0, 2, 1, 3)            # [N,Hkv,ps,D]
-        sc = bank['scale'].astype(jnp.float32).transpose(0, 2, 1)
-        return pages, sc.reshape(n, h_kv, 1, ps)
-    kt, ks = flat(k_bank)
-    vt, vs = flat(v_bank)
-    kernel = functools.partial(
-        _paged_decode_kernel_int8, scale=1.0 / math.sqrt(d), ps=ps, tq=t,
-        p_max=p_max, h=h)
-    return _kernel_call(q, page_table, pos, kernel, [kt, vt, ks, vs],
-                        [page_spec, page_spec, scale_spec, scale_spec])
+    return _kernel_call(
+        _paged_decode_kernel_int8, q, page_table, pos,
+        [k_bank['int8'], v_bank['int8'], k_bank['scale'], v_bank['scale']])
 
 
 def paged_attention_fallback(q, k_pages, v_pages, page_table, pos, cdt):

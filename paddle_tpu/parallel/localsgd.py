@@ -22,15 +22,6 @@ __all__ = ['replicate_for_localsgd', 'collapse_replicas',
            'make_localsgd_train_step']
 
 
-def _shard_map():
-    try:
-        from jax import shard_map
-        return shard_map
-    except ImportError:      # older jax
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 def replicate_for_localsgd(tree, mesh, axis='dp'):
     """Stack n_dp copies of each leaf along a new leading axis sharded over
     ``axis`` — one independent replica per dp group."""
@@ -58,7 +49,6 @@ def make_localsgd_train_step(loss_fn, opt, mesh, k_steps=4, axis='dp',
     ``post_update(params) -> params`` runs after every local optimizer
     update (e.g. ASP mask re-application) — traced into the step.
     """
-    shard_map = _shard_map()
     rep_spec = P(axis)        # leading replica dim on every leaf
     dat_spec = P(axis)        # batch sharded over dp
 
@@ -85,9 +75,9 @@ def make_localsgd_train_step(loss_fn, opt, mesh, k_steps=4, axis='dp',
         exp = jax.tree_util.tree_map(lambda x: x[None], (params, state))
         return loss, exp[0], exp[1]
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(rep_spec, rep_spec, dat_spec, P(), P()),
-                   out_specs=(P(), rep_spec, rep_spec))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(rep_spec, rep_spec, dat_spec, P(), P()),
+                       out_specs=(P(), rep_spec, rep_spec))
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params_rep, state_rep, batch, step_idx, lr):

@@ -282,9 +282,8 @@ def sequence_parallel_attention(q, k, v, mesh, causal=True):
     """shard_map wrapper: q/k/v are [B, S, H, D] global arrays; runs ring
     attention with S sharded over the mesh 'sp' axis."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     spec = P(('dp',), 'sp', None, None)
-    f = shard_map(partial(ring_attention, axis_name='sp', causal=causal),
-                  mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                  check_rep=False)
+    f = jax.shard_map(partial(ring_attention, axis_name='sp', causal=causal),
+                      mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                      check_vma=False)
     return f(q, k, v)

@@ -5,7 +5,7 @@ identity-forward/all-reduce-backward ("f") and all-reduce-forward/identity-
 backward ("g") ops are implemented as autograd Functions over NCCL. TPU-native:
 jax.custom_vjp over lax.psum on a mesh axis, which also pins the AD semantics
 explicitly instead of relying on shard_map's transpose rule for a bare psum
-(whose cotangent convention under check_rep=False double-counts sharded
+(whose cotangent convention under check_vma=False double-counts sharded
 branches when a residual stream bypasses the collective).
 
 Column-parallel matmul: x -> f_identity(x) @ W_col      (backward all-reduces dx)

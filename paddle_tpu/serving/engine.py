@@ -142,9 +142,8 @@ class InferenceEngine:
                  queue_capacity=256, precision=None, default_deadline_ms=None,
                  breaker=None, autostart=True, clock=None, warmup=None,
                  input_spec=None, telemetry_port=None, mesh=None, mp=None):
-        if os.environ.get('PADDLE_TPU_COMPILE_CACHE'):
-            from .. import warmup as _warmup_mod
-            _warmup_mod.ensure_persistent_cache()
+        from .. import warmup as _warmup_mod
+        _warmup_mod.ensure_persistent_cache()
         layer, params, buffers, precision, example_spec = \
             _resolve_backend(net, precision)
         if precision not in _PRECISIONS:
@@ -281,7 +280,9 @@ class InferenceEngine:
         if wm is not None and wm.capturing():
             wm.record(wm.serving_bucket_entry(
                 bucket, sig, precision, max_batch=self.max_batch_size))
-        return jax.jit(infer)
+        from ..ops import mesh_kernel
+        return mesh_kernel.jit(
+            infer, self._mesh_ctx.mesh if self._mesh_ctx else None)
 
     def warmup(self, manifest='all_buckets', input_spec=None):
         """AOT-precompile serving executables before traffic.

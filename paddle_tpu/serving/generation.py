@@ -274,9 +274,8 @@ class GenerationEngine:
                  forward_fn=None, clock=None, precision=None,
                  telemetry_port=None, prefix_cache=None,
                  prefix_cache_pages=None, mesh=None, mp=None):
-        if os.environ.get('PADDLE_TPU_COMPILE_CACHE'):
-            from .. import warmup as _warmup_mod
-            _warmup_mod.ensure_persistent_cache()
+        from .. import warmup as _warmup_mod
+        _warmup_mod.ensure_persistent_cache()
         if precision not in (None, 'float32', 'int8_wo'):
             raise ValueError(
                 f"GenerationEngine precision must be None/'float32'/"
@@ -521,8 +520,11 @@ class GenerationEngine:
             nxt = sample_rows(logits[:, 0], seeds, pos)
             return nxt, {'k': cache['k'], 'v': cache['v']}
 
-        return (jax.jit(prefill, donate_argnums=(1,)),
-                jax.jit(step, donate_argnums=(1,)))
+        # under a mesh the paged kernel shards over it (ops/mesh_kernel)
+        from ..ops import mesh_kernel
+        mesh = self._mesh_ctx.mesh if self._mesh_ctx else None
+        return (mesh_kernel.jit(prefill, mesh, donate_argnums=(1,)),
+                mesh_kernel.jit(step, mesh, donate_argnums=(1,)))
 
     def _fns_pair(self):
         if self._fns is None:

@@ -29,15 +29,16 @@ Recipe::
     engine = serving.InferenceEngine(net, max_batch_size=64,
                                      warmup='warmup.json')
 
-Env knob: ``PADDLE_TPU_COMPILE_CACHE=<dir>`` enables the persistent cache
-without code changes (picked up by the serving engine and hapi Model).
+The serving engines and hapi Model turn the persistent cache on by
+themselves (``ensure_persistent_cache``): at ``$JAX_COMPILATION_CACHE_DIR``
+when the environment sets it, else at ``<repo>/.jax_cache``.
 """
 from .manifest import (Manifest, array_sig, capture, capture_start,  # noqa: F401
                        capture_stop, capturing, eval_step_entry,
                        generation_entry, predictor_entry, record,
                        serving_bucket_entry, train_step_entry)
-from .persistent import (ENV_CACHE_DIR, cache_key_component,  # noqa: F401
-                         cache_stats, disable_persistent_cache,
+from .persistent import (DEFAULT_CACHE_DIR, cache_stats,  # noqa: F401
+                         disable_persistent_cache,
                          enable_persistent_cache, ensure_persistent_cache,
                          persistent_cache_dir)
 from .prebuild import all_buckets_manifest, prebuild  # noqa: F401
@@ -48,6 +49,6 @@ __all__ = [
     'eval_step_entry', 'predictor_entry', 'generation_entry',
     'enable_persistent_cache', 'disable_persistent_cache',
     'ensure_persistent_cache', 'persistent_cache_dir', 'cache_stats',
-    'cache_key_component', 'ENV_CACHE_DIR',
+    'DEFAULT_CACHE_DIR',
     'prebuild', 'all_buckets_manifest',
 ]

@@ -1,22 +1,30 @@
-"""Persistent XLA compile cache behind one switch.
+"""Persistent XLA compile cache, placed from outside.
 
-``enable_persistent_cache(dir)`` points JAX's on-disk compilation cache at
-``dir/<cache key>`` where the key folds in the framework version, the JAX
-version, and the backend — a cache written by one build/backend is never
-read by another. Activation is corruption tolerant: the directory probe
-runs under ``fault.retry`` with a ``warmup.cache`` inject point, and any
-persistent failure degrades to cold in-process compiles with a warning
-instead of taking the run down. Individual corrupt cache *entries* are
-handled by JAX itself (``jax_raise_persistent_cache_errors=False`` → the
-entry is recompiled, never raised).
+Where the cache lives is the environment's decision, not the program's:
+
+ - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself. This
+   module sets no directory and appends nothing to it; it only counts
+   hits and misses.
+ - not set: ``ensure_persistent_cache()`` puts the cache at one fixed path
+   inside the checkout (``<repo>/.jax_cache``, git-ignored). It never
+   moves — a temp name, pid or timestamp in the path would never hit —
+   and it needs no version component: JAX's own cache key already covers
+   the jax/jaxlib versions, the backend and the device kind.
+
+The serving engines and hapi Model call ``ensure_persistent_cache()`` on
+construction. ``enable_persistent_cache(dir)`` points the cache at an
+explicit directory (tests, tools). Activation is corruption tolerant: the
+directory probe runs under ``fault.retry`` with a ``warmup.cache`` inject
+point, and any persistent failure degrades to cold in-process compiles
+with a warning instead of taking the run down. Individual corrupt cache
+*entries* are handled by JAX itself
+(``jax_raise_persistent_cache_errors=False`` → the entry is recompiled,
+never raised).
 
 Cache traffic is observable: JAX's monitoring events are forwarded into
-the PR-4 registry as ``warmup.cache.hit_total`` / ``warmup.cache.miss_total``
+the registry as ``warmup.cache.hit_total`` / ``warmup.cache.miss_total``
 counters, and ``cache_stats()`` reports entry count / on-disk bytes (also
 exported as ``warmup.cache.bytes`` / ``warmup.cache.entries`` gauges).
-
-Zero-code activation: set ``PADDLE_TPU_COMPILE_CACHE=<dir>`` — the serving
-engine and hapi Model call ``ensure_persistent_cache()`` on construction.
 """
 import os
 import threading
@@ -27,7 +35,10 @@ import jax
 from .. import fault
 from .. import observability as _obs
 
-ENV_CACHE_DIR = 'PADDLE_TPU_COMPILE_CACHE'
+JAX_ENV_CACHE_DIR = 'JAX_COMPILATION_CACHE_DIR'
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.jax_cache')
 
 _HIT_EVENT = '/jax/compilation_cache/cache_hits'
 _MISS_EVENT = '/jax/compilation_cache/cache_misses'
@@ -35,16 +46,7 @@ _MISS_EVENT = '/jax/compilation_cache/cache_misses'
 _lock = threading.Lock()
 _cache_dir = None
 _listener_installed = False
-_env_attempted = False
-
-
-def cache_key_component(backend=None):
-    """Directory component that keys the cache: framework version + JAX
-    version + backend. Executables are not portable across any of these."""
-    from ..version import full_version
-    if backend is None:
-        backend = jax.default_backend()
-    return f'pt{full_version}-jax{jax.__version__}-{backend}'
+_ensured = False
 
 
 def _on_monitoring_event(name, **kwargs):
@@ -80,25 +82,20 @@ def _install_listener():
             pass
 
 
-def enable_persistent_cache(directory=None, *, backend=None,
-                            min_compile_time_secs=0.0):
-    """Enable the on-disk compile cache under ``directory`` (or
-    ``$PADDLE_TPU_COMPILE_CACHE``). Returns the resolved per-version cache
-    path, or None when the directory is unusable — the process then falls
-    back to cold compiles and keeps running."""
-    global _cache_dir
-    directory = directory or os.environ.get(ENV_CACHE_DIR)
-    if not directory:
-        raise ValueError('enable_persistent_cache needs a directory '
-                         f'(argument or ${ENV_CACHE_DIR})')
-    resolved = os.path.join(os.path.expanduser(str(directory)),
-                            cache_key_component(backend))
+def enable_persistent_cache(directory, *, min_compile_time_secs=0.0):
+    """Enable the on-disk compile cache at ``directory``. Returns the
+    directory, or None when it is unusable — the process then falls back
+    to cold compiles and keeps running."""
+    global _cache_dir, _ensured
+    _ensured = True          # an explicit placement is never re-placed
+    resolved = os.path.abspath(os.path.expanduser(str(directory)))
 
     def _activate():
         fault.inject('warmup.cache')
         os.makedirs(resolved, exist_ok=True)
         # Write probe: catch read-only mounts / quota exhaustion / a file
-        # squatting on the path now, not at the first compile.
+        # squatting on the path now, not at the first compile. Dot-named,
+        # so it cannot be mistaken for a cache entry.
         probe = os.path.join(resolved, f'.probe.{os.getpid()}')
         with open(probe, 'w') as f:
             f.write('ok')
@@ -131,9 +128,10 @@ def enable_persistent_cache(directory=None, *, backend=None,
 
 def disable_persistent_cache():
     """Detach the on-disk cache (compiles stay in-process only)."""
-    global _cache_dir
+    global _cache_dir, _ensured
     with _lock:
         _cache_dir = None
+        _ensured = True      # an explicit off is not undone by ensure
     try:
         jax.config.update('jax_compilation_cache_dir', None)
         _reset_jax_cache()
@@ -147,17 +145,22 @@ def persistent_cache_dir():
 
 
 def ensure_persistent_cache():
-    """Idempotent env-knob activation: enable from
-    ``$PADDLE_TPU_COMPILE_CACHE`` once per process. A failed attempt is
-    remembered so construction paths don't retry the probe forever."""
-    global _env_attempted
-    if _cache_dir is not None or _env_attempted:
+    """Idempotent activation, once per process. With
+    ``JAX_COMPILATION_CACHE_DIR`` set the directory is JAX's business and
+    only the hit/miss listener is installed; otherwise the cache goes to
+    ``DEFAULT_CACHE_DIR``. A failed attempt is remembered so construction
+    paths don't retry the probe forever. Returns the active directory."""
+    global _ensured, _cache_dir
+    if _ensured:
         return _cache_dir
-    _env_attempted = True
-    directory = os.environ.get(ENV_CACHE_DIR)
-    if not directory:
-        return None
-    return enable_persistent_cache(directory)
+    placed = os.environ.get(JAX_ENV_CACHE_DIR)
+    if placed:
+        _ensured = True
+        _install_listener()
+        with _lock:
+            _cache_dir = placed
+        return placed
+    return enable_persistent_cache(DEFAULT_CACHE_DIR)
 
 
 def cache_stats():

@@ -12,6 +12,14 @@ jax.config.update('jax_platforms', 'cpu')
 
 import pytest  # noqa: E402
 
+# The on-disk compile cache stays off in the test process unless a test
+# turns it on (tests/test_warmup.py): engines and hapi.Model enable it on
+# construction, and entries left in <repo>/.jax_cache by an earlier run
+# would turn this run's compiles into cache hits, which tests count.
+from paddle_tpu import warmup  # noqa: E402
+
+warmup.disable_persistent_cache()
+
 
 @pytest.fixture(autouse=True)
 def _seed():

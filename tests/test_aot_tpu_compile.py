@@ -1,0 +1,277 @@
+"""What only the chip's compiler can refuse, checked without the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED, not attached (a ``v5e:2x2`` topology): nothing runs, so this says
+nothing about results or speed, but it raises what Mosaic and the SPMD
+partitioner would raise on the machine — which interpret mode never did.
+Two refusals got past every interpret-mode test before PR 21: the in-kernel
+dropout (``Unsupported cast: uint32 -> float32``) and any kernel under a
+mesh (``Mosaic kernels cannot be automatically partitioned``). Each compile
+takes a second or two. Skipped where the topology cannot be described.
+
+All the compiles run in ONE child process (this file, run as a script),
+once per test session: describing a TPU loads libtpu, and a test process
+that has loaded it records profiler traces differently
+(tests/test_devtime.py). Each case is still its own test.
+
+Code that asks ``jax.devices()`` still sees the CPU, so the child steers the
+kernels' platform gate itself (``_platform_ok``) — the program has no
+option for it.
+
+Also here, because they are about the same machine: one process per chip
+(imports initialise no backend; the launcher refuses to share chips) and
+one compilation per train step.
+"""
+import importlib
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------------------
+# the child: every described-chip compile, one JSON object out
+# ---------------------------------------------------------------------------
+
+def _cases(devices):
+    """name -> thunk returning the compiled program's text."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    pa = importlib.import_module('paddle_tpu.ops.paged_attention')
+    mesh_kernel = importlib.import_module('paddle_tpu.ops.mesh_kernel')
+    fa._platform_ok = lambda: True       # the kernels' TPU branch
+    one = SingleDeviceSharding(devices[0])
+    bf16 = jnp.bfloat16
+
+    def S(shape, dtype, sharding=one):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def text(fn, *args, mesh=None):
+        def scoped(*a):
+            with mesh_kernel.kernel_mesh(mesh):
+                return fn(*a)
+        return jax.jit(scoped).lower(*args).compile().as_text()
+
+    def flash_fwd_bwd(dropout=0.0):
+        def f(q, k, v):
+            def loss(q, k, v):
+                out = fa.flash_attention(
+                    q, k, v, causal=True, dropout_rate=dropout,
+                    dropout_seed=jnp.uint32(7) if dropout else None)
+                return jnp.sum(out.astype(jnp.float32) ** 2)
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return f
+
+    def flash(seq, d, kv_heads, dropout):
+        q, kv = S((2, seq, 8, d), bf16), S((2, seq, kv_heads, d), bf16)
+        return lambda: text(flash_fwd_bwd(dropout), q, kv, kv)
+
+    def pool(d, int8, sharding=one):
+        pages = (65, 128, 16, d)              # the 337M engine's pool
+        if not int8:
+            return S(pages, bf16, sharding)
+        return {'int8': S(pages, jnp.int8, sharding),
+                'scale': S(pages[:3], jnp.float32, sharding)}
+
+    def paged(d, int8):
+        q = S((8, 1, 16, d), bf16)
+        assert pa.paged_attention_available(q, pool(d, False))
+        kernel = pa.paged_flash_decode_int8 if int8 else pa.paged_flash_decode
+        return lambda: text(kernel, q, pool(d, int8), pool(d, int8),
+                            S((8, 8), jnp.int32), S((8,), jnp.int32))
+
+    def decode(int8):
+        q, cache = S((8, 1, 16, 64), bf16), (8, 1024, 16, 64)
+        bank = ({'int8': S(cache, jnp.int8), 'scale': S(cache[:3],
+                                                        jnp.float32)}
+                if int8 else S(cache, bf16))
+        kernel = fa.flash_decode_int8 if int8 else fa.flash_decode
+        return lambda: text(kernel, q, bank, bank, S((), jnp.int32))
+
+    dpmp = Mesh(np.array(devices).reshape(2, 2), ('dp', 'mp'))
+    qm = S((4, 1024, 8, 64), bf16,
+           NamedSharding(dpmp, P('dp', None, 'mp', None)))
+    mp4 = Mesh(np.array(devices), ('mp',))
+    heads = NamedSharding(mp4, P(None, None, 'mp', None))
+    rep = NamedSharding(mp4, P())
+
+    return {
+        'flash_s1024_d64': flash(1024, 64, 8, 0.0),
+        'flash_s2048_d128': flash(2048, 128, 8, 0.0),
+        'flash_gqa': flash(1024, 64, 2, 0.0),
+        'flash_dropout': flash(1024, 64, 8, 0.1),
+        'paged_bf16_d64': paged(64, False),
+        'paged_int8_d64': paged(64, True),
+        'paged_bf16_d128': paged(128, False),
+        'paged_int8_d128': paged(128, True),
+        'decode_bf16': decode(False),
+        'decode_int8': decode(True),
+        'flash_dropout_dp2_mp2': lambda: text(flash_fwd_bwd(0.1), qm, qm, qm,
+                                              mesh=dpmp),
+        'flash_dp2_mp2_no_mesh_named': lambda: text(flash_fwd_bwd(), qm, qm,
+                                                    qm),
+        'paged_mp4': lambda: text(
+            pa.paged_flash_decode, S((8, 1, 16, 64), bf16, heads),
+            pool(64, False, heads), pool(64, False, heads),
+            S((8, 8), jnp.int32, rep), S((8,), jnp.int32, rep), mesh=mp4),
+    }
+
+
+def _child():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    # off: an entry written for a described chip cannot be read back
+    jax.config.update('jax_enable_compilation_cache', False)
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:   # noqa: BLE001 — no TPU compiler installed
+        return {'skip': f'cannot describe a v5e:2x2 topology here: {e!r}'}
+    out = {}
+    for name, compile_text in _cases(list(topo.devices)).items():
+        try:
+            text = compile_text()
+            out[name] = {
+                'kernels': text.count('tpu_custom_call'),
+                'collectives': [c for c in (
+                    'all-reduce', 'all-gather', 'all-to-all',
+                    'collective-permute') if c in text]}
+        except Exception as e:   # noqa: BLE001 — the refusal IS the result
+            out[name] = {'error': f'{type(e).__name__}: {e}'[:400]}
+    return out
+
+
+if __name__ == '__main__':
+    sys.path.insert(0, REPO)
+    print(json.dumps(_child()))
+    sys.exit(0)
+
+
+# ---------------------------------------------------------------------------
+# the tests: one per compiled program
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope='module')
+def compiled():
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__)], cwd=REPO,
+        env={**os.environ, 'JAX_PLATFORMS': 'cpu'},
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    if 'skip' in result:
+        pytest.skip(result['skip'])
+    return result
+
+
+@pytest.mark.parametrize('case,kernels', [
+    ('flash_s1024_d64', 3),            # fwd, dq, dk/dv
+    ('flash_s2048_d128', 3),
+    ('flash_gqa', 3),
+    ('flash_dropout', 3),              # refused before PR 21: u32->f32 cast
+    ('paged_bf16_d64', 1), ('paged_int8_d64', 1),
+    ('paged_bf16_d128', 1), ('paged_int8_d128', 1),
+    ('decode_bf16', 1), ('decode_int8', 1),
+])
+def test_kernel_compiles_for_v5e(compiled, case, kernels):
+    assert compiled[case] == {'kernels': kernels, 'collectives': []}
+
+
+def test_flash_under_dp2_mp2_mesh_compiles_for_v5e(compiled):
+    """Refused before PR 21. The kernels shard themselves over the mesh the
+    program names (ops/mesh_kernel.py); with no mesh named, the partitioner's
+    refusal still surfaces — it is never swallowed into a jnp fallback."""
+    assert compiled['flash_dropout_dp2_mp2'].get('kernels') == 3, compiled
+    refused = compiled['flash_dp2_mp2_no_mesh_named'].get('error', '')
+    assert refused.startswith('NotImplementedError') \
+        and 'shard_map' in refused, compiled
+
+
+def test_paged_decode_under_mp4_mesh_compiles_for_v5e(compiled):
+    """The mesh-sharded engine's decode kernel: pool and heads split over
+    'mp', page table and positions whole on every chip. Heads stay where
+    the pool put them: no page crosses chips."""
+    assert compiled['paged_mp4'] == {'kernels': 1, 'collectives': []}
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_imports_initialise_no_backend():
+    """A parent that imports the package (a launcher, bench.py's parent)
+    must not take the chip from the child that needs it."""
+    code = ('import paddle_tpu, paddle_tpu.serving, paddle_tpu.models, '
+            'paddle_tpu.distributed.launch\n'
+            'from jax._src import xla_bridge\n'
+            'assert not xla_bridge.backends_are_initialized()\n')
+    proc = subprocess.run([sys.executable, '-c', code], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_launch_refuses_to_share_tpu_chips(monkeypatch):
+    # the package binds the name ``launch`` to a function: get the module
+    launch = importlib.import_module('paddle_tpu.distributed.launch')
+    monkeypatch.setattr(launch, '_local_tpu_chips', lambda: 4)
+    monkeypatch.setenv('JAX_PLATFORMS', '')
+    launch._refuse_shared_chips(1)                 # one process: the layout
+    with pytest.raises(SystemExit, match='one process'):
+        launch._refuse_shared_chips(2)
+    monkeypatch.setenv('JAX_PLATFORMS', 'cpu')     # CPU emulation is fine
+    launch._refuse_shared_chips(2)
+    monkeypatch.setenv('JAX_PLATFORMS', '')
+    monkeypatch.setattr(launch, '_local_tpu_chips', lambda: 0)
+    launch._refuse_shared_chips(2)                 # no chips to fight over
+
+
+# ---------------------------------------------------------------------------
+# one compilation per train step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize('placed', [True, False], ids=['placed', 'unplaced'])
+def test_train_step_traces_once(placed):
+    """The state a step returns goes back in as it came out: same layout,
+    same types, no second trace (parallel/train_jit.py)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.topology import HybridTopology
+    from paddle_tpu.models import gpt
+    cfg = gpt.GPTConfig(vocab_size=128, hidden_size=32, num_layers=2,
+                        num_heads=2, max_seq_len=16, use_flash=False)
+    mesh = HybridTopology(devices=jax.devices()[:1]).mesh
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    if placed:
+        params = gpt.place_params(params, cfg, mesh)
+    opt_state = opt.functional_init(params)
+    traces = []
+    real_loss = gpt.loss_fn
+
+    def counting_loss(*a, **k):
+        traces.append(1)
+        return real_loss(*a, **k)
+    step = gpt.make_train_step(cfg, opt, mesh)
+    toks = jnp.zeros((2, 16), jnp.int32)
+    gpt.loss_fn = counting_loss
+    try:
+        shardings = None
+        for i in range(3):
+            loss, params, opt_state = step(
+                params, opt_state, jax.random.PRNGKey(i),
+                jnp.asarray(1e-3, jnp.float32), toks, toks)
+            now = [x.sharding for x in
+                   jax.tree_util.tree_leaves((params, opt_state))]
+            assert shardings is None or now == shardings
+            shardings = now
+    finally:
+        gpt.loss_fn = real_loss
+    assert np.isfinite(float(loss))
+    assert len(traces) == 1, f'train step traced {len(traces)} times'

@@ -215,6 +215,13 @@ def test_device_api():
     assert ':' in d
     p = paddle.CPUPlace()
     assert p.jax_device() is not None
+    assert paddle.set_device('cpu').kind == 'cpu'
+    # a place the process does not have raises; it never becomes a CPU
+    with pytest.raises(RuntimeError, match='no tpu device'):
+        paddle.TPUPlace().jax_device()
+    with pytest.raises(RuntimeError, match='no tpu device'):
+        paddle.set_device('tpu')
+    assert paddle.get_device().startswith('cpu')
 
 
 def test_beam_decode():

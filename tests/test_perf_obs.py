@@ -59,10 +59,12 @@ def test_peaks_table_and_env_override(monkeypatch):
     monkeypatch.delenv(perf.ENV_PEAK_BW, raising=False)
     f, b, src = perf.peaks('TPU v5p')
     assert (f, b, src) == (459e12, 2.76e12, 'table')
-    f, b, src = perf.peaks('TPU v5 lite')       # v5e matched by substring?
-    assert src in ('table', 'default')
-    f, b, src = perf.peaks('sparkletron-9000')
-    assert (f, b, src) == (*perf._DEFAULT_PEAKS, 'default')
+    # what a v5e chip reports as its device_kind lands on the v5e row
+    assert perf.peaks('TPU v5 lite') == perf.peaks('v5e') \
+        == (197e12, 0.82e12, 'table')
+    assert perf.peaks('TPU v5 lite', precision='int8')[0] == 394e12
+    with pytest.raises(ValueError, match='sparkletron-9000'):
+        perf.peaks('sparkletron-9000')     # unknown kind: error, no default
     # env overrides win and are read per call (no import-time freeze)
     monkeypatch.setenv(perf.ENV_PEAK_FLOPS, '2e12')
     monkeypatch.setenv(perf.ENV_PEAK_BW, '1e11')

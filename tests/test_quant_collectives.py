@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -21,7 +21,7 @@ def _psum_rows(x, mesh, **kw):
     returns one (replicated) reduced row."""
     f = shard_map(lambda v: qc.quantized_psum(v, 'dp', **kw), mesh=mesh,
                   in_specs=P('dp', None), out_specs=P('dp', None),
-                  check_rep=False)
+                  check_vma=False)
     out = np.asarray(jax.jit(f)(x))
     np.testing.assert_array_equal(out[0], out[-1])   # ranks agree
     return out[0]
@@ -107,7 +107,7 @@ def test_psum_tree_small_leaves_stay_exact(cpu_mesh):
     sm = shard_map(f, mesh=topo.mesh,
                    in_specs=({'w': P('dp', None), 'b': P('dp', None)},),
                    out_specs={'w': P('dp', None), 'b': P('dp', None)},
-                   check_rep=False)
+                   check_vma=False)
     out = jax.jit(sm)({'w': jnp.asarray(big), 'b': jnp.asarray(small)})
     # small leaf (< min_size) rides the exact full-width reduction
     np.testing.assert_allclose(np.asarray(out['b'])[0], small.mean(0),

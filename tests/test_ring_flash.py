@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import sys
@@ -60,7 +60,7 @@ def test_ring_flash_forward_exact(causal, sp):
     f = shard_map(partial(ra.ring_flash_attention, axis_name='sp',
                           causal=causal),
                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     out = f(q, k, v)
     ref = _naive(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
@@ -79,7 +79,7 @@ def test_ring_flash_grads_exact():
         f = shard_map(partial(ra.ring_flash_attention, axis_name='sp',
                               causal=True),
                       mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                      check_rep=False)
+                      check_vma=False)
         out = f(q, k, v)
         return jnp.sum(jnp.sin(out.astype(jnp.float32)))
 
@@ -107,7 +107,7 @@ def test_ring_flash_matches_jnp_ring():
     def run(fn):
         f = shard_map(partial(fn, axis_name='sp', causal=True),
                       mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                      check_rep=False)
+                      check_vma=False)
         return np.asarray(f(q, k, v), np.float32)
 
     np.testing.assert_allclose(run(ra.ring_flash_attention),
@@ -156,7 +156,7 @@ def test_ring_flash_gqa_parity():
     f = shard_map(partial(ra.ring_flash_attention, axis_name='sp',
                           causal=True),
                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     got = f(q, k, v)
     kr = jnp.repeat(k, H // HKV, axis=2)
     vr = jnp.repeat(v, H // HKV, axis=2)
@@ -234,7 +234,7 @@ def test_ring_flash_dropout_forward_exact(causal):
                           causal=causal, drop_rate=0.3,
                           seed=jnp.uint32(99)),
                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                  check_rep=False)
+                  check_vma=False)
     got = f(q, k, v)
     want = _ring_drop_reference(q, k, v, causal, 0.3, 99, sp)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -257,7 +257,7 @@ def test_ring_flash_dropout_grad_exact():
                               causal=True, drop_rate=0.25,
                               seed=jnp.uint32(7)),
                       mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_rep=False)
+                      out_specs=spec, check_vma=False)
         return f(q, k, v).astype(jnp.float32).sum()
 
     def ref_loss(q, k, v):
@@ -286,7 +286,7 @@ def test_ring_flash_dropout_gqa_and_zero_rate():
         f = shard_map(partial(ra.ring_flash_attention, axis_name='sp',
                               causal=True, **kw),
                       mesh=mesh, in_specs=(qs, qs, qs), out_specs=qs,
-                      check_rep=False)
+                      check_vma=False)
         return np.asarray(f(q, k, v))
 
     base = run()
@@ -357,7 +357,7 @@ def test_ring_flash_dropout_gqa_grad_exact():
                               causal=True, drop_rate=0.2,
                               seed=jnp.uint32(21)),
                       mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_rep=False)
+                      out_specs=spec, check_vma=False)
         return f(q, k, v).astype(jnp.float32).sum()
 
     def ref_loss(q, k, v):
@@ -411,6 +411,6 @@ def test_ring_dropout_without_seed_is_rejected():
         partial(ra.ring_flash_attention, axis_name='sp', causal=True,
                 drop_rate=0.5),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     with pytest.raises(ValueError, match='requires seed'):
         fn(q, q, q)

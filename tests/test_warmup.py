@@ -414,20 +414,44 @@ def test_predictor_capture_prebuild_no_retrace(tmp_path):
 # persistent cache
 # ---------------------------------------------------------------------------
 
-def test_persistent_cache_key_component():
-    key = warmup.cache_key_component(backend='cpu')
-    from paddle_tpu.version import full_version
+@pytest.mark.parametrize('placed', [True, False])
+def test_ensure_persistent_cache_placement(tmp_path, monkeypatch, placed):
+    """Placed from outside: with JAX_COMPILATION_CACHE_DIR set the code
+    sets NO directory (JAX reads the variable itself) and only counts
+    hits/misses; unset, the cache goes to the one fixed in-checkout path."""
     import jax
-    assert full_version in key and jax.__version__ in key \
-        and key.endswith('cpu')
+    from paddle_tpu.warmup import persistent
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, 'update',
+        lambda k, v: (updates.append((k, v)), real_update(k, v))[1])
+    monkeypatch.setattr(persistent, '_ensured', False)
+    monkeypatch.setattr(persistent, '_cache_dir', None)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed:
+        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
+        assert warmup.ensure_persistent_cache() == str(tmp_path)
+        assert updates == []                   # no config touched at all
+        assert list(tmp_path.iterdir()) == []  # nothing appended/created
+    else:
+        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+        assert warmup.DEFAULT_CACHE_DIR == os.path.join(repo, '.jax_cache')
+        assert warmup.ensure_persistent_cache() == warmup.DEFAULT_CACHE_DIR
+        assert ('jax_compilation_cache_dir',
+                warmup.DEFAULT_CACHE_DIR) in updates
+    assert persistent._listener_installed
+    # idempotent: construction paths call it every time
+    n = len(updates)
+    assert warmup.ensure_persistent_cache() == warmup.persistent_cache_dir()
+    assert len(updates) == n
 
 
 def test_persistent_cache_enable_write_and_stats(tmp_path):
     root = str(tmp_path / 'cache')
     resolved = warmup.enable_persistent_cache(root)
-    assert resolved is not None
+    assert resolved == root                    # no sub-directory appended
     assert warmup.persistent_cache_dir() == resolved
-    assert os.path.basename(resolved) == warmup.cache_key_component()
     import jax
     jax.jit(lambda a: a * 2 + 1).lower(
         jax.ShapeDtypeStruct((4, 4), np.float32)).compile()
@@ -440,10 +464,9 @@ def test_persistent_cache_enable_write_and_stats(tmp_path):
 
 def test_persistent_cache_corrupted_dir_falls_back(tmp_path):
     root = str(tmp_path / 'bad')
-    os.makedirs(root)
-    # a FILE squatting on the resolved cache path: makedirs must fail, the
-    # engine must degrade to cold compiles instead of crashing
-    with open(os.path.join(root, warmup.cache_key_component()), 'w') as f:
+    # a FILE squatting on the cache path: makedirs must fail, the engine
+    # must degrade to cold compiles instead of crashing
+    with open(root, 'w') as f:
         f.write('not a directory')
     before = obs.counter('warmup.cache.fallback_total').value
     with warnings.catch_warnings(record=True) as caught:
