@@ -250,6 +250,9 @@ def engine_report(engine):
     st = engine.stats()
     return {'traces': st['traces'], 'evictions': st['evictions'],
             'circuit_state': st['circuit_state'],
+            'decode_steps': st['steps'],
+            'decode_step_ms_p50': st['decode_step_ms_p50'],
+            'prefill_ms_p50': st['prefill_ms_p50'],
             'decode_tpu_custom_calls': kernel_calls(engine._aot['gen_decode']),
             'prefill_tpu_custom_calls':
                 kernel_calls(engine._aot['gen_prefill']),

@@ -16,6 +16,8 @@ import threading
 
 import numpy as np
 
+from .. import observability as _obs
+
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), '..', '..', 'native')
 _LIB_PATH = os.path.join(_NATIVE_DIR, 'libpaddle_tpu_native.so')
 _lib = None
@@ -204,9 +206,12 @@ class LMTokenLoader:
             self._next_submit += 1
 
     def next_batch(self):
-        self._lib.pt_pool_submit(self._pool, self._next_submit)
-        self._next_submit += 1
-        n = self._lib.pt_pool_next(self._pool, self._buf)
+        # the wait for the native pool, on the profiler's clock, timed
+        # where it happens
+        with _obs.span('data.next_batch', n_bytes=self._nbytes):
+            self._lib.pt_pool_submit(self._pool, self._next_submit)
+            self._next_submit += 1
+            n = self._lib.pt_pool_next(self._pool, self._buf)
         assert n == self._nbytes
         arr = np.frombuffer(bytes(self._buf[:n]), np.int32).reshape(
             self.batch_size, self.seq_len)

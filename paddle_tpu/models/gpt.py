@@ -330,24 +330,30 @@ def block_fn(bp, x, config, explicit_mp=False, drop_seed=None,
         from ..parallel.tp_ad import f_identity, g_allreduce
 
     fm = fp8_meta or {}
-    y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
-    if mp > 1:
-        y = f_identity(y, 'mp')
-    q, k, v = _block_qkv(bp, y, nh, hd, cdt, kvh, fp8_meta=fm.get('qkv'))
-    a = _attention(q, k, v, config,
-                   drop_seed=drop_seed).reshape(B, S, h // mp)
-    a = _mm(a, bp['proj_w'], cdt, fm.get('proj'))
-    if mp > 1:
-        a = g_allreduce(a, 'mp')
-    x = x + a + bp['proj_b'].astype(cdt)
-
-    y = _layer_norm(x, bp['ln2_g'], bp['ln2_b']).astype(cdt)
-    if mp > 1:
-        y = f_identity(y, 'mp')
-    y = _block_mlp(bp, y, cdt, fp8_fc=fm.get('fc'), fp8_out=fm.get('out'))
-    if mp > 1:
-        y = g_allreduce(y, 'mp')
-    x = x + y + bp['out_b'].astype(cdt)
+    # the profiler reads these scopes (PERF.md section 3); jax adds the
+    # phase itself: jvp, transpose(jvp), checkpoint/rematted_computation
+    with jax.named_scope('gpt.block'):
+        with jax.named_scope('attn'):
+            y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
+            if mp > 1:
+                y = f_identity(y, 'mp')
+            q, k, v = _block_qkv(bp, y, nh, hd, cdt, kvh,
+                                 fp8_meta=fm.get('qkv'))
+            a = _attention(q, k, v, config,
+                           drop_seed=drop_seed).reshape(B, S, h // mp)
+            a = _mm(a, bp['proj_w'], cdt, fm.get('proj'))
+            if mp > 1:
+                a = g_allreduce(a, 'mp')
+            x = x + a + bp['proj_b'].astype(cdt)
+        with jax.named_scope('mlp'):
+            y = _layer_norm(x, bp['ln2_g'], bp['ln2_b']).astype(cdt)
+            if mp > 1:
+                y = f_identity(y, 'mp')
+            y = _block_mlp(bp, y, cdt, fp8_fc=fm.get('fc'),
+                           fp8_out=fm.get('out'))
+            if mp > 1:
+                y = g_allreduce(y, 'mp')
+            x = x + y + bp['out_b'].astype(cdt)
     return x
 
 
@@ -362,9 +368,10 @@ def forward_hidden(params, tokens, config: GPTConfig, dropout_seed=None,
     grads w.r.t. it are the UPDATED state (quantization/fp8.py)."""
     cdt = jnp.dtype(config.dtype)
     B, S = tokens.shape
-    pos = jnp.arange(S)
-    x = wo_take(params['wte'], tokens) + params['wpe'][pos]
-    x = x.astype(cdt)
+    with jax.named_scope('gpt.embed'):
+        pos = jnp.arange(S)
+        x = wo_take(params['wte'], tokens) + params['wpe'][pos]
+        x = x.astype(cdt)
 
     body = partial(block_fn, config=config)
     if config.remat:
@@ -400,15 +407,20 @@ def forward_hidden(params, tokens, config: GPTConfig, dropout_seed=None,
         def scan_body(carry, bp):
             return body(bp, carry), None
 
-    x, _ = jax.lax.scan(scan_body, x, xs,
-                        unroll=max(1, int(config.scan_unroll)))
-    return _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
+    # gpt.layers: the scan's own work (slicing the stacked parameters,
+    # stacking saved activations and weight gradients) beside gpt.block's
+    with jax.named_scope('gpt.layers'):
+        x, _ = jax.lax.scan(scan_body, x, xs,
+                            unroll=max(1, int(config.scan_unroll)))
+    with jax.named_scope('gpt.head'):
+        return _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
 
 
 def forward(params, tokens, config: GPTConfig, dropout_seed=None):
     """tokens: [B, S] int32 -> logits [B, S, V]. lax.scan over stacked blocks."""
     x = forward_hidden(params, tokens, config, dropout_seed=dropout_seed)
-    return wo_lm_head(x, params['wte'], x.dtype)
+    with jax.named_scope('gpt.head'):
+        return wo_lm_head(x, params['wte'], x.dtype)
 
 
 def loss_fn(params, tokens, targets, config: GPTConfig, dropout_key=None,
@@ -427,12 +439,18 @@ def loss_fn(params, tokens, targets, config: GPTConfig, dropout_key=None,
         x = forward_hidden(params, tokens, config, dropout_seed=seed,
                            fp8_state=fp8_state)
         B, S, H = x.shape
-        return softmax_xent_blockwise(x.reshape(B * S, H), params['wte'],
-                                      targets.reshape(B * S),
-                                      config.xent_chunk)
+        with jax.named_scope('gpt.head'):
+            return softmax_xent_blockwise(
+                x.reshape(B * S, H), params['wte'], targets.reshape(B * S),
+                config.xent_chunk)
     x = forward_hidden(params, tokens, config, dropout_seed=seed,
                        fp8_state=fp8_state)
-    logits = wo_lm_head(x, params['wte'], x.dtype)
+    with jax.named_scope('gpt.head'):
+        return _xent(wo_lm_head(x, params['wte'], x.dtype), targets)
+
+
+def _xent(logits, targets):
+    """Mean token cross-entropy of whole [..., V] logits, in float32."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return -jnp.mean(ll)
@@ -612,14 +630,18 @@ def _cached_block(bp, x, k_cache, v_cache, pos, config, page_table=None,
                   valid=None, tail=False):
     """One block over a [B, T, H] slice starting at ``pos``."""
     cdt = jnp.dtype(config.dtype)
-    y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
-    q, k, v = _block_qkv(bp, y, config.num_heads, config.head_dim, cdt,
-                         config.kv_heads)
-    x, k_cache, v_cache = cached_attention(
-        x, q, k, v, k_cache, v_cache, pos, bp['proj_w'], bp['proj_b'], cdt,
-        page_table=page_table, valid=valid, tail=tail)
-    y = _layer_norm(x, bp['ln2_g'], bp['ln2_b']).astype(cdt)
-    x = x + _block_mlp(bp, y, cdt) + bp['out_b'].astype(cdt)
+    with jax.named_scope('gpt.block'):      # as block_fn names its halves
+        with jax.named_scope('attn'):
+            y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
+            q, k, v = _block_qkv(bp, y, config.num_heads, config.head_dim,
+                                 cdt, config.kv_heads)
+            x, k_cache, v_cache = cached_attention(
+                x, q, k, v, k_cache, v_cache, pos, bp['proj_w'],
+                bp['proj_b'], cdt, page_table=page_table, valid=valid,
+                tail=tail)
+        with jax.named_scope('mlp'):
+            y = _layer_norm(x, bp['ln2_g'], bp['ln2_b']).astype(cdt)
+            x = x + _block_mlp(bp, y, cdt) + bp['out_b'].astype(cdt)
     return x, k_cache, v_cache
 
 
@@ -830,6 +852,12 @@ def _uses_shard_map(config: GPTConfig):
             or getattr(config, 'grad_quant', 'none') not in (None, 'none'))
 
 
+def _apply(optimizer, params, grads, opt_state, lr):
+    """The optimizer's update, under the scope the profiler reads."""
+    with jax.named_scope('gpt.optimizer'):
+        return optimizer.functional_apply(params, grads, opt_state, lr)
+
+
 def make_train_step(config: GPTConfig, optimizer, mesh=None):
     """Returns jitted step(params, opt_state, key, lr, tokens, targets) ->
     (loss, params, opt_state) sharded over the mesh. Shardings:
@@ -874,8 +902,7 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
                                       key if config.dropout > 0.0 else None,
                                       fp8_state=f8),
                 argnums=(0, 1))(params, fp8_state)
-            new_p, new_s = optimizer.functional_apply(params, grads,
-                                                      opt_state, lr)
+            new_p, new_s = _apply(optimizer, params, grads, opt_state, lr)
             return loss, new_p, new_s, new_fp8
         return jit_train_step(step, mesh, n_state=3)
 
@@ -886,7 +913,7 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
             loss, grads = jax.value_and_grad(loss_fn)(
                 params, tokens, targets, config,
                 key if config.dropout > 0.0 else None)
-            new_p, new_s = optimizer.functional_apply(params, grads, opt_state, lr)
+            new_p, new_s = _apply(optimizer, params, grads, opt_state, lr)
             return loss, new_p, new_s
         # GSPMD path: the flash kernels shard over this mesh themselves
         return jit_train_step(step, mesh, n_state=2)
@@ -907,10 +934,11 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
     def spmd_loss(params, tokens, targets, seed=None):
         cdt = jnp.dtype(config.dtype)
         B, S = tokens.shape
-        sp_idx = jax.lax.axis_index('sp') if config.sp > 1 else 0
-        pos = sp_idx * S + jnp.arange(S)
-        x = jnp.take(params['wte'], tokens, axis=0) + params['wpe'][pos]
-        x = x.astype(cdt)
+        with jax.named_scope('gpt.embed'):
+            sp_idx = jax.lax.axis_index('sp') if config.sp > 1 else 0
+            pos = sp_idx * S + jnp.arange(S)
+            x = jnp.take(params['wte'], tokens, axis=0) + params['wpe'][pos]
+            x = x.astype(cdt)
 
         body = partial(block_fn, config=config, explicit_mp=explicit_mp)
         if config.remat:
@@ -945,20 +973,19 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
             def scan_body(c, bp):
                 return body(bp, c), None
 
-        if config.pp > 1:
-            def stage_fn(stage_params, xx):
-                out, _ = jax.lax.scan(scan_body, xx, stage_params)
-                return out
-            x = pipeline_apply(stage_fn, params['blocks'], x,
-                               config.n_microbatches, axis_name='pp')
-        else:
-            x, _ = jax.lax.scan(scan_body, x, xs)
+        with jax.named_scope('gpt.layers'):
+            if config.pp > 1:
+                def stage_fn(stage_params, xx):
+                    out, _ = jax.lax.scan(scan_body, xx, stage_params)
+                    return out
+                x = pipeline_apply(stage_fn, params['blocks'], x,
+                                   config.n_microbatches, axis_name='pp')
+            else:
+                x, _ = jax.lax.scan(scan_body, x, xs)
 
-        x = _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
-        logits = x @ params['wte'].T.astype(cdt)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        loss = -jnp.mean(ll)
+        with jax.named_scope('gpt.head'):
+            x = _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
+            loss = _xent(x @ params['wte'].T.astype(cdt), targets)
         if config.pp > 1:
             # head/loss are only valid on the last stage; the psum over 'pp'
             # happens AFTER the vjp (in spmd_valgrad) so no collective with an
@@ -984,24 +1011,25 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
                              lambda g: jax.lax.psum(g, 'pp'), v))
                      for k, v in grads.items()}
         reduce_axes = ['dp'] + (['sp'] if config.sp > 1 else [])
-        for ax in reduce_axes:
-            loss = jax.lax.pmean(loss, ax)
-            if ax == 'dp' and quant != 'none':
-                from ..distributed import quant_collectives as qc
-                from ..ops.flash_attention import mix_seed
-                qseed = None
-                if seed is not None:
-                    # decorrelate the rounding stream from the dropout
-                    # stream sharing the same step seed
-                    qseed = mix_seed(jnp.asarray(seed, jnp.uint32)
-                                     ^ jnp.uint32(0xA5A5F00D))
-                grads = qc.psum_tree(grads, 'dp', mode=quant,
-                                     seed=qseed,
-                                     stochastic=qseed is not None,
-                                     mean=True)
-            else:
-                grads = jax.tree_util.tree_map(
-                    lambda g, _ax=ax: jax.lax.pmean(g, _ax), grads)
+        with jax.named_scope('gpt.grad_reduce'):
+            for ax in reduce_axes:
+                loss = jax.lax.pmean(loss, ax)
+                if ax == 'dp' and quant != 'none':
+                    from ..distributed import quant_collectives as qc
+                    from ..ops.flash_attention import mix_seed
+                    qseed = None
+                    if seed is not None:
+                        # decorrelate the rounding stream from the dropout
+                        # stream sharing the same step seed
+                        qseed = mix_seed(jnp.asarray(seed, jnp.uint32)
+                                         ^ jnp.uint32(0xA5A5F00D))
+                    grads = qc.psum_tree(grads, 'dp', mode=quant,
+                                         seed=qseed,
+                                         stochastic=qseed is not None,
+                                         mean=True)
+                else:
+                    grads = jax.tree_util.tree_map(
+                        lambda g, _ax=ax: jax.lax.pmean(g, _ax), grads)
         return loss, grads
 
     pspec_tree = train_specs(config)
@@ -1021,8 +1049,7 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
         def step(params, opt_state, key, lr, tokens, targets):
             seed = jax.random.bits(key, (), jnp.uint32)
             loss, grads = smapped(params, tokens, targets, seed)
-            new_p, new_s = optimizer.functional_apply(params, grads,
-                                                      opt_state, lr)
+            new_p, new_s = _apply(optimizer, params, grads, opt_state, lr)
             return loss, new_p, new_s
 
         return jit_train_step(step, mesh, n_state=2)
@@ -1033,7 +1060,7 @@ def make_train_step(config: GPTConfig, optimizer, mesh=None):
 
     def step(params, opt_state, key, lr, tokens, targets):
         loss, grads = smapped(params, tokens, targets)
-        new_p, new_s = optimizer.functional_apply(params, grads, opt_state, lr)
+        new_p, new_s = _apply(optimizer, params, grads, opt_state, lr)
         return loss, new_p, new_s
 
     return jit_train_step(step, mesh, n_state=2)
@@ -1053,27 +1080,27 @@ def _make_train_step_1f1b(config: GPTConfig, optimizer, mesh, explicit_mp):
         shared = {k: params[k] for k in shared_keys}
 
         def embed_fn(sh, tok):
-            S = tok.shape[1]
-            sp_idx = jax.lax.axis_index('sp') if config.sp > 1 else 0
-            pos = sp_idx * S + jnp.arange(S)
-            return (jnp.take(sh['wte'], tok, axis=0)
-                    + sh['wpe'][pos]).astype(cdt)
+            with jax.named_scope('gpt.embed'):
+                S = tok.shape[1]
+                sp_idx = jax.lax.axis_index('sp') if config.sp > 1 else 0
+                pos = sp_idx * S + jnp.arange(S)
+                return (jnp.take(sh['wte'], tok, axis=0)
+                        + sh['wpe'][pos]).astype(cdt)
 
         body = partial(block_fn, config=config, explicit_mp=explicit_mp)
         if config.remat:
             body = _remat(body, config)
 
         def stage_fn(stage_params, xx):
-            out, _ = jax.lax.scan(lambda c, bp: (body(bp, c), None),
-                                  xx, stage_params)
+            with jax.named_scope('gpt.layers'):
+                out, _ = jax.lax.scan(lambda c, bp: (body(bp, c), None),
+                                      xx, stage_params)
             return out
 
         def head_fn(sh, h, tgt):
-            x = _layer_norm(h, sh['lnf_g'], sh['lnf_b']).astype(cdt)
-            logits = x @ sh['wte'].T.astype(cdt)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-            return -jnp.mean(ll)
+            with jax.named_scope('gpt.head'):
+                x = _layer_norm(h, sh['lnf_g'], sh['lnf_b']).astype(cdt)
+                return _xent(x @ sh['wte'].T.astype(cdt), tgt)
 
         loss, g_blocks, g_shared = pipeline_train_1f1b(
             stage_fn, embed_fn, head_fn, params['blocks'], shared,
@@ -1081,13 +1108,11 @@ def _make_train_step_1f1b(config: GPTConfig, optimizer, mesh, explicit_mp):
 
         grads = dict(g_shared)
         grads['blocks'] = g_blocks
-        loss = jax.lax.pmean(loss, 'dp')
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.pmean(g, 'dp'), grads)
-        if config.sp > 1:
-            loss = jax.lax.pmean(loss, 'sp')
-            grads = jax.tree_util.tree_map(
-                lambda g: jax.lax.pmean(g, 'sp'), grads)
+        with jax.named_scope('gpt.grad_reduce'):
+            for ax in ['dp'] + (['sp'] if config.sp > 1 else []):
+                loss = jax.lax.pmean(loss, ax)
+                grads = jax.tree_util.tree_map(
+                    lambda g, _ax=ax: jax.lax.pmean(g, _ax), grads)
         return loss, grads
 
     pspec_tree = train_specs(config)
@@ -1098,7 +1123,7 @@ def _make_train_step_1f1b(config: GPTConfig, optimizer, mesh, explicit_mp):
 
     def step(params, opt_state, key, lr, tokens, targets):
         loss, grads = smapped(params, tokens, targets)
-        new_p, new_s = optimizer.functional_apply(params, grads, opt_state, lr)
+        new_p, new_s = _apply(optimizer, params, grads, opt_state, lr)
         return loss, new_p, new_s
 
     return jit_train_step(step, mesh, n_state=2)

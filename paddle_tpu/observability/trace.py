@@ -107,7 +107,9 @@ class Span:
         mod = _jax_profiler()
         if mod is not None:
             try:
-                ann = mod.TraceAnnotation(self.name)
+                # attributes ride along as the event's statistics in
+                # the profiler's trace (req_ids, slot, step)
+                ann = mod.TraceAnnotation(self.name, **(self.attrs or {}))
                 ann.__enter__()
                 self._ann = ann
             except Exception:

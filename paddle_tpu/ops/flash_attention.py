@@ -348,6 +348,7 @@ def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
             jax.ShapeDtypeStruct((bh, s_q, _LANES), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name='flash_fwd',
     )(*args)
     return out, lse[:, :, 0]
 
@@ -615,6 +616,7 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
         out_specs=pl.BlockSpec((1, _BQ, d), blk),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
         interpret=_INTERPRET,
+        name='flash_bwd_dq',
     )(*dq_args)
 
     dkv_in_specs = [
@@ -647,6 +649,7 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
             jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
         ],
         interpret=_INTERPRET,
+        name='flash_bwd_dkv',
     )(*dkv_args)
     if groups > 1:
         shp = (bh // groups, groups, s_k, d)
@@ -961,6 +964,7 @@ def _decode_call(kernel, q, pos, caches):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, _TQ_DECODE, d), q.dtype),
         interpret=_INTERPRET,
+        name='flash_decode',
     )(pos, qt, *caches)
     return out[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
 

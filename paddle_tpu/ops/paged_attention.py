@@ -205,6 +205,7 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((bh, _TQ, d), q.dtype),
             interpret=_fa._INTERPRET,
+            name='paged_attention',
         )(page_table.reshape(-1), pos, qt, *planes)
         return out[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
 

@@ -22,6 +22,7 @@ Kernels inside the step shard over ``mesh`` (ops/mesh_kernel.py).
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from .. import observability as _obs
 from ..ops import mesh_kernel
 
 
@@ -40,6 +41,7 @@ class jit_train_step:
     def __init__(self, step, mesh, n_state):
         self._step, self._mesh, self._n_state = step, mesh, n_state
         self._jit = self._keep = None
+        self._calls = 0
 
     def _jitted(self, args):
         if self._jit is None:
@@ -70,8 +72,12 @@ class jit_train_step:
             args[:self._n_state], self._keep) + args[self._n_state:]
 
     def __call__(self, *args):
-        jitted = self._jitted(args)
-        return jitted(*self._settled(args))
+        # the host side of one step, numbered so that it can be laid
+        # against that step's run of jit_step in the device trace
+        self._calls += 1
+        with _obs.span('train.dispatch', step=self._calls):
+            jitted = self._jitted(args)
+            return jitted(*self._settled(args))
 
     def lower(self, *args):
         jitted = self._jitted(args)

@@ -248,7 +248,13 @@ class InferenceEngine:
         if warmup is not None:
             # precompile before submit() is ever accepted: the first real
             # request must find its executable already in the bucket cache
-            self.warmup(warmup)
+            try:
+                self.warmup(warmup)
+            except BaseException:
+                # an engine that never came to be leaves no probe behind
+                # (it would hold /readyz at 503 for the process's life)
+                _obs.remove_readiness(self._probe_name)
+                raise
 
     # ---- compile path ----------------------------------------------------
     def _build(self, bucket, sig, precision):

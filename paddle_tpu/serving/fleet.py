@@ -714,7 +714,7 @@ class FleetRouter:
             raise
         att.inner = inner
         if freq.kind == 'gen':
-            inner._subscribe(freq.mirror)
+            inner.subscribe(freq.mirror)
             att.subscribed = True
         freq.master.note('route', replica=rep.name, depth=depth)
         return 'ok'

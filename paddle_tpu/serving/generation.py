@@ -94,9 +94,10 @@ class GenerationFuture:
     Eviction/readmission never re-yields: regenerated tokens are only
     appended past what the future already holds."""
 
-    def __init__(self):
+    def __init__(self, want_logits=False):
         self._cv = threading.Condition()
         self._tokens = []
+        self._logits = [] if want_logits else None
         self._done = False
         self._exc = None
         self._listeners = []
@@ -112,7 +113,7 @@ class GenerationFuture:
         with self._cv:
             return [int(t) for t in self._tokens[:n]]
 
-    def _subscribe(self, fn):
+    def subscribe(self, fn):
         """Register ``fn(kind, *args)`` invoked OUTSIDE the future's lock:
         ``('token', idx, tok)`` per emission and ``('finish', exc)`` once.
         Tokens already emitted are replayed so a late subscriber (a fleet
@@ -128,11 +129,13 @@ class GenerationFuture:
         if done:
             fn('finish', exc)
 
-    def _append(self, tok):
+    def _append(self, tok, logits=None):
         with self._cv:
             if self._done:
                 return
             self._tokens.append(int(tok))
+            if self._logits is not None:
+                self._logits.append(logits)
             idx = len(self._tokens) - 1
             listeners = list(self._listeners)
             self._cv.notify_all()
@@ -171,6 +174,17 @@ class GenerationFuture:
         with self._cv:
             return list(self._tokens)
 
+    def logits(self):
+        """One float32 ``[vocab]`` row per token emitted so far, aligned
+        with the tokens: the logits each token was chosen from, as the
+        engine's executable computed them (before temperature and top-k).
+        Only for a request submitted with ``want_logits=True``."""
+        with self._cv:
+            if self._logits is None:
+                raise ValueError('this request did not ask for logits: '
+                                 'submit(..., want_logits=True)')
+            return list(self._logits)
+
     def stream(self, timeout=None):
         """Generator of tokens in emission order; returns at EOS/limit,
         raises the failure exception if the sequence failed."""
@@ -192,11 +206,13 @@ class GenerationFuture:
 
 class _Request:
     __slots__ = ('prompt', 'eff_max_new', 'seed', 'future', 'enqueue_t',
-                 'deadline_t', 'evictions', 'ttft_noted', 'rec', 'tenant')
+                 'deadline_t', 'evictions', 'ttft_noted', 'rec', 'tenant',
+                 'want_logits')
 
     def __init__(self, prompt, eff_max_new, seed, future, enqueue_t,
-                 deadline_t, rec=None, tenant='default'):
+                 deadline_t, rec=None, tenant='default', want_logits=False):
         self.prompt = prompt
+        self.want_logits = want_logits
         self.eff_max_new = eff_max_new
         self.seed = seed
         self.tenant = tenant
@@ -508,17 +524,21 @@ class GenerationEngine:
                                 last_only=True)
             # absolute position start+valid-1: the sampling key of the
             # prompt's last row must not depend on how much was cached
-            tok = sample_rows(logits[:, 0], seed,
-                              pos0 + valid.astype(jnp.int32) - 1)
-            return tok, {'k': cache['k'], 'v': cache['v']}
+            row = logits[:, 0]
+            tok = sample_rows(row, seed, pos0 + valid.astype(jnp.int32) - 1)
+            # the logits the token was chosen from stay on the device in
+            # the compute dtype; the host reads them, and widens them to
+            # float32, only for a request that asked (want_logits)
+            return tok, row, {'k': cache['k'], 'v': cache['v']}
 
         def step(params, pool, tok, pos, page_table, seeds):
             self._trace_count += 1
             cache = {'k': pool['k'], 'v': pool['v'],
                      'page_table': page_table}
             logits, cache = fwd(params, tok[:, None], cache, pos, cfg)
-            nxt = sample_rows(logits[:, 0], seeds, pos)
-            return nxt, {'k': cache['k'], 'v': cache['v']}
+            rows = logits[:, 0]
+            nxt = sample_rows(rows, seeds, pos)
+            return nxt, rows, {'k': cache['k'], 'v': cache['v']}
 
         # under a mesh the paged kernel shards over it (ops/mesh_kernel)
         from ..ops import mesh_kernel
@@ -620,14 +640,16 @@ class GenerationEngine:
 
     # ---- admission -------------------------------------------------------
     def submit(self, prompt, max_new_tokens=32, deadline_ms=None, seed=0,
-               tenant='default', *, _record=None, _enqueue_t=None,
-               _deadline_t=_UNSET):
+               tenant='default', *, want_logits=False, _record=None,
+               _enqueue_t=None, _deadline_t=_UNSET):
         """Enqueue one sequence. ``prompt`` is a 1-D token id sequence of
         length 1..prefill_width; returns a ``GenerationFuture``. Tokens
         stop at ``eos_id`` (emitted), ``max_new_tokens``, or the context
         window (a prompt of exactly max_seq_len still yields one token).
         ``tenant`` namespaces the prefix cache: KV pages are only ever
-        reused within one tenant's own traffic.
+        reused within one tenant's own traffic. ``want_logits`` keeps the
+        float32 logits every token was chosen from on the future
+        (``future.logits()``); the tokens are the same either way.
 
         The underscore params are the fleet router's resubmission hooks:
         a failed-over request keeps its original ``RequestRecord``,
@@ -656,7 +678,7 @@ class GenerationEngine:
         else:
             deadline_t = (now + deadline_ms / 1e3
                           if deadline_ms is not None else None)
-        fut = GenerationFuture()
+        fut = GenerationFuture(want_logits=bool(want_logits))
         # request-scoped trace: minted here, rides the request across the
         # submit -> scheduler thread boundary (NULL_RECORD when disabled)
         if _record is not None:
@@ -676,7 +698,8 @@ class GenerationEngine:
             rec.finish('expired', err)
             raise err
         req = _Request(arr, eff, int(seed) & 0xFFFFFFFF, fut, enqueue_t,
-                       deadline_t, rec=rec, tenant=str(tenant))
+                       deadline_t, rec=rec, tenant=str(tenant),
+                       want_logits=bool(want_logits))
         try:
             with self._cv:
                 if self._closed:
@@ -761,7 +784,10 @@ class GenerationEngine:
             # longest cached prefix: matched full pages arrive retained
             # (this slot's references); the COW source page stays owned by
             # the cache and is copied into a private page before any write
-            hit = (self._prefix.acquire(req.tenant, req.prompt, req.seed)
+            # a request that wants logits never replays a recorded first
+            # token: its last prompt row is prefilled again for the logits
+            hit = (self._prefix.acquire(req.tenant, req.prompt, req.seed,
+                                        replay=not req.want_logits)
                    if self._prefix is not None else None)
             shared = hit['pages'] if hit else []
             cow_src = hit['cow'] if hit else None
@@ -850,16 +876,19 @@ class GenerationEngine:
 
         def dev():
             fault.inject('gen.step')
-            tok, pool = pf(self._params, self._pool, jnp.asarray(prompt),
-                           jnp.asarray(startv), jnp.asarray(valid),
-                           jnp.asarray(table), jnp.asarray(seed))
-            return int(np.asarray(tok)[0]), pool
+            tok, lg, pool = pf(self._params, self._pool,
+                               jnp.asarray(prompt), jnp.asarray(startv),
+                               jnp.asarray(valid), jnp.asarray(table),
+                               jnp.asarray(seed))
+            row = (np.asarray(lg)[0].astype(np.float32)
+                   if req.want_logits else None)
+            return int(np.asarray(tok)[0]), row, pool
 
         req.rec.note('prefill', slot=idx, prompt_len=t0, start=start)
         try:
             with _obs.span('gen.prefill', slot=idx, prompt_len=t0,
                            req_id=req.rec.rid):
-                tok, pool = self._breaker.call(dev)
+                tok, row, pool = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
@@ -868,7 +897,7 @@ class GenerationEngine:
         self._n['prefills'] += 1
         with self._cv:
             slot.last_tok = tok
-            self._emit_locked(slot, tok)
+            self._emit_locked(slot, tok, row)
             if self._slot_finished(slot, tok):
                 self._finish_slot_locked(idx)
             self._update_gauges_locked()
@@ -880,6 +909,7 @@ class GenerationEngine:
         table = np.zeros((s, self.p_max), np.int32)
         seeds = np.zeros((s,), np.uint32)
         rids = []
+        want = False
         with self._cv:
             self._ensure_pages_locked()
             active = []
@@ -891,6 +921,7 @@ class GenerationEngine:
                 table[i] = slot.table
                 seeds[i] = slot.req.seed
                 active.append(i)
+                want = want or slot.req.want_logits
                 if slot.req.rec.rid:
                     rids.append(slot.req.rec.rid)
         if not active:
@@ -901,16 +932,17 @@ class GenerationEngine:
 
         def dev():
             fault.inject('gen.step')
-            nxt, pool = st(self._params, self._pool, jnp.asarray(tok),
-                           jnp.asarray(pos), jnp.asarray(table),
-                           jnp.asarray(seeds))
-            # ONE host readback per iteration for every slot
-            return np.asarray(nxt), pool
+            nxt, lg, pool = st(self._params, self._pool, jnp.asarray(tok),
+                               jnp.asarray(pos), jnp.asarray(table),
+                               jnp.asarray(seeds))
+            # ONE host readback per iteration for every slot; the logits
+            # follow only when a request in a slot asked for them
+            return np.asarray(nxt), (np.asarray(lg) if want else None), pool
 
         try:
             with _obs.span('gen.decode_step', slots=len(active),
                            req_ids=rids):
-                nxt, pool = self._breaker.call(dev)
+                nxt, rows, pool = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
@@ -926,20 +958,23 @@ class GenerationEngine:
                 slot.pos += 1
                 slot.last_tok = t
                 slot.req.rec.note_decode(slot.pos)
-                self._emit_locked(slot, t)
+                # astype copies: a view would keep every slot's rows alive
+                self._emit_locked(
+                    slot, t, rows[i].astype(np.float32)
+                    if slot.req.want_logits else None)
                 if self._slot_finished(slot, t):
                     self._finish_slot_locked(i)
             self._update_gauges_locked()
             self._cv.notify_all()
 
     # ---- slot state (all called under the lock) --------------------------
-    def _emit_locked(self, slot, tok):
+    def _emit_locked(self, slot, tok, logits=None):
         req = slot.req
         idx = slot.produced
         slot.produced += 1
         self._note('tokens')
         if idx >= req.future._count():
-            req.future._append(tok)
+            req.future._append(tok, logits)
             if not req.ttft_noted:
                 req.ttft_noted = True
                 ttft_ms = 1e3 * (self._clock() - req.enqueue_t)

@@ -764,7 +764,7 @@ class ModelHost:
                 _done[0] = True
                 self._request_done(m, tenant, lane,
                                    event_args[0] if event_args else None)
-            fut._subscribe(_on_event)
+            fut.subscribe(_on_event)
         else:
             def _on_done(f):
                 exc = None if f.cancelled() else f.exception()

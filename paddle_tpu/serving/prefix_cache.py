@@ -134,7 +134,7 @@ class PrefixCache:
                     self._evict_leaves_locked(over)
 
     # ---- lookup / acquire ------------------------------------------------
-    def acquire(self, tenant, prompt, seed):
+    def acquire(self, tenant, prompt, seed, replay=True):
         """Longest cached prefix of ``prompt`` under ``tenant``.
 
         Returns ``None`` on a miss, else a dict:
@@ -156,11 +156,14 @@ class PrefixCache:
         this seed, the match is trimmed to ``len(prompt) - 1`` so at least
         one token re-prefills (the engine needs the last row's logits) —
         the final page becomes the COW source since the re-prefilled row
-        lands mid-page."""
+        lands mid-page. ``replay=False`` (a request that wants the last
+        row's logits) asks for that trimmed match even where a first token
+        is recorded."""
         prompt = [int(t) for t in prompt]
         t0 = len(prompt)
         ps = self.page_size
-        skey = int(seed) & 0xFFFFFFFF
+        # recorded first tokens are keyed by int seeds: None finds none
+        skey = int(seed) & 0xFFFFFFFF if replay else None
         with self._lock:
             self._tick += 1
             chain = []

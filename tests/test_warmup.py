@@ -158,10 +158,15 @@ def test_stale_serving_entry_skipped_not_fatal():
         report = eng.warmup(man)
     assert report['skipped'] == 1 and report['prebuilt'] == 1
     assert any('stale' in str(w.message) for w in caught)
-    with pytest.raises(Exception):
-        warmup.prebuild(man, engine=InferenceEngine(
-            _net(), max_batch_size=4, max_delay_ms=0.2), strict=True)
-    eng.shutdown()
+    # shut down too: an engine left open keeps its not-ready probe in the
+    # process, and /readyz tests that run after it read 503
+    strict = InferenceEngine(_net(), max_batch_size=4, max_delay_ms=0.2)
+    try:
+        with pytest.raises(Exception):
+            warmup.prebuild(man, engine=strict, strict=True)
+    finally:
+        strict.shutdown()
+        eng.shutdown()
 
 
 def test_oversized_bucket_entry_skipped():
