@@ -16,6 +16,12 @@ Under a device mesh every kernel call goes through ops/mesh_kernel.py
 (shard_map over batch -> 'dp', heads -> 'mp'): Mosaic kernels cannot be
 partitioned by the compiler.
 
+The causal tile schedule (``causal_tile_plan``): a tile wholly under the
+diagonal runs a body with no iota, compare or select; a tile the diagonal
+crosses runs first, in ``_SUB``-wide sub-tiles of which those above the
+diagonal are skipped and only the crossed ones masked; a tile that a cut last
+k/v block makes is masked whole. State lives in VMEM scratch, not in carries.
+
 Round 4 widened the gate to serving/training reality (judge r3 'Next' #2):
  - key-padding masks (bool or additive, [B,S_k]/[B,1,S_k]/[B,1,1,S_k])
    handled IN the kernels — padded-batch attention no longer falls back;
@@ -43,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import mesh_kernel
+from .. import observability as _obs
 
 
 def _env_block(name, default):
@@ -147,12 +154,139 @@ _NEG_INF = _np.float32(-1e30)
 _EPS = _np.float32(1e-30)
 
 
-def _n_kv_blocks(causal, qi, bq, bk, q_off, kv_valid, nkb):
-    """Number of k/v blocks the q block ``qi`` must visit (i32, traced)."""
-    n = jnp.int32(nkb if kv_valid is None else -(-kv_valid // bk))
+_SUB = 256   # edge of a diagonal tile's sub-tiles (measured on the v5e at
+             # D 64 and D 128; PERF.md, PR 25)
+
+
+def _cdiv(a, b):
+    return -(-a // b)
+
+
+def _lo(a, b):
+    """min of two block counts, python ints or traced i32."""
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
+def _hi(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
+
+
+# The causal tile schedule. A score (r, c) is kept iff c <= r + q_off (causal)
+# and c < kv_valid (padded keys). The two functions below are the ONE place
+# that turns this into block bounds: the kernels call them with the traced
+# program index, _program_tiles (the plan's source) with python ints.
+
+def _kv_bounds(qi, causal, bq, bk, q_off, kv_valid, nkb):
+    """(n_int, n_iter) for q block ``qi`` (forward, dq): k/v tiles
+    [0, n_int) keep every score, [n_int, n_iter) are crossed by the diagonal
+    or cut by ``kv_valid``, the rest keep none."""
+    n_int = nkb if kv_valid is None else kv_valid // bk
+    n_iter = nkb if kv_valid is None else _cdiv(kv_valid, bk)
     if causal:
-        n = jnp.minimum(n, ((qi + 1) * bq + q_off + bk - 1) // bk)
-    return jnp.asarray(n, jnp.int32)
+        last = qi * bq + q_off      # last key the block's FIRST row keeps
+        n_int = _hi(0, _lo(n_int, (last + 1) // bk))
+        n_iter = _hi(n_int, _lo(n_iter, (last + bq - 1) // bk + 1))
+    return n_int, n_iter
+
+
+def _q_bounds(ki, causal, bq, bk, q_off, nqb):
+    """(start, first_int) for k/v block ``ki`` (dkv): q tiles
+    [start, first_int) are crossed by the diagonal, [first_int, nqb) keep
+    every score, those before ``start`` none."""
+    if not causal:
+        return 0, 0
+    first = ki * bk - q_off         # first q row that keeps the FIRST key
+    start = _lo(nqb, _hi(0, first // bq))
+    first_int = _lo(nqb, _hi(start, _cdiv(first + bk - 1, bq)))
+    return start, first_int
+
+
+def _sub_edges(bq, bk):
+    return math.gcd(bq, _SUB), math.gcd(bk, _SUB)
+
+
+def _sub_grid(rel, bq, bk, tr, tc):
+    """Class of every [tr, tc] sub-tile of a [bq, bk] tile whose score
+    (r, c) is kept iff c <= r + rel: 'free' (all kept), 'skip' (none) or
+    'mask'. Rows run free.. mask.. skip, columns skip.. mask.. free."""
+    def cls(i, j):
+        if j * tc + tc - 1 <= i * tr + rel:
+            return 'free'
+        return 'skip' if j * tc > i * tr + tr - 1 + rel else 'mask'
+    return [[cls(i, j) for j in range(bk // tc)] for i in range(bq // tr)]
+
+
+def _program_tiles(kv_major, i, causal, bq, bk, q_off, kv_valid, nqb, nkb):
+    """What program ``i`` of a kernel visits: (interior tiles, the ``rel`` of
+    each diagonal tile in stream order — its score (r, c) is kept iff
+    c <= r + rel —, padded tiles). A cut last k/v block makes padded tiles of
+    the tiles that touch it in the forward and dq (masked whole), and of
+    its program's interior tiles in dkv (one additive [1, BK] row)."""
+    if kv_major:
+        start, first_int = _q_bounds(i, causal, bq, bk, q_off, nqb)
+        rels = [qb * bq + q_off - i * bk for qb in range(start, first_int)]
+        cut = kv_valid is not None and (i + 1) * bk > kv_valid
+        rest = nqb - first_int
+        return (0, rels, rest) if cut else (rest, rels, 0)
+    n_int, n_iter = _kv_bounds(i, causal, bq, bk, q_off, kv_valid, nkb)
+    whole = [kb for kb in range(n_int, n_iter)
+             if kv_valid is None or (kb + 1) * bk <= kv_valid]
+    return (n_int, [i * bq + q_off - kb * bk for kb in whole],
+            n_iter - n_int - len(whole))
+
+
+def _diag_rels(kv_major, *geometry):
+    """The diagonal tiles' ``rel``s when they are the same in every program,
+    else None (blocks of unequal size, ends that clamp, a cut last block in
+    the forward and dq): the tiles are then masked whole, in a loop.
+    ``geometry``: causal, bq, bk, q_off, kv_valid, nqb, nkb."""
+    kv_valid, nqb, nkb = geometry[-3:]
+    if kv_valid is not None and not kv_major:
+        return None
+    tails = {tuple(_program_tiles(kv_major, i, *geometry)[1])
+             for i in range(nkb if kv_major else nqb)}
+    return tails.pop() if len(tails) == 1 else None
+
+
+def causal_tile_plan(s_q, s_k, bq, bk, q_off=0, kv_valid=None, causal=True):
+    """What the three kernels do with the tiles of ONE attention row (a head
+    of a batch element) of padded lengths ``s_q`` x ``s_k``: per variant
+    the tiles that take the mask-free body (``interior``), those the
+    diagonal crosses (``diagonal``), those a cut last k/v block makes
+    (``padded``), and of the diagonal tiles' ``sub``-shaped sub-tiles those
+    skipped, masked and mask-free."""
+    geometry = (causal, bq, bk, q_off, kv_valid, s_q // bq, s_k // bk)
+    plan = {}
+    for variant, kv_major in (('fwd', False), ('dq', False), ('dkv', True)):
+        rels = _diag_rels(kv_major, *geometry)
+        sub = _sub_edges(bq, bk) if rels is not None else (bq, bk)
+        n = dict.fromkeys(('interior', 'diagonal', 'padded', 'sub_skipped',
+                           'sub_masked', 'sub_free'), 0)
+        for i in range(geometry[-1] if kv_major else geometry[-2]):
+            interior, rels, padded = _program_tiles(kv_major, i, *geometry)
+            n['interior'] += interior
+            n['diagonal'] += len(rels)
+            n['padded'] += padded
+            for rel in rels:
+                for row in _sub_grid(rel, bq, bk, *sub):
+                    n['sub_skipped'] += row.count('skip')
+                    n['sub_masked'] += row.count('mask')
+                    n['sub_free'] += row.count('free')
+        plan[variant] = dict(n, sub=sub)
+    return plan
+
+
+def _count_tiles(kernel, rows, plan):
+    """Trace-time record of the static schedule: how many tiles of this
+    ``pallas_call`` run mask-free and how many masked."""
+    for name, n in (
+            ('flash.tiles_unmasked_total', plan['interior']),
+            ('flash.tiles_masked_total', plan['diagonal'] + plan['padded'])):
+        _obs.counter(name, {'kernel': kernel}).inc(rows * n)
 
 
 def _mask_scores(s, causal, qi_or_qb, kb, bq, bk, q_off, kv_valid):
@@ -225,24 +359,79 @@ def per_layer_seeds(seed, n_layers):
                     * jnp.uint32(0x27D4EB2F))
 
 
-def _drop_mult(shape, seed, row, qb, kb, bq, bk, rate):
-    """[BQ, BK] f32 dropout multiplier tile: 1/(1-rate) kept, 0 dropped.
-    Tile coordinates are converted to GLOBAL q/k positions so forward and
-    backward agree regardless of how each kernel blocks the sequence."""
-    q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+def _drop_mult(shape, seed, row, q0, k0, rate):
+    """[rows, cols] f32 dropout multiplier tile: 1/(1-rate) kept, 0 dropped.
+    ``q0``/``k0`` are the GLOBAL positions of the tile's first row and key,
+    so forward and backward agree regardless of how each kernel blocks (and
+    sub-tiles) the sequence."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     keep = _dropout_keep(seed, row, q_pos, k_pos, rate)
     return jnp.where(keep, _np.float32(1.0 / (1.0 - rate)),
                      _np.float32(0.0))
 
 
+def _lanes(x, n):
+    """A [rows, LANES] lane-broadcast column at width ``n``."""
+    return x[:, :n] if n <= _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _mask_tail(s, n_mask, axis, rel):
+    """Of a strip of sub-tiles, mask the last ``n_mask`` columns (axis 1) or
+    the first ``n_mask`` rows (axis 0), where a score (r, c) is kept iff
+    c <= r + rel; the rest of the strip is wholly kept."""
+    if axis == 1:
+        free, cut = s[:, :s.shape[1] - n_mask], s[:, s.shape[1] - n_mask:]
+    else:
+        cut, free = s[:n_mask], s[n_mask:]
+    keep = (jax.lax.broadcasted_iota(jnp.int32, cut.shape, 1) <=
+            jax.lax.broadcasted_iota(jnp.int32, cut.shape, 0) + jnp.int32(rel))
+    cut = jnp.where(keep, cut, _NEG_INF)
+    if not free.shape[axis]:
+        return cut
+    return jnp.concatenate([free, cut] if axis == 1 else [cut, free], axis)
+
+
+def _diag_strips(diag, bq, bk, kv_major):
+    """The diagonal tiles ``diag`` (see _diag_rels) cut into strips of
+    sub-tiles along the axis a kernel owns: rows of q in the forward and dq,
+    keys in dkv. Yields (strip start, strip size, pieces); a piece
+    (u, lo, n, mask) tells the kernel to take, of tile ``u``, the range
+    [lo, lo + n) of the OTHER axis — the strip's mask-free and crossed
+    sub-tiles, never the skipped ones — and to apply ``mask`` (None when no
+    sub-tile of the piece is crossed) to its scores."""
+    tr, tc = _sub_edges(bq, bk)
+    own, other = (tc, tr) if kv_major else (tr, tc)
+    grids = [_sub_grid(rel, bq, bk, tr, tc) for rel in diag]
+    for i in range((bk if kv_major else bq) // own):
+        pieces = []
+        for u, (rel, grid) in enumerate(zip(diag, grids)):
+            line = [row[i] for row in grid] if kv_major else grid[i]
+            n_mask, n_free = line.count('mask'), line.count('free')
+            if n_mask + n_free == 0:
+                continue
+            # skipped sub-tiles lead a column of the grid and trail a row
+            lo = line.count('skip') * other if kv_major else 0
+            mask = None
+            if n_mask and kv_major:
+                mask = functools.partial(_mask_tail, n_mask=n_mask * tr,
+                                         axis=0, rel=lo + rel - i * tc)
+            elif n_mask:
+                mask = functools.partial(_mask_tail, n_mask=n_mask * tc,
+                                         axis=1,
+                                         rel=i * tr + rel - n_free * tc)
+            pieces.append((u, lo, (n_mask + n_free) * other, mask))
+        yield i * own, own, pieces
+
+
 def _fwd_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
-                drop_rate=0.0):
+                diag, drop_rate=0.0):
     # Scalar constants pinned to f32 (Mosaic rejects f64). MXU dtype policy:
     # q/k/v stay in their NATIVE dtype for the dot_generals (bf16 inputs run
     # the MXU at full rate) with f32 accumulation via preferred_element_type;
     # the softmax scale is applied to the f32 scores AFTER the dot, so no
     # precision is lost to a bf16 pre-scale.
+    *refs, acc_ref, m_ref, l_ref = refs     # the online softmax's state
     if drop_rate:
         seed_ref, refs = refs[-3], refs[:-3] + refs[-2:]
     if has_kmask:
@@ -253,52 +442,87 @@ def _fwd_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
     # program_id must be read OUTSIDE the fori_loop body (the interpret-mode
     # lowering can't resolve it inside the loop's inner jaxpr)
     bh_row = pl.program_id(0) if drop_rate else None
-    q = q_ref[0]                                            # [BQ, D] native
-    s_total = k_ref.shape[1]
-    nkb = s_total // bk
-    d = q.shape[-1]
+    nkb = k_ref.shape[1] // bk
+    d = q_ref.shape[-1]
 
-    def body(kb, carry):
-        # carries kept 2-D ([BQ,1]) — Mosaic vectorizes 2-D ops cleanly
-        acc, m, l = carry
-        kblk = k_ref[0, pl.ds(kb * bk, bk), :]                       # [BK, D]
-        vblk = v_ref[0, pl.ds(kb * bk, bk), :]
+    def step(carry, q, q0, k0, width, mask=None):
+        """One online-softmax step of rows ``q`` (global row q0) over the
+        keys [k0, k0 + width); ``carry`` None starts the rows' softmax."""
+        kblk = k_ref[0, pl.ds(k0, width), :]                      # [W, D]
+        vblk = v_ref[0, pl.ds(k0, width), :]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
-                                ) * _np.float32(scale)               # [BQ,BK]
+                                ) * _np.float32(scale)            # [rows,W]
         if has_kmask:
             # kmask rides as [B,1,S_k]: a (1,1,S_k) block keeps the minor-2
             # dims Mosaic-tileable (a raw [B,S_k] block (1,S_k) is rejected
             # on real TPU — caught by tools/tpu_kernel_check.py on silicon)
-            s = s + kmask_ref[0, :, pl.ds(kb * bk, bk)]              # [1,BK]
-        s = _mask_scores(s, causal, qi, kb, bq, bk, q_off, kv_valid)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))   # [BQ,1]
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)                                   # [BQ,1]
+            s = s + kmask_ref[0, :, pl.ds(k0, width)]             # [1,W]
+        if mask is not None:
+            s = mask(s)
+        # m and l are kept broadcast over a vreg's 128 lanes, as lse is
+        # stored: every operation on them is a whole-vreg one
+        m_new = jnp.broadcast_to(jnp.max(s, axis=-1, keepdims=True),
+                                 (s.shape[0], _LANES))
+        if carry is not None:
+            acc, m, l = carry
+            m_new = jnp.maximum(m, m_new)
+        p = jnp.exp(s - _lanes(m_new, width))
         # the softmax normalizer accumulates the UNdropped p (dropout acts
         # on the post-softmax probabilities, not inside the softmax)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = jnp.broadcast_to(jnp.sum(p, axis=-1, keepdims=True),
+                                 m_new.shape)
         if drop_rate:
-            p = p * _drop_mult(p.shape, seed_ref[0], bh_row,
-                               qi, kb, bq, bk, drop_rate)
+            p = p * _drop_mult(p.shape, seed_ref[0], bh_row, q0, k0,
+                               drop_rate)
         # p cast to v's dtype: bf16×bf16→f32 keeps the MXU at full rate;
         # identity for f32 inputs
-        acc = acc * alpha + jax.lax.dot_general(
+        pv = jax.lax.dot_general(
             p.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
+        if carry is None:
+            return pv, m_new, l_new
+        alpha = jnp.exp(m - m_new)                            # [rows,LANES]
+        return acc * _lanes(alpha, d) + pv, m_new, l * alpha + l_new
 
+    q = q_ref[0]                                            # [BQ, D] native
     # loop bounds pinned to i32 (Mosaic rejects mixed i32/i64 scalars)
-    n_iter = _n_kv_blocks(causal, qi, bq, bk, q_off, kv_valid, nkb)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(jnp.int32(0), n_iter, body, (acc0, m0, l0))
-    out = acc / jnp.maximum(l, _EPS)
-    o_ref[0] = out.astype(o_ref.dtype)
-    # TPU tiling: store lse broadcast across a 128-lane trailing dim
-    lse = m + jnp.log(jnp.maximum(l, _EPS))                          # [BQ,1]
-    lse_ref[0] = jnp.broadcast_to(lse, (bq, _LANES))
+    n_int, n_iter = (jnp.asarray(n, jnp.int32) for n in
+                     _kv_bounds(qi, causal, bq, bk, q_off, kv_valid, nkb))
+
+    state = (acc_ref, m_ref, l_ref)
+
+    def tile(kb, mask=None):
+        carry = step(tuple(x[...] for x in state), q, qi * bq, kb * bk, bk,
+                     mask)
+        for ref, x in zip(state, carry):
+            ref[...] = x
+
+    if diag:
+        # the diagonal tiles first, by row strips of sub-tiles: they start
+        # the softmax, so nothing is initialised and nothing rescaled
+        for r0, rows, pieces in _diag_strips(diag, bq, bk, False):
+            carry = None
+            for u, lo, n, mask in pieces:
+                carry = step(carry, q[r0:r0 + rows], qi * bq + r0,
+                             (n_int + u) * bk + lo, n, mask)
+            for ref, x in zip(state, carry):
+                ref[pl.ds(r0, rows), :] = x
+    else:
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    # interior tiles: no iota, no compare, no select
+    jax.lax.fori_loop(jnp.int32(0), n_int, lambda kb, _: tile(kb), None)
+    if diag is None:        # diagonal / cut tiles masked whole
+        jax.lax.fori_loop(
+            n_int, n_iter,
+            lambda kb, _: tile(kb, lambda s: _mask_scores(
+                s, causal, qi, kb, bq, bk, q_off, kv_valid)), None)
+    l = jnp.maximum(l_ref[...], _EPS)
+    o_ref[0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
+    # TPU tiling: lse is stored broadcast across the 128 lanes too
+    lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
@@ -315,10 +539,14 @@ def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
         bq, bk = _pick_blocks(s_q, s_k)
     scale = 1.0 / math.sqrt(d)
     grid = (bh, s_q // bq)
-    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               bq=bq, bk=bk, q_off=q_off, kv_valid=kv_valid,
-                               has_kmask=kmask is not None,
-                               drop_rate=drop_rate)
+    _count_tiles('flash_fwd', bh, causal_tile_plan(
+        s_q, s_k, bq, bk, q_off, kv_valid, causal)['fwd'])
+    kernel = functools.partial(
+        _fwd_kernel, causal=causal, scale=scale, bq=bq, bk=bk, q_off=q_off,
+        kv_valid=kv_valid, has_kmask=kmask is not None,
+        diag=_diag_rels(False, causal, bq, bk, q_off, kv_valid, s_q // bq,
+                        s_k // bk),
+        drop_rate=drop_rate)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i: (b, i, _np.int32(0))),
         pl.BlockSpec((1, s_k, d),
@@ -347,6 +575,9 @@ def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
             jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s_q, _LANES), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32)],
         interpret=_INTERPRET,
         name='flash_fwd',
     )(*args)
@@ -429,7 +660,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal, q_off=0, kv_valid=None,
 
 
 def _bwd_dq_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
-                   drop_rate=0.0):
+                   diag, drop_rate=0.0):
     """dq: each program owns one q block, streams k/v blocks.
 
     Recomputes p = exp(s - lse) from the saved row log-sum-exp; constants
@@ -438,6 +669,7 @@ def _bwd_dq_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
     delta = rowsum(g*out) already equals sum_k p*dP under dropout, so the
     flash-backward identity is unchanged).
     """
+    *refs, acc_ref = refs                   # dq in float32
     if drop_rate:
         seed_ref, refs = refs[-2], refs[:-2] + refs[-1:]
     if has_kmask:
@@ -446,43 +678,62 @@ def _bwd_dq_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
         q_ref, k_ref, v_ref, g_ref, lse_ref, dta_ref, dq_ref = refs
     qi = pl.program_id(1)
     bh_row = pl.program_id(0) if drop_rate else None   # see _fwd_kernel note
-    q = q_ref[0]                                               # [BQ, D] native
-    g = g_ref[0]                                               # [BQ, D]
-    lse = lse_ref[0][:, :1]                                    # [BQ, 1]
-    delta = dta_ref[0][:, :1]                                  # [BQ, 1]
     nkb = k_ref.shape[1] // bk
-    d = q.shape[-1]
 
-    def body(kb, dq):
+    def step(dq, r0, rows, k0, width, mask=None):
+        """The share of the keys [k0, k0 + width) in dq of the block's rows
+        [r0, r0 + rows), added to ``dq`` (None: the rows' first share)."""
         # native-dtype MXU operands, f32 accumulation (see _fwd_kernel note)
-        kblk = k_ref[0, pl.ds(kb * bk, bk), :]
-        vblk = v_ref[0, pl.ds(kb * bk, bk), :]
+        q, g, lse, delta = (x[r0:r0 + rows] for x in block)
+        kblk = k_ref[0, pl.ds(k0, width), :]
+        vblk = v_ref[0, pl.ds(k0, width), :]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * _np.float32(scale)
         if has_kmask:
-            s = s + kmask_ref[0, :, pl.ds(kb * bk, bk)]
-        s = _mask_scores(s, causal, qi, kb, bq, bk, q_off, kv_valid)
-        p = jnp.exp(s - lse)                                   # [BQ, BK] f32
+            s = s + kmask_ref[0, :, pl.ds(k0, width)]
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse)                                   # [rows, W] f32
         dp = jax.lax.dot_general(g, vblk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if drop_rate:
             dp = dp * _drop_mult(dp.shape, seed_ref[0], bh_row,
-                                 qi, kb, bq, bk, drop_rate)
+                                 qi * bq + r0, k0, drop_rate)
         ds = (p * (dp - delta)).astype(kblk.dtype)
-        dq = dq + jax.lax.dot_general(ds, kblk, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dq
+        share = jax.lax.dot_general(ds, kblk, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        return share if dq is None else dq + share
 
-    n_iter = _n_kv_blocks(causal, qi, bq, bk, q_off, kv_valid, nkb)
-    dq0 = jnp.zeros((bq, d), jnp.float32)
-    dq = jax.lax.fori_loop(jnp.int32(0), n_iter, body, dq0)
-    dq_ref[0] = (dq * _np.float32(scale)).astype(dq_ref.dtype)
+    def tile(kb, mask=None):
+        acc_ref[...] = step(acc_ref[...], 0, bq, kb * bk, bk, mask)
+
+    # the block's rows: q, g [BQ, D] native; lse, delta [BQ, 1]
+    block = (q_ref[0], g_ref[0], lse_ref[0][:, :1], dta_ref[0][:, :1])
+    n_int, n_iter = (jnp.asarray(n, jnp.int32) for n in
+                     _kv_bounds(qi, causal, bq, bk, q_off, kv_valid, nkb))
+    if diag:
+        # the diagonal tiles first, by row strips of sub-tiles
+        for r0, rows, pieces in _diag_strips(diag, bq, bk, False):
+            dq = None
+            for u, lo, n, mask in pieces:
+                dq = step(dq, r0, rows, (n_int + u) * bk + lo, n, mask)
+            acc_ref[pl.ds(r0, rows), :] = dq
+    else:
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    jax.lax.fori_loop(jnp.int32(0), n_int, lambda kb, _: tile(kb), None)
+    if diag is None:        # diagonal / cut tiles masked whole
+        jax.lax.fori_loop(
+            n_int, n_iter,
+            lambda kb, _: tile(kb, lambda s: _mask_scores(
+                s, causal, qi, kb, bq, bk, q_off, kv_valid)), None)
+    dq_ref[0] = (acc_ref[...] * _np.float32(scale)).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
-                    drop_rate=0.0):
+                    diag, drop_rate=0.0):
     """dk/dv: each program owns one k/v block, streams q blocks."""
+    *refs, dk_acc, dv_acc = refs            # dk, dv in float32
     if drop_rate:
         seed_ref, refs = refs[-3], refs[:-3] + refs[-2:]
     if has_kmask:
@@ -492,55 +743,75 @@ def _bwd_dkv_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
         q_ref, k_ref, v_ref, g_ref, lse_ref, dta_ref, dk_ref, dv_ref = refs
     ki = pl.program_id(1)
     bh_row = pl.program_id(0) if drop_rate else None   # see _fwd_kernel note
-    kblk = k_ref[0]                                            # [BK, D] native
-    vblk = v_ref[0]
     nqb = q_ref.shape[1] // bq
-    d = kblk.shape[-1]
-    if has_kmask:
-        km = kmask_ref[0, :, pl.ds(ki * bk, bk)]               # [1, BK]
+    km = kmask_ref[0, :, pl.ds(ki * bk, bk)] if has_kmask else None  # [1,BK]
+    if kv_valid is not None:
+        # a cut k/v block: its padded keys dropped by one additive row
+        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        cut = jnp.where(k_pos < kv_valid, _np.float32(0), _NEG_INF)
+        km = cut if km is None else km + cut
 
-    def body(qb, carry):
+    def step(carry, q0, n, c0, keys, mask=None):
+        """The share of the q rows [q0, q0 + n) in dk/dv of the block's keys
+        [c0, c0 + keys), added to ``carry`` (None: the keys' first share)."""
         # native-dtype MXU operands, f32 accumulation (see _fwd_kernel
         # note); softmax scale folded into the f32 score and the final dk
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * bq, bq), :]                    # [BQ, D]
-        g = g_ref[0, pl.ds(qb * bq, bq), :]
-        lse = lse_ref[0, pl.ds(qb * bq, bq), :][:, :1]         # [BQ, 1]
-        delta = dta_ref[0, pl.ds(qb * bq, bq), :][:, :1]
+        rows, cols = pl.ds(q0, n), slice(c0, c0 + keys)
+        q, g = q_ref[0, rows, :], g_ref[0, rows, :]               # [n, D]
+        lse = lse_ref[0, rows, :][:, :1]                          # [n, 1]
+        delta = dta_ref[0, rows, :][:, :1]
+        kblk, vblk = block[0][cols], block[1][cols]               # [keys, D]
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * _np.float32(scale)
-        if has_kmask:
-            s = s + km
-        s = _mask_scores(s, causal, qb, ki, bq, bk, q_off, kv_valid)
-        p = jnp.exp(s - lse)                                   # [BQ, BK] f32
+        if km is not None:
+            s = s + km[:, cols]
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse)                                   # [n, keys] f32
         if drop_rate:
-            mult = _drop_mult(p.shape, seed_ref[0], bh_row,
-                              qb, ki, bq, bk, drop_rate)
+            mult = _drop_mult(p.shape, seed_ref[0], bh_row, q0,
+                              ki * bk + c0, drop_rate)
             pd = p * mult                    # dropped probs: out = pd @ v
         else:
             pd = p
-        dv = dv + jax.lax.dot_general(pd.astype(g.dtype), g,
-                                      (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
+        dv = jax.lax.dot_general(pd.astype(g.dtype), g,
+                                 (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(g, vblk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if drop_rate:
             dp = dp * mult
         ds = (p * (dp - delta)).astype(q.dtype)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
+        dk = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        return (dk, dv) if carry is None else (carry[0] + dk, carry[1] + dv)
 
-    # causal: the first q block whose rows can attend to this k block
-    start = (jnp.maximum(jnp.int32(0), (ki * bk - q_off) // bq)
-             if causal else jnp.int32(0))
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start, jnp.asarray(nqb, jnp.int32), body,
-                               (dk0, dv0))
-    dk_ref[0] = (dk * _np.float32(scale)).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    def tile(qb, mask=None):
+        dk_acc[...], dv_acc[...] = step((dk_acc[...], dv_acc[...]), qb * bq,
+                                        bq, 0, bk, mask)
+
+    block = (k_ref[0], v_ref[0])                           # [BK, D] native
+    start, first_int = (jnp.asarray(n, jnp.int32) for n in
+                        _q_bounds(ki, causal, bq, bk, q_off, nqb))
+    if diag:
+        # the diagonal tiles first, by strips of keys
+        for c0, keys, pieces in _diag_strips(diag, bq, bk, True):
+            carry = None
+            for u, lo, n, mask in pieces:
+                carry = step(carry, (start + u) * bq + lo, n, c0, keys, mask)
+            dk_acc[pl.ds(c0, keys), :], dv_acc[pl.ds(c0, keys), :] = carry
+    else:
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+    if diag is None:        # diagonal tiles masked whole
+        jax.lax.fori_loop(
+            start, first_int,
+            lambda qb, _: tile(qb, lambda s: _mask_scores(
+                s, causal, qb, ki, bq, bk, q_off, None)), None)
+    jax.lax.fori_loop(first_int, jnp.int32(nqb), lambda qb, _: tile(qb), None)
+    dk_ref[0] = (dk_acc[...] * _np.float32(scale)).astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def bwd_broadcasts(out, lse, g):
@@ -580,6 +851,10 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
     _BQ, _BK = bq, bk            # local block sizes for the specs below
     scale = 1.0 / math.sqrt(d)
     has_kmask = kmask is not None
+    geometry = (causal, _BQ, _BK, q_off, kv_valid, s_q // _BQ, s_k // _BK)
+    plan = causal_tile_plan(s_q, s_k, _BQ, _BK, q_off, kv_valid, causal)
+    _count_tiles('flash_bwd_dq', bh, plan['dq'])
+    _count_tiles('flash_bwd_dkv', bh, plan['dkv'])
 
     full = lambda b, i: (b, _np.int32(0), _np.int32(0))
     kvfull = lambda b, i: (b // groups, _np.int32(0), _np.int32(0))
@@ -610,11 +885,13 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, scale=scale,
                           bq=_BQ, bk=_BK, q_off=q_off, kv_valid=kv_valid,
-                          has_kmask=has_kmask, drop_rate=drop_rate),
+                          has_kmask=has_kmask, drop_rate=drop_rate,
+                          diag=_diag_rels(False, *geometry)),
         grid=(bh, s_q // _BQ),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, _BQ, d), blk),
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((_BQ, d), jnp.float32)],
         interpret=_INTERPRET,
         name='flash_bwd_dq',
     )(*dq_args)
@@ -637,7 +914,8 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale,
                           bq=_BQ, bk=_BK, q_off=q_off, kv_valid=kv_valid,
-                          has_kmask=has_kmask, drop_rate=drop_rate),
+                          has_kmask=has_kmask, drop_rate=drop_rate,
+                          diag=_diag_rels(True, *geometry)),
         grid=(bh, s_k // _BK),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -648,6 +926,8 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
             jax.ShapeDtypeStruct((bh, s_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s_k, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((_BK, d), jnp.float32),
+                        pltpu.VMEM((_BK, d), jnp.float32)],
         interpret=_INTERPRET,
         name='flash_bwd_dkv',
     )(*dkv_args)

@@ -70,8 +70,9 @@ def _cases(devices):
             return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
         return f
 
-    def flash(seq, d, kv_heads, dropout):
-        q, kv = S((2, seq, 8, d), bf16), S((2, seq, kv_heads, d), bf16)
+    def flash(seq, d, kv_heads, dropout, seq_q=None):
+        q = S((2, seq_q or seq, 8, d), bf16)
+        kv = S((2, seq, kv_heads, d), bf16)
         return lambda: text(flash_fwd_bwd(dropout), q, kv, kv)
 
     def pool(d, int8, sharding=one):
@@ -108,6 +109,12 @@ def _cases(devices):
         'flash_s2048_d128': flash(2048, 128, 8, 0.0),
         'flash_gqa': flash(1024, 64, 2, 0.0),
         'flash_dropout': flash(1024, 64, 8, 0.1),
+        # what the causal tile schedule makes new (PR 25): a diagonal that
+        # does not start at the first key, blocks that fall to 128 rows,
+        # and a cut last k/v block
+        'flash_cross_q512_k1024': flash(1024, 64, 8, 0.0, seq_q=512),
+        'flash_s1152_blocks_128': flash(1152, 64, 8, 0.0),
+        'flash_s1100_kv_valid': flash(1100, 64, 8, 0.0),
         'paged_bf16_d64': paged(64, False),
         'paged_int8_d64': paged(64, True),
         'paged_bf16_d128': paged(128, False),
@@ -177,6 +184,8 @@ def compiled():
     ('flash_s2048_d128', 3),
     ('flash_gqa', 3),
     ('flash_dropout', 3),              # refused before PR 21: u32->f32 cast
+    ('flash_cross_q512_k1024', 3), ('flash_s1152_blocks_128', 3),
+    ('flash_s1100_kv_valid', 3),
     ('paged_bf16_d64', 1), ('paged_int8_d64', 1),
     ('paged_bf16_d128', 1), ('paged_int8_d128', 1),
     ('decode_bf16', 1), ('decode_int8', 1),

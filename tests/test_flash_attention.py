@@ -748,3 +748,161 @@ def test_dropout_gqa_grad_parity():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
+
+
+# ---------------------------------------------------------------------------
+# The causal tile schedule (PR 25): interior tiles run mask-free, diagonal
+# tiles in sub-tiles (skipped / masked / mask-free), cut tiles masked whole.
+# ---------------------------------------------------------------------------
+
+# name: (s_q, s_k, bq, bk, causal, h, h_kv, dropout, key-padding mask)
+_SCHEDULE_CASES = {
+    # 3 q blocks: interior and diagonal (2 x 2 sub-tiles) tiles in all three
+    'causal_self': (768, 768, 256, 256, True, 2, 2, 0.0, False),
+    # a q block spans two k/v blocks: dkv's diagonal differs by program
+    'bq_gt_bk': (512, 512, 256, 128, True, 2, 2, 0.0, False),
+    # the diagonal starts at key 256 (aligned-ends causal cross-attention)
+    'cross_q_off': (256, 768, 256, 256, True, 2, 2, 0.0, False),
+    # q_off 220 no block multiple, both lengths padded, kv_valid set
+    'cross_q_off_ragged': (200, 420, 256, 128, True, 2, 2, 0.0, False),
+    # causal self-attention with a cut last k/v block
+    'kv_valid': (600, 600, 256, 256, True, 2, 2, 0.0, False),
+    'kv_valid_noncausal': (300, 300, 256, 128, False, 2, 2, 0.0, False),
+    'key_mask': (512, 512, 256, 256, True, 2, 2, 0.0, True),
+    'key_mask_ragged': (300, 300, 128, 128, True, 2, 2, 0.0, True),
+    'dropout': (512, 512, 256, 256, True, 2, 2, 0.25, False),
+    'dropout_cross': (256, 512, 256, 256, True, 2, 2, 0.25, False),
+    'gqa': (512, 512, 256, 256, True, 4, 2, 0.0, False),
+    'noncausal': (512, 512, 256, 256, False, 2, 2, 0.0, False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_SCHEDULE_CASES))
+def test_tile_schedule_parity(case, monkeypatch):
+    """Forward and all three gradients against _jnp_attention for every
+    tile class in every kernel (sub-tiles of 128 so that small blocks
+    still split)."""
+    s_q, s_k, bq, bk, causal, h, h_kv, drop, masked = _SCHEDULE_CASES[case]
+    monkeypatch.setattr(fa, '_SUB', 128)
+    monkeypatch.setattr(fa, '_pick_blocks', lambda a, b: (bq, bk))
+    ks = jax.random.split(jax.random.PRNGKey(len(case) + s_q), 4)
+    q = jax.random.normal(ks[0], (1, s_q, h, 64))
+    k = jax.random.normal(ks[1], (1, s_k, h_kv, 64))
+    v = jax.random.normal(ks[2], (1, s_k, h_kv, 64))
+    tgt = jax.random.normal(ks[3], q.shape)
+    mask = (jnp.arange(s_k)[None, :] < s_k - 37) if masked else None
+    seed = 11 if drop else None
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum((attn(q, k, v) - tgt) ** 2)
+
+    got, g_got = jax.value_and_grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, mask=mask, dropout_rate=drop,
+        dropout_seed=seed)), (0, 1, 2))(q, k, v)
+    want, g_want = jax.value_and_grad(loss(lambda q, k, v: fa._jnp_attention(
+        q, k, v, causal, mask, drop_rate=drop, seed=seed)),
+        (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b, name in zip(g_got, g_want, 'qkv'):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=5e-4, err_msg=f'd{name} ({case})')
+
+
+def _brute_plan(s_q, s_k, bq, bk, q_off, kv_valid, causal, plan):
+    """The plan's numbers found by LOOKING at the dense keep-mask."""
+    r, c = np.arange(s_q)[:, None], np.arange(s_k)[None, :]
+    under = (c <= r + q_off) if causal else np.ones((s_q, s_k), bool)
+    valid = np.broadcast_to(c < (s_k if kv_valid is None else kv_valid),
+                            (s_q, s_k))
+
+    def look(m):
+        return 'all' if m.all() else ('mixed' if m.any() else 'none')
+
+    out = {}
+    for variant in ('fwd', 'dq', 'dkv'):
+        tr, tc = plan[variant]['sub']
+        n = dict.fromkeys(('interior', 'diagonal', 'padded', 'sub_skipped',
+                           'sub_masked', 'sub_free'), 0)
+        for qb in range(s_q // bq):
+            for kb in range(s_k // bk):
+                rows = slice(qb * bq, (qb + 1) * bq)
+                cols = slice(kb * bk, (kb + 1) * bk)
+                diag, cut = look(under[rows, cols]), look(valid[rows, cols])
+                if diag == 'none' or cut == 'none':
+                    continue                       # never visited
+                if variant == 'dkv':               # a cut block adds a row
+                    cls = ('diagonal' if diag == 'mixed' else
+                           'padded' if cut == 'mixed' else 'interior')
+                else:                            # a cut tile: masked whole
+                    cls = ('padded' if cut == 'mixed' else
+                           'diagonal' if diag == 'mixed' else 'interior')
+                n[cls] += 1
+                if cls != 'diagonal':
+                    continue
+                tile = under[rows, cols]
+                for i in range(bq // tr):
+                    for j in range(bk // tc):
+                        seen = look(tile[i * tr:(i + 1) * tr,
+                                         j * tc:(j + 1) * tc])
+                        n[{'all': 'sub_free', 'none': 'sub_skipped',
+                           'mixed': 'sub_masked'}[seen]] += 1
+        out[variant] = dict(n, sub=(tr, tc))
+    return out
+
+
+@pytest.mark.parametrize('sub', [128, 256])
+def test_causal_tile_plan_matches_dense_mask(sub, monkeypatch):
+    monkeypatch.setattr(fa, '_SUB', sub)
+    grid = [
+        # s_q, s_k, bq, bk, q_off, kv_valid, causal
+        (1024, 1024, 512, 512, 0, None, True),
+        (2048, 2048, 512, 512, 0, None, True),
+        (1024, 1024, 256, 256, 0, None, True),
+        (1024, 1024, 512, 128, 0, None, True),
+        (1152, 1152, 128, 128, 0, None, True),
+        (1152, 1152, 128, 128, 0, 1100, True),
+        (512, 1024, 512, 512, 512, None, True),
+        (512, 1024, 256, 256, 512, None, True),
+        (256, 512, 256, 128, 220, 420, True),
+        (256, 640, 256, 128, 384, None, True),
+        (384, 384, 128, 128, 37, None, True),
+        (1024, 1024, 512, 512, 0, None, False),
+        (384, 384, 128, 128, 0, 300, False),
+        (512, 512, 256, 256, 0, 500, True),
+    ]
+    for s_q, s_k, bq, bk, q_off, kv_valid, causal in grid:
+        plan = fa.causal_tile_plan(s_q, s_k, bq, bk, q_off, kv_valid, causal)
+        assert plan == _brute_plan(s_q, s_k, bq, bk, q_off, kv_valid, causal,
+                                   plan), (s_q, s_k, bq, bk, q_off, kv_valid)
+        assert plan['fwd'] == plan['dq']
+    # the two benchmark cells, per head (ISSUE 25)
+    for s, interior, diagonal in ((1024, 1, 2), (2048, 6, 4)):
+        for n in fa.causal_tile_plan(s, s, 512, 512).values():
+            assert (n['interior'], n['diagonal'], n['padded']) == (
+                interior, diagonal, 0)
+            assert n['sub'] == (sub, sub) and n['sub_skipped'] > 0
+
+
+def test_tile_counters_hold_the_plan_after_one_traced_call():
+    from paddle_tpu import observability as obs
+
+    def value(name, kernel):
+        c = obs.find(name, {'kernel': kernel})
+        return c.value if c is not None else 0
+
+    names = ('flash.tiles_unmasked_total', 'flash.tiles_masked_total')
+    kernels = {'flash_fwd': 'fwd', 'flash_bwd_dq': 'dq',
+               'flash_bwd_dkv': 'dkv'}
+    before = {(n, k): value(n, k) for n in names for k in kernels}
+    q, k, v = _rand_qkv(jax.random.PRNGKey(5), 2, 1024, 3, 64)
+    step = jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True).sum(),
+        (0, 1, 2)))
+    step.lower(q, k, v)                            # traced, never run
+    plan = fa.causal_tile_plan(1024, 1024, *fa._pick_blocks(1024, 1024))
+    rows = 2 * 3                                   # B x H attention rows
+    for kernel, variant in kernels.items():
+        n = plan[variant]
+        got = [value(name, kernel) - before[name, kernel] for name in names]
+        assert got == [rows * n['interior'],
+                       rows * (n['diagonal'] + n['padded'])], kernel
