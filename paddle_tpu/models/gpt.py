@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.tensor import Tensor
 from ..nn.layer_base import Layer, Parameter
 from ..ops.weight_only import wo_lm_head, wo_matmul, wo_take
+from . import family as _family
 
 
 def validate_gqa(num_heads, num_kv_heads, mp):
@@ -749,6 +750,13 @@ def forward_with_cache(params, tokens, cache, pos, config: GPTConfig,
     x = _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
     logits = wo_lm_head(x, params['wte'], cdt)
     return logits, {'k': k_new, 'v': v_new}
+
+
+# the pair the serving engine asks for (models/family.py)
+_family.register(GPTConfig, _family.GenerationFamily(
+    name='gpt', init_pool=init_paged_kv_cache,
+    forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
+    quantize_decode_params=quantize_decode_params))
 
 
 def _sample(logits, temperature, top_k, top_p=None, key=None):

@@ -16,8 +16,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.weight_only import is_weight_only, wo_lm_head, wo_matmul, wo_take
 from ..parallel.moe import moe_ffn
+from . import family as _family
 from .gpt import (_layer_norm, _attention, _block_qkv, _mm,
-                  cached_attention, validate_gqa)
+                  cached_attention, init_paged_kv_cache, validate_gqa)
 
 
 def _c(w, cdt):
@@ -327,6 +328,12 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
         x = x[:, -1:]
     x = _layer_norm(x, params['lnf_g'], params['lnf_b']).astype(cdt)
     return wo_lm_head(x, params['wte'], cdt), {'k': k_new, 'v': v_new}
+
+
+_family.register(MoEConfig, _family.GenerationFamily(
+    name='moe_gpt', init_pool=init_paged_kv_cache,
+    forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
+    quantize_decode_params=quantize_decode_params))
 
 
 def make_decode_fns(config):

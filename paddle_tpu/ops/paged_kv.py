@@ -173,8 +173,9 @@ def flat_write_indices(page_table, pos, n_rows, page_size, valid=None):
 def paged_write(pages, rows, page_table, pos, valid=None):
     """Scatter new KV rows into the (single-layer) page pool.
 
-    ``pages``: [N, page_size, H, D] (or an int8 bank of that shape);
-    ``rows``: [B, T, H, D] fresh k or v rows for absolute positions
+    ``pages``: [N, page_size, H, D] (or an int8 bank of that shape), or a
+    plane with no heads axis, [N, page_size, W];
+    ``rows``: [B, T, H, D] (or [B, T, W]) fresh rows for absolute positions
     ``pos[b] + j``; ``page_table``: [B, P_max]; ``valid``: [B] or None
     (rows past it go to the trash page). Returns the updated pool.
 
@@ -192,11 +193,15 @@ def paged_write(pages, rows, page_table, pos, valid=None):
         sc = sc.at[idx].set(scale.reshape(b * t, h))
         return {'int8': int8.reshape(n, ps, h, d),
                 'scale': sc.reshape(n, ps, h)}
-    n, ps, h, d = pages.shape
+    # a plane is [N, page_size, ...]: heads and head size for a K or V
+    # plane, one row of values for a plane without a heads axis (a latent
+    # cache's)
+    n, ps = pages.shape[:2]
+    row = pages.shape[2:]
     idx = flat_write_indices(page_table, pos, t, ps, valid).reshape(-1)
-    flat = pages.reshape(n * ps, h, d)
-    flat = flat.at[idx].set(rows.reshape(b * t, h, d).astype(pages.dtype))
-    return flat.reshape(n, ps, h, d)
+    flat = pages.reshape((n * ps,) + row)
+    flat = flat.at[idx].set(rows.reshape((b * t,) + row).astype(pages.dtype))
+    return flat.reshape(pages.shape)
 
 
 def copy_page(pool, src, dst):

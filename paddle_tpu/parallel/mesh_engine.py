@@ -132,13 +132,14 @@ class MeshContext:
         (gpt vs moe_gpt picked off the config type)."""
         return self.place(params, model_logical_axes(config))
 
-    def place_pool(self, pool):
-        """Shard the paged-KV pool planes along the heads axis; the page
-        tables and the allocator stay host-side and mesh-agnostic. int8
-        pools ({'int8','scale'} banks) shard both planes — the per-row
-        scale drops the head_dim axis but keeps the heads dim."""
-        sh = self.pool_sharding()
-        scale_sh = self.sharding(POOL_LOGICAL_AXES[:-1], label='kv_scale')
+    def place_pool(self, pool, logical_axes=POOL_LOGICAL_AXES):
+        """Place the page pool's planes by ``logical_axes`` (the model
+        family's; the K/V pool shards its heads axis); the page tables and
+        the allocator stay host-side and mesh-agnostic. int8 pools
+        ({'int8','scale'} banks) place both planes — the per-row scale
+        drops the last axis."""
+        sh = self.sharding(logical_axes, label='kv_pool')
+        scale_sh = self.sharding(logical_axes[:-1], label='kv_scale')
 
         def put(v):
             if isinstance(v, dict):
@@ -157,12 +158,13 @@ class MeshContext:
 
 
 def model_logical_axes(config):
-    """The LOGICAL_AXES tree for a model config's family."""
-    if 'moe' in type(config).__name__.lower():
-        from ..models import moe_gpt
-        return moe_gpt.LOGICAL_AXES
-    from ..models import gpt
-    return gpt.LOGICAL_AXES
+    """The LOGICAL_AXES tree of a model config's family."""
+    from ..models import family
+    fam = family.family_of(config)
+    if fam.logical_axes is None:
+        raise ValueError(f'the {fam.name} family has no logical axes: it '
+                         f'serves on one chip')
+    return fam.logical_axes
 
 
 def resolve(mesh, mp=None, devices=None):

@@ -16,7 +16,11 @@ the whole workload:
  - ``step``: all ``num_slots`` rows advance one token — inactive slots
    decode garbage into the trash page and their sample is discarded.
 
-KV state lives in a paged pool (``ops/paged_kv.py``): fixed-size pages
+What a page holds is the model family's (``models/family.py``): the
+engine asks the family of the config for its pool (a dict of planes whose
+axis 1 is pages: K and V a head for ``gpt``, one latent row for
+``latent_moe``) and hands it back whole to the family's cached forward.
+State lives in that paged pool (``ops/paged_kv.py``): fixed-size pages
 in one shared buffer, a per-slot page table, and a host-side free-list
 allocator, so slot occupancy — not worst-case sequence length — bounds
 HBM. Pages are allocated lazily at each page boundary; on exhaustion the
@@ -65,6 +69,7 @@ import numpy as np
 
 from .. import fault
 from .. import observability as _obs
+from ..models import family as _family
 from ..models import gpt as _gpt
 from ..ops import paged_kv as _pkv
 from .errors import DeadlineExceededError, EngineClosedError, QueueFullError
@@ -245,10 +250,18 @@ class _Slot:
                                         # instead of running prefill
 
 
+def _with_counts(tokens, counts):
+    """The sampled tokens with a family's counts (int32) behind them, so
+    that both come to the host in the step's one read."""
+    if counts is None:
+        return tokens
+    return jnp.concatenate([tokens, counts.astype(tokens.dtype)])
+
+
 def _resolve_generation_model(net, config, forward_fn):
     """Accept a GPTForCausalLM-style Layer (has .config + _params) or a
-    (params, config) functional pair; infer the forward fn from the config
-    family when not given."""
+    (params, config) functional pair. -> (params, config, the config's
+    model family, the cached forward: the family's unless one is given)."""
     if config is None:
         cfg = getattr(net, 'config', None)
         if cfg is None:
@@ -261,14 +274,9 @@ def _resolve_generation_model(net, config, forward_fn):
             params = net._params()
     else:
         params, cfg = net, config
-    if forward_fn is None:
-        if 'moe' in type(cfg).__name__.lower():
-            from ..models import moe_gpt
-            forward_fn = moe_gpt.forward_with_cache
-        else:
-            forward_fn = _gpt.forward_with_cache
+    family = _family.family_of(cfg)
     params = jax.tree_util.tree_map(jnp.asarray, params)
-    return params, cfg, forward_fn
+    return params, cfg, family, forward_fn or family.forward_with_cache
 
 
 class GenerationEngine:
@@ -296,18 +304,19 @@ class GenerationEngine:
             raise ValueError(
                 f"GenerationEngine precision must be None/'float32'/"
                 f"'int8_wo', got {precision!r}")
-        params, cfg, fwd = _resolve_generation_model(net, config, forward_fn)
+        params, cfg, family, fwd = _resolve_generation_model(
+            net, config, forward_fn)
+        self._family = family
         if precision == 'int8_wo':
             from ..ops.weight_only import is_weight_only
-            if not is_weight_only(params.get('wte')):
-                # family-matched snapshot (qkv/proj/mlp/wte int8, per-output-
-                # channel scales); a model already snapshot (e.g. via
+            if family.quantize_decode_params is None:
+                raise ValueError(
+                    f"the {family.name} family has no int8_wo snapshot")
+            if not any(is_weight_only(v) for v in params.values()):
+                # the family's snapshot (int8 matrices, per-output-channel
+                # scales); a model already snapshot (e.g. via
                 # enable_int8_decode) passes through untouched
-                if 'moe' in type(cfg).__name__.lower():
-                    from ..models import moe_gpt as _fam
-                else:
-                    _fam = _gpt
-                params = _fam.quantize_decode_params(params)
+                params = family.quantize_decode_params(params)
         # mesh-sharded replica (mp=N): ONE SPMD program over N chips.
         # Params are placed by the logical-axis rules table, the forward
         # pins the KV pool to the kv_heads layout, and everything else —
@@ -367,6 +376,10 @@ class GenerationEngine:
         if prefix_cache is None:
             prefix_cache = (prefix_cache_pages is not None
                             or _env_int(ENV_PREFIX, 0) > 0)
+        if prefix_cache and not family.tail_prefill:
+            raise ValueError(
+                f'the {family.name} family prefills from row 0 only: it '
+                f'cannot read a cached prefix, so no prefix cache')
         self._prefix = (PrefixCache(self._alloc, ps, prefix_cache_pages)
                         if prefix_cache else None)
         self._slots = [None] * self.num_slots
@@ -401,12 +414,15 @@ class GenerationEngine:
                           if telemetry_port is not None else _obs.NULL_SERVER)
 
     def _init_pool(self):
-        """Fresh paged-KV pool, head-sharded over the mesh when one is
-        active (the allocator and page tables stay host-side either way)."""
-        pool = _gpt.init_paged_kv_cache(self.config, self.num_pages,
-                                        self.page_size)
+        """Fresh page pool, as the model family makes it: a dict of planes
+        whose axis 1 is pages, opaque to the engine. Under a mesh it is
+        placed by the family's pool axes (the allocator and page tables
+        stay host-side either way)."""
+        pool = self._family.init_pool(self.config, self.num_pages,
+                                      self.page_size)
         if self._mesh_ctx is not None:
-            pool = self._mesh_ctx.place_pool(pool)
+            pool = self._mesh_ctx.place_pool(
+                pool, self._family.pool_logical_axes)
         return pool
 
     def _readiness_probe(self):
@@ -517,8 +533,8 @@ class GenerationEngine:
             # earlier sequence. ONE executable serves cold prefills
             # (start=0) and cached-prefix tails alike: start is traced,
             # so prefix-cache hits never trace or compile anything new.
-            cache = {'k': pool['k'], 'v': pool['v'],
-                     'page_table': page_table, 'valid': valid, 'tail': True}
+            cache = dict(pool, page_table=page_table, valid=valid,
+                         tail=True)
             pos0 = start.astype(jnp.int32)
             logits, cache = fwd(params, prompt, cache, pos0, cfg,
                                 last_only=True)
@@ -529,16 +545,17 @@ class GenerationEngine:
             # the logits the token was chosen from stay on the device in
             # the compute dtype; the host reads them, and widens them to
             # float32, only for a request that asked (want_logits)
-            return tok, row, {'k': cache['k'], 'v': cache['v']}
+            return (_with_counts(tok, cache.get('counts')), row,
+                    {k: cache[k] for k in pool})
 
         def step(params, pool, tok, pos, page_table, seeds):
             self._trace_count += 1
-            cache = {'k': pool['k'], 'v': pool['v'],
-                     'page_table': page_table}
+            cache = dict(pool, page_table=page_table)
             logits, cache = fwd(params, tok[:, None], cache, pos, cfg)
             rows = logits[:, 0]
             nxt = sample_rows(rows, seeds, pos)
-            return nxt, rows, {'k': cache['k'], 'v': cache['v']}
+            return (_with_counts(nxt, cache.get('counts')), rows,
+                    {k: cache[k] for k in pool})
 
         # under a mesh the paged kernel shards over it (ops/mesh_kernel)
         from ..ops import mesh_kernel
@@ -876,23 +893,25 @@ class GenerationEngine:
 
         def dev():
             fault.inject('gen.step')
-            tok, lg, pool = pf(self._params, self._pool,
-                               jnp.asarray(prompt), jnp.asarray(startv),
-                               jnp.asarray(valid), jnp.asarray(table),
-                               jnp.asarray(seed))
+            tok, lg, pool = pf(
+                self._params, self._pool, jnp.asarray(prompt),
+                jnp.asarray(startv), jnp.asarray(valid), jnp.asarray(table),
+                jnp.asarray(seed))
             row = (np.asarray(lg)[0].astype(np.float32)
                    if req.want_logits else None)
-            return int(np.asarray(tok)[0]), row, pool
+            tok = np.asarray(tok)
+            return int(tok[0]), row, pool, tok[1:]
 
         req.rec.note('prefill', slot=idx, prompt_len=t0, start=start)
         try:
             with _obs.span('gen.prefill', slot=idx, prompt_len=t0,
                            req_id=req.rec.rid):
-                tok, row, pool = self._breaker.call(dev)
+                tok, row, pool, counts = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
         self._pool = pool
+        self._note_counts(counts, 'prefill')
         self._h['prefill'].observe(1e3 * (time.perf_counter() - wall0))
         self._n['prefills'] += 1
         with self._cv:
@@ -901,6 +920,12 @@ class GenerationEngine:
             if self._slot_finished(slot, tok):
                 self._finish_slot_locked(idx)
             self._update_gauges_locked()
+
+    def _note_counts(self, counts, phase):
+        """What a family counted inside a step (routed rows, say), to its
+        own counters."""
+        if len(counts) and self._family.note_counts is not None:
+            self._family.note_counts(counts, phase)
 
     def _decode_step(self):
         s = self.num_slots
@@ -932,21 +957,25 @@ class GenerationEngine:
 
         def dev():
             fault.inject('gen.step')
-            nxt, lg, pool = st(self._params, self._pool, jnp.asarray(tok),
-                               jnp.asarray(pos), jnp.asarray(table),
-                               jnp.asarray(seeds))
-            # ONE host readback per iteration for every slot; the logits
-            # follow only when a request in a slot asked for them
-            return np.asarray(nxt), (np.asarray(lg) if want else None), pool
+            nxt, lg, pool = st(
+                self._params, self._pool, jnp.asarray(tok), jnp.asarray(pos),
+                jnp.asarray(table), jnp.asarray(seeds))
+            # ONE host readback per iteration for every slot (a family's
+            # counts ride behind the tokens in it); the logits follow only
+            # when a request in a slot asked for them
+            nxt = np.asarray(nxt)
+            return (nxt[:s], (np.asarray(lg) if want else None), pool,
+                    nxt[s:])
 
         try:
             with _obs.span('gen.decode_step', slots=len(active),
                            req_ids=rids):
-                nxt, rows, pool = self._breaker.call(dev)
+                nxt, rows, pool, counts = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
         self._pool = pool
+        self._note_counts(counts, 'decode')
         self._h['step'].observe(1e3 * (time.perf_counter() - wall0))
         self._n['steps'] += 1
         with self._cv:

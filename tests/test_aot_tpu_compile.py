@@ -25,6 +25,7 @@ one compilation per train step.
 import importlib
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -104,7 +105,39 @@ def _cases(devices):
     heads = NamedSharding(mp4, P(None, None, 'mp', None))
     rep = NamedSharding(mp4, P())
 
+    # the latent family's kernels at dots-vlm1-ep16-serve's own shapes
+    # (PR 27): 64 slots of 128 heads against the headless pool, the grouped
+    # expert product at a decode step's and a prefill's tile sizes, and the
+    # prefill's attention at widths 192 / 128 through the flash forward
+    pla = importlib.import_module('paddle_tpu.ops.paged_latent_attention')
+    gmm = importlib.import_module('paddle_tpu.ops.expert_grouped_matmul')
+
+    def latent(width):
+        return lambda: text(
+            lambda q, pool, pt, pos: pla.paged_latent_attention(
+                q, pool, pt, pos, 2, scale=0.135, rank=512),
+            S((64, 128, width), bf16), S((5, 1025, 128, width), bf16),
+            S((64, 16), jnp.int32), S((64,), jnp.int32))
+
+    def grouped(m, tm, k, n):
+        return lambda: text(
+            lambda x, w, te, nt: gmm.expert_grouped_matmul(x, w, te, nt,
+                                                           tm=tm),
+            S((m, k), bf16), S((16, k, n), bf16), S((m // tm,), jnp.int32),
+            S((1,), jnp.int32))
+
     return {
+        'latent_decode_w640': latent(640),
+        'latent_decode_w576': latent(576),
+        'grouped_decode_up': grouped(768, 16, 7168, 2048),
+        'grouped_decode_down': grouped(768, 16, 2048, 7168),
+        'grouped_prefill_up': grouped(10240, 128, 7168, 2048),
+        'grouped_prefill_down': grouped(10240, 128, 2048, 7168),
+        'latent_prefill_192_128': lambda: text(
+            lambda q, k, v: pla.latent_prefill_attention(q, k, v,
+                                                         scale=0.135),
+            S((1, 1024, 128, 192), bf16), S((1, 1024, 128, 192), bf16),
+            S((1, 1024, 128, 128), bf16)),
         'flash_s1024_d64': flash(1024, 64, 8, 0.0),
         'flash_s2048_d128': flash(2048, 128, 8, 0.0),
         'flash_gqa': flash(1024, 64, 2, 0.0),
@@ -148,6 +181,8 @@ def _child():
             text = compile_text()
             out[name] = {
                 'kernels': text.count('tpu_custom_call'),
+                'pool_copies': len(re.findall(
+                    r'= bf16\[5,1025,128,\d+\]\S* copy\(', text)),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -191,7 +226,26 @@ def compiled():
     ('decode_bf16', 1), ('decode_int8', 1),
 ])
 def test_kernel_compiles_for_v5e(compiled, case, kernels):
-    assert compiled[case] == {'kernels': kernels, 'collectives': []}
+    assert compiled[case] == {'kernels': kernels, 'pool_copies': 0,
+                              'collectives': []}
+
+
+@pytest.mark.parametrize('case', [
+    'latent_decode_w640', 'grouped_decode_up', 'grouped_decode_down',
+    'grouped_prefill_up', 'grouped_prefill_down', 'latent_prefill_192_128'])
+def test_latent_family_kernel_compiles_for_v5e(compiled, case):
+    """At the published widths: Mosaic takes the blocks, the grouped
+    product's whole-K weight block fits the fast memory it asks for."""
+    assert compiled[case] == {'kernels': 1, 'pool_copies': 0,
+                              'collectives': []}
+
+
+def test_a_latent_pool_of_whole_lanes_is_not_copied_for_the_kernel(compiled):
+    """Why a pool row is 640 columns and not 576 (models/latent_moe.py
+    ``pool_width``): at 576 the compiler re-tiles the whole pool (0.76 GB)
+    in front of every call of the kernel; at 640 it hands it over."""
+    assert compiled['latent_decode_w576']['pool_copies'] == 1
+    assert compiled['latent_decode_w640']['pool_copies'] == 0
 
 
 def test_flash_under_dp2_mp2_mesh_compiles_for_v5e(compiled):
@@ -208,7 +262,8 @@ def test_paged_decode_under_mp4_mesh_compiles_for_v5e(compiled):
     """The mesh-sharded engine's decode kernel: pool and heads split over
     'mp', page table and positions whole on every chip. Heads stay where
     the pool put them: no page crosses chips."""
-    assert compiled['paged_mp4'] == {'kernels': 1, 'collectives': []}
+    assert compiled['paged_mp4'] == {'kernels': 1, 'pool_copies': 0,
+                                     'collectives': []}
 
 
 # ---------------------------------------------------------------------------
