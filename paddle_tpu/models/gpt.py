@@ -526,7 +526,7 @@ def is_paged(cache):
 
 def init_paged_kv_cache(config, num_pages, page_size):
     """Shared page pool for the continuous-batching decode path:
-    ``{'k','v': [L, num_pages, page_size, H_kv, Dh]}`` (int8 banks with
+    ``{'k','v': [L, num_pages, H_kv, page_size, Dh]}`` (int8 banks with
     ``config.kv_cache_int8``). Pair with a per-slot page table + ``pos``
     vector to form the paged cache ``forward_with_cache`` accepts; the
     dense ``init_kv_cache`` remains the default for ``generate()``."""
@@ -547,11 +547,13 @@ def cached_attention(x, q, k, v, k_cache, v_cache, pos, proj_w, proj_b, cdt,
     ``kv_cache_int8``): fresh rows quantize on write and attention runs
     the int8 flash decode kernel (or a dequantizing fallback).
 
-    Paged mode (``page_table`` not None): the caches are single-layer page
-    pools ``[N, page_size, H_kv, D]`` (or int8 banks), ``pos`` is a [B]
-    i32 vector (slots decode at different depths), and multi-token calls
-    are prefills starting at position 0 per slot. Rows past ``valid[b]``
-    are prompt padding and land in the trash page (ops/paged_kv).
+    Paged mode (``page_table`` not None): the caches are page planes
+    ``[N, H_kv, page_size, D]`` (or int8 banks) — one layer's, or the whole
+    pool's with ``page_table`` offset to the layer's pages
+    (paged_forward_with_cache) — ``pos`` is a [B] i32 vector (slots decode
+    at different depths), and multi-token calls are prefills starting at
+    position 0 per slot. Rows past ``valid[b]`` are prompt padding and
+    reach no page of a sequence (ops/paged_kv).
     ``tail=True`` (static) marks a prefix-cache TAIL prefill: ``pos`` may
     be nonzero per slot and the q rows must attend KV already resident in
     earlier pages, so the fresh-rows causal-flash shortcut is invalid and
@@ -655,6 +657,17 @@ def paged_forward_with_cache(params, tokens, cache, pos, config,
     block body. Returns (logits, cache) with the table/valid passed
     through so the caller's cache pytree keeps one structure.
 
+    The pool is never re-made. The layers' loop carries it whole, as the
+    ``[L * N, ...]`` view of its planes (a bitcast), beside the hidden
+    state; layer ``l`` sees it through ``page_table + l * N``, writes its
+    rows into the carried buffer and attends out of the same buffer. With
+    the pool donated (serving/generation.py) the compiled program holds
+    no copy of the pool or of a layer's plane
+    (tests/test_aot_tpu_compile.py). The planes must not be the scan's
+    scanned inputs and stacked outputs: those are different buffers, so
+    every layer's plane is copied out, into the stack, and the stack onto
+    the donated parameter (35-40 % of busy time, PERF.md, PR 28).
+
     ``partitioner`` (a mesh-bound parallel.Partitioner) makes the trace
     mesh-aware: the KV pool planes are constrained to the ``kv_heads``
     layout on entry AND exit, so GSPMD keeps pages head-sharded across the
@@ -677,7 +690,7 @@ def paged_forward_with_cache(params, tokens, cache, pos, config,
         return jax.lax.with_sharding_constraint(
             plane, partitioner.sharding(POOL_LOGICAL_AXES))
 
-    cache = dict(cache, k=pin_pool(cache['k']), v=pin_pool(cache['v']))
+    pool = {'k': pin_pool(cache['k']), 'v': pin_pool(cache['v'])}
     # STATIC marker set by the prefix-cache tail-prefill path (the engine
     # builds the cache dict in-trace, so a plain bool survives): q rows
     # must attend KV resident in earlier pages, not just the fresh rows
@@ -687,16 +700,25 @@ def paged_forward_with_cache(params, tokens, cache, pos, config,
     x = (wo_take(params['wte'], tokens)
          + jnp.take(params['wpe'], ppos, axis=0)).astype(cdt)
 
-    def scan_body(carry, inp):
-        xx = carry
-        bp, kc, vc = inp
-        xx, kc, vc = block(bp, xx, kc, vc, pos_v, config,
-                           page_table=page_table, valid=valid, tail=tail)
-        return xx, (kc, vc)
+    n_layers, n_pages = jax.tree_util.tree_leaves(pool)[0].shape[:2]
+    flat = jax.tree_util.tree_map(
+        lambda a: a.reshape((n_layers * n_pages,) + a.shape[2:]), pool)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        scan_body, x, (params['blocks'], cache['k'], cache['v']))
-    k_new, v_new = pin_pool(k_new), pin_pool(v_new)
+    def scan_body(carry, inp):
+        xx, kc, vc = carry
+        bp, layer = inp
+        xx, kc, vc = block(bp, xx, kc, vc, pos_v, config,
+                           page_table=page_table + layer * n_pages,
+                           valid=valid, tail=tail)
+        return (xx, kc, vc), None
+
+    (x, k_new, v_new), _ = jax.lax.scan(
+        scan_body, (x, flat['k'], flat['v']),
+        (params['blocks'], jnp.arange(n_layers, dtype=jnp.int32)))
+    new = jax.tree_util.tree_map(
+        lambda a: a.reshape((n_layers, n_pages) + a.shape[1:]),
+        {'k': k_new, 'v': v_new})
+    k_new, v_new = pin_pool(new['k']), pin_pool(new['v'])
     if last_only:
         if valid is not None:
             # per-slot prompt lengths: pick each slot's last REAL row

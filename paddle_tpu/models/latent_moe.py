@@ -17,8 +17,10 @@ engine refuses it a prefix cache.
 
 ``held = (first, count)`` says which routed experts' weights are here; the
 router scores all ``n_routed_experts``. Layers are a list, not a stacked
-scan: five layers of two kinds, and an unrolled step updates the donated
-pool in place where a scan would carry a copy of it.
+scan: five layers of two kinds do not stack. The unrolled step updates
+the donated pool in place; so does a scan that CARRIES the pool (what
+copied it in ``models/gpt.py`` before PR 28 was a scan with the planes
+among its scanned inputs and stacked outputs).
 
 Rotary dims pair half-split ([x1 | x2]); a checkpoint that interleaves
 them loads with those columns of W_qb and W_kva permuted.

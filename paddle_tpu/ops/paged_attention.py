@@ -7,7 +7,10 @@ attends q rows to that paged cache two ways:
  - a Pallas TPU kernel (``_paged_decode_kernel``): grid (B*H, P_max) with
    the flattened page table + per-slot positions riding scalar prefetch,
    so each grid step DMAs exactly the page the table points at — the
-   kernel never materializes the gathered cache. Online-softmax state
+   kernel never materializes the gathered cache, and its wrapper never
+   re-lays the pool: a page is stored head-major (ops/paged_kv.py), so
+   the ``[page_size, D]`` block of one head is read where it lies, for
+   every head size the kernel takes. Online-softmax state
    (acc/m/l) lives in VMEM scratch and persists across the sequential
    page dimension, exactly the "Ragged Paged Attention" structure
    (PAPERS.md arxiv 2604.15464). An int8 variant streams int8 pages with
@@ -52,13 +55,13 @@ _TQ = _fa._TQ_DECODE
 
 def paged_attention_available(q, pages):
     """Kernel path gate. q: [B,T,H,D]; ``pages``: the k page pool
-    [N, page_size, H_kv, D] (pass the bank's ``['int8']`` plane for int8
+    [N, H_kv, page_size, D] (pass the bank's ``['int8']`` plane for int8
     pools). Interpret mode (ops/flash_attention.set_interpret) counts as
     available so CPU tests exercise the kernel."""
     if not _fa._platform_ok():
         return False
     b, t, h, d = (int(x) for x in q.shape)
-    n, ps, h_kv = (int(x) for x in pages.shape[:3])
+    n, h_kv, ps = (int(x) for x in pages.shape[:3])
     if h_kv == 0 or h % h_kv != 0:
         return False
     return (t <= _TQ and ps % 128 == 0 and d in (64, 128, 256)
@@ -118,10 +121,13 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
                               vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
                               scale, ps, tq, p_max, h):
     """int8-page variant: k scale applied to score columns, v scale folded
-    into probability rows (see flash_attention._decode_kernel_int8)."""
+    into probability rows (see flash_attention._decode_kernel_int8). The
+    scales come as the page's [H_kv, ps] block, as stored; the kernel
+    takes its KV head's row."""
     i = pl.program_id(0)
     p = pl.program_id(1)
     pos = pos_ref[i // h]
+    head = pl.ds((i % h) // (h // ks_ref.shape[1]), 1)
 
     @pl.when(p == 0)
     def _init():
@@ -135,7 +141,7 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
     def _compute():
         q = q_ref[0]
         kblk = k_ref[0, 0].astype(q.dtype)             # [ps, D]
-        ksc = ks_ref[0, 0]                             # [1, ps] f32
+        ksc = ks_ref[0, head, :]                       # [1, ps] f32
         s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * _np.float32(scale)
@@ -151,7 +157,7 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         vblk = v_ref[0, 0].astype(q.dtype)
-        vsc = vs_ref[0, 0]                             # [1, ps] f32
+        vsc = vs_ref[0, head, :]                       # [1, ps] f32
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             (pr * vsc).astype(q.dtype), vblk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -168,30 +174,31 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
     """One paged decode kernel over (under a mesh) the block each device
     holds: slots split over 'dp', heads over 'mp' — the pool's own layout
     (ops/paged_kv.POOL_LOGICAL_AXES), so no page moves between devices.
-    pools: planes in pool layout — pages [N, page_size, H_kv, D] and, for
-    int8 banks, their scales [N, page_size, H_kv]."""
+    pools: planes in pool layout — pages [N, H_kv, page_size, D] and, for
+    int8 banks, their scales [N, H_kv, page_size]. N may be every layer's
+    pages, with the table offset to one layer's."""
     b, t, h, d = q.shape
-    ps, h_kv = (int(x) for x in pools[0].shape[1:3])
+    h_kv, ps = (int(x) for x in pools[0].shape[1:3])
     p_max = int(page_table.shape[1])
 
     def core(q, page_table, pos, *pools):
         b, _, h, _ = q.shape                  # this device's slots / heads
-        g = h // pools[0].shape[2]
+        g = h // pools[0].shape[1]
         bh = b * h
         qt = _fa._pad_seq(q.transpose(0, 2, 1, 3).reshape(bh, t, d), _TQ)
-        # a page lands as one block of the [N, H_kv, ...] transpose; the
-        # page id comes straight out of the prefetched table
-        page = lambda i, p, pt, _pos: (pt[(i // h) * p_max + p],
-                                       (i % h) // g, 0, 0)
-        # -> pages [N, H_kv, ps, D], scales [N, H_kv, 1, ps]
-        planes = [x.transpose(0, 2, 1, 3) if x.ndim == 4
-                  else x.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
-                  for x in pools]
+        # nothing is re-laid: a head's rows of a page are one block of the
+        # pool as it is stored, and a bank's scales come a page at a time,
+        # every head's (a block's last two dims are whole tiles). The page
+        # id comes straight out of the prefetched table
+        page_id = lambda i, p, pt: pt[(i // h) * p_max + p]
+        page = lambda i, p, pt, _pos: (page_id(i, p, pt), (i % h) // g, 0, 0)
+        scales = lambda i, p, pt, _pos: (page_id(i, p, pt), 0, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, p_max),
             in_specs=[pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0))]
-            + [pl.BlockSpec((1, 1) + x.shape[2:], page) for x in planes],
+            + [pl.BlockSpec((1, 1) + x.shape[2:], page) if x.ndim == 4
+               else pl.BlockSpec((1,) + x.shape[1:], scales) for x in pools],
             out_specs=pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((_TQ, d), jnp.float32),        # acc
@@ -206,7 +213,7 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
             out_shape=jax.ShapeDtypeStruct((bh, _TQ, d), q.dtype),
             interpret=_fa._INTERPRET,
             name='paged_attention',
-        )(page_table.reshape(-1), pos, qt, *planes)
+        )(page_table.reshape(-1), pos, qt, *pools)
         return out[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
     return mesh_kernel.sharded_call(
@@ -214,12 +221,12 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
         (q, page_table.astype(jnp.int32),
          jnp.asarray(pos, jnp.int32).reshape(-1), *pools),
         (_fa._BSHD, ('batch', None), ('batch',),
-         *((None, None, 'heads') + (None,) * (x.ndim - 3) for x in pools)),
+         *((None, 'heads') + (None,) * (x.ndim - 2) for x in pools)),
         _fa._BSHD, batch=b, heads=(h, h_kv))
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, pos):
-    """Pallas paged decode. q: [B,T,H,D]; pages [N, page_size, H_kv, D];
+    """Pallas paged decode. q: [B,T,H,D]; pages [N, H_kv, page_size, D];
     page_table [B, P_max] i32; pos [B] i32 -> [B,T,H,D]."""
     return _kernel_call(_paged_decode_kernel, q, page_table, pos,
                         [k_pages, v_pages])
@@ -227,8 +234,8 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, pos):
 
 def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos):
     """``paged_flash_decode`` over int8 page pools: banks are
-    ``{'int8': [N, page_size, H_kv, D] int8, 'scale': [N, page_size,
-    H_kv] f32}`` (ops/paged_kv.paged_write rows)."""
+    ``{'int8': [N, H_kv, page_size, D] int8, 'scale': [N, H_kv,
+    page_size] f32}`` (ops/paged_kv.paged_write rows)."""
     return _kernel_call(
         _paged_decode_kernel_int8, q, page_table, pos,
         [k_bank['int8'], v_bank['int8'], k_bank['scale'], v_bank['scale']])
@@ -266,7 +273,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, cdt=None):
     """Decode attention over a paged KV pool; dispatches to the Pallas
     kernel when the shapes/platform allow, else the jnp gather fallback.
 
-    q: [B, T, H, D]; pools: [N, page_size, H_kv, D] arrays or int8 banks;
+    q: [B, T, H, D]; pools: [N, H_kv, page_size, D] arrays or int8 banks;
     page_table: [B, P_max] i32; pos: [B] i32 (first q row's absolute
     position per slot) -> [B, T, H, D]."""
     cdt = q.dtype if cdt is None else cdt

@@ -7,7 +7,7 @@ its worst-case footprint for its whole lifetime even when the sequence is
 PAPERS.md arxiv 2604.15464) breaks the cache into fixed-size pages in one
 shared pool:
 
-    pool      [L, N_pages, page_size, H_kv, Dh]   (k and v each)
+    pool      [L, N_pages, H_kv, page_size, Dh]   (k and v each)
     table     [slots, P_max] int32                (page ids per slot)
 
 so a sequence only pins ``ceil(len/page_size)`` pages and the continuous-
@@ -18,12 +18,23 @@ compiled step as a traced int32 table — page churn never recompiles.
 Conventions shared by every consumer:
 
  - **Page 0 is the trash page.** The allocator never hands it out. Writes
-   that must go nowhere (prompt padding rows past a sequence's valid
-   length, decode rows of inactive slots) are routed to page 0, and
+   that must go nowhere (decode rows of inactive slots, rows past the
+   table's end, and in a plane without a heads axis the prompt padding
+   rows past a sequence's valid length) are routed to page 0, and
    unassigned page-table entries stay 0 — a gather through a fresh table
-   reads zeros, and the attention mask discards those positions anyway.
- - Pages are layer-major so ``lax.scan`` over the layer stack slices the
-   leading dim exactly like the dense cache.
+   reads page 0, and the attention mask discards those positions anyway.
+ - **A page is head-major**: ``[H_kv, page_size, Dh]``, so the rows one
+   head holds in a page are one contiguous ``[page_size, Dh]`` block — the
+   block the paged kernel (ops/paged_attention.py) fetches, straight out
+   of the pool as it is stored. (In rows-major pages, ``[page_size, H_kv,
+   Dh]``, a head's rows lie H_kv * Dh values apart, inside the chip's
+   (sublane, lane) tiles: no block can name them, and the planes have to
+   be transposed for the kernel in every step. PERF.md, PR 28.)
+ - The pool is layer-major and a forward pass never takes a layer's plane
+   out of it: the layers' loop carries the pool whole, viewed as
+   ``[L * N_pages, ...]`` with layer ``l``'s page table offset by
+   ``l * N_pages`` (models/gpt.paged_forward_with_cache), and a write
+   updates the carried buffer in place.
  - int8-KV pools reuse the ``{'int8', 'scale'}`` bank layout of
    ops/weight_only (per-row scales), so the +32% int8 decode win composes.
 """
@@ -41,7 +52,7 @@ TRASH_PAGE = 0   # reserved; see module docstring
 # replicated by rule — the +1 trash page makes the page count indivisible
 # by any mesh degree, so a logical page spans every head-shard and the
 # HOST-side allocator/table machinery below never sees the mesh).
-POOL_LOGICAL_AXES = ('layers', 'kv_pages', None, 'kv_heads', None)
+POOL_LOGICAL_AXES = ('layers', 'kv_pages', 'kv_heads', None, None)
 
 
 def pages_for(n_tokens, page_size):
@@ -52,11 +63,12 @@ def pages_for(n_tokens, page_size):
 def init_paged_pool(num_layers, num_pages, page_size, kv_heads, head_dim,
                     dtype, int8=False):
     """Allocate the shared page pool: ``{'k': pages, 'v': pages}`` with
-    pages ``[L, N, page_size, H_kv, Dh]`` (int8: weight_only banks of the
-    same shape). ``num_pages`` INCLUDES the reserved trash page 0."""
+    pages ``[L, N, H_kv, page_size, Dh]`` (int8: weight_only banks of the
+    same shape, their scales ``[L, N, H_kv, page_size]``). ``num_pages``
+    INCLUDES the reserved trash page 0."""
     if num_pages < 2:
         raise ValueError('num_pages must be >= 2 (page 0 is reserved)')
-    shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
+    shape = (num_layers, num_pages, kv_heads, page_size, head_dim)
     if int8:
         return {'k': init_kv_bank(shape), 'v': init_kv_bank(shape)}
     return {'k': jnp.zeros(shape, dtype), 'v': jnp.zeros(shape, dtype)}
@@ -170,32 +182,75 @@ def flat_write_indices(page_table, pos, n_rows, page_size, valid=None):
     return flat
 
 
-def paged_write(pages, rows, page_table, pos, valid=None):
-    """Scatter new KV rows into the (single-layer) page pool.
+def _write_pages(plane, rows, page_table, pos, valid):
+    """Rows into a head-major plane, a page at a time.
 
-    ``pages``: [N, page_size, H, D] (or an int8 bank of that shape), or a
-    plane with no heads axis, [N, page_size, W];
+    ``plane``: [N, H, page_size, ...]; ``rows``: [B, T, H, ...]. A row of a
+    sequence is H pieces of a page, one a head, and a scatter of single
+    pieces takes the chip 70 ns each (a 1024-row prefill: 57 ms, my chip
+    run, PR 28). So the pages the rows fall in are read, the rows laid
+    over them, and the pages written back whole: T rows from any offset
+    span at most ``(T + page_size - 2) // page_size + 1`` pages. Rows past
+    ``valid`` are not written at all; a page past the table's end is the
+    trash page. The pages a sequence writes are its own (the allocator's,
+    or the prefix cache's copy-on-write), so laying the old rows back
+    changes nothing anybody reads."""
+    ps = int(plane.shape[2])
+    b, t = rows.shape[:2]
+    p_max = int(page_table.shape[1])
+    npg = (t + ps - 2) // ps + 1
+    pos = pos.astype(jnp.int32)
+    logical = (pos // ps)[:, None] + jnp.arange(npg, dtype=jnp.int32)[None]
+    phys = jnp.where(
+        logical < p_max,
+        jnp.take_along_axis(page_table, jnp.minimum(logical, p_max - 1),
+                            axis=1),
+        TRASH_PAGE).reshape(-1)                                # [B * npg]
+    old = plane[phys]                              # [B * npg, H, ps, ...]
+
+    def window(rows_b, off):
+        # this sequence's rows at their offset in the pages they span
+        buf = jnp.zeros((npg * ps,) + rows_b.shape[1:], plane.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, rows_b.astype(plane.dtype), off, axis=0)
+    off = pos % ps
+    fresh = jax.vmap(window)(rows, off)            # [B, npg * ps, H, ...]
+    fresh = jnp.moveaxis(
+        fresh.reshape((b * npg, ps) + fresh.shape[2:]), 1, 2)
+    at = jnp.arange(npg * ps, dtype=jnp.int32)[None] - off[:, None]
+    n_rows = t if valid is None else valid.astype(jnp.int32)[:, None]
+    ok = ((at >= 0) & (at < n_rows)).reshape(
+        (b * npg, 1, ps) + (1,) * (plane.ndim - 3))
+    return plane.at[phys].set(jnp.where(ok, fresh, old))
+
+
+def paged_write(pages, rows, page_table, pos, valid=None):
+    """Write new KV rows into a page plane, in place where the caller's
+    buffer allows it (a donated pool carried through the layers' loop).
+
+    ``pages``: [N, H, page_size, D] (or an int8 bank of that shape), or a
+    plane with no heads axis, [N, page_size, W]; N may span every layer's
+    pages when ``page_table`` is offset to the layer's
+    (models/gpt.paged_forward_with_cache, models/latent_moe._attention);
     ``rows``: [B, T, H, D] (or [B, T, W]) fresh rows for absolute positions
     ``pos[b] + j``; ``page_table``: [B, P_max]; ``valid``: [B] or None
-    (rows past it go to the trash page). Returns the updated pool.
+    (rows past it are padding and reach no page of a sequence). Returns
+    the updated plane.
 
     int8 banks quantize the incoming rows with the same per-row scheme as
     the dense int8 cache (ops/weight_only.quantize_kv), so paged int8
     decode matches dense int8 decode row-for-row."""
-    b, t = rows.shape[:2]
     if is_weight_only(pages):
-        n, ps, h, d = pages['int8'].shape
-        idx = flat_write_indices(page_table, pos, t, ps, valid).reshape(-1)
         q, scale = quantize_kv(rows)
-        int8 = pages['int8'].reshape(n * ps, h, d)
-        int8 = int8.at[idx].set(q.reshape(b * t, h, d))
-        sc = pages['scale'].reshape(n * ps, h)
-        sc = sc.at[idx].set(scale.reshape(b * t, h))
-        return {'int8': int8.reshape(n, ps, h, d),
-                'scale': sc.reshape(n, ps, h)}
-    # a plane is [N, page_size, ...]: heads and head size for a K or V
-    # plane, one row of values for a plane without a heads axis (a latent
-    # cache's)
+        return {'int8': _write_pages(pages['int8'], q, page_table, pos,
+                                     valid),
+                'scale': _write_pages(pages['scale'], scale, page_table,
+                                      pos, valid)}
+    if rows.ndim == 4:
+        return _write_pages(pages, rows, page_table, pos, valid)
+    # a plane without a heads axis (a latent cache's): a row is whole, one
+    # [W] piece of the plane flattened to rows, and is scattered as such
+    b, t = rows.shape[:2]
     n, ps = pages.shape[:2]
     row = pages.shape[2:]
     idx = flat_write_indices(page_table, pos, t, ps, valid).reshape(-1)
@@ -208,7 +263,7 @@ def copy_page(pool, src, dst):
     """Copy-on-write primitive: duplicate physical page ``src`` into
     ``dst`` across every pool plane (k and v, all layers; int8 banks copy
     both the int8 and scale planes). ``pool`` is the engine's full paged
-    cache pytree ``{'k': [L, N, ps, H, D], 'v': ...}``.
+    cache pytree ``{'k': [L, N, H, ps, D], 'v': ...}``.
 
     Compiled ONCE per pool signature (src/dst are traced scalars) and the
     input pool is donated, so a divergence mid-page costs one tiny
@@ -232,15 +287,17 @@ _copy_page_jit = jax.jit(_copy_page_impl, donate_argnums=(0,))
 
 def gather_virtual(pages, page_table):
     """Reconstruct each slot's virtual dense cache from its pages:
-    ``[N, page_size, H, D]`` + ``[B, P_max]`` -> ``[B, P_max*page_size,
-    H, D]``. int8 banks gather both planes. This is the pure-jnp fallback
-    the paged-attention path (and CPU tier-1 tests) build on: the result
-    is value-identical to the dense cache regardless of physical page
-    placement, which is what makes paged-vs-dense greedy bit-parity a
+    ``[N, H, page_size, D]`` + ``[B, P_max]`` -> ``[B, P_max*page_size,
+    H, D]`` (a bank's scales ``[N, H, page_size]`` -> ``[B, P_max*
+    page_size, H]``). int8 banks gather both planes. This is the pure-jnp
+    fallback the paged-attention path (and CPU tier-1 tests) build on: the
+    result is value-identical to the dense cache regardless of physical
+    page placement, which is what makes paged-vs-dense greedy bit-parity a
     testable property."""
     if is_weight_only(pages):
         return {'int8': gather_virtual(pages['int8'], page_table),
                 'scale': gather_virtual(pages['scale'], page_table)}
-    g = jnp.take(pages, page_table, axis=0)       # [B, P_max, ps, ...]
+    g = jnp.take(pages, page_table, axis=0)       # [B, P_max, H, ps, ...]
+    g = jnp.moveaxis(g, 2, 3)                     # rows before heads
     b, p_max, ps = g.shape[:3]
     return g.reshape((b, p_max * ps) + g.shape[3:])

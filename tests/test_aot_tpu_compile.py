@@ -77,7 +77,7 @@ def _cases(devices):
         return lambda: text(flash_fwd_bwd(dropout), q, kv, kv)
 
     def pool(d, int8, sharding=one):
-        pages = (65, 128, 16, d)              # the 337M engine's pool
+        pages = (65, 16, 128, d)    # the 337M engine's pool, head-major
         if not int8:
             return S(pages, bf16, sharding)
         return {'int8': S(pages, jnp.int8, sharding),
@@ -102,8 +102,48 @@ def _cases(devices):
     qm = S((4, 1024, 8, 64), bf16,
            NamedSharding(dpmp, P('dp', None, 'mp', None)))
     mp4 = Mesh(np.array(devices), ('mp',))
-    heads = NamedSharding(mp4, P(None, None, 'mp', None))
+    heads = NamedSharding(mp4, P(None, None, 'mp', None))    # q's heads
+    pool_heads = NamedSharding(mp4, P(None, 'mp', None, None))
     rep = NamedSharding(mp4, P())
+
+    # the engine's two WHOLE executables (PR 28), built as the engine
+    # builds them (``GenerationEngine._build_fns``: sampling, the logits
+    # row, the pool donated) from abstract weights and an abstract pool
+    def engine_program(which, cfg, slots, pages, ps=128):
+        import types
+        from paddle_tpu.models import family as _family
+        from paddle_tpu.serving.generation import GenerationEngine
+        fam = _family.family_of(cfg)
+        prefill, step = GenerationEngine._build_fns(types.SimpleNamespace(
+            config=cfg, _forward_fn=fam.forward_with_cache, temperature=0.0,
+            top_k=0, top_p=1.0, _trace_count=0, _mesh_ctx=None))
+        model = importlib.import_module(type(cfg).__module__)
+
+        def abstract(make):
+            return jax.tree_util.tree_map(
+                lambda a: S(a.shape, a.dtype), jax.eval_shape(make))
+        params = abstract(
+            lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
+        pool = abstract(lambda: fam.init_pool(cfg, pages, ps))
+        i32 = lambda *shape: S(shape, jnp.int32)            # noqa: E731
+        p_max = -(-cfg.max_seq_len // ps)
+        if which == 'step':
+            return lambda: step.lower(
+                params, pool, i32(slots), i32(slots), i32(slots, p_max),
+                i32(slots)).compile().as_text()
+        return lambda: prefill.lower(
+            params, pool, i32(1, cfg.max_seq_len), i32(1), i32(1),
+            i32(1, p_max), i32(1)).compile().as_text()
+
+    from paddle_tpu.models import gpt, moe_gpt
+    # benchmark/configs/gpt-1.3b-serve.json: 24 layers, 129 pages of 128
+    # rows, 16 heads of 128, 16 slots, bf16 over float32 weights
+    xl = dict(vocab_size=50304, hidden_size=2048, num_layers=24,
+              num_heads=16, max_seq_len=1024, dtype='bfloat16',
+              param_dtype='float32')
+    moe = moe_gpt.MoEConfig(vocab_size=50304, hidden_size=1024, num_layers=4,
+                            num_heads=8, n_experts=4, max_seq_len=1024,
+                            dtype='bfloat16')
 
     # the latent family's kernels at dots-vlm1-ep16-serve's own shapes
     # (PR 27): 64 slots of 128 heads against the headless pool, the grouped
@@ -127,6 +167,12 @@ def _cases(devices):
             S((1,), jnp.int32))
 
     return {
+        'gpt_xl_step': engine_program('step', gpt.GPTConfig(**xl), 16, 129),
+        'gpt_xl_prefill': engine_program('prefill', gpt.GPTConfig(**xl),
+                                         16, 129),
+        'gpt_xl_step_int8_kv': engine_program(
+            'step', gpt.GPTConfig(kv_cache_int8=True, **xl), 16, 129),
+        'moe_gpt_step': engine_program('step', moe, 16, 129),
         'latent_decode_w640': latent(640),
         'latent_decode_w576': latent(576),
         'grouped_decode_up': grouped(768, 16, 7168, 2048),
@@ -160,9 +206,45 @@ def _cases(devices):
                                                     qm),
         'paged_mp4': lambda: text(
             pa.paged_flash_decode, S((8, 1, 16, 64), bf16, heads),
-            pool(64, False, heads), pool(64, False, heads),
+            pool(64, False, pool_heads), pool(64, False, pool_heads),
             S((8, 8), jnp.int32, rep), S((8,), jnp.int32, rep), mesh=mp4),
     }
+
+
+# a pool, one layer's plane, or either flattened over layers and pages
+# ([3096,...] is [24*129,...]); the scales of an int8 bank among them
+_POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
+         r'|3096,16,128(?:,128)?|129,16,128(?:,128)?'
+         r'|4,129,8,128,128|516,8,128,128|129,8,128,128)\]')
+
+
+def _pool_copies(text):
+    """The operations of a compiled program whose RESULT is pool-shaped
+    and that move it: everything but the parameter, views of it (a
+    bitcast, an element of the loop's state) and the update in place (a
+    scatter or dynamic-update-slice, or the fusion whose computation ends
+    in one: the compiler aliases that fusion's result to its operand)."""
+    result = re.compile(r'(%\S+) = ' + _POOL + r'\S* ([\w\-]+)\(')
+    views = ('parameter', 'bitcast', 'get-tuple-element')
+    updates = ('scatter', 'dynamic-update-slice')
+    in_place, computation = set(), None
+    for line in text.splitlines():
+        head = re.match(r'(?:ENTRY )?(%\S+) \(.*\{$', line)
+        if head:
+            computation = head.group(1)
+        m = result.search(line)
+        if m and 'ROOT' in line and m.group(3) in updates:
+            in_place.add(computation)
+    moved = []
+    for line in text.splitlines():
+        m = result.search(line)
+        if not m or m.group(3) in views + updates:
+            continue
+        calls = re.search(r'calls=(%[\w.\-]+)', line)
+        if m.group(3) == 'fusion' and calls and calls.group(1) in in_place:
+            continue
+        moved.append(f'{m.group(3)} of {m.group(2)}')
+    return moved
 
 
 def _child():
@@ -179,10 +261,10 @@ def _child():
     for name, compile_text in _cases(list(topo.devices)).items():
         try:
             text = compile_text()
+            moved = _pool_copies(text)
             out[name] = {
                 'kernels': text.count('tpu_custom_call'),
-                'pool_copies': len(re.findall(
-                    r'= bf16\[5,1025,128,\d+\]\S* copy\(', text)),
+                'pool_copies': len(moved), 'moved': sorted(set(moved)),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -214,6 +296,10 @@ def compiled():
     return result
 
 
+def _summary(case):
+    return {k: case[k] for k in ('kernels', 'pool_copies', 'collectives')}
+
+
 @pytest.mark.parametrize('case,kernels', [
     ('flash_s1024_d64', 3),            # fwd, dq, dk/dv
     ('flash_s2048_d128', 3),
@@ -226,8 +312,40 @@ def compiled():
     ('decode_bf16', 1), ('decode_int8', 1),
 ])
 def test_kernel_compiles_for_v5e(compiled, case, kernels):
-    assert compiled[case] == {'kernels': kernels, 'pool_copies': 0,
-                              'collectives': []}
+    """The paged cases at D 64 and D 128 take ONE path: a page is stored
+    head-major, so the kernel's ``[page_size, D]`` block is read out of
+    the pool as it lies whatever the head size; ``pool_copies`` 0 says
+    the wrapper re-lays nothing in front of the call (before PR 28 it
+    transposed every plane it was handed)."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+
+
+@pytest.mark.parametrize('case,kernels', [
+    ('gpt_xl_step', 1),       # the paged kernel, once in the layers' loop
+    ('gpt_xl_prefill', 0),    # a tail prefill gathers; no kernel under it
+    ('moe_gpt_step', 1),
+])
+def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
+    """The engine's WHOLE decode and prefill executables at
+    ``gpt-1.3b-serve``'s shapes (and ``moe_gpt``'s step through the same
+    driver): no operation's result is the pool, a layer's plane or their
+    flattened forms except views and the in-place row write. Before PR 28
+    the scan's stacked outputs and the kernel's transposes made nine a
+    step (PERF.md section 6)."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+
+
+def test_int8_kv_step_moves_only_its_scales(compiled):
+    """With int8 KV banks the int8 planes stay where they lie too. What
+    the compiler does move, once a step and not once a layer, is the two
+    25 MB float32 scale planes into its fast memory and back (``S(1)`` in
+    the layouts): its own choice, not a re-layout."""
+    case = compiled['gpt_xl_step_int8_kv']
+    assert case['kernels'] == 1 and case['collectives'] == [], case
+    assert case['moved'] in ([], ['copy-done of f32']), case
+    assert case['pool_copies'] <= 4, case
 
 
 @pytest.mark.parametrize('case', [
@@ -236,8 +354,8 @@ def test_kernel_compiles_for_v5e(compiled, case, kernels):
 def test_latent_family_kernel_compiles_for_v5e(compiled, case):
     """At the published widths: Mosaic takes the blocks, the grouped
     product's whole-K weight block fits the fast memory it asks for."""
-    assert compiled[case] == {'kernels': 1, 'pool_copies': 0,
-                              'collectives': []}
+    assert _summary(compiled[case]) == {'kernels': 1, 'pool_copies': 0,
+                                        'collectives': []}
 
 
 def test_a_latent_pool_of_whole_lanes_is_not_copied_for_the_kernel(compiled):
@@ -262,8 +380,8 @@ def test_paged_decode_under_mp4_mesh_compiles_for_v5e(compiled):
     """The mesh-sharded engine's decode kernel: pool and heads split over
     'mp', page table and positions whole on every chip. Heads stay where
     the pool put them: no page crosses chips."""
-    assert compiled['paged_mp4'] == {'kernels': 1, 'pool_copies': 0,
-                                     'collectives': []}
+    assert _summary(compiled['paged_mp4']) == {
+        'kernels': 1, 'pool_copies': 0, 'collectives': []}
 
 
 # ---------------------------------------------------------------------------

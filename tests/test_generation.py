@@ -46,19 +46,32 @@ def _prompts(lens, seed=0, vocab=None):
     return [rng.randint(0, v, size=t).astype(np.int32) for t in lens]
 
 
-def _dense_greedy(params, cfg, prompt, n_new):
-    """Reference: dense-cache greedy decode of ONE sequence."""
+def _dense_rows(params, cfg, prompt, n_new, fwd=gpt.forward_with_cache):
+    """Reference: dense-cache greedy decode of ONE sequence -> (tokens,
+    the float32 logits row each was chosen from)."""
     cache = gpt.init_kv_cache(cfg, 1)
-    logits, cache = gpt.forward_with_cache(
-        params, jnp.asarray(prompt[None]), cache, 0, cfg, last_only=True)
-    toks = [int(jnp.argmax(logits[0, -1]))]
-    pos = len(prompt)
-    for _ in range(n_new - 1):
-        lg, cache = gpt.forward_with_cache(
-            params, jnp.asarray([[toks[-1]]], jnp.int32), cache, pos, cfg)
-        toks.append(int(jnp.argmax(lg[0, -1])))
-        pos += 1
-    return toks
+    lg, cache = fwd(params, jnp.asarray(prompt[None]), cache, 0, cfg,
+                    last_only=True)
+    rows = [np.asarray(lg[0, -1], np.float32)]
+    toks = [int(np.argmax(rows[-1]))]
+    for pos in range(len(prompt), len(prompt) + n_new - 1):
+        lg, cache = fwd(params, jnp.asarray([[toks[-1]]], jnp.int32), cache,
+                        pos, cfg)
+        rows.append(np.asarray(lg[0, -1], np.float32))
+        toks.append(int(np.argmax(rows[-1])))
+    return toks, np.stack(rows)
+
+
+def _dense_greedy(params, cfg, prompt, n_new):
+    return _dense_rows(params, cfg, prompt, n_new)[0]
+
+
+def _moe_case():
+    cfg = moe_gpt.MoEConfig(vocab_size=97, hidden_size=32, num_layers=2,
+                            num_heads=2, n_experts=4, max_seq_len=32,
+                            dtype='float32', remat=False, use_flash=False,
+                            capacity_factor=8.0)
+    return cfg, moe_gpt.init_params(cfg, jax.random.PRNGKey(1))
 
 
 def _paged_greedy_batch(params, cfg, prompts, n_new, ps=PS,
@@ -121,7 +134,7 @@ def test_pages_for_and_allocator():
 def test_paged_write_gather_roundtrip():
     rng = np.random.RandomState(1)
     n, ps, h, d, b = 6, 4, 2, 8, 2
-    pool = jnp.zeros((n, ps, h, d), jnp.float32)
+    pool = jnp.zeros((n, h, ps, d), jnp.float32)     # a page is head-major
     # deliberately scattered, non-contiguous physical pages
     table = jnp.asarray([[3, 1, 0, 0], [5, 2, 4, 0]], jnp.int32)
     rows = jnp.asarray(rng.randn(b, 6, h, d), jnp.float32)
@@ -134,7 +147,7 @@ def test_paged_write_gather_roundtrip():
                                   np.asarray(rows[0, :5]))
     np.testing.assert_array_equal(np.asarray(virt[1, :6]),
                                   np.asarray(rows[1, :6]))
-    # the padding row landed in the trash page, not slot 0's virtual cache
+    # the padding row reached no page of slot 0's
     np.testing.assert_array_equal(np.asarray(virt[0, 5]),
                                   np.zeros((h, d), np.float32))
 
@@ -163,27 +176,10 @@ def test_paged_vs_dense_bitwise_at_matched_shape(params):
 
 
 def test_paged_vs_dense_parity_moe():
-    mcfg = moe_gpt.MoEConfig(vocab_size=97, hidden_size=32, num_layers=2,
-                             num_heads=2, n_experts=4, max_seq_len=32,
-                             dtype='float32', remat=False, use_flash=False,
-                             capacity_factor=8.0)
-    mp = moe_gpt.init_params(mcfg, jax.random.PRNGKey(1))
+    mcfg, mp = _moe_case()
     prompts = _prompts([4, 7], seed=5)
-
-    def dense_one(prompt, n_new):
-        cache = gpt.init_kv_cache(mcfg, 1)
-        lg, cache = moe_gpt.forward_with_cache(
-            mp, jnp.asarray(prompt[None]), cache, 0, mcfg, last_only=True)
-        toks = [int(jnp.argmax(lg[0, -1]))]
-        pos = len(prompt)
-        for _ in range(n_new - 1):
-            lg, cache = moe_gpt.forward_with_cache(
-                mp, jnp.asarray([[toks[-1]]], jnp.int32), cache, pos, mcfg)
-            toks.append(int(jnp.argmax(lg[0, -1])))
-            pos += 1
-        return toks
-
-    want = [dense_one(p, 5) for p in prompts]
+    want = [_dense_rows(mp, mcfg, p, 5, moe_gpt.forward_with_cache)[0]
+            for p in prompts]
     got, _ = _paged_greedy_batch(mp, mcfg, prompts, 5,
                                  fwd=moe_gpt.forward_with_cache)
     assert got == want
@@ -214,10 +210,10 @@ def _kernel_setup(int8=False, seed=0):
           for _ in range(2)]
     pools = []
     for rows in kv:
-        pool = jnp.zeros((n, ps, h, d), jnp.float32)
+        pool = jnp.zeros((n, h, ps, d), jnp.float32)
         if int8:
-            pool = {'int8': jnp.zeros((n, ps, h, d), jnp.int8),
-                    'scale': jnp.zeros((n, ps, h), jnp.float32)}
+            pool = {'int8': jnp.zeros((n, h, ps, d), jnp.int8),
+                    'scale': jnp.zeros((n, h, ps), jnp.float32)}
         pools.append(paged_kv.paged_write(pool, rows, table,
                                           jnp.zeros((b,), jnp.int32)))
     return q, pools[0], pools[1], table, pos
@@ -242,6 +238,87 @@ def test_paged_kernel_interpret_parity(int8):
                                rtol=rtol, atol=rtol)
 
 
+# what PR 28 changed under the kernel: a page is head-major and is read
+# where it lies, for every head size; the pool comes whole (every layer's
+# pages) with the table offset to one layer's
+KERNEL_CASES = {
+    #            d   h  h_kv  t  layers  int8
+    'd128':     (128, 2, 2,   1, 1, False),
+    'd256':     (256, 2, 2,   1, 1, False),
+    'gqa':      (64,  4, 2,   1, 1, False),
+    'gqa_int8': (128, 4, 2,   1, 1, True),
+    'layer_2_of_3': (128, 2, 2, 1, 3, False),
+    'layer_2_of_3_int8': (64, 2, 2, 1, 3, True),
+    'tail_rows': (128, 2, 2,  4, 1, False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(KERNEL_CASES))
+def test_paged_kernel_reads_pages_where_they_lie(case):
+    d, h, h_kv, t, layers, int8 = KERNEL_CASES[case]
+    rng = np.random.RandomState(len(case))
+    b, ps, p_max = 2, 128, 2
+    n = b * p_max + 1
+    layer = layers - 1
+    q = jnp.asarray(rng.randn(b, t, h, d), jnp.float32) * 0.3
+    pos = jnp.asarray([130, 200], jnp.int32)
+    table = jnp.asarray([[3, 1], [2, 4]], jnp.int32) + layer * n
+    pools = []
+    for _ in range(2):
+        pool = jnp.asarray(rng.randn(layers * n, h_kv, ps, d),
+                           jnp.float32) * 0.3
+        if int8:
+            pool = {'int8': jnp.zeros(pool.shape, jnp.int8),
+                    'scale': jnp.zeros(pool.shape[:3], jnp.float32)}
+        rows = jnp.asarray(rng.randn(b, 256, h_kv, d), jnp.float32) * 0.3
+        pools.append(paged_kv.paged_write(pool, rows, table,
+                                          jnp.zeros((b,), jnp.int32)))
+    kp, vp = pools
+    fa.set_interpret(True)
+    try:
+        assert pa.paged_attention_available(q, kp['int8'] if int8 else kp)
+        got = pa.paged_attention(q, kp, vp, table, pos)
+    finally:
+        fa.set_interpret(False)
+    want = pa.paged_attention_fallback(q, kp, vp, table, pos, jnp.float32)
+    tol = 2e-2 if int8 else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize('t,start', [(1, 0), (1, 7), (1, 8), (5, 6), (16, 0),
+                                     (16, 3), (17, 15)])
+def test_paged_write_lays_rows_over_their_pages(t, start):
+    """Rows from any offset, a page at a time: what the gather gives back
+    is the rows written so far and zeros, whatever pages the table names;
+    rows past ``valid`` and pages past the table's end reach no page of
+    the sequence, and a neighbour's pages stay as they were."""
+    rng = np.random.RandomState(t * 31 + start)
+    n, ps, h, d = 9, 8, 2, 4
+    pool = jnp.zeros((n, h, ps, d), jnp.float32)
+    table = jnp.asarray([[5, 2, 7, 0], [1, 6, 3, 8]], jnp.int32)
+    before = jnp.asarray(rng.randn(2, start, h, d), jnp.float32)
+    if start:
+        pool = paged_kv.paged_write(pool, before, table,
+                                    jnp.zeros((2,), jnp.int32))
+    rows = jnp.asarray(rng.randn(2, t, h, d), jnp.float32)
+    valid = jnp.asarray([max(t - 2, 1), t], jnp.int32)
+    pool = paged_kv.paged_write(pool, rows, table,
+                                jnp.asarray([start, start], jnp.int32),
+                                valid)
+    virt = np.asarray(paged_kv.gather_virtual(pool, table))
+    for i in range(2):
+        want = np.zeros((ps * 4, h, d), np.float32)
+        want[:start] = np.asarray(before[i])
+        keep = int(valid[i])
+        if i == 0:
+            keep = min(keep, 3 * ps - start)     # slot 0 holds three pages
+        want[start:start + keep] = np.asarray(rows[i, :keep])
+        if i == 0:
+            want[3 * ps:] = virt[0, 3 * ps:]      # the trash page: anything
+        np.testing.assert_array_equal(virt[i], want)
+
+
 # ---------------------------------------------------------------------------
 # GenerationEngine
 # ---------------------------------------------------------------------------
@@ -260,6 +337,114 @@ def test_engine_greedy_matches_dense_reference(params):
         futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         got = [f.result(timeout=120) for f in futs]
     assert got == want
+
+
+# case -> (config overrides, engine keywords, prompt lengths, new tokens)
+ENGINE_CASES = {
+    'float32': ({}, {}, [5, 9, 3, 12], 6),
+    'bf16_pool': (dict(dtype='bfloat16'), {}, [5, 9, 3, 12], 6),
+    'kv_cache_int8': (dict(kv_cache_int8=True), {}, [6, 8, 11], 5),
+    # six pages for two sequences that want eight: a slot is evicted and
+    # re-admitted onto whatever pages are free then
+    'evicted_and_readmitted': ({}, dict(num_pages=6), [9, 9], 16),
+    'moe_gpt': (None, {}, [4, 7, 10], 5),
+}
+
+
+@pytest.mark.parametrize('case', sorted(ENGINE_CASES))
+def test_engine_tokens_and_rows_against_the_dense_cache(params, case):
+    """The pool carried whole through the layers, written a page at a
+    time and read head-major serves what the dense cache computes: the
+    same tokens, and the row each was chosen from."""
+    over, kw, lens, n_new = ENGINE_CASES[case]
+    if over is None:
+        cfg, weights = _moe_case()
+        fwd = moe_gpt.forward_with_cache
+    else:
+        cfg = gpt.GPTConfig(**{**CFG.__dict__, **over})
+        weights, fwd = params, gpt.forward_with_cache
+    prompts = _prompts(lens, seed=len(case))
+    want = [_dense_rows(weights, cfg, p, n_new, fwd) for p in prompts]
+    with _engine(weights, cfg, **kw) as eng:
+        futs = [eng.submit(p, max_new_tokens=n_new, want_logits=True)
+                for p in prompts]
+        got = [(f.result(timeout=300), np.stack(f.logits())) for f in futs]
+        stats = eng.stats()
+    if 'num_pages' in kw:
+        assert stats['evictions'] >= 1
+    # bf16 rounds a product by its shape: a padded prefill's row may differ
+    # from the unpadded one's in the last place
+    tol = 4e-2 if cfg.dtype == 'bfloat16' else 2e-5
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        np.testing.assert_allclose(rows, want_rows, rtol=tol, atol=tol)
+        if cfg.dtype != 'bfloat16':
+            assert toks == want_toks
+        assert toks == [int(np.argmax(r)) for r in rows]
+
+
+@pytest.mark.parametrize('shared', [8, 11, 16],
+                         ids=['page_boundary', 'mid_page', 'two_pages'])
+def test_prefix_tail_prefill_against_the_dense_cache(params, shared):
+    """A prefix-cache hit prefills only the tail: rows from a traced
+    start, over pages another sequence wrote (copied first when the
+    prefix ends mid-page). Same tokens and rows as the dense cache."""
+    rng = np.random.RandomState(shared)
+    first = rng.randint(0, CFG.vocab_size, size=shared).astype(np.int32)
+    second = np.concatenate([first,
+                             rng.randint(0, 97, size=4).astype(np.int32)])
+    want = [_dense_rows(params, CFG, p, 5) for p in (first, second)]
+    with _engine(params, prefix_cache=True, prefill_width=24) as eng:
+        got = []
+        for p in (first, second):           # the second arrives after
+            f = eng.submit(p, max_new_tokens=5, want_logits=True)
+            got.append((f.result(timeout=300), np.stack(f.logits())))
+        stats = eng.stats()
+    assert stats['prefix']['hits'] >= 1
+    # the whole first prompt is reused: its last page, when it ends
+    # mid-page, as a private copy the tail's rows are laid into
+    assert stats['prefix_tokens_saved'] == shared
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        np.testing.assert_allclose(rows, want_rows, rtol=2e-5, atol=2e-5)
+
+
+def test_copy_page_between_steps_changes_nothing(params):
+    """``copy_page`` on the head-major pool: every slot's pages copied to
+    spare ones and the table turned to the copies between two decode
+    steps; the rows that follow are the dense cache's."""
+    prompts = _prompts([6, 8], seed=41)
+    want = [_dense_rows(params, CFG, p, 6) for p in prompts]
+    b, p_max = 2, 4
+    pool = gpt.init_paged_kv_cache(CFG, 2 * b * p_max + 1, PS)
+    table = np.arange(1, b * p_max + 1, dtype=np.int32).reshape(b, p_max)
+    toks_in = np.zeros((b, 8), np.int32)
+    valid = np.asarray([len(p) for p in prompts], np.int32)
+    for i, p in enumerate(prompts):
+        toks_in[i, :len(p)] = p
+    cache = dict(pool, page_table=jnp.asarray(table),
+                 valid=jnp.asarray(valid))
+    lg, cache = gpt.forward_with_cache(params, jnp.asarray(toks_in), cache,
+                                       jnp.zeros((b,), jnp.int32), CFG,
+                                       last_only=True)
+    rows = [[np.asarray(lg[i, 0], np.float32)] for i in range(b)]
+    pos = valid.copy()
+    for step in range(5):
+        pool = {k: cache[k] for k in ('k', 'v')}
+        if step == 2:
+            for page in table.reshape(-1):
+                pool = paged_kv.copy_page(pool, int(page),
+                                          int(page) + b * p_max)
+            table = table + b * p_max
+        tok = np.asarray([[int(np.argmax(r[-1]))] for r in rows], np.int32)
+        cache = dict(pool, page_table=jnp.asarray(table))
+        lg, cache = gpt.forward_with_cache(params, jnp.asarray(tok), cache,
+                                           jnp.asarray(pos), CFG)
+        for i in range(b):
+            rows[i].append(np.asarray(lg[i, 0], np.float32))
+        pos += 1
+    for i in range(b):
+        np.testing.assert_allclose(np.stack(rows[i]), want[i][1],
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_prompt_exactly_fills_cache(params):

@@ -3,6 +3,7 @@ axes, MeshContext placement, mp=1 vs mp>1 byte parity at matched seeds,
 trace-count uniformity, warm clone portability, and per-chip ModelHost
 admission (8-device CPU mesh)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -52,9 +53,9 @@ def test_serving_rules_resolve_pool_axes(mp):
     # GSPMD convention: kv_heads maps to 'mp' at every degree (a size-1
     # mesh axis is a no-op), kv_pages is pinned replicated
     pt = Partitioner(rules=serving_rules(mp=mp))
-    # pool plane [layers, pages, page_size, kv_heads, head_dim]
+    # pool plane [layers, pages, kv_heads, page_size, head_dim]
     spec = pt.spec(POOL_LOGICAL_AXES)
-    assert spec == P(None, None, None, 'mp', None)
+    assert spec == P(None, None, 'mp', None, None)
 
 
 @pytest.mark.parametrize('mp', [1, 2, 4])
@@ -63,7 +64,7 @@ def test_pool_spec_on_live_mesh(mp):
     # mp=1 mesh resolves the same rule to an effective no-op
     ctx = MeshContext.build(mp)
     sh = ctx.pool_sharding()
-    assert tuple(sh.spec)[:4] == (None, None, None, 'mp')
+    assert tuple(sh.spec)[:3] == (None, None, 'mp')
     assert sh.mesh.size == mp
 
 
@@ -124,7 +125,7 @@ def test_place_pool_shards_heads_axis():
     for plane in (placed['k'], placed['v']):
         sh = plane.sharding
         assert isinstance(sh, NamedSharding)
-        assert tuple(sh.spec)[:4] == (None, None, None, 'mp')
+        assert tuple(sh.spec)[:3] == (None, None, 'mp')
 
 
 def test_indivisible_param_falls_back_replicated():
@@ -183,6 +184,61 @@ def test_byte_parity_mp1_vs_mp2(temperature):
     assert s1['traces'] == 2 and s2['traces'] == 2
     assert s1['mesh'] is None
     assert s2['mesh']['mp'] == 2
+
+
+# case -> (mesh degree, config overrides, engine keywords)
+DENSE_CASES = {
+    'mp2': (2, {}, {}),
+    'mp4_heads4': (4, dict(num_heads=4), {}),
+    'mp2_gqa': (2, dict(num_heads=4, num_kv_heads=2), {}),
+    'mp2_kv_cache_int8': (2, dict(kv_cache_int8=True), {}),
+    'mp2_prefix_tail': (2, {}, dict(prefix_cache=True)),
+}
+
+
+@pytest.mark.parametrize('case', sorted(DENSE_CASES))
+def test_sharded_engine_rows_against_the_dense_cache(case):
+    """A pool whose pages are split over 'mp' by heads, carried whole
+    through the layers and written a page at a time, serves what the
+    dense cache on one device computes: the same greedy tokens and the
+    row each was chosen from (PR 28: a head's rows of a page stay one
+    block inside a shard)."""
+    mp, over, kw = DENSE_CASES[case]
+    cfg = tiny_cfg(**over)
+    params = tiny_params(cfg)
+    rng = np.random.RandomState(len(case))
+    first = rng.randint(1, 96, size=21).astype(np.int32)
+    prompts = [first, np.concatenate([first, [7, 9, 3]]).astype(np.int32),
+               rng.randint(1, 96, size=5).astype(np.int32)]
+    n_new = 6
+
+    def dense(prompt):
+        cache = gpt.init_kv_cache(cfg, 1)
+        lg, cache = gpt.forward_with_cache(
+            params, jnp.asarray(prompt[None]), cache, 0, cfg, last_only=True)
+        rows = [np.asarray(lg[0, -1], np.float32)]
+        for pos in range(len(prompt), len(prompt) + n_new - 1):
+            tok = jnp.asarray([[int(np.argmax(rows[-1]))]], jnp.int32)
+            lg, cache = gpt.forward_with_cache(params, tok, cache, pos, cfg)
+            rows.append(np.asarray(lg[0, -1], np.float32))
+        return np.stack(rows)
+
+    engine = gen_engine(params, cfg, mp, **kw)
+    try:
+        got = []
+        for p in prompts:       # one after the other: the second's prefix
+            fut = engine.submit(p, max_new_tokens=n_new, want_logits=True)
+            got.append((fut.result(timeout=300), np.stack(fut.logits())))
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+    assert stats['traces'] == 2 and stats['mesh']['mp'] == mp
+    if kw.get('prefix_cache'):
+        assert stats['prefix_tokens_saved'] == len(first)    # ends mid-page
+    for p, (toks, rows) in zip(prompts, got):
+        want = dense(p)
+        np.testing.assert_allclose(rows, want, rtol=2e-5, atol=2e-5)
+        assert list(toks) == [int(np.argmax(r)) for r in want]
 
 
 def test_mesh_gauge_and_uniform_labels():

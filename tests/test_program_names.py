@@ -181,11 +181,11 @@ def test_the_decode_kernel_is_named(interpret):
 def test_the_paged_kernel_is_named(interpret, int8):
     n, ps, h, d = 5, 128, 2, 64
     q = jnp.ones((2, 1, h, d))
-    pages = jnp.ones((n, ps, h, d), jnp.int8 if int8 else jnp.float32)
+    pages = jnp.ones((n, h, ps, d), jnp.int8 if int8 else jnp.float32)
     table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     pos = jnp.asarray([130, 7], jnp.int32)
     if int8:
-        bank = {'int8': pages, 'scale': jnp.ones((n, ps, h), jnp.float32)}
+        bank = {'int8': pages, 'scale': jnp.ones((n, h, ps), jnp.float32)}
         names = _pallas_names(
             lambda: pa.paged_flash_decode_int8(q, bank, bank, table, pos))
     else:

@@ -224,7 +224,7 @@ def run_battery():
     def paged(d, int8):
         nb, hq, ps, p_max = 4, 8, 128, 4
         n_pages = nb * p_max + 1                      # page 0 = trash
-        kp, vp = (rand(i + 51, (n_pages, ps, hq, d), jnp.bfloat16)
+        kp, vp = (rand(i + 51, (n_pages, hq, ps, d), jnp.bfloat16)  # head-major
                   for i in range(2))
         qd = rand(53, (nb, 1, hq, d), jnp.bfloat16)
         # every slot owns p_max pages in a scrambled order, at its own depth
