@@ -188,7 +188,7 @@ def summarize(dims, layout, hbm_gib=None):
 # --------------------------------------------------------------------------
 
 def gpt_1p3b_dims():
-    """The bench.py >=1B rung (GPT-3 1.3B-class)."""
+    """GPT-3 1.3B-class widths at seq 1024 (BASELINE.json's >=1B rung)."""
     return ModelDims(vocab_size=32768, hidden_size=2048, num_layers=24,
                      num_heads=16, max_seq_len=1024)
 

@@ -21,12 +21,13 @@ compiles path), ``fleet.route`` (serving.FleetRouter's routing decision;
 an armed fault parks the request for control-loop retry rather than
 losing it), ``fleet.failover`` (the fleet health sweep; an armed
 fault kills one replica via ``shutdown(drain=False)``, driving the full
-resubmit-without-loss failover path — the hook tools/fleet_drill.py is
-built on), ``host.admit`` (serving.ModelHost admission, before any side
-effect — an armed fault aborts the deploy/swap-in with accounting
-unchanged), and ``host.evict`` (ModelHost eviction — an armed fault
-aborts the eviction, leaving the victim live; an admission that needed
-the space fails without side effects).
+resubmit-without-loss failover path — the hook tests/test_fleet.py's
+failover cases are built on), ``host.admit`` (serving.ModelHost
+admission, before any side effect — an armed fault aborts the
+deploy/swap-in with accounting unchanged), and ``host.evict``
+(ModelHost eviction — an armed fault aborts the eviction, leaving the
+victim live; an admission that needed the space fails without side
+effects).
 
 When no spec is armed, ``inject()`` is a single falsy-dict check — zero cost
 on hot paths.

@@ -67,9 +67,10 @@ class GPTConfig:
     # 'full': recompute everything (min memory); 'dots': save matmul/flash
     # outputs, recompute only cheap elementwise (near-full speed, ~matmul
     # activations memory) — the TPU sweet spot since MXU results are the
-    # expensive thing to recompute and HBM is better spent on them.
-    # Measured on v5e (tools/tpu_tune.py r4, 350M/seq1024): dots +1.5-3%
-    # over full at modest extra HBM — the default
+    # expensive thing to recompute and HBM is better spent on them. The
+    # 345M training cell runs 'dots', the 1.3B one 'full' to fit its chips
+    # (benchmark/configs); the two were not measured against each other on
+    # this installation.
     remat_policy: str = 'dots'
     use_flash: bool = True
     # parallel degrees (must multiply to the mesh size together with dp)
@@ -91,8 +92,8 @@ class GPTConfig:
     kv_cache_int8: bool = False
     # lax.scan unroll over the layer stack (single-chip path): >1 lets XLA
     # software-pipeline across layer boundaries at the cost of program
-    # size. Numerics are unchanged (tested); throughput is a chip-side
-    # tuning knob (tools/tpu_tune.py --round3 rung).
+    # size. Numerics are unchanged (tests/test_user_journeys2.py); its
+    # throughput was not measured on this installation: no cell sets it.
     scan_unroll: int = 1
     # quantized dp-gradient all-reduce (distributed/quant_collectives,
     # EQuARX-style): 'none' keeps the full-width reduction; 'bf16' is the

@@ -23,7 +23,8 @@ Design points:
   capture window to the highest-priority *active* category
   (collective > matmul > copy > infeed > compute) or to ``idle`` when
   nothing is running, so ``sum(categories) + idle == window`` holds by
-  construction — the invariant ``tools/devtime_check.py`` gates on.
+  construction — the invariant ``tests/test_devtime.py`` holds a live
+  capture to.
 - **Overlap fraction.** The same sweep measures how much collective time
   is *hidden* under concurrently-running compute:
   ``overlap = |union(collective) ∩ union(matmul ∪ compute)| /
@@ -98,9 +99,8 @@ _CLASSIFIERS = {
 
 
 class Classifier:
-    """One published version of the event-classification table. The single
-    shared table: ``tools/tpu_breakdown.py`` and the capture path both
-    classify through it, so categories cannot drift between tools."""
+    """One published version of the event-classification table: every
+    capture classifies through it, so categories cannot drift."""
 
     __slots__ = ('version', '_ops', '_compute', '_host')
 

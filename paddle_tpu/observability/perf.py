@@ -49,7 +49,7 @@ ENV_PEAK_FLOPS_FP8 = 'PADDLE_TPU_PEAK_FLOPS_FP8'
 ENV_PEAK_FLOPS_INT8 = 'PADDLE_TPU_PEAK_FLOPS_INT8'
 
 # (peak_flops/s, peak_HBM_bytes/s) by device-kind substring, checked in
-# order. FLOPs numbers match bench.py's PEAK_FLOPS; 'cpu' is nominal so
+# order. The v5e row is benchmark/peaks.json's; 'cpu' is nominal so
 # ratios stay comparable across runs, not a physical claim. A kind that
 # matches no row is an error, never a default.
 PEAKS = (

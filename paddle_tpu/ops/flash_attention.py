@@ -9,8 +9,9 @@ fused_attention_op.cc).
 Backward is ALSO pallas: the classic two-kernel split — a dq kernel (each
 program owns a q block, streams k/v blocks) and a dk/dv kernel (each program
 owns a k/v block, streams q blocks) — recomputing p = exp(s - lse) from the
-saved log-sum-exp so the S×S matrix never hits HBM in training either. A jnp
-blockwise fallback remains behind PADDLE_TPU_FLASH_JNP_BWD=1.
+saved log-sum-exp so the S×S matrix never hits HBM in training either.
+``_bwd_blockwise`` is the same gradient in jnp: the tests' reference for the
+two kernels, which nothing in the package calls.
 
 Under a device mesh every kernel call goes through ops/mesh_kernel.py
 (shard_map over batch -> 'dp', heads -> 'mp'): Mosaic kernels cannot be
@@ -41,7 +42,6 @@ numerics parity against naive attention.
 """
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -52,41 +52,22 @@ from . import mesh_kernel
 from .. import observability as _obs
 
 
-def _env_block(name, default):
-    """Tunable block size: positive multiple of 128 (TPU sublane tiling);
-    anything else falls back to the default rather than crashing or feeding
-    Mosaic an untileable shape."""
-    try:
-        v = int(os.environ.get(name, default))
-    except ValueError:
-        return default
-    return v if v > 0 and v % 128 == 0 else default
+_BLOCKS = (512, 256, 128)   # block rows, largest first; the cells run 512
 
 
-_BQ_CAP = _env_block('PADDLE_TPU_FLASH_BQ', 512)   # q-block row cap
-_BK_CAP = _env_block('PADDLE_TPU_FLASH_BK', 512)   # k/v-block row cap
-
-
-def _pick_block(s, cap):
-    """Largest block ≤ cap dividing the 128-padded seq length. 512 is the
-    measured v5e sweet spot (tools/tpu_tune.py r4: 512/512 beats 256/256 by
-    ~13% on the 350M bench config); shorter/ragged seqs fall back to the
-    largest divisor so padding stays at 128-row granularity."""
+def _pick_block(s):
+    """Largest block dividing the 128-padded seq length, so a ragged seq
+    keeps its padding at 128-row granularity."""
     sp = -(-s // 128) * 128
-    for b in (cap, 512, 256, 128):
-        if 0 < b <= cap and sp % b == 0:
-            return b
-    return 128
+    return next(b for b in _BLOCKS if sp % b == 0)
 
 
 def _pick_blocks(s_q, s_k):
-    bq = _pick_block(s_q, _BQ_CAP)
-    bk = min(_pick_block(s_k, _BK_CAP), bq)
-    # kernels require bk | bq; non-power-of-two env caps can break it, so
-    # halve (floored at the 128 tiling minimum, which divides any pick)
-    while bq % bk and bk > 128:
-        bk = max(128, bk // 2)
-    return bq, bk
+    """(bq, bk). The kernels need bk | bq: powers of two with bk <= bq."""
+    bq = _pick_block(s_q)
+    return bq, min(_pick_block(s_k), bq)
+
+
 _LANES = 128   # TPU lane width; lse is stored lane-broadcast to tile cleanly
 _BSHD = ('batch', None, 'heads', None)   # mesh_kernel dims of [B,S,H,D]
 _TQ_DECODE = 128   # decode q-tile rows (real q rows are 1..few, padded up)
@@ -587,9 +568,11 @@ def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
 def _bwd_blockwise(q, k, v, out, lse, g, causal, q_off=0, kv_valid=None,
                    kmask=None, h=1, groups=1, bk=None, drop_rate=0.0,
                    seed=None):
-    """Blockwise gradients (scan over k-blocks), fp32 accumulation.
-    GQA (groups>1): kv repeated across the group here (fallback path),
-    group-partial dk/dv summed at the end."""
+    """Blockwise gradients in jnp (scan over k-blocks), fp32 accumulation:
+    the reference tests/test_flash_attention.py holds ``_bwd_pallas`` to, on
+    the same forward's residuals. Not called by the package.
+    GQA (groups>1): kv repeated across the group, group-partial dk/dv summed
+    at the end."""
     if groups > 1:
         kx = jnp.repeat(k, groups, axis=0)
         vx = jnp.repeat(v, groups, axis=0)
@@ -604,7 +587,7 @@ def _bwd_blockwise(q, k, v, out, lse, g, causal, q_off=0, kv_valid=None,
     bh, s_q, d = q.shape
     s_k = k.shape[1]
     if bk is None:
-        bk = _pick_block(int(s_k), _BK_CAP)
+        bk = _pick_block(int(s_k))
     _BK = bk                     # local block size for the k-scan below
     scale = 1.0 / math.sqrt(d)
     qf = q.astype(jnp.float32) * scale
@@ -958,16 +941,10 @@ def _flash_f(q, k, v, kmask, seed, causal, q_off, kv_valid, h, groups, bq,
 
 def _flash_b(causal, q_off, kv_valid, h, groups, bq, bk, drop_rate, res, g):
     q, k, v, kmask, seed, out, lse = res
-    if os.environ.get('PADDLE_TPU_FLASH_JNP_BWD') == '1':
-        dq, dk, dv = _bwd_blockwise(q, k, v, out, lse, g, causal,
-                                    q_off=q_off, kv_valid=kv_valid,
-                                    kmask=kmask, h=h, groups=groups, bk=bk,
-                                    drop_rate=drop_rate, seed=seed)
-    else:
-        dq, dk, dv = _bwd_pallas(q, k, v, out, lse, g, causal, q_off=q_off,
-                                 kv_valid=kv_valid, kmask=kmask, h=h,
-                                 groups=groups, bq=bq, bk=bk,
-                                 drop_rate=drop_rate, seed=seed)
+    dq, dk, dv = _bwd_pallas(q, k, v, out, lse, g, causal, q_off=q_off,
+                             kv_valid=kv_valid, kmask=kmask, h=h,
+                             groups=groups, bq=bq, bk=bk,
+                             drop_rate=drop_rate, seed=seed)
     dmask = None if kmask is None else jnp.zeros_like(kmask)
     # integer primal (the dropout seed): float0 cotangent per custom_vjp
     dseed = _np.zeros(jnp.shape(seed), jax.dtypes.float0)

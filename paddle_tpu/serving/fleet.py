@@ -42,7 +42,7 @@ Chaos inject points: ``fleet.route`` (routing decision; an armed fault
 parks the request for retry instead of losing it) and
 ``fleet.failover`` (health sweep; an armed fault SIGKILL-simulates a
 replica via ``shutdown(drain=False)``, exercising the full failover
-path — ``tools/fleet_drill.py`` builds on this).
+path — the failover cases of ``tests/test_fleet.py`` build on this).
 
 Env knobs: ``PADDLE_TPU_FLEET_REPLICAS`` (initial size),
 ``PADDLE_TPU_FLEET_MIN`` / ``PADDLE_TPU_FLEET_MAX`` (autoscale bounds),
@@ -100,8 +100,9 @@ def _clone_warmth(src, dst):
     bucket-cache entries. Both engine families pass params as traced
     ARGUMENTS (never closed-over constants), which is what makes the
     executables replica-portable. The clone marks ``dst`` warm: its
-    first request runs with zero retraces — the scale-up-without-cold-
-    compile proof the fleet drill asserts on."""
+    first request runs with zero retraces when ``src`` holds the
+    executables ``warmup()`` builds (``_aot``); a ``src`` compiled by live
+    traffic has none to copy (tests/test_fleet.py, the autoscaler case)."""
     aot_src = getattr(src, '_aot', None)
     if aot_src is not None and hasattr(dst, '_aot'):
         dst._aot.update(aot_src)
@@ -307,7 +308,7 @@ class ReplicaSet:
     def kill(self, name):
         """Abrupt: fail everything queued/in-flight on the replica
         (EngineClosedError) — the SIGKILL simulation the failover path
-        and the chaos drill are tested against."""
+        and its tests are held to."""
         with self._lock:
             rep = self._replicas.get(name)
             if rep is None or rep.state in (Replica.DEAD, Replica.STOPPED):

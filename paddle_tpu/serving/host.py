@@ -39,10 +39,10 @@ The fleet router targets hosted models as ``model@host``
 (``FleetRouter.submit(..., target='chat@host0')``) through the
 process-local registry (``get_host`` / ``resolve_target``).
 
-``tools/tenant_drill.py`` is the acceptance gate: a 3-model host under
-2x mixed-lane overload must keep interactive p99 within budget while
-batch sheds, never exceed the watermark, and evict/swap-in a cold model
-mid-traffic with zero lost interactive requests and zero new compiles.
+``tests/test_host.py`` holds it to this: batch sheds with a backoff hint
+while interactive flows, the watermark is never exceeded, and a cold
+model is evicted and swapped back in mid-traffic with zero lost
+interactive requests and zero new compiles.
 """
 import itertools
 import threading

@@ -1,4 +1,4 @@
-"""VERDICT r2 #5: the exact code path bench.py executes — bf16 GPT with
+"""VERDICT r2 #5: the code path the training cells execute — bf16 GPT with
 remat and flash attention — is CI-covered on CPU, and GradScaler's dynamic
 loss-scaling reacts correctly to injected inf gradients."""
 import importlib

@@ -389,7 +389,7 @@ def test_paged_decode_under_mp4_mesh_compiles_for_v5e(compiled):
 # ---------------------------------------------------------------------------
 
 def test_imports_initialise_no_backend():
-    """A parent that imports the package (a launcher, bench.py's parent)
+    """A parent that imports the package (a launcher, a benchmark's parent)
     must not take the chip from the child that needs it."""
     code = ('import paddle_tpu, paddle_tpu.serving, paddle_tpu.models, '
             'paddle_tpu.distributed.launch\n'

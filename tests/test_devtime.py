@@ -63,7 +63,9 @@ def test_golden_category_bucketing():
 
 def test_golden_sum_invariant_and_idle_gap():
     # events window: [0, 24] ms -> idle fills the uncovered 6 ms
+    spans = len(obs.trace_events())
     s = devtime.attribute(FIXTURE, publish=False)
+    assert len(obs.trace_events()) == spans    # attribution opens no span
     assert s['window_ms'] == 24.0
     assert s['idle_ms'] == 6.0
     assert sum(s['categories_ms'].values()) == pytest.approx(
@@ -218,6 +220,23 @@ def test_ledger_run_window_and_ratio():
     assert 0.0 < snap['ratio'] < 1.0
     assert snap['goodput_s'] == pytest.approx(
         snap['elapsed_s'] - 0.02, abs=1e-6)
+
+
+def test_a_stalled_save_is_booked_as_checkpoint_badput(tmp_path):
+    """paddle.save reports its whole duration, an injected stall included,
+    to the process ledger under 'checkpoint'."""
+    import numpy as np
+    import paddle_tpu as paddle
+    from paddle_tpu import fault
+    led = goodput.ledger()
+    led.run_start()
+    fault.configure('ckpt.write:1.0:delay:0.2', seed=7, max_faults=1)
+    try:
+        paddle.save({'w': np.ones(4, np.float32)}, str(tmp_path / 'w.pd'))
+    finally:
+        fault.configure(None)
+        led.run_end()
+    assert goodput.snapshot()['badput_s']['checkpoint'] >= 0.2
 
 
 def test_badput_outside_run_counts_lifetime_only():

@@ -75,17 +75,31 @@ def test_grad_parity(causal, d):
                                    err_msg=f'd{name} mismatch')
 
 
-def test_grad_parity_vs_jnp_bwd(monkeypatch):
-    """The pallas backward and the jnp blockwise backward agree exactly
-    on the same fwd residuals (same lse), so either path is safe."""
+def _both_backwards(q, k, v, cotangent, drop=0.0, seed=0):
+    """(dq, dk, dv) of the causal kernel rows from ``_bwd_pallas`` and from
+    ``_bwd_blockwise``, its jnp reference, on one forward's residuals.
+    ``cotangent`` maps the forward's output to the gradient that reaches it."""
+    _, s, hh, d = q.shape
+    groups = hh // k.shape[2]
+    bq, bk = fa._pick_blocks(s, s)
+
+    def rows(x):
+        return x.transpose(0, 2, 1, 3).reshape(-1, s, d)
+
+    seed = jnp.asarray([seed], jnp.uint32)
+    _, (qt, kt, vt, _, _, out, lse) = fa._flash_f(
+        rows(q), rows(k), rows(v), None, seed, True, 0, None, hh, groups,
+        bq, bk, drop)
+    kw = dict(h=hh, groups=groups, bk=bk, drop_rate=drop, seed=seed)
+    args = (qt, kt, vt, out, lse, cotangent(out), True)
+    return fa._bwd_pallas(*args, bq=bq, **kw), fa._bwd_blockwise(*args, **kw)
+
+
+def test_grad_parity_vs_jnp_bwd():
+    """The pallas backward and the jnp blockwise backward agree on the same
+    fwd residuals (same lse)."""
     q, k, v = _rand_qkv(jax.random.PRNGKey(3), 2, 256, 1, 64)
-
-    def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, causal=True) ** 2)
-
-    g_pallas = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv('PADDLE_TPU_FLASH_JNP_BWD', '1')
-    g_jnp = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g_pallas, g_jnp = _both_backwards(q, k, v, lambda out: 2 * out)
     for gp, gj in zip(g_pallas, g_jnp):
         np.testing.assert_allclose(np.asarray(gp), np.asarray(gj),
                                    atol=1e-4, rtol=1e-4)
@@ -497,20 +511,11 @@ def test_gqa_grad_parity():
                                    err_msg=f'd{nm} mismatch')
 
 
-def test_gqa_grad_parity_jnp_bwd(monkeypatch):
-    monkeypatch.setenv('PADDLE_TPU_FLASH_JNP_BWD', '1')
+def test_gqa_grad_parity_jnp_bwd():
     H, h_kv = 4, 1                              # MQA
     q, _, _ = _rand_qkv(jax.random.PRNGKey(45), 1, 256, H, 64)
     _, k, v = _rand_qkv(jax.random.PRNGKey(46), 1, 256, h_kv, 64)
-
-    def lf(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, causal=True) ** 2)
-
-    def lr(q, k, v):
-        return jnp.sum(_naive_gqa(q, k, v, True) ** 2)
-
-    g1 = jax.grad(lf, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+    g1, g2 = _both_backwards(q, k, v, lambda out: 2 * out)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
@@ -533,9 +538,8 @@ def test_gqa_flash_decode():
 
 
 def test_pick_blocks_invariants():
-    """r4: blocks are auto-picked per call (512-row cap measured fastest on
-    v5e). Invariants the kernels rely on: bk | bq, both divide the padded
-    seqs, 128-row tiling minimum."""
+    """Blocks are picked per call from the shapes. Invariants the kernels
+    rely on: bk | bq, both divide the padded seqs, 128-row tiling minimum."""
     for s_q in (128, 256, 300, 384, 512, 640, 1024, 4096, 130):
         for s_k in (128, 256, 300, 512, 1024, 4096):
             bq, bk = fa._pick_blocks(s_q, s_k)
@@ -547,23 +551,11 @@ def test_pick_blocks_invariants():
             s_k128 = -(-s_k // 128) * 128
             assert s_q128 % bq == 0, (s_q, bq)
             assert s_k128 % bk == 0, (s_k, bk)
-    # the tuned default: big seqs pick the 512 sweet spot
+    # the training cells' shapes (345M, 1.3B) run 512-row blocks
     assert fa._pick_blocks(1024, 1024) == (512, 512)
+    assert fa._pick_blocks(2048, 2048) == (512, 512)
     # ragged seqs keep 128-granularity padding
     assert fa._pick_blocks(300, 300)[0] == 128
-
-
-def test_pick_blocks_env_cap(monkeypatch):
-    """Non-power-of-two env caps can't break the bk | bq invariant
-    (review r4): bk halves down to the 128 floor."""
-    monkeypatch.setattr(fa, '_BQ_CAP', 384)
-    monkeypatch.setattr(fa, '_BK_CAP', 512)
-    bq, bk = fa._pick_blocks(768, 256)
-    assert bq % bk == 0 and bk >= 128
-    monkeypatch.setattr(fa, '_BQ_CAP', 512)
-    monkeypatch.setattr(fa, '_BK_CAP', 384)
-    bq, bk = fa._pick_blocks(512, 768)
-    assert bq % bk == 0 and bk >= 128
 
 
 # ---- in-kernel attention dropout (VERDICT r5 #5) ---------------------------
@@ -635,20 +627,10 @@ def test_dropout_grad_parity():
                                    atol=5e-5, rtol=5e-4)
 
 
-def test_dropout_grad_parity_jnp_bwd(monkeypatch):
-    """The blockwise jnp fallback backward regenerates the same mask too."""
-    monkeypatch.setenv('PADDLE_TPU_FLASH_JNP_BWD', '1')
+def test_dropout_grad_parity_jnp_bwd():
+    """The blockwise jnp reference backward regenerates the same mask too."""
     q, k, v = _rand_qkv(jax.random.PRNGKey(2), 1, 256, 2, 64)
-
-    def loss_flash(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True, dropout_rate=0.25,
-                                  dropout_seed=9).sum()
-
-    def loss_ref(q, k, v):
-        return _naive_dropout(q, k, v, True, 0.25, 9).sum()
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g1, g2 = _both_backwards(q, k, v, jnp.ones_like, drop=0.25, seed=9)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)

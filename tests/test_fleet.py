@@ -14,8 +14,9 @@ import paddle_tpu as paddle
 from paddle_tpu import fault, nn
 from paddle_tpu import observability as obs
 from paddle_tpu.models import gpt
-from paddle_tpu.serving import (Autoscaler, FleetRouter, GenerationEngine,
-                                InferenceEngine, QueueFullError, ReplicaSet)
+from paddle_tpu.serving import (Autoscaler, FleetRouter, InferenceEngine,
+                                QueueFullError, ReplicaSet,
+                                sharded_generation_engine)
 
 pytestmark = pytest.mark.fleet
 
@@ -30,12 +31,12 @@ def params():
     return gpt.init_params(CFG, jax.random.PRNGKey(0))
 
 
-def _gen_engine(params, **kw):
+def _gen_engine(params, mp=1, **kw):
     kw.setdefault('num_slots', 2)
     kw.setdefault('page_size', PS)
     kw.setdefault('prefill_width', 16)
     kw.setdefault('queue_capacity', 64)
-    return GenerationEngine(params, CFG, **kw)
+    return sharded_generation_engine(params, CFG, mp=mp, **kw)  # 1: plain
 
 
 def _prompts(lens, seed):
@@ -110,11 +111,16 @@ def test_router_skips_replica_with_open_breaker(params):
 # failover: kill a replica mid-decode (fleet.failover inject point)
 # ---------------------------------------------------------------------------
 
-def test_failover_mid_decode_byte_identical_no_duplicates(params):
+@pytest.mark.parametrize('mp', [1, 2], ids=['mp1+mp1', 'mp1+mp2'])
+def test_failover_mid_decode_byte_identical_no_duplicates(params, mp):
+    """``mp1+mp2``: the router cannot tell a mesh-sharded replica from a
+    one-device one, and a stream that fails over between the two shapes is
+    still the single engine's (vocab 97 does not split over 2: the
+    embedding rides the fall-back-to-replicated rule on purpose)."""
     prompts = _prompts([9, 7, 8, 6, 9, 5], seed=17)
     n_new = 24
     want = _reference(params, prompts, n_new)
-    engines = _warm(_gen_engine(params), _gen_engine(params))
+    engines = _warm(_gen_engine(params), _gen_engine(params, mp=mp))
     rs = ReplicaSet(replicas=list(engines))
     router = FleetRouter(rs, tick_s=0.005)
     try:
@@ -242,12 +248,22 @@ def test_rolling_restart_drops_nothing(params):
 def test_autoscaler_scales_up_warm_then_back_down(params):
     rs = ReplicaSet(lambda: _gen_engine(params, num_slots=1),
                     initial=1, min_replicas=1, max_replicas=3)
+    # a clone is as warm as its template: spawn() copies the executables
+    # warmup() built, so the template holds both before the burst, whenever
+    # the breach is first seen (a template compiled by live traffic has
+    # none to copy, and its clone would trace on its first request)
+    assert rs.snapshot()[0].engine.warmup()['prebuilt'] == 2
     asc = Autoscaler(qwait_p99_ms=1.0, idle_s=0.4, cooldown_s=0.2,
                      debounce=1)
     router = FleetRouter(rs, autoscaler=asc, tick_s=0.01)
+    held = threading.Event()
     try:
         futs = [router.submit(_prompts([8], seed=i)[0], max_new_tokens=16,
                               seed=i) for i in range(12)]
+        # the burst stays in flight until the scale-up has been seen: the
+        # last request's first token parks its engine's thread here, with
+        # every queue wait before it already observed
+        futs[-1].subscribe(lambda *event: held.wait(120))
         # the serve.queue_wait p99 breach must spawn a replica while the
         # burst is still in flight
         spawned = None
@@ -260,7 +276,9 @@ def test_autoscaler_scales_up_warm_then_back_down(params):
         # warm template clone: the new replica serves with ZERO retraces
         assert spawned.engine.stats()['traces'] == 0
         assert spawned.engine._warmed
+        held.set()
         [f.result(timeout=120) for f in futs]
+        assert spawned.engine.stats()['traces'] == 0
         # idle replicas drain back down to the floor
         deadline = time.time() + 60
         while time.time() < deadline and rs.counts()[0] > 1:
@@ -269,6 +287,7 @@ def test_autoscaler_scales_up_warm_then_back_down(params):
         h = obs.find('fleet.scale_up_ms', {'fleet': rs.name})
         assert h is not None and h.count >= 1
     finally:
+        held.set()
         router.close()
 
 

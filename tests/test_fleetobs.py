@@ -499,6 +499,42 @@ def _infer_factory(**kw):
     return factory
 
 
+def test_every_counter_family_of_a_live_fleet_sums_its_replicas():
+    """Two real engines behind a router, scraped over HTTP: the fleet row
+    of EVERY counter family the engines publish is the exact sum of its
+    replicas' rows."""
+    from paddle_tpu.serving import FleetRouter, ReplicaSet
+    rset = ReplicaSet(_infer_factory(), initial=2)
+    router = FleetRouter(rset, tick_s=0.01)
+    fobs = fleetobs.FleetObs(name=rset.name).watch_router(router)
+    srv = fobs.serve(port=0)
+    try:
+        rng = np.random.RandomState(0)
+        for f in [router.submit(rng.rand(n, 8).astype('float32'))
+                  for n in (1, 3, 2, 4, 1, 3, 2, 1)]:
+            f.result(timeout=120)
+        code, text = _get(srv.url + '/metrics')
+        assert code == 200
+        snap = promparse.parse_text(text)
+        fleet_rows, replica_rows = {}, {}
+        for key, val in snap['counters'].items():
+            labels = dict(snap['labels'][key])
+            replica = labels.pop('replica', None)
+            family = promparse.fmt_key(key.split('{', 1)[0], labels)
+            if replica is None:
+                fleet_rows[family] = val
+            else:
+                replica_rows.setdefault(family, []).append(val)
+        assert 'serve_requests_submitted' in replica_rows
+        assert sum(replica_rows['serve_requests_submitted']) == 8
+        assert len(replica_rows) >= 9
+        for family, vals in replica_rows.items():
+            assert fleet_rows[family] == sum(vals), family
+    finally:
+        srv.stop()
+        router.close(drain=False)
+
+
 def test_host_debug_table_reports_residency_and_sheds():
     with ModelHost(hbm_watermark_bytes=256 * MB, name='dbghost') as host:
         host.deploy('a', _infer_factory(), input_spec=[((8,), 'float32')])

@@ -4,6 +4,7 @@ priority lanes with SLO-driven batch shedding, per-tenant quotas and
 request accounting, fleet ``model@host`` targeting, and the
 ``host.admit`` / ``host.evict`` chaos points."""
 import json
+import threading
 import time
 import urllib.request
 
@@ -142,6 +143,54 @@ def test_lru_eviction_and_zero_trace_swap_in(params):
         assert host._models['a'].engine.stats()['traces'] == 0
         assert host.stats()['swap_ins'] == 1
         assert host.stats()['hbm_used_bytes'] <= 9 * MB
+
+
+def test_evict_and_swap_in_mid_traffic_lose_no_interactive_request(params):
+    """A hot model's interactive stream goes on, every request answered
+    with the single engine's tokens, while a deploy evicts the cold model
+    beside it and a later submit swaps that one back in."""
+    prompt = np.array([2, 7, 1, 8])
+    want = _reference(params, prompt, 4, seed=3)
+    answers, errors, stop = [], [], threading.Event()
+    with ModelHost(hbm_watermark_bytes=13 * MB, name='midtraffic') as host:
+        host.deploy('draft', _gen_factory(params), footprint_bytes=4 * MB)
+        host.deploy('side', _gen_factory(params), footprint_bytes=4 * MB)
+        host.deploy('chat', _gen_factory(params), footprint_bytes=4 * MB)
+
+        def pacer():
+            while not stop.is_set():
+                try:
+                    answers.append(host.submit(
+                        'chat', prompt, tenant='acme', lane='interactive',
+                        max_new_tokens=4, seed=3).result(timeout=120))
+                except Exception as e:       # noqa: BLE001 - recorded
+                    errors.append(e)
+
+        pace = threading.Thread(target=pacer)
+        pace.start()
+        try:
+            # 'draft' is the LRU cold model: the deploy must evict it
+            host.submit('side', prompt, max_new_tokens=2).result(timeout=120)
+            host.deploy('extra', _gen_factory(params),
+                        footprint_bytes=4 * MB)
+            assert host.models()['draft']['state'] == 'evicted'
+            before = len(answers)
+            got = host.submit('draft', prompt, max_new_tokens=4,
+                              seed=3).result(timeout=120)
+            deadline = time.time() + 60     # the stream outlives the swap
+            while len(answers) <= before and time.time() < deadline:
+                time.sleep(0.01)
+        finally:
+            stop.set()
+            pace.join(timeout=120)
+        assert got == want
+        assert host.models()['draft']['state'] == 'live'
+        assert host.models()['chat']['state'] == 'live'
+        assert host._models['draft'].engine.stats()['traces'] == 0
+        assert host.stats()['evictions'] >= 2 and host.stats()['swap_ins'] == 1
+        assert host.stats()['hbm_used_bytes'] <= 13 * MB
+    assert not errors, errors[:3]
+    assert len(answers) > before and all(a == want for a in answers)
 
 
 def test_explicit_evict_refuses_inflight_and_pinned(params):

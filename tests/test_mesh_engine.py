@@ -170,20 +170,22 @@ def _run_stream(engine, prompt, n_new, seed=7):
         engine.shutdown()
 
 
-@pytest.mark.parametrize('temperature', [0.0, 0.8],
-                         ids=['greedy', 'sampled'])
-def test_byte_parity_mp1_vs_mp2(temperature):
-    cfg = tiny_cfg()
+# mp=4 splits four heads; its greedy tokens are held to the dense cache by
+# test_sharded_engine_rows_against_the_dense_cache[mp4_heads4]
+@pytest.mark.parametrize('mp, temperature', [(2, 0.0), (2, 0.8), (4, 0.8)],
+                         ids=['greedy', 'sampled', 'mp4-sampled'])
+def test_byte_parity_mp1_vs_mp2(mp, temperature):
+    cfg = tiny_cfg(num_heads=mp)
     params = tiny_params(cfg)
     prompt = [5, 11, 23, 42]
     t1, s1 = _run_stream(gen_engine(params, cfg, 1,
                                     temperature=temperature), prompt, 12)
-    t2, s2 = _run_stream(gen_engine(params, cfg, 2,
+    t2, s2 = _run_stream(gen_engine(params, cfg, mp,
                                     temperature=temperature), prompt, 12)
     assert t1 == t2
     assert s1['traces'] == 2 and s2['traces'] == 2
     assert s1['mesh'] is None
-    assert s2['mesh']['mp'] == 2
+    assert s2['mesh']['mp'] == mp
 
 
 # case -> (mesh degree, config overrides, engine keywords)

@@ -177,7 +177,7 @@ def test_gpt_mfu_cost_model_within_20pct_of_analytic(monkeypatch):
     loss, params, opt_state = step(*args)
     loss.block_until_ready()
 
-    monkeypatch.setenv(perf.ENV_PEAK_FLOPS, '1e12')   # bench.py CPU peak
+    monkeypatch.setenv(perf.ENV_PEAK_FLOPS, '1e12')   # the nominal CPU peak
     rec = perf.analyze('gpt.train_step', step, args)
     assert rec is not None and rec['flops'] > 0
     analytic_flops = 6.0 * n_params * B * S

@@ -101,6 +101,8 @@ def test_warm_repeat_is_byte_identical_with_zero_new_traces(params):
         st = eng.stats()['prefix']
         assert eng._trace_count == traces == 2
         assert st['full_hits'] >= 1
+        # a full hit skips the whole prompt's prefill
+        assert eng.stats()['prefix_tokens_saved'] == len(prompt)
     assert cold == want and warm == want
 
 

@@ -210,6 +210,22 @@ def test_every_pallas_call_in_ops_has_a_name():
             assert re.search(r"\bname='\w+'", src[at:i]), (path, at)
 
 
+def test_no_module_in_ops_reads_the_environment():
+    """A kernel's choice is made from shapes and platform, not by whoever
+    launched the process."""
+    import ast
+    import os
+    ops = os.path.dirname(fa.__file__)
+    for path in glob.glob(os.path.join(ops, '*.py')):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.Attribute):
+                assert node.attr not in ('environ', 'getenv'), (
+                    path, node.lineno)
+            if isinstance(node, ast.ImportFrom) and node.module == 'os':
+                assert not {a.name for a in node.names} & {
+                    'environ', 'getenv'}, (path, node.lineno)
+
+
 # ---- spans ----------------------------------------------------------------
 
 def _captured(tmp_path, fn):

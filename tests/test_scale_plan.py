@@ -37,7 +37,7 @@ def test_param_count_matches_init_params_gqa():
 
 
 def test_1p3b_fits_v5e_with_bf16_everything():
-    """The bench.py >=1B rung's memory story: bf16 params + bf16 moments +
+    """The 1.3B widths' memory story: bf16 params + bf16 moments +
     full remat fit one 16 GiB v5e chip..."""
     plan = sp.assert_fits(sp.gpt_1p3b_dims(), sp.gpt_1p3b_v5e_layout(),
                           sp.HBM_GB['v5e'], label='gpt1.3b/v5e')

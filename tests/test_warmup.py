@@ -555,7 +555,6 @@ print(json.dumps({'prebuilt': prebuilt, 'misses': engine._cache.misses,
 '''
 
 
-@pytest.mark.slow
 def test_manifest_roundtrip_fresh_subprocess(tmp_path):
     """Capture + persistent cache in THIS process; a brand-new process
     prebuilds from the saved manifest and serves live traffic with zero

@@ -3,7 +3,7 @@
 ``paddle_tpu.analysis`` (trace hygiene, lock order, sharding rules).
 
     python tools/lint.py [paths...]            # human output, exit 1 on findings
-    python tools/lint.py paddle_tpu --json     # machine output (bench.py, CI)
+    python tools/lint.py paddle_tpu --json     # machine output (tests, CI)
     python tools/lint.py --list-rules          # rule catalogue
     python tools/lint.py --write-baseline      # grandfather current findings
 
