@@ -4,17 +4,28 @@ The continuous-batching engine (serving/generation.py) stores each slot's
 KV rows in non-contiguous fixed-size pages (ops/paged_kv.py). This module
 attends q rows to that paged cache two ways:
 
- - a Pallas TPU kernel (``_paged_decode_kernel``): grid (B*H, P_max) with
-   the flattened page table + per-slot positions riding scalar prefetch,
-   so each grid step DMAs exactly the page the table points at — the
-   kernel never materializes the gathered cache, and its wrapper never
-   re-lays the pool: a page is stored head-major (ops/paged_kv.py), so
-   the ``[page_size, D]`` block of one head is read where it lies, for
-   every head size the kernel takes. Online-softmax state
-   (acc/m/l) lives in VMEM scratch and persists across the sequential
-   page dimension, exactly the "Ragged Paged Attention" structure
-   (PAPERS.md arxiv 2604.15464). An int8 variant streams int8 pages with
-   per-row scales folded into scores/probs like flash_decode_int8.
+ - a Pallas TPU kernel (``_paged_decode_kernel``): grid (slots, blocks of
+   KV heads, P_max) with the flattened page table + per-slot positions
+   riding scalar prefetch, so each grid step DMAs exactly the page the
+   table points at — the kernel never materializes the gathered cache,
+   and its wrapper never re-lays the pool: a page is stored head-major
+   (ops/paged_kv.py), so every head of it is ONE contiguous block,
+   ``[H_kv, page_size, D]``, which a step takes whole (``decode_plan``
+   says how many heads where that is too much for the fast memory). The
+   query heads of a KV group, and a tail call's T rows, are stacked as
+   rows against their group's K block, padded to one sublane tile (16
+   rows in bf16), so a grouped model reads a page once. A step past the
+   pages a slot holds (``ceil((pos + T) / page_size)``; one for an idle
+   slot) names the slot's last page again: the pipeline fetches no block
+   twice in a row, so such a step moves no byte and computes nothing —
+   it costs the step itself, ~0.25 us, 128 a call at 16 slots x 8 pages.
+   Online-softmax state (acc/m/l, a heads axis in front) lives in VMEM
+   scratch and persists across the sequential page dimension, the
+   "Ragged Paged Attention" structure (PAPERS.md arxiv 2604.15464) but
+   for who fetches: that paper's kernel copies its own pages out of HBM,
+   which Mosaic here refuses for D 64 (PERF.md section 6, PR 30). An
+   int8 variant of the same body streams int8 pages with per-row scales
+   folded into scores/probs like flash_decode_int8.
  - a pure-``jax.numpy`` fallback: gather pages through the table into each
    slot's virtual dense cache and run the SAME masked-softmax sequence as
    the dense decode fallback in models/gpt.cached_attention — op-for-op,
@@ -25,6 +36,7 @@ attends q rows to that paged cache two ways:
 that is the whole point of continuous batching); q row j of slot b attends
 virtual positions <= pos[b] + j. Inference only (no vjp).
 """
+import collections
 import functools
 import math
 
@@ -53,6 +65,59 @@ _LANES = _fa._LANES
 _TQ = _fa._TQ_DECODE
 
 
+# What one grid step of the kernel may hold in fast memory: its page
+# buffers, q and output blocks, softmax state and the scores it works on.
+# Under the compiler's own default limit for a kernel (16 MiB on a v5e),
+# with room for what Mosaic keeps beside them, so no limit is raised.
+VMEM_BUDGET = 12 * 2 ** 20
+_IN_FLIGHT = 2      # page buffers: one being read, one on its way
+
+
+class DecodePlan(collections.namedtuple(
+        'DecodePlan', 'kv_heads rows in_flight vmem_bytes')):
+    """How one call of the kernel is tiled. ``kv_heads``: KV heads of a
+    page a grid step takes (a divisor of the heads this device holds);
+    ``rows``: q rows against each KV head (its group's query heads times
+    T, rounded up to the sublane tile of q's dtype); ``in_flight``: page
+    buffers; ``vmem_bytes``: what a step holds of ``VMEM_BUDGET``."""
+
+
+def decode_plan(h, h_kv, d, page_size, t, kv_itemsize, q_itemsize):
+    """The plan for q ``[B, t, h, d]`` over pages ``[N, h_kv, page_size,
+    d]`` (the heads ONE device holds), from the shapes alone: the most KV
+    heads a step whose buffers fit ``VMEM_BUDGET`` — every head of a page
+    where that fits, so a page is one contiguous block of the pool — or
+    None where not even one head fits (the caller then gathers)."""
+    g = h // h_kv
+    tile = 32 // q_itemsize                     # sublanes: 8 f32, 16 bf16
+    rows = -(-g * t // tile) * tile
+    d = -(-d // _LANES) * _LANES                # a row of 64 fills 128 lanes
+    quantized = kv_itemsize == 1
+    # int8 banks: every head's scales of a page come whole, K and V
+    scales = _IN_FLIGHT * 2 * h_kv * page_size * 4 if quantized else 0
+
+    def step_bytes(heads):
+        pages = _IN_FLIGHT * 2 * page_size * d * kv_itemsize     # K and V
+        if quantized:
+            pages += 2 * page_size * d * q_itemsize    # a head's, widened
+        q_out = 2 * 2 * rows * d * q_itemsize          # both double-buffered
+        state = rows * (d + 2 * _LANES) * 4            # acc, m, l
+        scores = 2 * rows * page_size * 4              # s and p, float32
+        return heads * (pages + q_out + state) + scores + scales
+
+    for heads in range(h_kv, 0, -1):
+        if h_kv % heads == 0 and step_bytes(heads) <= VMEM_BUDGET:
+            return DecodePlan(heads, rows, _IN_FLIGHT, step_bytes(heads))
+    return None
+
+
+def _plan_of(q, pages):
+    _, t, h, d = (int(x) for x in q.shape)
+    h_kv, ps = (int(x) for x in pages.shape[1:3])
+    return decode_plan(h, h_kv, d, ps, t, pages.dtype.itemsize,
+                       q.dtype.itemsize)
+
+
 def paged_attention_available(q, pages):
     """Kernel path gate. q: [B,T,H,D]; ``pages``: the k page pool
     [N, H_kv, page_size, D] (pass the bank's ``['int8']`` plane for int8
@@ -65,69 +130,35 @@ def paged_attention_available(q, pages):
     if h_kv == 0 or h % h_kv != 0:
         return False
     return (t <= _TQ and ps % 128 == 0 and d in (64, 128, 256)
-            and q.dtype in (jnp.float32, jnp.bfloat16))
+            and q.dtype in (jnp.float32, jnp.bfloat16)
+            and _plan_of(q, pages) is not None)
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, ps, tq, p_max, h):
-    """Grid (B*H, P_max); the page dim is sequential so the online-softmax
-    scratch carries across pages of one (batch, head) row. Pages past the
-    slot's needed count are skipped (their DMA still lands — a trash-page
-    read — but no FLOPs run)."""
-    i = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[i // h]
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # pages holding keys for q rows at absolute positions pos..pos+tq-1
-    needed = (pos + jnp.int32(tq) + jnp.int32(ps - 1)) // jnp.int32(ps)
-
-    @pl.when(p < needed)
-    def _compute():
-        q = q_ref[0]                                   # [TQ_PAD, D] native
-        kblk = k_ref[0, 0]                             # [ps, D]
-        vblk = v_ref[0, 0]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32
-                                ) * _np.float32(scale)            # [TQ, ps]
-        q_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = p * jnp.int32(ps) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= pos + q_row, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pr.astype(vblk.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(p == p_max - 1)
-    def _emit():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[:, :1], _EPS)).astype(o_ref.dtype)
+def _pages_held(pos, t, ps, p_max):
+    """Pages holding keys for q rows at pos..pos+t-1: at least one (an
+    idle slot's, the trash page), never past the table."""
+    return jnp.clip((pos + jnp.int32(t + ps - 1)) // jnp.int32(ps), 1, p_max)
 
 
-def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
-                              vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                              scale, ps, tq, p_max, h):
-    """int8-page variant: k scale applied to score columns, v scale folded
-    into probability rows (see flash_attention._decode_kernel_int8). The
-    scales come as the page's [H_kv, ps] block, as stored; the kernel
-    takes its KV head's row."""
-    i = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[i // h]
-    head = pl.ds((i % h) // (h // ks_ref.shape[1]), 1)
+def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
+                         ps, t, p_max):
+    """Grid (slots, blocks of KV heads, P_max); the page dim is sequential
+    so the online-softmax scratch carries across the pages of one slot. A
+    step holds ``[heads, ps, D]`` of K and of V — every head of the page
+    where the plan allows, one contiguous piece of the pool — and the q
+    rows of a KV group stacked against their group's K block. Steps past
+    the pages a slot holds name its last page again, which the pipeline
+    does not fetch twice, and compute nothing.
+
+    refs: for int8 pages the page's scales ``[H_kv, ps]`` of K and of V
+    (k scale on score columns, v scale folded into probability rows, as
+    flash_attention._decode_kernel_int8), then the output block and
+    acc / m / l."""
+    scales, (o_ref, acc_ref, m_ref, l_ref) = refs[:-4], refs[-4:]
+    heads, rows = q_ref.shape[1:3]
+    i, j, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    pos = pos_ref[i]
+    held = _pages_held(pos, t, ps, p_max)
 
     @pl.when(p == 0)
     def _init():
@@ -135,43 +166,56 @@ def _paged_decode_kernel_int8(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    needed = (pos + jnp.int32(tq) + jnp.int32(ps - 1)) // jnp.int32(ps)
-
-    @pl.when(p < needed)
+    @pl.when(p < held)
     def _compute():
-        q = q_ref[0]
-        kblk = k_ref[0, 0].astype(q.dtype)             # [ps, D]
-        ksc = ks_ref[0, head, :]                       # [1, ps] f32
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32
-                                ) * _np.float32(scale)
-        s = s * ksc
-        q_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        # row r of a KV head is query head r // t of its group, q row r % t
         k_pos = p * jnp.int32(ps) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= pos + q_row, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-        vblk = v_ref[0, 0].astype(q.dtype)
-        vsc = vs_ref[0, head, :]                       # [1, ps] f32
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            (pr * vsc).astype(q.dtype), vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            jnp.int32, (rows, ps), 1)
+        q_pos = pos
+        if t > 1:
+            q_pos = pos + jax.lax.rem(jax.lax.broadcasted_iota(
+                jnp.int32, (rows, ps), 0), jnp.int32(t))
+        visible = k_pos <= q_pos
+        for hd in range(heads):
+            q = q_ref[0, hd]                               # [rows, D] native
+            kblk, vblk = k_ref[0, hd], v_ref[0, hd]        # [ps, D]
+            if scales:
+                row = pl.ds(j * heads + hd, 1)
+                kblk, vblk = kblk.astype(q.dtype), vblk.astype(q.dtype)
+            s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32
+                                    ) * _np.float32(scale)        # [rows, ps]
+            if scales:
+                s = s * scales[0][0, row, :]               # [1, ps] f32
+            s = jnp.where(visible, s, _NEG_INF)
+            # m, l and alpha stay as they are stored, one value a row in
+            # every lane: taking lane 0 and spreading it again costs a
+            # lane permute each, and those were most of a head's time
+            m_prev, l_prev = m_ref[hd], l_ref[hd]          # [rows, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            pr = jnp.exp(s - _fa._lanes(m_new, ps))
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+            if scales:
+                pr = pr * scales[1][0, row, :]
+            acc_ref[hd] = (acc_ref[hd] * _fa._lanes(alpha, acc_ref.shape[2])
+                           + jax.lax.dot_general(
+                               pr.astype(vblk.dtype), vblk,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32))
+            m_ref[hd] = m_new
+            l_ref[hd] = l_new
 
     @pl.when(p == p_max - 1)
     def _emit():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[:, :1], _EPS)).astype(o_ref.dtype)
+        for hd in range(heads):
+            o_ref[0, hd] = (acc_ref[hd] / _fa._lanes(
+                jnp.maximum(l_ref[hd], _EPS), acc_ref.shape[2])
+                ).astype(o_ref.dtype)
 
 
-def _kernel_call(kernel_fn, q, page_table, pos, pools):
-    """One paged decode kernel over (under a mesh) the block each device
+def _kernel_call(q, page_table, pos, pools):
+    """The paged decode kernel over (under a mesh) the block each device
     holds: slots split over 'dp', heads over 'mp' — the pool's own layout
     (ops/paged_kv.POOL_LOGICAL_AXES), so no page moves between devices.
     pools: planes in pool layout — pages [N, H_kv, page_size, D] and, for
@@ -183,38 +227,52 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
 
     def core(q, page_table, pos, *pools):
         b, _, h, _ = q.shape                  # this device's slots / heads
-        g = h // pools[0].shape[1]
-        bh = b * h
-        qt = _fa._pad_seq(q.transpose(0, 2, 1, 3).reshape(bh, t, d), _TQ)
-        # nothing is re-laid: a head's rows of a page are one block of the
-        # pool as it is stored, and a bank's scales come a page at a time,
-        # every head's (a block's last two dims are whole tiles). The page
-        # id comes straight out of the prefetched table
-        page_id = lambda i, p, pt: pt[(i // h) * p_max + p]
-        page = lambda i, p, pt, _pos: (page_id(i, p, pt), (i % h) // g, 0, 0)
-        scales = lambda i, p, pt, _pos: (page_id(i, p, pt), 0, 0)
+        h_kv = pools[0].shape[1]
+        plan = _plan_of(q, pools[0])
+        if plan is None:
+            raise ValueError(
+                f'no head of a page {tuple(pools[0].shape[1:])} fits the '
+                f'kernel\'s fast memory: paged_attention_available says so')
+        heads, rows = plan.kv_heads, plan.rows
+        # a KV group's query heads are neighbours, so stacking them (and
+        # their t rows) against the group's K block is a view when t is 1
+        qt = q.transpose(0, 2, 1, 3).reshape(b, h_kv, (h // h_kv) * t, d)
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, rows - qt.shape[2]), (0, 0)))
+
+        # nothing is re-laid: the heads of a page are one block of the pool
+        # as it is stored, and a bank's scales come a page at a time, every
+        # head's. The page id comes straight out of the prefetched table,
+        # and past the pages a slot holds it stays the last one's
+        def page_id(i, p, pt, pos):
+            return pt[i * p_max
+                      + jnp.minimum(p, _pages_held(pos[i], t, ps, p_max) - 1)]
+        page = lambda i, j, p, pt, pos: (page_id(i, p, pt, pos), j, 0, 0)
+        scales = lambda i, j, p, pt, pos: (page_id(i, p, pt, pos), 0, 0)
+        block = pl.BlockSpec((1, heads, rows, d),
+                             lambda i, j, p, *_: (i, j, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bh, p_max),
-            in_specs=[pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0))]
-            + [pl.BlockSpec((1, 1) + x.shape[2:], page) if x.ndim == 4
-               else pl.BlockSpec((1,) + x.shape[1:], scales) for x in pools],
-            out_specs=pl.BlockSpec((1, _TQ, d), lambda i, p, *_: (i, 0, 0)),
+            grid=(b, h_kv // heads, p_max),
+            in_specs=[block] + [
+                pl.BlockSpec((1, heads) + x.shape[2:], page) if x.ndim == 4
+                else pl.BlockSpec((1,) + x.shape[1:], scales) for x in pools],
+            out_specs=block,
             scratch_shapes=[
-                pltpu.VMEM((_TQ, d), jnp.float32),        # acc
-                pltpu.VMEM((_TQ, _LANES), jnp.float32),   # m (lane-bcast)
-                pltpu.VMEM((_TQ, _LANES), jnp.float32),   # l
+                pltpu.VMEM((heads, rows, d), jnp.float32),        # acc
+                pltpu.VMEM((heads, rows, _LANES), jnp.float32),   # m
+                pltpu.VMEM((heads, rows, _LANES), jnp.float32),   # l
             ],
         )
         out = pl.pallas_call(
-            functools.partial(kernel_fn, scale=1.0 / math.sqrt(d), ps=ps,
-                              tq=t, p_max=p_max, h=h),
+            functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
+                              ps=ps, t=t, p_max=p_max),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((bh, _TQ, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
             interpret=_fa._INTERPRET,
             name='paged_attention',
         )(page_table.reshape(-1), pos, qt, *pools)
-        return out[:, :t].reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        return out[:, :, :(h // h_kv) * t].reshape(b, h, t, d).transpose(
+            0, 2, 1, 3)
 
     return mesh_kernel.sharded_call(
         core,
@@ -228,8 +286,7 @@ def _kernel_call(kernel_fn, q, page_table, pos, pools):
 def paged_flash_decode(q, k_pages, v_pages, page_table, pos):
     """Pallas paged decode. q: [B,T,H,D]; pages [N, H_kv, page_size, D];
     page_table [B, P_max] i32; pos [B] i32 -> [B,T,H,D]."""
-    return _kernel_call(_paged_decode_kernel, q, page_table, pos,
-                        [k_pages, v_pages])
+    return _kernel_call(q, page_table, pos, [k_pages, v_pages])
 
 
 def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos):
@@ -237,7 +294,7 @@ def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos):
     ``{'int8': [N, H_kv, page_size, D] int8, 'scale': [N, H_kv,
     page_size] f32}`` (ops/paged_kv.paged_write rows)."""
     return _kernel_call(
-        _paged_decode_kernel_int8, q, page_table, pos,
+        q, page_table, pos,
         [k_bank['int8'], v_bank['int8'], k_bank['scale'], v_bank['scale']])
 
 

@@ -83,8 +83,8 @@ def _cases(devices):
         return {'int8': S(pages, jnp.int8, sharding),
                 'scale': S(pages[:3], jnp.float32, sharding)}
 
-    def paged(d, int8):
-        q = S((8, 1, 16, d), bf16)
+    def paged(d, int8, t=1, heads=16):
+        q = S((8, t, heads, d), bf16)
         assert pa.paged_attention_available(q, pool(d, False))
         kernel = pa.paged_flash_decode_int8 if int8 else pa.paged_flash_decode
         return lambda: text(kernel, q, pool(d, int8), pool(d, int8),
@@ -198,6 +198,11 @@ def _cases(devices):
         'paged_int8_d64': paged(64, True),
         'paged_bf16_d128': paged(128, False),
         'paged_int8_d128': paged(128, True),
+        # what PR 30 makes new: a KV group's query heads as rows of one
+        # block (64 heads over the pool's 16), a tail call's rows among
+        # them (the q row of a score is its row modulo T), 128 rows a head
+        'paged_gqa4_tail5': paged(128, False, t=5, heads=64),
+        'paged_int8_tail128': paged(64, True, t=128),
         'decode_bf16': decode(False),
         'decode_int8': decode(True),
         'flash_dropout_dp2_mp2': lambda: text(flash_fwd_bwd(0.1), qm, qm, qm,
@@ -265,6 +270,11 @@ def _child():
             out[name] = {
                 'kernels': text.count('tpu_custom_call'),
                 'pool_copies': len(moved), 'moved': sorted(set(moved)),
+                # q as the kernel took it before PR 30: 16 slots x 16
+                # heads, each head's one row padded to 128
+                # (8 heads in the moe_gpt case)
+                'padded_q': bool(re.search(r'bf16\[(256|128),128,128\]',
+                                           text)),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -309,14 +319,19 @@ def _summary(case):
     ('flash_s1100_kv_valid', 3),
     ('paged_bf16_d64', 1), ('paged_int8_d64', 1),
     ('paged_bf16_d128', 1), ('paged_int8_d128', 1),
+    ('paged_gqa4_tail5', 1), ('paged_int8_tail128', 1),
     ('decode_bf16', 1), ('decode_int8', 1),
 ])
 def test_kernel_compiles_for_v5e(compiled, case, kernels):
     """The paged cases at D 64 and D 128 take ONE path: a page is stored
-    head-major, so the kernel's ``[page_size, D]`` block is read out of
-    the pool as it lies whatever the head size; ``pool_copies`` 0 says
-    the wrapper re-lays nothing in front of the call (before PR 28 it
-    transposed every plane it was handed)."""
+    head-major, so the kernel's ``[heads, page_size, D]`` block (every
+    head of the page since PR 30) is read out of the pool as it lies
+    whatever the head size; ``pool_copies`` 0 says the wrapper re-lays
+    nothing in front of the call (before PR 28 it transposed every plane
+    it was handed). The form that copies its own pages out of HBM
+    (``memory_space=ANY``, ``make_async_copy``) compiles at D 128 and is
+    refused at D 64 (a 64-wide row lies in 128 lanes and Mosaic slices no
+    such array), which is why the pipeline fetches the pages."""
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
 
@@ -335,6 +350,17 @@ def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
     step (PERF.md section 6)."""
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+
+
+@pytest.mark.parametrize('case', ['gpt_xl_step', 'gpt_xl_step_int8_kv',
+                                  'moe_gpt_step'])
+def test_step_program_pads_no_q_to_128_rows(compiled, case):
+    """Before PR 30 every layer of every step made q ``[256, 128, 128]``
+    by a pad (8.4 MB at GPT-3 XL's 16 slots x 16 heads for 16 KB of rows),
+    the kernel read it, wrote as much, and a slice took the 16 KB back.
+    The kernel takes a head's rows padded to one sublane tile (16 in
+    bf16): nothing in the step has that shape."""
+    assert compiled[case]['padded_q'] is False, compiled[case]
 
 
 def test_int8_kv_step_moves_only_its_scales(compiled):

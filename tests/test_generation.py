@@ -240,40 +240,76 @@ def test_paged_kernel_interpret_parity(int8):
 
 # what PR 28 changed under the kernel: a page is head-major and is read
 # where it lies, for every head size; the pool comes whole (every layer's
-# pages) with the table offset to one layer's
-KERNEL_CASES = {
-    #            d   h  h_kv  t  layers  int8
-    'd128':     (128, 2, 2,   1, 1, False),
-    'd256':     (256, 2, 2,   1, 1, False),
-    'gqa':      (64,  4, 2,   1, 1, False),
-    'gqa_int8': (128, 4, 2,   1, 1, True),
-    'layer_2_of_3': (128, 2, 2, 1, 3, False),
-    'layer_2_of_3_int8': (64, 2, 2, 1, 3, True),
-    'tail_rows': (128, 2, 2,  4, 1, False),
+# pages) with the table offset to one layer's. What PR 30 changed: a grid
+# step takes every head of a page (fewer where ``decode_plan`` says they
+# do not fit), a KV group's query heads are rows against one K block, and
+# a step past the pages a slot holds names its last page again
+def _case(d, h, h_kv, t=1, layers=1, pos=(130, 200), p_max=2, budget=None):
+    return dict(d=d, h=h, h_kv=h_kv, t=t, layers=layers, pos=pos,
+                p_max=p_max, budget=budget)
+
+
+_KERNEL_SHAPES = {
+    'd128': _case(128, 2, 2),
+    'd256': _case(256, 2, 2),
+    'd64': _case(64, 2, 2),
+    'gqa': _case(64, 4, 2),
+    'gqa_d128': _case(128, 4, 2),
+    'layer_2_of_3': _case(128, 2, 2, layers=3),
+    'layer_2_of_3_d64': _case(64, 2, 2, layers=3),
+    # the served shape's heads; an idle slot (pos 0, its table all trash)
+    # beside a full one
+    'heads16_idle_and_full': _case(128, 16, 16, pos=(0, 1023), p_max=8),
+    'heads16_kv4': _case(128, 16, 4, pos=(1023, 0), p_max=8, layers=2),
+    # a slot's rows end on a page's last row, or begin the next page
+    'page_edges': _case(64, 2, 2, pos=(127, 128, 255)),
+    'held_1_3_8_of_8': _case(128, 2, 1, pos=(100, 300, 1000), p_max=8),
+    'tail_4': _case(128, 2, 2, t=4),
+    'tail_5_gqa': _case(64, 4, 2, t=5, pos=(130, 3)),
+    'tail_16': _case(128, 2, 2, t=16, pos=(127, 240)),
+    'tail_17_gqa': _case(128, 4, 1, t=17, pos=(111, 0)),
+    'tail_128': _case(64, 2, 2, t=128, pos=(0, 128)),
+    # a budget that holds one KV head (of four) and two (of four) a step:
+    # the grid's head-block axis, which no real shape of these sizes takes
+    'head_blocks_1_of_4': _case(128, 4, 4, budget=2 ** 19),
+    'head_blocks_2_of_4_gqa': _case(64, 8, 4, t=3, budget=2 ** 19 + 2 ** 18),
 }
+KERNEL_CASES = {f'{name}{"_int8" if int8 else ""}': dict(shape, int8=int8)
+                for name, shape in _KERNEL_SHAPES.items()
+                for int8 in (False, True)}
 
 
 @pytest.mark.parametrize('case', sorted(KERNEL_CASES))
-def test_paged_kernel_reads_pages_where_they_lie(case):
-    d, h, h_kv, t, layers, int8 = KERNEL_CASES[case]
+def test_paged_kernel_reads_pages_where_they_lie(case, monkeypatch):
+    c = KERNEL_CASES[case]
+    d, h, h_kv, t, int8 = c['d'], c['h'], c['h_kv'], c['t'], c['int8']
     rng = np.random.RandomState(len(case))
-    b, ps, p_max = 2, 128, 2
+    b, ps, p_max = len(c['pos']), 128, c['p_max']
     n = b * p_max + 1
-    layer = layers - 1
+    pos = jnp.asarray(c['pos'], jnp.int32)
+    # the pages a slot holds, in any order; the rest of its row is trash
+    held = [-(-(p + t) // ps) if p or t > 1 else 0 for p in c["pos"]]
+    free = iter(rng.permutation(np.arange(1, n)))
+    table = np.zeros((b, p_max), np.int32)
+    for i, k in enumerate(held):
+        table[i, :k] = [next(free) for _ in range(k)]
+    table = jnp.asarray(table + (c['layers'] - 1) * n)
     q = jnp.asarray(rng.randn(b, t, h, d), jnp.float32) * 0.3
-    pos = jnp.asarray([130, 200], jnp.int32)
-    table = jnp.asarray([[3, 1], [2, 4]], jnp.int32) + layer * n
     pools = []
-    for _ in range(2):
-        pool = jnp.asarray(rng.randn(layers * n, h_kv, ps, d),
-                           jnp.float32) * 0.3
+    for _ in range(2):      # every page holds something, the trash page too
+        shape = (c['layers'] * n, h_kv, ps, d)
         if int8:
-            pool = {'int8': jnp.zeros(pool.shape, jnp.int8),
-                    'scale': jnp.zeros(pool.shape[:3], jnp.float32)}
-        rows = jnp.asarray(rng.randn(b, 256, h_kv, d), jnp.float32) * 0.3
-        pools.append(paged_kv.paged_write(pool, rows, table,
-                                          jnp.zeros((b,), jnp.int32)))
+            pools.append({
+                'int8': jnp.asarray(rng.randint(-127, 128, shape), jnp.int8),
+                'scale': jnp.asarray(rng.uniform(1e-3, 5e-3, shape[:3]),
+                                     jnp.float32)})
+        else:
+            pools.append(jnp.asarray(rng.randn(*shape), jnp.float32) * 0.3)
     kp, vp = pools
+    if c['budget']:
+        monkeypatch.setattr(pa, 'VMEM_BUDGET', c['budget'])
+        plan = pa.decode_plan(h, h_kv, d, ps, t, 1 if int8 else 4, 4)
+        assert plan.kv_heads < h_kv, plan
     fa.set_interpret(True)
     try:
         assert pa.paged_attention_available(q, kp['int8'] if int8 else kp)
@@ -284,6 +320,61 @@ def test_paged_kernel_reads_pages_where_they_lie(case):
     tol = 2e-2 if int8 else 2e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
+
+
+# every shape a configuration or a test hands the kernel: head sizes 64 /
+# 128 / 256, 1-32 KV heads (whole, or a quarter of them under the mesh
+# engine's mp 4), groups of 1 and 4, decode steps and tails up to 128 rows
+@pytest.mark.parametrize('mp', [1, 4])
+@pytest.mark.parametrize('kv_itemsize,q_itemsize',
+                         [(1, 2), (1, 4), (2, 2), (4, 4)])
+@pytest.mark.parametrize('d', [64, 128, 256])
+def test_decode_plan_fits_its_budget_and_covers_every_head(
+        d, kv_itemsize, q_itemsize, mp):
+    for h_kv in (1, 2, 4, 8, 16, 32):
+        if h_kv % mp:
+            continue
+        for g in (1, 4):
+            for t in (1, 2, 5, 16, 17, 128):
+                for ps in (128, 256):
+                    plan = pa.decode_plan(h_kv * g // mp, h_kv // mp, d, ps,
+                                          t, kv_itemsize, q_itemsize)
+                    assert plan is not None, (h_kv, g, t, ps)
+                    assert plan.vmem_bytes <= pa.VMEM_BUDGET, plan
+                    # blocks of equal size, each head in exactly one
+                    assert (h_kv // mp) % plan.kv_heads == 0, plan
+                    assert plan.kv_heads >= 1 and plan.in_flight == 2, plan
+                    tile = 32 // q_itemsize
+                    assert plan.rows % tile == 0, plan
+                    assert g * t <= plan.rows < g * t + tile, plan
+
+
+def test_decode_plan_of_the_served_shapes():
+    """GPT-3 XL as ``gpt-1.3b-serve`` runs it (16 heads of 128, bf16 pages
+    of 128 rows) takes a page whole: all 16 heads, K and V double-buffered
+    2 x 2 x 512 KB, 16 q rows a head for the one that is real. So do its
+    int8 banks and the mesh engine's quarter. A step of more heads than
+    fit takes a divisor of them; a page of which one head does not fit has
+    no plan, and the gate sends the call to the gather."""
+    plan = pa.decode_plan(16, 16, 128, 128, 1, 2, 2)
+    assert (plan.kv_heads, plan.rows, plan.in_flight) == (16, 16, 2)
+    assert 4 * 512 * 1024 <= plan.vmem_bytes <= 3 * 2 ** 20
+    assert pa.decode_plan(16, 16, 128, 128, 1, 1, 2).kv_heads == 16
+    assert pa.decode_plan(4, 4, 64, 128, 1, 2, 2).kv_heads == 4
+    # a 128-row tail at D 256 over 32 KV heads: q, output and state are
+    # what is large, and the step takes fewer heads for them
+    tail = pa.decode_plan(32, 32, 256, 128, 128, 2, 2)
+    assert tail.kv_heads in (4, 8, 16) and tail.vmem_bytes <= pa.VMEM_BUDGET
+    assert pa.decode_plan(2, 2, 256, 16384, 1, 2, 2) is None
+    q = jnp.zeros((1, 1, 2, 256), jnp.bfloat16)
+    fa.set_interpret(True)
+    try:
+        assert not pa.paged_attention_available(
+            q, jax.ShapeDtypeStruct((3, 2, 16384, 256), jnp.bfloat16))
+        assert pa.paged_attention_available(
+            q, jax.ShapeDtypeStruct((3, 2, 128, 256), jnp.bfloat16))
+    finally:
+        fa.set_interpret(False)
 
 
 @pytest.mark.parametrize('t,start', [(1, 0), (1, 7), (1, 8), (5, 6), (16, 0),
