@@ -17,6 +17,17 @@ it, registered for its config class:
         small int32 array that rides in the step's one host read and is
         handed to ``note_counts(counts, phase)``
 
+A family whose layers do not all keep their rows alike names its KINDS of
+plane (``page_kinds``): full-attention layers, whose pages a slot keeps for
+its whole life, beside window layers, which read only a slot's last
+``window`` rows. The engine then keeps an allocator and a page table a
+kind, gives back every page of a window kind that has left the window,
+calls ``init_pool(config, {kind: pages}, page_size)`` and hands the forward
+``page_table`` as ``{kind: [B, P_max]}``. A table's entries before a slot's
+window read 0 (the trash page): the family writes no row there that it
+needs and reads none. A family that names no kinds has one, and nothing
+about it changes.
+
 The engine never asks what kind of model it serves: it looks the family up
 by the config's class (``family_of``).
 """
@@ -24,6 +35,18 @@ import dataclasses
 import typing
 
 from ..ops.paged_kv import POOL_LOGICAL_AXES
+
+
+@dataclasses.dataclass(frozen=True)
+class PageKind:
+    """One kind of pool plane. ``window``: the rows behind its position
+    (itself among them) that a slot's layers of this kind still read;
+    None: all of them."""
+    name: str
+    window: typing.Optional[int] = None
+
+
+ONE_KIND = (PageKind('kv'),)    # a family that names none
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +67,8 @@ class GenerationFamily:
     # pool (what a prefix cache's hit needs); False: the engine refuses the
     # family a prefix cache
     tail_prefill: bool = True
+    # config -> (PageKind, ...); None: one kind, pool and table as above
+    page_kinds: typing.Optional[typing.Callable] = None
 
 
 _FAMILIES = {}

@@ -137,6 +137,7 @@ _EPS = _np.float32(1e-30)
 
 _SUB = 256   # edge of a diagonal tile's sub-tiles (measured on the v5e at
              # D 64 and D 128; PERF.md, PR 25)
+_VMEM_UNASKED = 12 * 2 ** 20    # what a kernel may hold without asking
 
 
 def _cdiv(a, b):
@@ -172,6 +173,21 @@ def _kv_bounds(qi, causal, bq, bk, q_off, kv_valid, nkb):
         n_int = _hi(0, _lo(n_int, (last + 1) // bk))
         n_iter = _hi(n_int, _lo(n_iter, (last + bq - 1) // bk + 1))
     return n_int, n_iter
+
+
+def _window_bounds(qi, bq, bk, q_off, window, n_int):
+    """(n_skip, n_edge) for q block ``qi`` of a causal forward whose row r
+    keeps only the keys r + q_off - window < c: k/v tiles [0, n_skip) lie
+    wholly before the window of every row and are not visited, [n_skip,
+    n_edge) are crossed by the window's lower edge and masked, [n_edge,
+    n_int) keep every score as before. Both are clamped to ``n_int``: a
+    tile the diagonal crosses too is the diagonal's."""
+    first = qi * bq + q_off - window + 1    # first key the FIRST row keeps
+    n_skip = _lo(n_int, _hi(0, first // bk))
+    # the LAST row's first key, first + bq - 1, cuts the tile it lies in
+    # unless it is that tile's first
+    n_edge = _lo(n_int, _hi(n_skip, _cdiv(first + bq - 1, bk)))
+    return n_skip, n_edge
 
 
 def _q_bounds(ki, causal, bq, bk, q_off, nqb):
@@ -220,35 +236,56 @@ def _program_tiles(kv_major, i, causal, bq, bk, q_off, kv_valid, nqb, nkb):
             n_iter - n_int - len(whole))
 
 
-def _diag_rels(kv_major, *geometry):
+def _diag_rels(kv_major, *geometry, window=None):
     """The diagonal tiles' ``rel``s when they are the same in every program,
     else None (blocks of unequal size, ends that clamp, a cut last block in
-    the forward and dq): the tiles are then masked whole, in a loop.
+    the forward and dq, a forward's window whose lower edge crosses a tile
+    the diagonal crosses too, which it cannot once it is as long as a
+    block): the tiles are then masked whole, in a loop.
     ``geometry``: causal, bq, bk, q_off, kv_valid, nqb, nkb."""
-    kv_valid, nqb, nkb = geometry[-3:]
+    _, bq, bk, q_off, kv_valid, nqb, nkb = geometry
     if kv_valid is not None and not kv_major:
+        return None
+    if window is not None and any(
+            _window_bounds(qi, bq, bk, q_off, window, nkb)[1]
+            > _kv_bounds(qi, True, bq, bk, q_off, kv_valid, nkb)[0]
+            for qi in range(nqb)):
         return None
     tails = {tuple(_program_tiles(kv_major, i, *geometry)[1])
              for i in range(nkb if kv_major else nqb)}
     return tails.pop() if len(tails) == 1 else None
 
 
-def causal_tile_plan(s_q, s_k, bq, bk, q_off=0, kv_valid=None, causal=True):
+def causal_tile_plan(s_q, s_k, bq, bk, q_off=0, kv_valid=None, causal=True,
+                     window=None):
     """What the three kernels do with the tiles of ONE attention row (a head
     of a batch element) of padded lengths ``s_q`` x ``s_k``: per variant
     the tiles that take the mask-free body (``interior``), those the
     diagonal crosses (``diagonal``), those a cut last k/v block makes
     (``padded``), and of the diagonal tiles' ``sub``-shaped sub-tiles those
-    skipped, masked and mask-free."""
+    skipped, masked and mask-free. With a ``window`` (the forward alone
+    takes one) the plan is the forward's: of its interior tiles those
+    wholly before every row's window are ``window_skipped`` and those the
+    window's lower edge crosses ``window_edge``, and neither is interior."""
     geometry = (causal, bq, bk, q_off, kv_valid, s_q // bq, s_k // bk)
     plan = {}
     for variant, kv_major in (('fwd', False), ('dq', False), ('dkv', True)):
-        rels = _diag_rels(kv_major, *geometry)
+        if window is not None and variant != 'fwd':
+            continue
+        rels = _diag_rels(kv_major, *geometry, window=window)
         sub = _sub_edges(bq, bk) if rels is not None else (bq, bk)
         n = dict.fromkeys(('interior', 'diagonal', 'padded', 'sub_skipped',
                            'sub_masked', 'sub_free'), 0)
+        if window is not None:
+            n.update(window_skipped=0, window_edge=0)
         for i in range(geometry[-1] if kv_major else geometry[-2]):
             interior, rels, padded = _program_tiles(kv_major, i, *geometry)
+            if window is not None:
+                n_skip, n_edge = _window_bounds(i, bq, bk, q_off, window,
+                                                interior)
+                n['window_skipped'] += n_skip
+                n['window_edge'] += n_edge - n_skip
+                interior -= n_edge
             n['interior'] += interior
             n['diagonal'] += len(rels)
             n['padded'] += padded
@@ -266,12 +303,15 @@ def _count_tiles(kernel, rows, plan):
     ``pallas_call`` run mask-free and how many masked."""
     for name, n in (
             ('flash.tiles_unmasked_total', plan['interior']),
-            ('flash.tiles_masked_total', plan['diagonal'] + plan['padded'])):
+            ('flash.tiles_masked_total', plan['diagonal'] + plan['padded']
+             + plan.get('window_edge', 0))):
         _obs.counter(name, {'kernel': kernel}).inc(rows * n)
 
 
-def _mask_scores(s, causal, qi_or_qb, kb, bq, bk, q_off, kv_valid):
-    """Apply causal / valid-key-bound masking to one [BQ, BK] score tile."""
+def _mask_scores(s, causal, qi_or_qb, kb, bq, bk, q_off, kv_valid,
+                 window=None):
+    """Apply causal / window / valid-key-bound masking to one [BQ, BK] score
+    tile."""
     need_kpos = causal or kv_valid is not None
     if not need_kpos:
         return s
@@ -279,6 +319,8 @@ def _mask_scores(s, causal, qi_or_qb, kb, bq, bk, q_off, kv_valid):
     if causal:
         q_pos = qi_or_qb * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(q_pos + q_off >= k_pos, s, _NEG_INF)
+        if window is not None:
+            s = jnp.where(q_pos + (q_off - window) < k_pos, s, _NEG_INF)
     if kv_valid is not None:
         s = jnp.where(k_pos < kv_valid, s, _NEG_INF)
     return s
@@ -406,7 +448,7 @@ def _diag_strips(diag, bq, bk, kv_major):
 
 
 def _fwd_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
-                diag, drop_rate=0.0):
+                diag, drop_rate=0.0, window=None):
     # Scalar constants pinned to f32 (Mosaic rejects f64). MXU dtype policy:
     # q/k/v stay in their NATIVE dtype for the dot_generals (bf16 inputs run
     # the MXU at full rate) with f32 accumulation via preferred_element_type;
@@ -493,13 +535,22 @@ def _fwd_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    masked = lambda kb, _: tile(kb, lambda s: _mask_scores(
+        s, causal, qi, kb, bq, bk, q_off, kv_valid, window))
+    n_edge = jnp.int32(0)
+    if window is not None:
+        # tiles before every row's window are not visited; those its lower
+        # edge crosses are masked whole. A row with no key in such a tile
+        # adds nothing after the diagonal's tiles started its softmax, and
+        # where they did not, what it adds is scaled to nothing by the
+        # first tile that holds a key of its own (its diagonal's, at last)
+        n_skip, n_edge = (jnp.asarray(n, jnp.int32) for n in _window_bounds(
+            qi, bq, bk, q_off, window, n_int))
+        jax.lax.fori_loop(n_skip, n_edge, masked, None)
     # interior tiles: no iota, no compare, no select
-    jax.lax.fori_loop(jnp.int32(0), n_int, lambda kb, _: tile(kb), None)
+    jax.lax.fori_loop(n_edge, n_int, lambda kb, _: tile(kb), None)
     if diag is None:        # diagonal / cut tiles masked whole
-        jax.lax.fori_loop(
-            n_int, n_iter,
-            lambda kb, _: tile(kb, lambda s: _mask_scores(
-                s, causal, qi, kb, bq, bk, q_off, kv_valid)), None)
+        jax.lax.fori_loop(n_int, n_iter, masked, None)
     l = jnp.maximum(l_ref[...], _EPS)
     o_ref[0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
     # TPU tiling: lse is stored broadcast across the 128 lanes too
@@ -507,27 +558,38 @@ def _fwd_kernel(*refs, causal, scale, bq, bk, q_off, kv_valid, has_kmask,
 
 
 def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
-               g=1, bq=None, bk=None, drop_rate=0.0, seed=None):
+               g=1, bq=None, bk=None, drop_rate=0.0, seed=None, window=None):
     """q: [BH, S_q, D]; k/v: [BH//g, S_k, D] (g = query-group size, GQA)
     -> (out [BH,S_q,D], lse [BH,S_q]). Each kv row serves its g query heads
     via the block index map — repeated KV is never materialized.
     kmask: additive f32 [B, S_k] (BH = B*h, mask row b//h) or None.
     bq/bk: block rows (must divide s_q/s_k); auto-picked when None.
-    drop_rate/seed: in-kernel attention dropout (seed: u32[1], SMEM)."""
+    drop_rate/seed: in-kernel attention dropout (seed: u32[1], SMEM).
+    window: a causal row keeps its last ``window`` keys only (itself
+    among them); the call is then named ``flash_fwd_window``."""
     bh, s_q, d = q.shape
     s_k = int(k.shape[1])
     if bq is None or bk is None:
         bq, bk = _pick_blocks(s_q, s_k)
     scale = 1.0 / math.sqrt(d)
     grid = (bh, s_q // bq)
+    geometry = (causal, bq, bk, q_off, kv_valid, s_q // bq, s_k // bk)
     _count_tiles('flash_fwd', bh, causal_tile_plan(
-        s_q, s_k, bq, bk, q_off, kv_valid, causal)['fwd'])
+        s_q, s_k, bq, bk, q_off, kv_valid, causal, window)['fwd'])
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, bq=bq, bk=bk, q_off=q_off,
         kv_valid=kv_valid, has_kmask=kmask is not None,
-        diag=_diag_rels(False, causal, bq, bk, q_off, kv_valid, s_q // bq,
-                        s_k // bk),
-        drop_rate=drop_rate)
+        diag=_diag_rels(False, *geometry, window=window),
+        drop_rate=drop_rate, window=window)
+    # every key and value of a head lies in fast memory while its q blocks
+    # run: past what the compiler grants a kernel unasked (16 MiB on a v5e,
+    # at 16k keys of 128) the call asks for what it holds, and no call
+    # that fitted before asks for anything
+    held = 2 * 2 * s_k * d * k.dtype.itemsize + 4 * bq * d * q.dtype.itemsize
+    params = {}
+    if held > _VMEM_UNASKED:
+        params['compiler_params'] = pltpu.CompilerParams(
+            vmem_limit_bytes=held + _VMEM_UNASKED)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i: (b, i, _np.int32(0))),
         pl.BlockSpec((1, s_k, d),
@@ -560,7 +622,8 @@ def _flash_fwd(q, k, v, causal, q_off=0, kv_valid=None, kmask=None, h=1,
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, _LANES), jnp.float32)],
         interpret=_INTERPRET,
-        name='flash_fwd',
+        name='flash_fwd' if window is None else 'flash_fwd_window',
+        **params,
     )(*args)
     return out, lse[:, :, 0]
 
@@ -922,24 +985,29 @@ def _bwd_pallas_pre(q, k, v, g, lse_b, dta_b, causal, q_off=0, kv_valid=None,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash(q, k, v, kmask, seed, causal, q_off, kv_valid, h, groups, bq, bk,
-           drop_rate):
+           drop_rate, window=None):
     out, _ = _flash_fwd(q, k, v, causal, q_off=q_off, kv_valid=kv_valid,
                         kmask=kmask, h=h, g=groups, bq=bq, bk=bk,
-                        drop_rate=drop_rate, seed=seed)
+                        drop_rate=drop_rate, seed=seed, window=window)
     return out
 
 
 def _flash_f(q, k, v, kmask, seed, causal, q_off, kv_valid, h, groups, bq,
-             bk, drop_rate):
+             bk, drop_rate, window=None):
     out, lse = _flash_fwd(q, k, v, causal, q_off=q_off, kv_valid=kv_valid,
                           kmask=kmask, h=h, g=groups, bq=bq, bk=bk,
-                          drop_rate=drop_rate, seed=seed)
+                          drop_rate=drop_rate, seed=seed, window=window)
     return out, (q, k, v, kmask, seed, out, lse)
 
 
-def _flash_b(causal, q_off, kv_valid, h, groups, bq, bk, drop_rate, res, g):
+def _flash_b(causal, q_off, kv_valid, h, groups, bq, bk, drop_rate, window,
+             res, g):
+    if window is not None:
+        raise NotImplementedError(
+            'the flash backward kernels know no window: windowed attention '
+            'is a forward (a served prefill), nothing trains through it')
     q, k, v, kmask, seed, out, lse = res
     dq, dk, dv = _bwd_pallas(q, k, v, out, lse, g, causal, q_off=q_off,
                              kv_valid=kv_valid, kmask=kmask, h=h,
@@ -984,7 +1052,8 @@ def repeat_kv(k, v, n_q_heads):
     return jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
 
 
-def _jnp_attention(q, k, v, causal, mask, drop_rate=0.0, seed=None):
+def _jnp_attention(q, k, v, causal, mask, drop_rate=0.0, seed=None,
+                   window=None):
     """XLA-softmax fallback for shapes the kernels decline ([B,S,H,D]).
     With ``drop_rate``, applies the SAME counter-hash dropout mask as the
     kernels (row = b*H + h of the flattened layout), so kernel/fallback
@@ -996,6 +1065,9 @@ def _jnp_attention(q, k, v, causal, mask, drop_rate=0.0, seed=None):
     if causal:
         qlen, klen = scores.shape[-2], scores.shape[-1]
         cm = jnp.tril(jnp.ones((qlen, klen), jnp.bool_), k=klen - qlen)
+        if window is not None:
+            cm = cm & ~jnp.tril(jnp.ones((qlen, klen), jnp.bool_),
+                                k=klen - qlen - window)
         scores = jnp.where(cm, scores, _NEG_INF)
     if mask is not None:
         m = lift_mask_4d(mask)
@@ -1019,7 +1091,7 @@ def _jnp_attention(q, k, v, causal, mask, drop_rate=0.0, seed=None):
 
 
 def flash_attention(q, k, v, causal=False, mask=None, dropout_rate=0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, window=None):
     """q: [B, S_q, H, D]; k/v: [B, S_k, H, D] (paddle layout) -> [B,S_q,H,D].
 
     mask: optional KEY-PADDING mask — bool (True = attend) or additive
@@ -1034,8 +1106,16 @@ def flash_attention(q, k, v, causal=False, mask=None, dropout_rate=0.0,
     u32 scalar/[1] array (traced — vary it per step) hashed per
     (row, q, k) element by ``_dropout_keep``, so fwd and bwd regenerate
     the mask instead of storing it. rate >= 1 is rejected (use the jnp
-    path's all-dropped semantics via scaled_dot_product_attention)."""
+    path's all-dropped semantics via scaled_dot_product_attention).
+
+    window: with ``causal``, query i attends only the last ``window`` keys
+    it could see (key c with i + S_k - S_q - window < c); the forward
+    visits no tile that lies before every row's window. Forward only: the
+    backward kernels know no window and refuse one."""
     drop = float(dropout_rate or 0.0)
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError('a window needs causal=True and window >= 1')
+    window = None if window is None else int(window)
     if drop >= 1.0:
         raise ValueError('flash_attention dropout_rate must be < 1')
     if drop > 0.0 and dropout_seed is None:
@@ -1046,7 +1126,7 @@ def flash_attention(q, k, v, causal=False, mask=None, dropout_rate=0.0,
     if (not flash_attention_available(q, k, v, mask)
             or (causal and s_q > s_k)):
         return _jnp_attention(q, k, v, causal, mask, drop_rate=drop,
-                              seed=dropout_seed)
+                              seed=dropout_seed, window=window)
     kmask = (_normalize_key_mask(mask, b, s_k)
              if mask is not None else None)
     q_off = (s_k - s_q) if causal else 0
@@ -1085,7 +1165,7 @@ def flash_attention(q, k, v, causal=False, mask=None, dropout_rate=0.0,
                       s_k_pad)
         out = _flash(qt, kt, vt, kmask[0] if kmask else None,
                      seeds.reshape(1), causal, q_off, kv_valid, hh,
-                     hh // h_kv, bq, bk, drop)
+                     hh // h_kv, bq, bk, drop, window)
         return out[:, :s_q].reshape(b, hh, s_q, d).transpose(0, 2, 1, 3)
 
     args = (q, k, v, seeds)

@@ -35,6 +35,17 @@ attends q rows to that paged cache two ways:
 ``pos`` is a PER-SLOT [B] i32 vector (slots decode at different depths —
 that is the whole point of continuous batching); q row j of slot b attends
 virtual positions <= pos[b] + j. Inference only (no vjp).
+
+With a ``window`` q row j attends only the last ``window`` of those
+positions (``pos[b] + j - window < key``): the same kernel body walks from
+the first page that holds such a key (``_pages_first``) and not from page
+0, its grid is as deep as a window spans pages
+(``window_pages(window + T - 1, page_size)``) and not as the table is
+wide, and the rows of the first page that lie before the window are
+masked. Pages before the first are never named, so a slot may have given
+them back (serving/generation.py frees what leaves the window); the call
+is named ``paged_attention_window``, so a trace tells it from the full
+layers' call.
 """
 import collections
 import functools
@@ -140,9 +151,23 @@ def _pages_held(pos, t, ps, p_max):
     return jnp.clip((pos + jnp.int32(t + ps - 1)) // jnp.int32(ps), 1, p_max)
 
 
+def _pages_first(pos, ps, window):
+    """The first page that holds a key the q row at ``pos`` attends."""
+    return jnp.maximum(pos - jnp.int32(window - 1), 0) // jnp.int32(ps)
+
+
+def window_pages(rows, page_size):
+    """The most pages ``rows`` consecutive rows span, wherever they start:
+    what a slot holds of a window layer, and the depth of that layer's
+    grid."""
+    return (int(rows) + int(page_size) - 2) // int(page_size) + 1
+
+
 def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
-                         ps, t, p_max):
-    """Grid (slots, blocks of KV heads, P_max); the page dim is sequential
+                         ps, t, p_max, depth, window=None):
+    """Grid (slots, blocks of KV heads, pages: P_max of them, or as many
+    as a window spans, counted from the slot's first); the page dim is
+    sequential
     so the online-softmax scratch carries across the pages of one slot. A
     step holds ``[heads, ps, D]`` of K and of V — every head of the page
     where the plan allows, one contiguous piece of the pool — and the q
@@ -159,6 +184,7 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
     i, j, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     pos = pos_ref[i]
     held = _pages_held(pos, t, ps, p_max)
+    page = p if window is None else _pages_first(pos, ps, window) + p
 
     @pl.when(p == 0)
     def _init():
@@ -166,16 +192,18 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(p < held)
+    @pl.when(page < held)
     def _compute():
         # row r of a KV head is query head r // t of its group, q row r % t
-        k_pos = p * jnp.int32(ps) + jax.lax.broadcasted_iota(
+        k_pos = page * jnp.int32(ps) + jax.lax.broadcasted_iota(
             jnp.int32, (rows, ps), 1)
         q_pos = pos
         if t > 1:
             q_pos = pos + jax.lax.rem(jax.lax.broadcasted_iota(
                 jnp.int32, (rows, ps), 0), jnp.int32(t))
         visible = k_pos <= q_pos
+        if window is not None:
+            visible = visible & (k_pos > q_pos - jnp.int32(window))
         for hd in range(heads):
             q = q_ref[0, hd]                               # [rows, D] native
             kblk, vblk = k_ref[0, hd], v_ref[0, hd]        # [ps, D]
@@ -206,7 +234,7 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
             m_ref[hd] = m_new
             l_ref[hd] = l_new
 
-    @pl.when(p == p_max - 1)
+    @pl.when(p == depth - 1)
     def _emit():
         for hd in range(heads):
             o_ref[0, hd] = (acc_ref[hd] / _fa._lanes(
@@ -214,7 +242,7 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
                 ).astype(o_ref.dtype)
 
 
-def _kernel_call(q, page_table, pos, pools):
+def _kernel_call(q, page_table, pos, pools, window=None):
     """The paged decode kernel over (under a mesh) the block each device
     holds: slots split over 'dp', heads over 'mp' — the pool's own layout
     (ops/paged_kv.POOL_LOGICAL_AXES), so no page moves between devices.
@@ -224,6 +252,8 @@ def _kernel_call(q, page_table, pos, pools):
     b, t, h, d = q.shape
     h_kv, ps = (int(x) for x in pools[0].shape[1:3])
     p_max = int(page_table.shape[1])
+    depth = p_max if window is None else min(
+        p_max, window_pages(window + t - 1, ps))
 
     def core(q, page_table, pos, *pools):
         b, _, h, _ = q.shape                  # this device's slots / heads
@@ -242,8 +272,11 @@ def _kernel_call(q, page_table, pos, pools):
         # nothing is re-laid: the heads of a page are one block of the pool
         # as it is stored, and a bank's scales come a page at a time, every
         # head's. The page id comes straight out of the prefetched table,
-        # and past the pages a slot holds it stays the last one's
+        # counted from the slot's first page (0 without a window), and
+        # past the pages a slot holds it stays the last one's
         def page_id(i, p, pt, pos):
+            if window is not None:
+                p = _pages_first(pos[i], ps, window) + p
             return pt[i * p_max
                       + jnp.minimum(p, _pages_held(pos[i], t, ps, p_max) - 1)]
         page = lambda i, j, p, pt, pos: (page_id(i, p, pt, pos), j, 0, 0)
@@ -252,7 +285,7 @@ def _kernel_call(q, page_table, pos, pools):
                              lambda i, j, p, *_: (i, j, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h_kv // heads, p_max),
+            grid=(b, h_kv // heads, depth),
             in_specs=[block] + [
                 pl.BlockSpec((1, heads) + x.shape[2:], page) if x.ndim == 4
                 else pl.BlockSpec((1,) + x.shape[1:], scales) for x in pools],
@@ -265,11 +298,13 @@ def _kernel_call(q, page_table, pos, pools):
         )
         out = pl.pallas_call(
             functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
-                              ps=ps, t=t, p_max=p_max),
+                              ps=ps, t=t, p_max=p_max, depth=depth,
+                              window=window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
             interpret=_fa._INTERPRET,
-            name='paged_attention',
+            name='paged_attention' if window is None
+            else 'paged_attention_window',
         )(page_table.reshape(-1), pos, qt, *pools)
         return out[:, :, :(h // h_kv) * t].reshape(b, h, t, d).transpose(
             0, 2, 1, 3)
@@ -283,22 +318,24 @@ def _kernel_call(q, page_table, pos, pools):
         _fa._BSHD, batch=b, heads=(h, h_kv))
 
 
-def paged_flash_decode(q, k_pages, v_pages, page_table, pos):
+def paged_flash_decode(q, k_pages, v_pages, page_table, pos, window=None):
     """Pallas paged decode. q: [B,T,H,D]; pages [N, H_kv, page_size, D];
     page_table [B, P_max] i32; pos [B] i32 -> [B,T,H,D]."""
-    return _kernel_call(q, page_table, pos, [k_pages, v_pages])
+    return _kernel_call(q, page_table, pos, [k_pages, v_pages], window)
 
 
-def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos):
+def paged_flash_decode_int8(q, k_bank, v_bank, page_table, pos, window=None):
     """``paged_flash_decode`` over int8 page pools: banks are
     ``{'int8': [N, H_kv, page_size, D] int8, 'scale': [N, H_kv,
     page_size] f32}`` (ops/paged_kv.paged_write rows)."""
     return _kernel_call(
         q, page_table, pos,
-        [k_bank['int8'], v_bank['int8'], k_bank['scale'], v_bank['scale']])
+        [k_bank['int8'], v_bank['int8'], k_bank['scale'], v_bank['scale']],
+        window)
 
 
-def paged_attention_fallback(q, k_pages, v_pages, page_table, pos, cdt):
+def paged_attention_fallback(q, k_pages, v_pages, page_table, pos, cdt,
+                             window=None):
     """Pure-jnp path: gather each slot's virtual dense cache through the
     page table, then run the EXACT op sequence of the dense decode
     fallback (models/gpt.cached_attention) — einsum in the compute dtype,
@@ -320,26 +357,35 @@ def paged_attention_fallback(q, k_pages, v_pages, page_table, pos, cdt):
     q_pos = (jnp.asarray(pos, jnp.int32)[:, None, None]
              + jnp.arange(T)[None, :, None])                  # [B,T,1]
     k_pos = jnp.arange(S)[None, None, :]                      # [1,1,S]
-    mask = (k_pos <= q_pos)[:, None]                          # [B,1,T,S]
+    mask = k_pos <= q_pos
+    if window is not None:
+        # what lies before the window may be a page given back and written
+        # by another slot since: masked like what lies after the position
+        mask = mask & (k_pos > q_pos - int(window))
+    mask = mask[:, None]                                      # [B,1,T,S]
     s = jnp.where(mask, s.astype(jnp.float32), jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1).astype(cdt)
     return jnp.einsum('bhqk,bkhd->bqhd', p, vc)
 
 
-def paged_attention(q, k_pages, v_pages, page_table, pos, cdt=None):
+def paged_attention(q, k_pages, v_pages, page_table, pos, cdt=None,
+                    window=None):
     """Decode attention over a paged KV pool; dispatches to the Pallas
     kernel when the shapes/platform allow, else the jnp gather fallback.
 
     q: [B, T, H, D]; pools: [N, H_kv, page_size, D] arrays or int8 banks;
     page_table: [B, P_max] i32; pos: [B] i32 (first q row's absolute
-    position per slot) -> [B, T, H, D]."""
+    position per slot) -> [B, T, H, D]. ``window`` (static): a q row
+    attends its last ``window`` positions only, and the table's entries
+    for pages wholly before them are never read."""
     cdt = q.dtype if cdt is None else cdt
     int8 = is_weight_only(k_pages)
     k_arr = k_pages['int8'] if int8 else k_pages
     if paged_attention_available(q, k_arr):
         if int8:
             return paged_flash_decode_int8(q, k_pages, v_pages, page_table,
-                                           pos)
-        return paged_flash_decode(q, k_pages, v_pages, page_table, pos)
+                                           pos, window)
+        return paged_flash_decode(q, k_pages, v_pages, page_table, pos,
+                                  window)
     return paged_attention_fallback(q, k_pages, v_pages, page_table, pos,
-                                    cdt)
+                                    cdt, window)

@@ -23,7 +23,11 @@ axis 1 is pages: K and V a head for ``gpt``, one latent row for
 State lives in that paged pool (``ops/paged_kv.py``): fixed-size pages
 in one shared buffer, a per-slot page table, and a host-side free-list
 allocator, so slot occupancy — not worst-case sequence length — bounds
-HBM. Pages are allocated lazily at each page boundary; on exhaustion the
+HBM. A family with several KINDS of plane (``family.page_kinds``: window
+layers beside full ones) gets an allocator and a table a kind; a window
+kind's slot holds only the pages its next step reads, and every page
+that has left the window goes back to the free list before that step.
+Pages are allocated lazily at each page boundary; on exhaustion the
 most-recently-admitted active slot — possibly the requester itself — is
 evicted (pages freed, request requeued at the queue FRONT), so the oldest
 sequence always advances and no pair of growing sequences can livelock
@@ -71,6 +75,7 @@ from .. import fault
 from .. import observability as _obs
 from ..models import family as _family
 from ..models import gpt as _gpt
+from ..ops import paged_attention as _pa
 from ..ops import paged_kv as _pkv
 from .errors import DeadlineExceededError, EngineClosedError, QueueFullError
 from .prefix_cache import PrefixCache
@@ -232,16 +237,18 @@ class _Request:
 
 
 class _Slot:
-    __slots__ = ('req', 'pos', 'last_tok', 'produced', 'table', 'admit_seq',
-                 'start', 'cow', 'first_tok')
+    __slots__ = ('req', 'pos', 'last_tok', 'produced', 'table', 'tables',
+                 'first', 'admit_seq', 'start', 'cow', 'first_tok')
 
-    def __init__(self, req, table, admit_seq, start=0, cow=None,
+    def __init__(self, req, tables, first, admit_seq, start=0, cow=None,
                  first_tok=None):
         self.req = req
         self.pos = len(req.prompt)      # next KV write position
         self.last_tok = 0
         self.produced = 0
-        self.table = table              # np [p_max] i32, 0 = unallocated
+        self.tables = tables            # kind -> np [p_max] i32, 0 = none
+        self.table = next(iter(tables.values()))    # the first kind's
+        self.first = first              # kind -> first page still held
         self.admit_seq = admit_seq
         self.start = start              # first prompt row prefill computes
                                         # (cached rows < start are mapped)
@@ -354,9 +361,28 @@ class GenerationEngine:
             raise ValueError(
                 f'prefill_width {self.prefill_width} outside '
                 f'[1, {s_max}]')
+        # the family's kinds of plane; one ('kv') unless it names them.
+        # A slot holds at most ``_held_max[kind]`` pages of a kind: the
+        # table's width, or what a window spans
+        self._kinds = (family.page_kinds(cfg) if family.page_kinds
+                       else _family.ONE_KIND)
+        self._held_max = {
+            k.name: (self.p_max if k.window is None else min(
+                self.p_max, _pa.window_pages(k.window, ps)))
+            for k in self._kinds}
         # +1: page 0 is the reserved trash page
-        self.num_pages = int(num_pages if num_pages is not None
-                             else self.num_slots * self.p_max + 1)
+        if not isinstance(num_pages, dict):
+            num_pages = {k.name: num_pages for k in self._kinds}
+        self._num_pages = {
+            name: int(n if n is not None
+                      else self.num_slots * self._held_max[name] + 1)
+            for name, n in num_pages.items()}
+        if set(self._num_pages) != set(self._held_max):
+            raise ValueError(
+                f'num_pages names {sorted(self._num_pages)}, the '
+                f'{family.name} family\'s kinds are '
+                f'{sorted(self._held_max)}')
+        self.num_pages = sum(self._num_pages.values())
         self.temperature = temperature
         self.top_k = top_k
         self.top_p = top_p
@@ -369,14 +395,17 @@ class GenerationEngine:
         self._autostart = autostart
 
         self._pool = self._init_pool()
-        self._alloc = _pkv.PageAllocator(self.num_pages)
+        self._allocs = {name: _pkv.PageAllocator(n)
+                        for name, n in self._num_pages.items()}
+        self._alloc = self._allocs[self._kinds[0].name]
         # prefix cache: opt-in (constructor flag, giving it a residency
         # bound, or the env knob) — page accounting changes when finished
         # sequences stay resident, so it is never silently enabled
         if prefix_cache is None:
             prefix_cache = (prefix_cache_pages is not None
                             or _env_int(ENV_PREFIX, 0) > 0)
-        if prefix_cache and not family.tail_prefill:
+        if prefix_cache and (not family.tail_prefill
+                             or family.page_kinds is not None):
             raise ValueError(
                 f'the {family.name} family prefills from row 0 only: it '
                 f'cannot read a cached prefix, so no prefix cache')
@@ -418,8 +447,9 @@ class GenerationEngine:
         whose axis 1 is pages, opaque to the engine. Under a mesh it is
         placed by the family's pool axes (the allocator and page tables
         stay host-side either way)."""
-        pool = self._family.init_pool(self.config, self.num_pages,
-                                      self.page_size)
+        pool = self._family.init_pool(
+            self.config, (self._num_pages if self._family.page_kinds
+                          else self.num_pages), self.page_size)
         if self._mesh_ctx is not None:
             pool = self._mesh_ctx.place_pool(
                 pool, self._family.pool_logical_axes)
@@ -454,15 +484,15 @@ class GenerationEngine:
             ).set(self._mesh_ctx.size)
         if _obs.enabled():
             reg = _obs.registry()
-            mk_c = lambda n: reg.counter(n, labels)             # noqa: E731
+            mk_c = lambda n, **k: reg.counter(n, {**labels, **k})  # noqa: E731
             mk_h = lambda n: reg.histogram(n, labels,           # noqa: E731
                                            window=_HIST_WINDOW)
-            mk_g = lambda n: reg.gauge(n, labels)               # noqa: E731
+            mk_g = lambda n, **k: reg.gauge(n, {**labels, **k})  # noqa: E731
         else:
-            mk_c = lambda n: _obs.Counter(n, labels)            # noqa: E731
+            mk_c = lambda n, **k: _obs.Counter(n, {**labels, **k})  # noqa: E731
             mk_h = lambda n: _obs.Histogram(n, labels,          # noqa: E731
                                             window=_HIST_WINDOW)
-            mk_g = lambda n: _obs.Gauge(n, labels)              # noqa: E731
+            mk_g = lambda n, **k: _obs.Gauge(n, {**labels, **k})  # noqa: E731
         self._c = {k: mk_c(f'gen.requests_{k}') for k in
                    ('submitted', 'completed', 'rejected', 'expired',
                     'failed')}
@@ -486,6 +516,13 @@ class GenerationEngine:
         self._g = {'occupancy': mk_g('gen.slot_occupancy'),
                    'pages': mk_g('gen.page_utilization'),
                    'prefix_pages': mk_g('gen.prefix.cached_pages')}
+        # the pool by kind of plane: pages the slots hold now, and pages
+        # given back because they left a window (none of a kind without)
+        self._g_kind = {k.name: mk_g('kv.pages_in_use', kind=k.name)
+                        for k in self._kinds}
+        self._c_released = {
+            k.name: mk_c('kv.pages_released_total', kind=k.name)
+            for k in self._kinds if k.window is not None}
 
     def _note(self, key, n=1):
         self._n[key] += n
@@ -498,8 +535,11 @@ class GenerationEngine:
         self._g['occupancy'].set(active / max(self.num_slots, 1))
         # page 0 (the reserved trash page) is excluded from the
         # denominator: a fully loaded pool reads 1.0
-        usable = max(self.num_pages - 1, 1)
-        self._g['pages'].set(self._alloc.used_pages / usable)
+        used = {name: a.used_pages for name, a in self._allocs.items()}
+        usable = max(self.num_pages - len(used), 1)
+        self._g['pages'].set(sum(used.values()) / usable)
+        for name, n in used.items():
+            self._g_kind[name].set(n)
         if self._prefix is not None:
             self._g['prefix_pages'].set(self._prefix.cached_pages)
             ev = self._prefix.stats()['evictions']
@@ -562,6 +602,16 @@ class GenerationEngine:
         mesh = self._mesh_ctx.mesh if self._mesh_ctx else None
         return (mesh_kernel.jit(prefill, mesh, donate_argnums=(1,)),
                 mesh_kernel.jit(step, mesh, donate_argnums=(1,)))
+
+    def _tables(self, rows, of=None):
+        """The page-table argument of a compiled call for ``rows`` slots:
+        one [rows, p_max] int32 array, or {kind: one} for a family that
+        names its kinds. ``of(kind name) -> [rows, p_max]`` fills it;
+        without it the tables are empty (what warmup lowers)."""
+        of = of or (lambda name: np.zeros((rows, self.p_max), np.int32))
+        if self._family.page_kinds is None:
+            return of(self._kinds[0].name)
+        return {k.name: of(k.name) for k in self._kinds}
 
     def _fns_pair(self):
         if self._fns is None:
@@ -789,11 +839,18 @@ class GenerationEngine:
                     self._note('expired')
                 continue
             need = _pkv.pages_for(len(req.prompt), self.page_size)
-            if need > self.num_pages - 1:
+            # of a window kind the first decode step reads only the pages
+            # from ``first`` on: the prefill's rows before them go nowhere
+            first = {k.name: self._first_page(k, len(req.prompt))
+                     for k in self._kinds}
+            short = [name for name, n in self._num_pages.items()
+                     if need - first[name] > n - 1]
+            if short:
                 self._queue.popleft()
                 err = ValueError(
-                    f'prompt needs {need} pages but the pool only has '
-                    f'{self.num_pages - 1} allocatable')
+                    f'prompt needs {need - first[short[0]]} pages but the '
+                    f'pool only has {self._num_pages[short[0]] - 1} '
+                    f'allocatable')
                 req.rec.finish('error', err)
                 req.future._finish(err)
                 self._note('failed')
@@ -811,13 +868,20 @@ class GenerationEngine:
             # the COW destination is one of the `need` logical pages and
             # comes out of the fresh allocation (pages[0] below)
             fresh = need - len(shared)
-            pages = self._alloc_with_release_locked(fresh)
-            if pages is None:
+            got = self._alloc_kinds_locked(
+                {name: fresh - at for name, at in first.items()})
+            if got is None:
                 if shared:
                     self._alloc.free(shared)    # undo; re-acquired on retry
                 break       # active slots will free pages; retry next round
             self._queue.popleft()
-            table = np.zeros((self.p_max,), np.int32)
+            tables = {k.name: np.zeros((self.p_max,), np.int32)
+                      for k in self._kinds}
+            # the first kind's pages are laid below, beside what a prefix
+            # cache shared (which only a family of one kind has)
+            table, pages = (x[self._kinds[0].name] for x in (tables, got))
+            for k in self._kinds[1:]:
+                tables[k.name][first[k.name]:need] = got[k.name]
             n_shared = len(shared)
             table[:n_shared] = shared
             cow = None
@@ -843,9 +907,9 @@ class GenerationEngine:
                              full=first_tok is not None)
             elif self._prefix is not None:
                 self._note('prefix_misses')
-            self._slots[free_idx] = _Slot(req, table, self._admit_seq,
-                                          start=start, cow=cow,
-                                          first_tok=first_tok)
+            self._slots[free_idx] = _Slot(req, tables, first,
+                                          self._admit_seq, start=start,
+                                          cow=cow, first_tok=first_tok)
             self._admit_seq += 1
             out.append(free_idx)
         if out:
@@ -885,7 +949,7 @@ class GenerationEngine:
         prompt[0, :tail] = req.prompt[start:]
         startv = np.asarray([start], np.int32)
         valid = np.asarray([tail], np.int32)
-        table = slot.table[None].copy()
+        table = self._tables(1, lambda name: slot.tables[name][None].copy())
         seed = np.asarray([req.seed], np.uint32)
         self._maybe_record()
         pf = self._aot.get('gen_prefill') or self._fns_pair()[0]
@@ -895,7 +959,8 @@ class GenerationEngine:
             fault.inject('gen.step')
             tok, lg, pool = pf(
                 self._params, self._pool, jnp.asarray(prompt),
-                jnp.asarray(startv), jnp.asarray(valid), jnp.asarray(table),
+                jnp.asarray(startv), jnp.asarray(valid),
+                jax.tree_util.tree_map(jnp.asarray, table),
                 jnp.asarray(seed))
             row = (np.asarray(lg)[0].astype(np.float32)
                    if req.want_logits else None)
@@ -931,7 +996,8 @@ class GenerationEngine:
         s = self.num_slots
         tok = np.zeros((s,), np.int32)
         pos = np.zeros((s,), np.int32)
-        table = np.zeros((s, self.p_max), np.int32)
+        tables = {k.name: np.zeros((s, self.p_max), np.int32)
+                  for k in self._kinds}
         seeds = np.zeros((s,), np.uint32)
         rids = []
         want = False
@@ -943,7 +1009,8 @@ class GenerationEngine:
                     continue
                 tok[i] = slot.last_tok
                 pos[i] = slot.pos
-                table[i] = slot.table
+                for name, table in tables.items():
+                    table[i] = slot.tables[name]
                 seeds[i] = slot.req.seed
                 active.append(i)
                 want = want or slot.req.want_logits
@@ -951,6 +1018,7 @@ class GenerationEngine:
                     rids.append(slot.req.rec.rid)
         if not active:
             return
+        table = self._tables(s, tables.__getitem__)
         self._maybe_record()
         st = self._aot.get('gen_decode') or self._fns_pair()[1]
         wall0 = time.perf_counter()
@@ -959,7 +1027,8 @@ class GenerationEngine:
             fault.inject('gen.step')
             nxt, lg, pool = st(
                 self._params, self._pool, jnp.asarray(tok), jnp.asarray(pos),
-                jnp.asarray(table), jnp.asarray(seeds))
+                jax.tree_util.tree_map(jnp.asarray, table),
+                jnp.asarray(seeds))
             # ONE host readback per iteration for every slot (a family's
             # counts ride behind the tokens in it); the logits follow only
             # when a request in a slot asked for them
@@ -1019,9 +1088,10 @@ class GenerationEngine:
 
     def _free_slot_locked(self, idx):
         slot = self._slots[idx]
-        pages = [int(p) for p in slot.table if p != _pkv.TRASH_PAGE]
-        if pages:
-            self._alloc.free(pages)
+        for name, table in slot.tables.items():
+            pages = [int(p) for p in table if p != _pkv.TRASH_PAGE]
+            if pages:
+                self._allocs[name].free(pages)
         self._slots[idx] = None
 
     def _publish_locked(self, slot):
@@ -1060,46 +1130,98 @@ class GenerationEngine:
         sequence's wait (the no-livelock invariant — evicting "the other
         slot" instead lets two growing sequences destroy each other's
         progress forever). An evicted request requeues at the FRONT and
-        later regenerates identical tokens from its seeded keys."""
+        later regenerates identical tokens from its seeded keys. Before
+        anything is allocated, a window kind's slots give back the pages
+        that left their windows."""
+        for k in self._kinds:
+            if k.window is not None:
+                self._release_left_locked(k)
         for i, slot in enumerate(self._slots):
             if slot is None:
                 continue
             li = slot.pos // self.page_size
-            if li >= self.p_max or slot.table[li] != _pkv.TRASH_PAGE:
+            if li >= self.p_max:
                 continue
-            while True:
-                # cold cache residency yields before any live slot does
-                pg = self._alloc_with_release_locked(1)
-                if pg is not None:
-                    slot.table[li] = pg[0]
-                    break
-                victim = self._pick_victim_locked()
-                only = sum(1 for s in self._slots if s is not None) == 1
-                if victim == i and only:
-                    # alone and exhausted: this request's total demand
-                    # exceeds the whole pool — retrying cannot succeed
-                    self._free_slot_locked(i)
-                    if slot.req.future._finish(RuntimeError(
-                            f'request needs more KV pages than the pool '
-                            f'holds ({self.num_pages - 1} allocatable)')):
-                        self._note('failed')
-                    break
-                self._evict_locked(victim)
-                if victim == i:
-                    break       # self-preempted; re-admitted when pages free
-            # fall through to the next slot whether or not i survived
+            for k in self._kinds:
+                if (self._slots[i] is slot
+                        and slot.tables[k.name][li] == _pkv.TRASH_PAGE):
+                    self._next_page_locked(i, slot, k.name, li)
 
-    def _alloc_with_release_locked(self, n):
-        """``alloc(n)``, releasing LRU prefix-cache residency on failure
-        until the allocation fits or the cache is dry. A released page
-        only reaches the free list at refcount zero, so keep releasing
-        while the cache still holds anything."""
-        pages = self._alloc.alloc(n)
+    def _first_page(self, kind, pos):
+        """The first page of ``kind`` that the step at row ``pos`` reads."""
+        if kind.window is None:
+            return 0
+        return max(0, pos - kind.window + 1) // self.page_size
+
+    def _release_left_locked(self, kind):
+        """Give back every page of a window kind that the slots' next
+        steps no longer read: a slot then holds ``_held_max`` pages of the
+        kind at most, and what it gave back is free for the slots that
+        cross a page boundary in this same round."""
+        name, alloc = kind.name, self._allocs[kind.name]
+        released = 0
+        for slot in self._slots:
+            if slot is None:
+                continue
+            first, table = self._first_page(kind, slot.pos), slot.tables[name]
+            if first > slot.first[name]:
+                alloc.free([int(p) for p in table[slot.first[name]:first]])
+                table[slot.first[name]:first] = _pkv.TRASH_PAGE
+                released += first - slot.first[name]
+                slot.first[name] = first
+        if released:
+            self._c_released[name].inc(released)
+
+    def _next_page_locked(self, i, slot, name, li):
+        """Slot ``i``'s page ``li`` of kind ``name``, evicting for it as
+        ``_ensure_pages_locked`` says; the slot may be gone on return."""
+        while True:
+            # cold cache residency yields before any live slot does
+            pg = self._alloc_with_release_locked(1, name)
+            if pg is not None:
+                slot.tables[name][li] = pg[0]
+                return
+            victim = self._pick_victim_locked()
+            only = sum(1 for s in self._slots if s is not None) == 1
+            if victim == i and only:
+                # alone and exhausted: this request's total demand
+                # exceeds the whole pool — retrying cannot succeed
+                self._free_slot_locked(i)
+                if slot.req.future._finish(RuntimeError(
+                        f'request needs more KV pages than the pool '
+                        f'holds ({self._num_pages[name] - 1} '
+                        f'allocatable)')):
+                    self._note('failed')
+                return
+            self._evict_locked(victim)
+            if victim == i:
+                return      # self-preempted; re-admitted when pages free
+
+    def _alloc_with_release_locked(self, n, kind=None):
+        """``alloc(n)`` of ``kind`` (the first, unnamed), releasing LRU
+        prefix-cache residency on failure until the allocation fits or the
+        cache is dry (only a family of one kind has a cache). A released
+        page only reaches the free list at refcount zero, so keep
+        releasing while the cache still holds anything."""
+        alloc = self._allocs[kind] if kind else self._alloc
+        pages = alloc.alloc(n)
         while pages is None and self._prefix is not None:
             if not self._prefix.release_lru(n):
                 break
-            pages = self._alloc.alloc(n)
+            pages = alloc.alloc(n)
         return pages
+
+    def _alloc_kinds_locked(self, want):
+        """{kind: n pages} for every kind or for none: -> {kind: pages} or
+        None, with nothing held."""
+        got = {}
+        for name, n in want.items():
+            got[name] = self._alloc_with_release_locked(n, name)
+            if got[name] is None:
+                for done, pages in got.items():
+                    self._allocs[done].free(pages or ())
+                return None
+        return got
 
     def _pick_victim_locked(self):
         best, best_seq = None, -1
@@ -1183,7 +1305,7 @@ class GenerationEngine:
         with self._lock:
             active = sum(1 for s in self._slots if s is not None)
             depth = len(self._queue)
-            free_pages = self._alloc.free_pages
+            free_pages = sum(a.free_pages for a in self._allocs.values())
         out = dict(self._n)
         out.update({
             'active_slots': active,

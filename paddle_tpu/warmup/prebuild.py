@@ -186,7 +186,7 @@ def _prebuild_generation(engine, entry):
             _struct((1, engine.prefill_width), np.int32),
             _struct((1,), np.int32),    # start (prefix-cache tail offset)
             _struct((1,), np.int32),    # valid
-            _struct((1, engine.p_max), np.int32),
+            _tree_structs(engine._tables(1)),
             _struct((1,), np.uint32)).compile()
         _perf_analyze('gen.prefill', compiled)
     else:
@@ -194,7 +194,7 @@ def _prebuild_generation(engine, entry):
         compiled = st.lower(
             params, pool,
             _struct((s,), np.int32), _struct((s,), np.int32),
-            _struct((s, engine.p_max), np.int32),
+            _tree_structs(engine._tables(s)),
             _struct((s,), np.uint32)).compile()
         _perf_analyze('gen.decode', compiled)
     # hand the AOT executable to the engine's live path: jit's own call

@@ -109,7 +109,7 @@ def _cases(devices):
     # the engine's two WHOLE executables (PR 28), built as the engine
     # builds them (``GenerationEngine._build_fns``: sampling, the logits
     # row, the pool donated) from abstract weights and an abstract pool
-    def engine_program(which, cfg, slots, pages, ps=128):
+    def engine_program(which, cfg, slots, pages, ps=128, width=None):
         import types
         from paddle_tpu.models import family as _family
         from paddle_tpu.serving.generation import GenerationEngine
@@ -127,13 +127,18 @@ def _cases(devices):
         pool = abstract(lambda: fam.init_pool(cfg, pages, ps))
         i32 = lambda *shape: S(shape, jnp.int32)            # noqa: E731
         p_max = -(-cfg.max_seq_len // ps)
+
+        def table(rows):        # a table a kind where the family names them
+            if fam.page_kinds is None:
+                return i32(rows, p_max)
+            return {k.name: i32(rows, p_max) for k in fam.page_kinds(cfg)}
         if which == 'step':
             return lambda: step.lower(
-                params, pool, i32(slots), i32(slots), i32(slots, p_max),
+                params, pool, i32(slots), i32(slots), table(slots),
                 i32(slots)).compile().as_text()
         return lambda: prefill.lower(
-            params, pool, i32(1, cfg.max_seq_len), i32(1), i32(1),
-            i32(1, p_max), i32(1)).compile().as_text()
+            params, pool, i32(1, width or cfg.max_seq_len), i32(1), i32(1),
+            table(1), i32(1)).compile().as_text()
 
     from paddle_tpu.models import gpt, moe_gpt
     # benchmark/configs/gpt-1.3b-serve.json: 24 layers, 129 pages of 128
@@ -166,7 +171,36 @@ def _cases(devices):
             S((m, k), bf16), S((16, k, n), bf16), S((m // tm,), jnp.int32),
             S((1,), jnp.int32))
 
+    # benchmark/configs/trinity-large-ep8-serve.json (PR 31): window and
+    # full attention over a pool of two kinds of plane, 48 query heads on 8
+    # KV heads of 128, experts 0-31 of 256, 24 slots of 16,384 rows
+    from paddle_tpu.models import afmoe
+    trinity = afmoe.AfmoeConfig(
+        vocab_size=25024, num_hidden_layers=5, num_dense_layers=1,
+        layer_types=('sliding_attention',) * 4 + ('full_attention',),
+        held=(0, 32), max_position_embeddings=16384)
+    trinity_pages = {'full': 24 * 128 + 1, 'window': 24 * 33 + 1}
+
+    def windowed_flash(seq, window):
+        q, kv = S((1, seq, 48, 128), bf16), S((1, seq, 8, 128), bf16)
+        return lambda: text(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, window=window), q, kv, kv)
+
+    def windowed_paged(window):
+        pages = S((3173, 8, 128, 128), bf16)    # four window layers' planes
+        return lambda: text(
+            lambda q, k, v, pt, pos: pa.paged_flash_decode(
+                q, k, v, pt, pos, window=window),
+            S((24, 1, 48, 128), bf16), pages, pages,
+            S((24, 128), jnp.int32), S((24,), jnp.int32))
+
     return {
+        'afmoe_step': engine_program('step', trinity, 24, trinity_pages),
+        'afmoe_prefill_1024': engine_program('prefill', trinity, 24,
+                                             trinity_pages, width=1024),
+        'flash_window_s16384_gqa6': windowed_flash(16384, 4096),
+        'flash_full_s16384_gqa6': windowed_flash(16384, None),
+        'paged_gqa6_window': windowed_paged(4096),
         'gpt_xl_step': engine_program('step', gpt.GPTConfig(**xl), 16, 129),
         'gpt_xl_prefill': engine_program('prefill', gpt.GPTConfig(**xl),
                                          16, 129),
@@ -220,7 +254,11 @@ def _cases(devices):
 # ([3096,...] is [24*129,...]); the scales of an int8 bank among them
 _POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
          r'|3096,16,128(?:,128)?|129,16,128(?:,128)?'
-         r'|4,129,8,128,128|516,8,128,128|129,8,128,128)\]')
+         r'|4,129,8,128,128|516,8,128,128|129,8,128,128'
+         # trinity-large-ep8-serve: one full layer's planes, four window
+         # layers', each flattened over its layers, and one layer's
+         r'|1,3073,8,128,128|3073,8,128,128|4,793,8,128,128'
+         r'|3172,8,128,128|3173,8,128,128|793,8,128,128)\]')
 
 
 def _pool_copies(text):
@@ -275,6 +313,9 @@ def _child():
                 # (8 heads in the moe_gpt case)
                 'padded_q': bool(re.search(r'bf16\[(256|128),128,128\]',
                                            text)),
+                'names': sorted(set(re.findall(
+                    r'%((?:paged_attention|flash_fwd)(?:_window)?)[.\d]* = ',
+                    text))),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -372,6 +413,42 @@ def test_int8_kv_step_moves_only_its_scales(compiled):
     assert case['kernels'] == 1 and case['collectives'] == [], case
     assert case['moved'] in ([], ['copy-done of f32']), case
     assert case['pool_copies'] <= 4, case
+
+
+@pytest.mark.parametrize('case,kernels,names', [
+    # a step: five paged calls (four window, one full) and the three
+    # grouped products of each of four routed layers
+    ('afmoe_step', 5 + 12, ['paged_attention', 'paged_attention_window']),
+    # one prefill body (1,024 rows): five flash forwards and the products
+    ('afmoe_prefill_1024', 5 + 12, ['flash_fwd', 'flash_fwd_window']),
+])
+def test_window_and_full_engine_programs_leave_their_pools_where_they_lie(
+        compiled, case, kernels, names):
+    """``trinity-large-ep8-serve``'s WHOLE decode step and a prefill body
+    at its published widths: both kinds of plane are carried through the
+    layers and written in place (no operation's result is a pool, a kind's
+    planes or a layer's plane but views and the in-place write: PRs 27 and
+    28 taught what to look for), and the window layers' call is the paged
+    kernel's (the flash forward's) one body under a name of its own."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+    assert compiled[case]['names'] == names
+
+
+@pytest.mark.parametrize('case,name', [
+    ('flash_window_s16384_gqa6', 'flash_fwd_window'),
+    ('flash_full_s16384_gqa6', 'flash_fwd'),
+    ('paged_gqa6_window', 'paged_attention_window'),
+])
+def test_windowed_kernel_compiles_for_v5e_at_the_published_widths(
+        compiled, case, name):
+    """A 16,384-row prefill's flash forward, six query heads a KV head:
+    every key and value of a head lies in fast memory (16 MiB with both
+    buffers, past what the compiler grants unasked: the call asks). The
+    window call of the paged kernel over the four window layers' planes."""
+    assert _summary(compiled[case]) == {'kernels': 1, 'pool_copies': 0,
+                                        'collectives': []}, compiled[case]
+    assert compiled[case]['names'] == [name]
 
 
 @pytest.mark.parametrize('case', [
