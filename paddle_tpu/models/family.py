@@ -61,6 +61,13 @@ class GenerationFamily:
     pool_logical_axes: tuple = POOL_LOGICAL_AXES
     # float params -> the int8 weight-only snapshot (precision='int8_wo')
     quantize_decode_params: typing.Optional[typing.Callable] = None
+    # (params, config) -> params as an engine holds them: every leaf that
+    # the cached forward only ever reads as ``.astype(config.dtype)``, the
+    # right-hand operand of a product, already in that dtype (a weight-only
+    # leaf and a leaf already in it are handed back as they are). The
+    # engine calls it once, so no call of its executables makes the cast
+    # again. None: the engine holds the parameters as given
+    serve_params: typing.Optional[typing.Callable] = None
     # (counts: np.ndarray, phase: 'prefill' | 'decode') -> None
     note_counts: typing.Optional[typing.Callable] = None
     # a prefill may start past row 0 and read the rows before it out of the

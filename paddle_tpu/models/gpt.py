@@ -504,6 +504,31 @@ def quantize_decode_params(params):
     return out
 
 
+# the stacked block matrices: the cached forward reads each only as
+# ``w.astype(cdt)``, the right-hand operand of a product (``wo_matmul``)
+PRODUCT_OPERANDS = ('qkv_w', 'proj_w', 'fc_w', 'out_w')
+
+
+def serve_params(params, config, operands=PRODUCT_OPERANDS):
+    """The parameters as a serving engine holds them (models/family.py):
+    ``blocks[name]`` for each of ``operands`` in the compute dtype, rounded
+    once here and not in every call of the engine's executables (at GPT-3
+    XL four float32 matrices of 1.2 G parameters: 7 GB read and written a
+    call, half a decode step's device time, PERF.md section 6, PR 32).
+    Every product gets the operand it got, so every logit is the same to
+    the last bit. ``wte`` (the embedding sum reads it as given), ``wpe``,
+    norms and biases stay as given; so does a weight-only leaf, and a leaf
+    already in the compute dtype is the leaf that comes back."""
+    from ..ops.weight_only import is_weight_only
+    cdt = jnp.dtype(config.dtype)
+    blocks = params['blocks']
+    cast = {k: blocks[k].astype(cdt) for k in operands
+            if not is_weight_only(blocks[k]) and blocks[k].dtype != cdt}
+    if not cast:
+        return params
+    return {**params, 'blocks': {**blocks, **cast}}
+
+
 def init_kv_cache(config: GPTConfig, batch):
     """-> {'k','v': [L, B, S_max, H_kv, Dh] in the compute dtype}, or with
     ``config.kv_cache_int8`` each of k/v is ``{'int8': that shape int8,
@@ -779,7 +804,8 @@ def forward_with_cache(params, tokens, cache, pos, config: GPTConfig,
 _family.register(GPTConfig, _family.GenerationFamily(
     name='gpt', init_pool=init_paged_kv_cache,
     forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
-    quantize_decode_params=quantize_decode_params))
+    quantize_decode_params=quantize_decode_params,
+    serve_params=serve_params))
 
 
 def _sample(logits, temperature, top_k, top_p=None, key=None):

@@ -18,7 +18,8 @@ from ..ops.weight_only import is_weight_only, wo_lm_head, wo_matmul, wo_take
 from ..parallel.moe import moe_ffn
 from . import family as _family
 from .gpt import (_layer_norm, _attention, _block_qkv, _mm,
-                  cached_attention, init_paged_kv_cache, validate_gqa)
+                  cached_attention, init_paged_kv_cache,
+                  serve_params as _gpt_serve_params, validate_gqa)
 
 
 def _c(w, cdt):
@@ -330,10 +331,22 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
     return wo_lm_head(x, params['wte'], cdt), {'k': k_new, 'v': v_new}
 
 
+# what ``_cached_block`` reads only as ``.astype(cdt)`` in front of a
+# product: the attention's two matrices (gpt's ``wo_matmul``), the router
+# (its logits are widened AFTER the product) and the experts' banks (``_c``)
+PRODUCT_OPERANDS = ('qkv_w', 'proj_w', 'gate_w', 'w_in', 'w_out')
+
+
+def serve_params(params, config):
+    """``gpt.serve_params`` over this family's product operands."""
+    return _gpt_serve_params(params, config, operands=PRODUCT_OPERANDS)
+
+
 _family.register(MoEConfig, _family.GenerationFamily(
     name='moe_gpt', init_pool=init_paged_kv_cache,
     forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
-    quantize_decode_params=quantize_decode_params))
+    quantize_decode_params=quantize_decode_params,
+    serve_params=serve_params))
 
 
 def make_decode_fns(config):

@@ -294,6 +294,16 @@ class GenerationEngine:
     token per decode iteration alongside every other active sequence.
     Sampling knobs (temperature/top_k/top_p, greedy by default) are
     engine-wide — one executable — while the RNG seed is per-request.
+
+    ``precision=None`` (or ``'float32'``: "not the int8 snapshot") holds
+    the family's product operands (``family.serve_params``: for ``gpt`` the
+    four stacked block matrices) in the configuration's compute dtype and
+    everything else as given, so a bfloat16-compute engine over float32
+    parameters rounds each matrix once, at construction, to the bits that
+    every call used to round it to; where the two dtypes agree it holds
+    exactly the leaves it was given. ``'int8_wo'`` holds the family's int8
+    snapshot of the parameters as given. ``stats()['param_bytes']`` says
+    what is held, by dtype.
     """
 
     _seq = itertools.count()
@@ -324,6 +334,12 @@ class GenerationEngine:
                 # scales); a model already snapshot (e.g. via
                 # enable_int8_decode) passes through untouched
                 params = family.quantize_decode_params(params)
+        # after the snapshot, which quantizes the parameters as given, and
+        # before placement: the family's product operands in the compute
+        # dtype, cast here once and not in every call. The given leaves are
+        # not kept: what a caller drops is freed
+        if family.serve_params is not None:
+            params = family.serve_params(params, cfg)
         # mesh-sharded replica (mp=N): ONE SPMD program over N chips.
         # Params are placed by the logical-axis rules table, the forward
         # pins the KV pool to the kv_heads layout, and everything else —
@@ -341,6 +357,11 @@ class GenerationEngine:
             fwd = _functools.partial(
                 fwd, partitioner=self._mesh_ctx.partitioner)
         self._params = params
+        # what the engine holds, by dtype: read once, here (``stats()``)
+        self._param_bytes = {}
+        for leaf in jax.tree_util.tree_leaves(params):
+            self._param_bytes[leaf.dtype.name] = (
+                self._param_bytes.get(leaf.dtype.name, 0) + int(leaf.nbytes))
         self.config = cfg
         self._forward_fn = fwd
         self._precision = precision or 'float32'
@@ -1325,6 +1346,7 @@ class GenerationEngine:
             'ttft_ms_p99': pct(self._h['ttft'], 99),
             'circuit_state': self._breaker.state,
             'precision': self._precision,
+            'param_bytes': dict(self._param_bytes),
             'warmed': self._warmed,
             'uptime_s': round(elapsed, 3),
         })

@@ -109,11 +109,16 @@ def _cases(devices):
     # the engine's two WHOLE executables (PR 28), built as the engine
     # builds them (``GenerationEngine._build_fns``: sampling, the logits
     # row, the pool donated) from abstract weights and an abstract pool
-    def engine_program(which, cfg, slots, pages, ps=128, width=None):
+    def engine_program(which, cfg, slots, pages, ps=128, width=None,
+                       as_given=False):
         import types
         from paddle_tpu.models import family as _family
         from paddle_tpu.serving.generation import GenerationEngine
         fam = _family.family_of(cfg)
+        # the parameters as the engine holds them (PR 32); ``as_given``:
+        # as it held them before, float32 under a bfloat16 program
+        held = (fam.serve_params if fam.serve_params and not as_given
+                else lambda params, cfg: params)
         prefill, step = GenerationEngine._build_fns(types.SimpleNamespace(
             config=cfg, _forward_fn=fam.forward_with_cache, temperature=0.0,
             top_k=0, top_p=1.0, _trace_count=0, _mesh_ctx=None))
@@ -122,8 +127,8 @@ def _cases(devices):
         def abstract(make):
             return jax.tree_util.tree_map(
                 lambda a: S(a.shape, a.dtype), jax.eval_shape(make))
-        params = abstract(
-            lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
+        params = abstract(lambda: held(
+            model.init_params(cfg, jax.random.PRNGKey(0)), cfg))
         pool = abstract(lambda: fam.init_pool(cfg, pages, ps))
         i32 = lambda *shape: S(shape, jnp.int32)            # noqa: E731
         p_max = -(-cfg.max_seq_len // ps)
@@ -206,6 +211,8 @@ def _cases(devices):
                                          16, 129),
         'gpt_xl_step_int8_kv': engine_program(
             'step', gpt.GPTConfig(kv_cache_int8=True, **xl), 16, 129),
+        'gpt_xl_step_params_as_given': engine_program(
+            'step', gpt.GPTConfig(**xl), 16, 129, as_given=True),
         'moe_gpt_step': engine_program('step', moe, 16, 129),
         'latent_decode_w640': latent(640),
         'latent_decode_w576': latent(576),
@@ -290,6 +297,29 @@ def _pool_copies(text):
     return moved
 
 
+def _block_matrices(text):
+    """-> ({stacked block matrix: the dtype the program takes it in},
+    [the operations that re-make one]). A stacked matrix is an entry
+    parameter ``params['blocks'][<name ending in _w, w_in, w_out>]``; an
+    operation re-makes it if its result has the matrix's shape, in any
+    dtype, and is no view of it: before PR 32 a ``convert`` of each of the
+    float32 stacks to bfloat16, in every call."""
+    entry = text[text.index('ENTRY '):]
+    taken = {}
+    for name, dtype, dims in re.findall(
+            r'%params__blocks____(\w+?)__[.\d]* = (\w+)\[([\d,]+)\]\S* '
+            r'parameter\(', entry):
+        if name.endswith('_w') or name in ('w_in', 'w_out'):
+            taken[name] = (dtype, dims)
+    if not taken:       # a kernel's program: no model under it
+        return {}, []
+    shapes = '|'.join(sorted({re.escape(dims) for _, dims in taken.values()}))
+    remade = [f'{op} of {dtype}[{dims}]' for dtype, dims, op in re.findall(
+        r'= (\w+)\[(' + shapes + r')\]\S* ([\w\-]+)\(', text)
+        if op not in ('parameter', 'bitcast', 'get-tuple-element')]
+    return {k: dtype for k, (dtype, _) in taken.items()}, sorted(remade)
+
+
 def _child():
     os.environ.setdefault('TPU_LOG_DIR', 'disabled')
     from jax.experimental import topologies
@@ -305,7 +335,9 @@ def _child():
         try:
             text = compile_text()
             moved = _pool_copies(text)
+            taken, remade = _block_matrices(text)
             out[name] = {
+                'block_matrices': taken, 'block_matrices_remade': remade,
                 'kernels': text.count('tpu_custom_call'),
                 'pool_copies': len(moved), 'moved': sorted(set(moved)),
                 # q as the kernel took it before PR 30: 16 slots x 16
@@ -393,6 +425,38 @@ def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
 
 
+@pytest.mark.parametrize('case,matrices', [
+    ('gpt_xl_step', ('fc_w', 'out_w', 'proj_w', 'qkv_w')),
+    ('gpt_xl_prefill', ('fc_w', 'out_w', 'proj_w', 'qkv_w')),
+    ('gpt_xl_step_int8_kv', ('fc_w', 'out_w', 'proj_w', 'qkv_w')),
+    ('moe_gpt_step', ('gate_w', 'proj_w', 'qkv_w', 'w_in', 'w_out')),
+])
+def test_engine_program_takes_its_block_matrices_in_the_compute_dtype(
+        compiled, case, matrices):
+    """The engine hands its executables the family's product operands in
+    bfloat16 (``family.serve_params``, cast once when the engine is built),
+    and no operation of the program has a stacked matrix's shape but the
+    parameter and views of it: nothing re-makes the weights in a call."""
+    assert compiled[case]['block_matrices'] == dict.fromkeys(
+        matrices, 'bf16'), compiled[case]
+    assert compiled[case]['block_matrices_remade'] == [], compiled[case]
+
+
+def test_a_step_over_float32_matrices_converts_every_one_in_every_call(
+        compiled):
+    """What PR 32 took out, and that the reading above can see it: handed
+    the parameters as given (float32 under a bfloat16 program), the step
+    converts the four stacks, 1.2 G parameters, before its first layer:
+    4.8 GB read and 2.4 GB written, half of a decode step's device time on
+    the chip (PERF.md section 6)."""
+    case = compiled['gpt_xl_step_params_as_given']
+    assert case['block_matrices'] == dict.fromkeys(
+        ('fc_w', 'out_w', 'proj_w', 'qkv_w'), 'f32'), case
+    assert case['block_matrices_remade'] == [
+        'convert of bf16[24,2048,2048]', 'convert of bf16[24,2048,6144]',
+        'convert of bf16[24,2048,8192]', 'convert of bf16[24,8192,2048]']
+
+
 @pytest.mark.parametrize('case', ['gpt_xl_step', 'gpt_xl_step_int8_kv',
                                   'moe_gpt_step'])
 def test_step_program_pads_no_q_to_128_rows(compiled, case):
@@ -408,10 +472,14 @@ def test_int8_kv_step_moves_only_its_scales(compiled):
     """With int8 KV banks the int8 planes stay where they lie too. What
     the compiler does move, once a step and not once a layer, is the two
     25 MB float32 scale planes into its fast memory and back (``S(1)`` in
-    the layouts): its own choice, not a re-layout."""
+    the layouts): its own choice, not a re-layout. Since PR 32 (bfloat16
+    block matrices, so no converted copies of them to make room for) it
+    brings them in by slices and joins those (a ``ConcatBitcast``
+    custom-call)."""
     case = compiled['gpt_xl_step_int8_kv']
     assert case['kernels'] == 1 and case['collectives'] == [], case
-    assert case['moved'] in ([], ['copy-done of f32']), case
+    assert set(case['moved']) <= {'copy-done of f32',
+                                  'custom-call of f32'}, case
     assert case['pool_copies'] <= 4, case
 
 

@@ -2,12 +2,16 @@
 (``submit(..., want_logits=True)`` -> ``GenerationFuture.logits()``), and the
 future's listener is public (``subscribe``). One executable either way:
 the tokens do not depend on who asked."""
+import dataclasses
+
 import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
-from paddle_tpu.models import gpt
+from paddle_tpu.models import family as _family
+from paddle_tpu.models import gpt, moe_gpt
 from paddle_tpu.serving import GenerationEngine, sharded_generation_engine
 
 pytestmark = pytest.mark.gen
@@ -159,6 +163,109 @@ def test_int8_rows_differ_from_bf16_rows_by_more_than_rounding():
     assert 0 < err_bf16 < 0.05
     assert err_int8 > 2 * err_bf16
     assert np.abs(int8 - bf16).max() / scale > err_bf16
+
+
+# ---------------------------------------------------------------------------
+# what the engine holds (PR 32): the family's product operands in the compute
+# dtype, cast once at construction; the same bits as casting in every call
+# ---------------------------------------------------------------------------
+
+MOE = dict(vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
+           n_experts=4, capacity_factor=8.0, max_seq_len=64, remat=False,
+           use_flash=False, dtype='bfloat16')
+# variant -> (module, config, engine keywords, mp); all from float32
+# parameters under bfloat16 compute ('mp2' of VARIANTS computes in float32)
+HELD = {
+    'bf16': (gpt, gpt.GPTConfig(**BASE, dtype='bfloat16'), {}, 1),
+    'kv_cache_int8': (gpt, gpt.GPTConfig(**BASE, dtype='bfloat16',
+                                         kv_cache_int8=True), {}, 1),
+    'prefix_cache': (gpt, gpt.GPTConfig(**BASE, dtype='bfloat16'),
+                     dict(prefix_cache=True), 1),
+    'mp2': (gpt, gpt.GPTConfig(**BASE, dtype='bfloat16'), {}, 2),
+    'moe_gpt': (moe_gpt, moe_gpt.MoEConfig(**MOE), {}, 1),
+}
+
+
+def _held_engine(variant):
+    model, cfg, kw, mp = HELD[variant]
+    params = model.init_params(cfg, jax.random.PRNGKey(0))
+    assert {str(a.dtype) for a in jax.tree_util.tree_leaves(params)} == {
+        'float32'}
+    kw = dict(KW, **kw)
+    if mp > 1:
+        return sharded_generation_engine(params, cfg, mp=mp, **kw), params
+    return GenerationEngine(params, cfg, **kw), params
+
+
+def _rows_and_tokens(engine):
+    try:
+        tokens, futs = _serve(engine, want=True)
+        assert engine.stats()['traces'] == 2
+        return tokens, [np.stack(f.logits()) for f in futs]
+    finally:
+        engine.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('variant', sorted(HELD))
+def test_an_engine_that_casts_once_serves_the_bits_of_one_that_casts_each_call(
+        variant, monkeypatch):
+    model, cfg = HELD[variant][:2]
+    family = _family.family_of(cfg)
+    operands = model.PRODUCT_OPERANDS
+    engine, params = _held_engine(variant)
+    held = engine._params['blocks']
+    assert {k: str(v.dtype) for k, v in held.items()} == {
+        k: 'bfloat16' if k in operands else 'float32'
+        for k in params['blocks']}
+    assert all(engine._params[k].dtype == jnp.float32
+               for k in params if k != 'blocks')
+    tokens, rows = _rows_and_tokens(engine)
+
+    # the parent's engine: it holds the leaves as given and casts in
+    # every call of both executables
+    monkeypatch.setitem(
+        _family._FAMILIES, type(cfg),
+        dataclasses.replace(family, serve_params=None))
+    engine, params = _held_engine(variant)
+    assert all(engine._params['blocks'][k].dtype == jnp.float32
+               for k in operands)
+    tokens_each_call, rows_each_call = _rows_and_tokens(engine)
+    assert tokens == tokens_each_call
+    for a, b in zip(rows, rows_each_call):
+        assert np.array_equal(a, b)
+
+
+def test_the_int8_snapshot_is_made_of_the_parameters_as_given():
+    engine = _engine('int8_wo')
+    try:
+        cfg = gpt.GPTConfig(**dict(BASE, dtype='bfloat16'))
+        want = gpt.quantize_decode_params(
+            gpt.init_params(cfg, jax.random.PRNGKey(0)))
+        got = engine._params
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        assert set(engine.stats()['param_bytes']) == {'int8', 'float32'}
+    finally:
+        engine.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_where_the_two_dtypes_agree_the_engine_holds_the_given_leaves(dtype):
+    cfg = gpt.GPTConfig(**BASE, dtype=dtype, param_dtype=dtype)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    engine = GenerationEngine(params, cfg, autostart=False, **KW)
+    try:
+        given = jax.tree_util.tree_leaves(params)
+        held = jax.tree_util.tree_leaves(engine._params)
+        assert len(given) == len(held)
+        assert all(a is b for a, b in zip(given, held))
+        assert engine.stats()['param_bytes'] == {
+            dtype: sum(a.nbytes for a in given)}
+    finally:
+        engine.shutdown(drain=False)
 
 
 def test_logits_of_a_request_that_did_not_ask_raise():

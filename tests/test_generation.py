@@ -751,6 +751,51 @@ def test_gen_metrics_present(params):
 
 
 # ---------------------------------------------------------------------------
+# what the engine holds, by dtype (PR 32)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize('family', ['gpt', 'moe_gpt'])
+def test_param_bytes_names_what_a_bf16_engine_holds_of_float32_weights(
+        family):
+    import gc
+    import weakref
+
+    from paddle_tpu.serving import host
+    if family == 'gpt':
+        model, cfg = gpt, gpt.GPTConfig(**dict(
+            vars(CFG), dtype='bfloat16', param_dtype='float32'))
+    else:
+        model, cfg = moe_gpt, moe_gpt.MoEConfig(
+            vocab_size=97, hidden_size=32, num_layers=2, num_heads=2,
+            n_experts=4, max_seq_len=32, remat=False, use_flash=False,
+            dtype='bfloat16', param_dtype='float32')
+    params = model.init_params(cfg, jax.random.PRNGKey(0))
+    blocks = params['blocks']
+    cast = sum(blocks[k].size for k in model.PRODUCT_OPERANDS)
+    total = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    given = [weakref.ref(blocks[k]) for k in model.PRODUCT_OPERANDS]
+    kept = weakref.ref(params['wte'])
+    eng = GenerationEngine(params, cfg, num_slots=3, page_size=PS,
+                           autostart=False)
+    try:
+        stats = eng.stats()
+        assert stats['param_bytes'] == {'bfloat16': 2 * cast,
+                                        'float32': 4 * (total - cast)}
+        assert stats['precision'] == 'float32'   # "not the int8 snapshot"
+        # the host's estimate of the model follows what is held
+        assert host._tree_nbytes(eng._params) == 2 * cast + 4 * (
+            total - cast)
+        # the caller's float32 matrices are the caller's alone: dropped,
+        # they are freed, while a leaf the engine took as given lives on
+        del params, blocks
+        gc.collect()
+        assert [ref() for ref in given] == [None] * len(given)
+        assert kept() is eng._params['wte']
+    finally:
+        eng.shutdown(drain=False)
+
+
+# ---------------------------------------------------------------------------
 # decode-fn cache satellite
 # ---------------------------------------------------------------------------
 
