@@ -120,7 +120,8 @@ def page_kinds(config):
     kinds = []
     for name, window in (('full', None), ('window', config.sliding_window)):
         if config.layers_of(name):
-            kinds.append(_family.PageKind(name, window))
+            kinds.append(_family.PageKind(
+                name, window, planes=(f'k_{name}', f'v_{name}')))
     return tuple(kinds)
 
 

@@ -8,7 +8,7 @@ it, registered for its config class:
     init_pool(config, num_pages, page_size)
         -> {plane name: array or int8 bank}; every plane's axis 1 is pages
            (``ops/paged_kv.copy_page`` copies a page across all of them) and
-           page 0 is the trash page
+           page 0 is the trash page (a ``per_slot`` kind's: slots, below)
     forward_with_cache(params, tokens, cache, pos, config, last_only=False,
                        partitioner=None) -> (logits, cache)
         ``cache`` is the pool's planes beside ``page_table`` [B, P_max],
@@ -28,6 +28,17 @@ window read 0 (the trash page): the family writes no row there that it
 needs and reads none. A family that names no kinds has one, and nothing
 about it changes.
 
+A kind may also be a row a SLOT and not pages (``per_slot``): the
+recurrent state of a layer that keeps no rows at all, the same size
+whatever the sequence's length. Its planes' axis 1 is the engine's slots
+(``init_pool`` is handed ``{kind: num_slots}`` for it), it has no
+allocator, no table and nothing to release, a prefix cache cannot index it
+and ``copy_page`` is never offered it. In place of a table the forward
+gets, under the kind's name in ``page_table``, WHICH slots the call's
+sequences are: ``[B]`` int32 (a prefill serves one slot, the step all of
+them). A prefill starts from row 0 and overwrites the slot's row: what the
+last occupant left there is never read.
+
 The engine never asks what kind of model it serves: it looks the family up
 by the config's class (``family_of``).
 """
@@ -41,9 +52,14 @@ from ..ops.paged_kv import POOL_LOGICAL_AXES
 class PageKind:
     """One kind of pool plane. ``window``: the rows behind its position
     (itself among them) that a slot's layers of this kind still read;
-    None: all of them."""
+    None: all of them. ``per_slot``: a row a slot, not pages. ``planes``:
+    the pool's planes of this kind by name, from which the engine counts a
+    page's (a slot's row's) bytes; a family of ONE paged kind may leave
+    them unnamed: every plane no other kind names is its."""
     name: str
     window: typing.Optional[int] = None
+    per_slot: bool = False
+    planes: tuple = ()
 
 
 ONE_KIND = (PageKind('kv'),)    # a family that names none

@@ -26,7 +26,12 @@ allocator, so slot occupancy — not worst-case sequence length — bounds
 HBM. A family with several KINDS of plane (``family.page_kinds``: window
 layers beside full ones) gets an allocator and a table a kind; a window
 kind's slot holds only the pages its next step reads, and every page
-that has left the window goes back to the free list before that step.
+that has left the window goes back to the free list before that step. A
+kind that is a row a SLOT (``per_slot``: the recurrent state of layers
+that keep no rows) has no allocator and no table: its planes are made with
+one row a slot, a call is told which slots its sequences are, a prefill
+overwrites its slot's row, and ``stats()`` counts the busy slots' rows as
+state held beside the pages in use.
 Pages are allocated lazily at each page boundary; on exhaustion the
 most-recently-admitted active slot — possibly the requester itself — is
 evicted (pages freed, request requeued at the queue FRONT), so the oldest
@@ -384,9 +389,18 @@ class GenerationEngine:
                 f'[1, {s_max}]')
         # the family's kinds of plane; one ('kv') unless it names them.
         # A slot holds at most ``_held_max[kind]`` pages of a kind: the
-        # table's width, or what a window spans
-        self._kinds = (family.page_kinds(cfg) if family.page_kinds
-                       else _family.ONE_KIND)
+        # table's width, or what a window spans. A kind that is a row a
+        # slot (recurrent state) has no pages: it is kept apart, and
+        # everything below that allocates, tables or releases walks the
+        # paged kinds alone
+        kinds = (family.page_kinds(cfg) if family.page_kinds
+                 else _family.ONE_KIND)
+        self._kinds = tuple(k for k in kinds if not k.per_slot)
+        self._slot_kinds = tuple(k for k in kinds if k.per_slot)
+        if not self._kinds:
+            raise ValueError(
+                f'the {family.name} family names no paged kind: an engine '
+                f'of per-slot state alone is not served yet')
         self._held_max = {
             k.name: (self.p_max if k.window is None else min(
                 self.p_max, _pa.window_pages(k.window, ps)))
@@ -416,6 +430,9 @@ class GenerationEngine:
         self._autostart = autostart
 
         self._pool = self._init_pool()
+        self._unit_bytes = self._kind_unit_bytes(kinds)
+        self._state_bytes_per_slot = sum(
+            self._unit_bytes[k.name] for k in self._slot_kinds)
         self._allocs = {name: _pkv.PageAllocator(n)
                         for name, n in self._num_pages.items()}
         self._alloc = self._allocs[self._kinds[0].name]
@@ -468,13 +485,39 @@ class GenerationEngine:
         whose axis 1 is pages, opaque to the engine. Under a mesh it is
         placed by the family's pool axes (the allocator and page tables
         stay host-side either way)."""
+        units = dict(self._num_pages,
+                     **{k.name: self.num_slots for k in self._slot_kinds})
         pool = self._family.init_pool(
-            self.config, (self._num_pages if self._family.page_kinds
+            self.config, (units if self._family.page_kinds
                           else self.num_pages), self.page_size)
         if self._mesh_ctx is not None:
             pool = self._mesh_ctx.place_pool(
                 pool, self._family.pool_logical_axes)
         return pool
+
+    def _kind_unit_bytes(self, kinds):
+        """{kind: bytes of one page of it (of one slot's row of a per-slot
+        kind)}, from the pool's planes as the family made them: the planes
+        a kind names, or for the one kind that names none every plane that
+        no other names."""
+        named = {p for k in kinds for p in k.planes}
+        out = {}
+        for k in kinds:
+            planes = k.planes or [n for n in self._pool if n not in named]
+            out[k.name] = sum(
+                int(leaf.nbytes) // int(leaf.shape[1]) for n in planes
+                for leaf in jax.tree_util.tree_leaves(self._pool[n]))
+        return out
+
+    def _held_bytes_locked(self):
+        """(bytes of per-slot state the busy slots hold, bytes of the
+        pages in use): a slot's state is held from admission to its end,
+        whatever its length."""
+        active = sum(1 for s in self._slots if s is not None)
+        state = active * self._state_bytes_per_slot
+        pages = sum(a.used_pages * self._unit_bytes[name]
+                    for name, a in self._allocs.items())
+        return state, pages
 
     def _readiness_probe(self):
         with self._lock:
@@ -544,6 +587,11 @@ class GenerationEngine:
         self._c_released = {
             k.name: mk_c('kv.pages_released_total', kind=k.name)
             for k in self._kinds if k.window is not None}
+        # a family with per-slot state: what the busy slots hold of it, in
+        # bytes, beside the bytes of the pages in use
+        self._g_bytes = ({'state': mk_g('kv.state_bytes_held'),
+                          'pages': mk_g('kv.page_bytes_held')}
+                         if self._slot_kinds else None)
 
     def _note(self, key, n=1):
         self._n[key] += n
@@ -561,6 +609,10 @@ class GenerationEngine:
         self._g['pages'].set(sum(used.values()) / usable)
         for name, n in used.items():
             self._g_kind[name].set(n)
+        if self._g_bytes is not None:
+            state, pages = self._held_bytes_locked()
+            self._g_bytes['state'].set(state)
+            self._g_bytes['pages'].set(pages)
         if self._prefix is not None:
             self._g['prefix_pages'].set(self._prefix.cached_pages)
             ev = self._prefix.stats()['evictions']
@@ -624,15 +676,21 @@ class GenerationEngine:
         return (mesh_kernel.jit(prefill, mesh, donate_argnums=(1,)),
                 mesh_kernel.jit(step, mesh, donate_argnums=(1,)))
 
-    def _tables(self, rows, of=None):
+    def _tables(self, rows, of=None, slots=None):
         """The page-table argument of a compiled call for ``rows`` slots:
         one [rows, p_max] int32 array, or {kind: one} for a family that
         names its kinds. ``of(kind name) -> [rows, p_max]`` fills it;
-        without it the tables are empty (what warmup lowers)."""
+        without it the tables are empty (what warmup lowers). A per-slot
+        kind's entry is ``slots``: [rows] int32, which slots the call's
+        sequences are."""
         of = of or (lambda name: np.zeros((rows, self.p_max), np.int32))
         if self._family.page_kinds is None:
             return of(self._kinds[0].name)
-        return {k.name: of(k.name) for k in self._kinds}
+        tables = {k.name: of(k.name) for k in self._kinds}
+        if slots is None:
+            slots = np.zeros((rows,), np.int32)
+        tables.update({k.name: slots for k in self._slot_kinds})
+        return tables
 
     def _fns_pair(self):
         if self._fns is None:
@@ -970,7 +1028,8 @@ class GenerationEngine:
         prompt[0, :tail] = req.prompt[start:]
         startv = np.asarray([start], np.int32)
         valid = np.asarray([tail], np.int32)
-        table = self._tables(1, lambda name: slot.tables[name][None].copy())
+        table = self._tables(1, lambda name: slot.tables[name][None].copy(),
+                             slots=np.asarray([idx], np.int32))
         seed = np.asarray([req.seed], np.uint32)
         self._maybe_record()
         pf = self._aot.get('gen_prefill') or self._fns_pair()[0]
@@ -1039,7 +1098,8 @@ class GenerationEngine:
                     rids.append(slot.req.rec.rid)
         if not active:
             return
-        table = self._tables(s, tables.__getitem__)
+        table = self._tables(s, tables.__getitem__,
+                             slots=np.arange(s, dtype=np.int32))
         self._maybe_record()
         st = self._aot.get('gen_decode') or self._fns_pair()[1]
         wall0 = time.perf_counter()
@@ -1327,6 +1387,7 @@ class GenerationEngine:
             active = sum(1 for s in self._slots if s is not None)
             depth = len(self._queue)
             free_pages = sum(a.free_pages for a in self._allocs.values())
+            state_bytes, page_bytes = self._held_bytes_locked()
         out = dict(self._n)
         out.update({
             'active_slots': active,
@@ -1335,6 +1396,11 @@ class GenerationEngine:
             'num_slots': self.num_slots,
             'page_size': self.page_size,
             'num_pages': self.num_pages,
+            # what the busy slots hold now: per-slot state (none but in a
+            # family with such a kind) and the pages in use, in bytes
+            'state_bytes': state_bytes,
+            'page_bytes': page_bytes,
+            'state_bytes_per_slot': self._state_bytes_per_slot,
             'prefill_width': self.prefill_width,
             'traces': self._trace_count,
             'tokens_per_sec': round(self._n['tokens'] / elapsed, 2),

@@ -136,7 +136,8 @@ def _cases(devices):
         def table(rows):        # a table a kind where the family names them
             if fam.page_kinds is None:
                 return i32(rows, p_max)
-            return {k.name: i32(rows, p_max) for k in fam.page_kinds(cfg)}
+            return {k.name: i32(rows) if k.per_slot else i32(rows, p_max)
+                    for k in fam.page_kinds(cfg)}
         if which == 'step':
             return lambda: step.lower(
                 params, pool, i32(slots), i32(slots), table(slots),
@@ -199,7 +200,17 @@ def _cases(devices):
             S((24, 1, 48, 128), bf16), pages, pages,
             S((24, 128), jnp.int32), S((24,), jnp.int32))
 
+    # benchmark/configs/granite-4.0-h-micro-serve.json (PR 34): 36
+    # state-space layers whose state is a row a slot beside 4 attention
+    # layers' pages, 64 slots of 2,048 rows, all 40 layers, scanned a period
+    from paddle_tpu.models import granite_hybrid
+    granite = granite_hybrid.GraniteHybridConfig(max_position_embeddings=2048)
+    granite_units = {'kv': 64 * 16 + 1, 'state': 64}
+
     return {
+        'granite_step': engine_program('step', granite, 64, granite_units),
+        'granite_prefill': engine_program('prefill', granite, 64,
+                                          granite_units, width=768),
         'afmoe_step': engine_program('step', trinity, 24, trinity_pages),
         'afmoe_prefill_1024': engine_program('prefill', trinity, 24,
                                              trinity_pages, width=1024),
@@ -265,7 +276,11 @@ _POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
          # trinity-large-ep8-serve: one full layer's planes, four window
          # layers', each flattened over its layers, and one layer's
          r'|1,3073,8,128,128|3073,8,128,128|4,793,8,128,128'
-         r'|3172,8,128,128|3173,8,128,128|793,8,128,128)\]')
+         r'|3172,8,128,128|3173,8,128,128|793,8,128,128'
+         # granite-4.0-h-micro-serve: the state and the convolution's tail
+         # a slot, K and V pages of two heads a row; whole and flat
+         r'|36,64,128,32,128|2304,128,32,128|36,64,13056|2304,13056'
+         r'|4,1025,4,128,128|4100,4,128,128|1025,4,128,128)\]')
 
 
 def _pool_copies(text):
@@ -346,8 +361,8 @@ def _child():
                 'padded_q': bool(re.search(r'bf16\[(256|128),128,128\]',
                                            text)),
                 'names': sorted(set(re.findall(
-                    r'%((?:paged_attention|flash_fwd)(?:_window)?)[.\d]* = ',
-                    text))),
+                    r'%((?:paged_attention|flash_fwd)(?:_window)?'
+                    r'|ssm_state_update)[.\d]* = ', text))),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -498,6 +513,27 @@ def test_window_and_full_engine_programs_leave_their_pools_where_they_lie(
     planes or a layer's plane but views and the in-place write: PRs 27 and
     28 taught what to look for), and the window layers' call is the paged
     kernel's (the flash forward's) one body under a name of its own."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+    assert compiled[case]['names'] == names
+
+
+@pytest.mark.parametrize('case,kernels,names', [
+    # a period's body, compiled once: nine state updates and the paged call
+    ('granite_step', 9 + 1, ['paged_attention', 'ssm_state_update']),
+    # four prefill bodies (128, 256, 512, 768 rows), a flash forward each
+    ('granite_prefill', 4, ['flash_fwd']),
+])
+def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
+        compiled, case, kernels, names):
+    """``granite-4.0-h-micro-serve``'s WHOLE decode step and prefill at the
+    published widths, all 40 layers: the state a slot (4.8 GB), the
+    convolution's tails and the K and V pages are carried through the
+    scan over periods and written in place (a decode step's state by the
+    kernel whose result is its operand's buffer), and a prefill's bodies
+    leave what they made for ONE write outside their ``lax.switch``: a
+    pool that passed through the conditional was copied whole (18 GB
+    asked of 16: the compiler refused the program)."""
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
     assert compiled[case]['names'] == names
