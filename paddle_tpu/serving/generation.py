@@ -16,6 +16,18 @@ the whole workload:
  - ``step``: all ``num_slots`` rows advance one token — inactive slots
    decode garbage into the trash page and their sample is discarded.
 
+The decode loop runs ONE STEP AHEAD of its read-back: step N+1 is
+dispatched before step N's tokens are read, its input tokens taken where
+step N left them on the device, so the host's work a step (the step's
+arrays, the dispatch, the read, emission, admission) runs while the device
+computes and the device's queue is never empty between two steps. The next
+step's only true dependence on a read is its input token; positions, page
+tables, seeds and an end by count or by the context are known to the host
+before a step runs. An end by ``eos_id`` is learnt one step late and costs
+the one row that step computed for the slot, which is dropped. What is in
+flight when, and what ``slot.pos`` means at each point, is in
+``_decode_step``'s docstring.
+
 What a page holds is the model family's (``models/family.py``): the
 engine asks the family of the config for its pool (a dict of planes whose
 axis 1 is pages: K and V a head for ``gpt``, one latent row for
@@ -242,15 +254,19 @@ class _Request:
 
 
 class _Slot:
-    __slots__ = ('req', 'pos', 'last_tok', 'produced', 'table', 'tables',
-                 'first', 'admit_seq', 'start', 'cow', 'first_tok')
+    __slots__ = ('req', 'pos', 'ahead', 'last_tok', 'produced', 'table',
+                 'tables', 'first', 'admit_seq', 'start', 'cow', 'first_tok')
 
     def __init__(self, req, tables, first, admit_seq, start=0, cow=None,
                  first_tok=None):
         self.req = req
-        self.pos = len(req.prompt)      # next KV write position
-        self.last_tok = 0
-        self.produced = 0
+        # the KV row the next step to be DISPATCHED writes: it advances
+        # when a step is handed to the device, not when its token is read
+        self.pos = len(req.prompt)
+        self.ahead = 0                  # steps dispatched and still unread:
+                                        # rows below pos - ahead are read
+        self.last_tok = 0               # the last token the host has read
+        self.produced = 0               # tokens read and emitted
         self.tables = tables            # kind -> np [p_max] i32, 0 = none
         self.table = next(iter(tables.values()))    # the first kind's
         self.first = first              # kind -> first page still held
@@ -260,6 +276,27 @@ class _Slot:
         self.cow = cow                  # pending (src, dst) page copy
         self.first_tok = first_tok      # full prefix hit: replay this token
                                         # instead of running prefill
+
+
+class _Step:
+    """One decode step handed to the device and not read yet: which slot
+    each row was computed for (None: no sequence's row, it went to the
+    trash page) and the step's results, still on the device."""
+    __slots__ = ('rows', 'want', 'rids', 'overlapped', 'inputs', 'toks',
+                 'out', 'lg')
+
+    def __init__(self, rows, want, rids, overlapped, inputs):
+        self.rows = rows                # slot index -> _Slot or None
+        self.want = want                # a row's request asked for logits
+        self.rids = rids
+        self.overlapped = overlapped    # dispatched with its forerunner
+                                        # still unread
+        self.inputs = inputs            # host-built, until it is dispatched
+        self.toks = None                # [slots] sampled tokens: the next
+                                        # step's input, never read
+        self.out = None                 # the tokens with a family's counts
+                                        # behind them: the one host read
+        self.lg = None                  # [slots, vocab] logits
 
 
 def _with_counts(tokens, counts):
@@ -299,6 +336,17 @@ class GenerationEngine:
     token per decode iteration alongside every other active sequence.
     Sampling knobs (temperature/top_k/top_p, greedy by default) are
     engine-wide — one executable — while the RNG seed is per-request.
+
+    Between two iterations of the scheduler at most one decode step is in
+    flight: dispatched, its tokens not yet read (``_inflight``). A slot's
+    ``pos`` is the row the next step to be DISPATCHED writes and
+    ``produced`` the tokens read and emitted; they differ by ``ahead``,
+    the slot's steps in flight, and everything that publishes, evicts or
+    finishes goes by what was read (``pos - ahead``). ``stats()`` counts
+    ``steps_overlapped`` (steps dispatched while their forerunner was
+    unread) beside ``steps``, and ``rows_discarded`` (rows computed for a
+    slot that had ended by EOS or been evicted before they were read; such
+    a row is no token and reaches no future).
 
     ``precision=None`` (or ``'float32'``: "not the int8 snapshot") holds
     the family's product operands (``family.serve_params``: for ``gpt`` the
@@ -430,6 +478,14 @@ class GenerationEngine:
         self._autostart = autostart
 
         self._pool = self._init_pool()
+        # what a step with no forerunner in flight is handed in place of
+        # the previous step's tokens (every row of such a step takes the
+        # host's token), placed as a step leaves its own: under a mesh
+        # replicated over it, so that both kinds of call are one executable
+        self._no_prev = jnp.zeros((self.num_slots,), jnp.int32)
+        if self._mesh_ctx is not None:
+            self._no_prev = jax.device_put(self._no_prev,
+                                           self._mesh_ctx.replicated())
         self._unit_bytes = self._kind_unit_bytes(kinds)
         self._state_bytes_per_slot = sum(
             self._unit_bytes[k.name] for k in self._slot_kinds)
@@ -457,6 +513,7 @@ class GenerationEngine:
         self._closed = False
         self._draining = False
         self._admit_seq = 0
+        self._inflight = None       # the decode step dispatched and unread
         self._trace_count = 0
         self._fns = None
         # kind -> AOT Compiled executable, seeded by warmup/prebuild; the
@@ -467,6 +524,7 @@ class GenerationEngine:
         self._n = {k: 0 for k in ('submitted', 'completed', 'rejected',
                                   'expired', 'failed', 'evictions',
                                   'tokens', 'prefills', 'steps',
+                                  'steps_overlapped', 'rows_discarded',
                                   'prefix_hits', 'prefix_misses',
                                   'prefix_full_hits', 'prefix_tokens_saved',
                                   'prefix_evictions')}
@@ -562,6 +620,11 @@ class GenerationEngine:
                     'failed')}
         self._c['evictions'] = mk_c('gen.evictions')
         self._c['tokens'] = mk_c('gen.tokens')
+        # the decode loop one step ahead of its read-back: steps dispatched
+        # while their forerunner was unread (beside stats()['steps']), and
+        # rows computed for a slot that had ended or been evicted meanwhile
+        self._c['steps_overlapped'] = mk_c('gen.steps_overlapped')
+        self._c['rows_discarded'] = mk_c('gen.rows_discarded')
         # gen.prefix.*: the prefix-cache surface fleetobs federates
         self._c['prefix_hits'] = mk_c('gen.prefix.hits')
         self._c['prefix_misses'] = mk_c('gen.prefix.misses')
@@ -661,14 +724,25 @@ class GenerationEngine:
             return (_with_counts(tok, cache.get('counts')), row,
                     {k: cache[k] for k in pool})
 
-        def step(params, pool, tok, pos, page_table, seeds):
+        def step(params, pool, prev, tok, fresh, pos, page_table, seeds):
             self._trace_count += 1
+            # a row's input token is the one the previous step sampled for
+            # it, taken where that step left it on the device (``prev``:
+            # its fourth result, which the host need not have read), unless
+            # the host says otherwise (``fresh``): a slot just prefilled,
+            # and every row of a step with no forerunner in flight. Data,
+            # not a second trace: one executable serves every step
+            tok = jnp.where(fresh, tok, prev)
             cache = dict(pool, page_table=page_table)
             logits, cache = fwd(params, tok[:, None], cache, pos, cfg)
             rows = logits[:, 0]
             nxt = sample_rows(rows, seeds, pos)
+            if self._mesh_ctx is not None:
+                # it comes back in as ``prev``: placed as ``_no_prev`` is
+                nxt = jax.lax.with_sharding_constraint(
+                    nxt, self._mesh_ctx.replicated())
             return (_with_counts(nxt, cache.get('counts')), rows,
-                    {k: cache[k] for k in pool})
+                    {k: cache[k] for k in pool}, nxt)
 
         # under a mesh the paged kernel shards over it (ops/mesh_kernel)
         from ..ops import mesh_kernel
@@ -867,36 +941,44 @@ class GenerationEngine:
 
     # ---- scheduler -------------------------------------------------------
     def _scheduler_loop(self):
+        """The scheduler thread: admit and prefill what fits, then one
+        iteration of the decode loop (``_decode_step``), until the engine
+        closes. Between two iterations at most ONE decode step is in
+        flight (``self._inflight``: dispatched, its tokens unread); it
+        counts as work to do, so the loop does not sleep on it and a
+        draining shutdown reads it before the thread returns. A shutdown
+        that does not drain has failed every future already: the step in
+        flight is dropped unread."""
         while True:
             with self._cv:
-                while (not self._closed and not self._queue
-                       and not any(s is not None for s in self._slots)):
+                while not self._closed and not self._busy_locked():
                     self._cv.wait(0.05)
-                if self._closed:
-                    if not self._draining:
-                        return
-                    if (not self._queue
-                            and not any(s is not None for s in self._slots)):
-                        return
+                if self._closed and not (self._draining
+                                         and self._busy_locked()):
+                    return
                 admitted = self._admit_locked()
-            for idx in admitted:
-                self._prefill_one(idx)
-            if any(s is not None for s in self._slots):
-                self._decode_step()
+            self._iterate(admitted)
 
     def _drain_inline(self):
         """Finish all admitted+queued work on the caller's thread (used by
         shutdown(drain=True) when no scheduler thread ever started)."""
         while True:
             with self._cv:
-                if (not self._queue
-                        and not any(s is not None for s in self._slots)):
+                if not self._busy_locked():
                     return
                 admitted = self._admit_locked()
-            for idx in admitted:
-                self._prefill_one(idx)
-            if any(s is not None for s in self._slots):
-                self._decode_step()
+            self._iterate(admitted)
+
+    def _busy_locked(self):
+        """Work to do: a queued request, a sequence in a slot, or a step
+        in flight whose tokens nobody has read."""
+        return bool(self._queue or self._inflight is not None
+                    or any(s is not None for s in self._slots))
+
+    def _iterate(self, admitted):
+        for idx in admitted:
+            self._prefill_one(idx)
+        self._decode_step()     # returns at once with no step to take or read
 
     def _admit_locked(self):
         out = []
@@ -1033,25 +1115,32 @@ class GenerationEngine:
         seed = np.asarray([req.seed], np.uint32)
         self._maybe_record()
         pf = self._aot.get('gen_prefill') or self._fns_pair()[0]
-        wall0 = time.perf_counter()
+        ahead = self._inflight
 
         def dev():
             fault.inject('gen.step')
+            wall0 = time.perf_counter()
             tok, lg, pool = pf(
                 self._params, self._pool, jnp.asarray(prompt),
                 jnp.asarray(startv), jnp.asarray(valid),
                 jax.tree_util.tree_map(jnp.asarray, table),
                 jnp.asarray(seed))
+            # the prefill is queued behind the decode step in flight: its
+            # own time starts where that step ends on the device (a wait,
+            # no read), so gen.prefill_ms keeps meaning the prefill alone
+            if ahead is not None and not ahead.toks.is_ready():
+                ahead.toks.block_until_ready()
+                wall0 = time.perf_counter()
             row = (np.asarray(lg)[0].astype(np.float32)
                    if req.want_logits else None)
             tok = np.asarray(tok)
-            return int(tok[0]), row, pool, tok[1:]
+            return int(tok[0]), row, pool, tok[1:], wall0
 
         req.rec.note('prefill', slot=idx, prompt_len=t0, start=start)
         try:
             with _obs.span('gen.prefill', slot=idx, prompt_len=t0,
                            req_id=req.rec.rid):
-                tok, row, pool, counts = self._breaker.call(dev)
+                tok, row, pool, counts, wall0 = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
@@ -1060,6 +1149,8 @@ class GenerationEngine:
         self._h['prefill'].observe(1e3 * (time.perf_counter() - wall0))
         self._n['prefills'] += 1
         with self._cv:
+            if self._slots[idx] is not slot:    # shut down meanwhile
+                return
             slot.last_tok = tok
             self._emit_locked(slot, tok, row)
             if self._slot_finished(slot, tok):
@@ -1073,70 +1164,91 @@ class GenerationEngine:
             self._family.note_counts(counts, phase)
 
     def _decode_step(self):
-        s = self.num_slots
-        tok = np.zeros((s,), np.int32)
-        pos = np.zeros((s,), np.int32)
-        tables = {k.name: np.zeros((s, self.p_max), np.int32)
-                  for k in self._kinds}
-        seeds = np.zeros((s,), np.uint32)
-        rids = []
-        want = False
-        with self._cv:
-            self._ensure_pages_locked()
-            active = []
-            for i, slot in enumerate(self._slots):
-                if slot is None:
-                    continue
-                tok[i] = slot.last_tok
-                pos[i] = slot.pos
-                for name, table in tables.items():
-                    table[i] = slot.tables[name]
-                seeds[i] = slot.req.seed
-                active.append(i)
-                want = want or slot.req.want_logits
-                if slot.req.rec.rid:
-                    rids.append(slot.req.rec.rid)
-        if not active:
+        """One iteration of the decode loop, which runs one step ahead of
+        its read-back: step N+1 is dispatched from what the host knows
+        BEFORE step N's tokens are read, so the device never waits for the
+        host between two steps.
+
+        On entry ``self._inflight`` is step N (dispatched by the previous
+        iteration, unread) or None (the first step, or the loop drained).
+        The iteration plans step N+1 under the lock: pages by ``slot.pos``,
+        which is the row the step to be dispatched writes and advances
+        HERE; positions, tables and seeds from the slots as they are now;
+        the input token left on the device (step N's fourth result) for a
+        row whose slot step N also served, the host's ``slot.last_tok``
+        (a prefill's token) for any other. A slot whose end the host knows
+        (its count, the context) gets no row: its last step is in flight
+        already. Then, outside the lock, it dispatches step N+1, reads
+        step N (tokens with a family's counts in one read; every slot's
+        logits only if a row's request asked), and under the lock again
+        emits a token a row, finishes and frees slots. A row whose slot is
+        no longer the one it was computed for (ended by EOS one step ago,
+        evicted, shut down) is dropped: no token, no listener,
+        ``rows_discarded``. On return ``self._inflight`` is step N+1, or
+        None when no slot had a step to take; ``slot.pos - slot.ahead``
+        rows of a slot are read, ``slot.produced`` tokens emitted.
+
+        One ``gen.decode_step`` span a step READ (the iteration that only
+        dispatches, with nothing in flight, opens none): dispatch of the
+        successor and the wait for this step's tokens."""
+        unread = self._inflight
+        ahead = self._plan_step(unread)
+        if ahead is None and unread is None:
             return
-        table = self._tables(s, tables.__getitem__,
-                             slots=np.arange(s, dtype=np.int32))
         self._maybe_record()
         st = self._aot.get('gen_decode') or self._fns_pair()[1]
         wall0 = time.perf_counter()
 
         def dev():
             fault.inject('gen.step')
-            nxt, lg, pool = st(
-                self._params, self._pool, jnp.asarray(tok), jnp.asarray(pos),
-                jax.tree_util.tree_map(jnp.asarray, table),
-                jnp.asarray(seeds))
-            # ONE host readback per iteration for every slot (a family's
+            if ahead is not None:
+                tok, fresh, pos, table, seeds = ahead.inputs
+                ahead.inputs = None
+                ahead.out, ahead.lg, self._pool, ahead.toks = st(
+                    self._params, self._pool,
+                    self._no_prev if unread is None else unread.toks,
+                    jnp.asarray(tok), jnp.asarray(fresh), jnp.asarray(pos),
+                    jax.tree_util.tree_map(jnp.asarray, table),
+                    jnp.asarray(seeds))
+            if unread is None:
+                return None, None
+            # ONE host readback per step for every slot (a family's
             # counts ride behind the tokens in it); the logits follow only
-            # when a request in a slot asked for them
-            nxt = np.asarray(nxt)
-            return (nxt[:s], (np.asarray(lg) if want else None), pool,
-                    nxt[s:])
+            # when a request in a row asked for them
+            return (np.asarray(unread.out),
+                    np.asarray(unread.lg) if unread.want else None)
 
         try:
-            with _obs.span('gen.decode_step', slots=len(active),
-                           req_ids=rids):
-                nxt, rows, pool, counts = self._breaker.call(dev)
+            with (_obs.span('gen.decode_step',
+                            slots=sum(r is not None for r in unread.rows),
+                            req_ids=unread.rids)
+                  if unread is not None else _obs.NULL_SPAN):
+                nxt, rows = self._breaker.call(dev)
         except Exception as e:
             self._handle_device_failure(e)
             return
-        self._pool = pool
-        self._note_counts(counts, 'decode')
+        self._inflight = ahead
+        if unread is None:
+            return
+        s = self.num_slots
+        self._note_counts(nxt[s:], 'decode')
         self._h['step'].observe(1e3 * (time.perf_counter() - wall0))
         self._n['steps'] += 1
+        if unread.overlapped:
+            self._note('steps_overlapped')
         with self._cv:
-            for i in active:
-                slot = self._slots[i]
-                if slot is None:        # evicted between snapshot and here
+            for i, slot in enumerate(unread.rows):
+                if slot is None:
+                    continue
+                if self._slots[i] is not slot:
+                    # the slot ended or was evicted after this row was
+                    # dispatched (the index may hold a NEW slot by now)
+                    self._note('rows_discarded')
                     continue
                 t = int(nxt[i])
-                slot.pos += 1
+                slot.ahead -= 1
                 slot.last_tok = t
-                slot.req.rec.note_decode(slot.pos)
+                slot.req.rec.note_decode(slot.pos - slot.ahead)
                 # astype copies: a view would keep every slot's rows alive
                 self._emit_locked(
                     slot, t, rows[i].astype(np.float32)
@@ -1145,6 +1257,54 @@ class GenerationEngine:
                     self._finish_slot_locked(i)
             self._update_gauges_locked()
             self._cv.notify_all()
+
+    def _plan_step(self, unread):
+        """The next decode step from what the host knows, with ``unread``
+        (or no step) in flight before it: -> a ``_Step`` with its
+        host-built inputs, not dispatched yet, or None when no slot has a
+        step to take. Advances ``slot.pos`` and ``slot.ahead``
+        of every slot it gives a row."""
+        s = self.num_slots
+        tok = np.zeros((s,), np.int32)
+        fresh = np.ones((s,), np.bool_)
+        pos = np.zeros((s,), np.int32)
+        tables = {k.name: np.zeros((s, self.p_max), np.int32)
+                  for k in self._kinds}
+        seeds = np.zeros((s,), np.uint32)
+        rows, rids = [None] * s, []
+        want = False
+        with self._cv:
+            self._ensure_pages_locked()
+            for i, slot in enumerate(self._slots):
+                if slot is None or not self._steps_on(slot):
+                    continue
+                if unread is not None and unread.rows[i] is slot:
+                    fresh[i] = False    # fed back on the device
+                else:
+                    tok[i] = slot.last_tok
+                pos[i] = slot.pos
+                for name, table in tables.items():
+                    table[i] = slot.tables[name]
+                seeds[i] = slot.req.seed
+                rows[i] = slot
+                slot.pos += 1
+                slot.ahead += 1
+                want = want or slot.req.want_logits
+                if slot.req.rec.rid:
+                    rids.append(slot.req.rec.rid)
+        if all(r is None for r in rows):
+            return None
+        table = self._tables(s, tables.__getitem__,
+                             slots=np.arange(s, dtype=np.int32))
+        return _Step(rows, want, rids, overlapped=unread is not None,
+                     inputs=(tok, fresh, pos, table, seeds))
+
+    def _steps_on(self, slot):
+        """Whether ``slot`` takes another decode step after those in
+        flight: its count and the context say so before any token is read
+        (``eos_id`` alone ends a sequence one step late)."""
+        return (slot.produced + slot.ahead < slot.req.eff_max_new
+                and slot.pos < self.max_seq_len)
 
     # ---- slot state (all called under the lock) --------------------------
     def _emit_locked(self, slot, tok, logits=None):
@@ -1165,7 +1325,7 @@ class GenerationEngine:
             return True
         if slot.produced >= slot.req.eff_max_new:
             return True
-        return slot.pos >= self.max_seq_len
+        return slot.pos - slot.ahead >= self.max_seq_len
 
     def _free_slot_locked(self, idx):
         slot = self._slots[idx]
@@ -1184,11 +1344,14 @@ class GenerationEngine:
         req = slot.req
         t0 = len(req.prompt)
         # KV row p >= t0 holds the (p - t0)-th generated token; the final
-        # sampled token was emitted but never written, so rows == slot.pos
-        gen = req.future._snapshot(slot.pos - t0)
+        # sampled token was emitted but never written, and a row that a
+        # step in flight is still writing is not published: by what was
+        # READ, rows == slot.pos - slot.ahead
+        written = slot.pos - slot.ahead
+        gen = req.future._snapshot(written - t0)
         tokens = [int(t) for t in req.prompt] + gen
         first = (req.future._snapshot(1) or [None])[0]
-        self._prefix.publish(req.tenant, tokens, slot.table, slot.pos,
+        self._prefix.publish(req.tenant, tokens, slot.table, written,
                              prompt_len=t0, seed=req.seed, first_tok=first)
 
     def _finish_slot_locked(self, idx):
@@ -1218,7 +1381,7 @@ class GenerationEngine:
             if k.window is not None:
                 self._release_left_locked(k)
         for i, slot in enumerate(self._slots):
-            if slot is None:
+            if slot is None or not self._steps_on(slot):
                 continue
             li = slot.pos // self.page_size
             if li >= self.p_max:
@@ -1242,7 +1405,7 @@ class GenerationEngine:
         name, alloc = kind.name, self._allocs[kind.name]
         released = 0
         for slot in self._slots:
-            if slot is None:
+            if slot is None or not self._steps_on(slot):
                 continue
             first, table = self._first_page(kind, slot.pos), slot.tables[name]
             if first > slot.first[name]:
@@ -1339,6 +1502,9 @@ class GenerationEngine:
             if self._prefix is not None:
                 # cached KV lives in the pool being rebuilt: drop it all
                 self._prefix.clear()
+            # a step in flight computed on the pool that failed: its rows'
+            # sequences are failed here, its tokens are never read
+            self._inflight = None
             self._pool = self._init_pool()
             self._update_gauges_locked()
             self._cv.notify_all()

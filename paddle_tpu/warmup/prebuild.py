@@ -193,7 +193,10 @@ def _prebuild_generation(engine, entry):
         s = engine.num_slots
         compiled = st.lower(
             params, pool,
-            _struct((s,), np.int32), _struct((s,), np.int32),
+            _tree_structs(engine._no_prev),     # the previous step's tokens
+            _struct((s,), np.int32),    # the host's tokens ...
+            _struct((s,), np.bool_),    # ... and which rows take them
+            _struct((s,), np.int32),
             _tree_structs(engine._tables(s)),
             _struct((s,), np.uint32)).compile()
         _perf_analyze('gen.decode', compiled)

@@ -52,3 +52,29 @@ def cpu_mesh():
     yield make
     if prev is not None:
         topo_mod.set_topology(prev)
+
+
+@pytest.fixture
+def read_first():
+    """-> a context manager under which every ``GenerationEngine`` reads a
+    decode step before it plans the next one: the order the engine had
+    before its loop ran one step ahead of its read-back (PR 36). A
+    test-local patch of ``_plan_step``, which gives no step a successor
+    while its tokens are unread; so every row's input token is the host's
+    and ``steps_overlapped`` stays 0. What the pipelined loop serves is
+    held equal to what this one serves."""
+    import contextlib
+
+    from paddle_tpu.serving import GenerationEngine
+
+    @contextlib.contextmanager
+    def patched():
+        plan = GenerationEngine._plan_step
+        GenerationEngine._plan_step = (
+            lambda self, unread: None if unread is not None
+            else plan(self, unread))
+        try:
+            yield
+        finally:
+            GenerationEngine._plan_step = plan
+    return patched

@@ -276,6 +276,10 @@ def _watched_engine(shape, **kw):
             for name, table in slot.tables.items():
                 mine = np.flatnonzero(table)
                 held[name] += len(mine)
+                if not eng._steps_on(slot):
+                    # its last step is in flight: it holds what that step
+                    # reads until its token is read, and asks for no more
+                    continue
                 if name == 'window':
                     seen['most'] = max(seen['most'], len(mine))
                     first = max(0, slot.pos - w + 1) // ps
@@ -329,6 +333,52 @@ def test_a_window_kind_holds_a_windows_pages_and_gives_back_the_rest(
             want = [roomy.submit(p, max_new_tokens=24).result(timeout=600)
                     for p in prompts]
         assert got == want
+
+
+@pytest.mark.parametrize('lens,slots,rows_equal', [
+    # a request a slot, queued before the engine starts: every step holds
+    # the same rows in both orders, so the routed layers group them alike
+    ((30, 3, 38), 3, True),
+    # five requests on three slots: a slot changes hands with a step in
+    # flight, and a neighbour's row then meets another group by a step
+    ((30, 3, 38, 2, 26), 3, False),
+], ids=['a_request_a_slot', 'slots_refilled'])
+def test_one_step_ahead_serves_what_reading_first_serves(
+        lens, slots, rows_equal, read_first):
+    """The decode loop dispatches step N+1 before it reads step N (PR 36):
+    a window kind gives back the pages that step N+1 no longer reads while
+    step N, which still reads them, is in flight, and whoever takes them
+    writes them behind it. Same tokens (and, where the steps hold the same
+    rows, the same logits to the last bit) as a loop that reads each step
+    before it dispatches the next."""
+    shape = tiny_shape()
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 96, size=n).astype(np.int32) for n in lens]
+
+    def serve():
+        eng, seen = _watched_engine(shape, num_slots=slots, page_size=4,
+                                    prefill_width=40, autostart=False)
+        released = obs.find('kv.pages_released_total',
+                            {**eng.labels, 'kind': 'window'})
+        futs = [eng.submit(p, max_new_tokens=12 + 4 * i, want_logits=True)
+                for i, p in enumerate(prompts)]
+        with eng:
+            out = [(f.result(timeout=600), np.stack(f.logits()))
+                   for f in futs]
+            return out, eng.stats(), released.value
+
+    got, stats, released = serve()
+    with read_first():
+        want, base, released_first = serve()
+    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
+    assert released == released_first >= 8      # pages left the windows
+    assert stats['free_pages'] == stats['num_pages'] - 2
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        if rows_equal:
+            np.testing.assert_array_equal(rows, want_rows)
+        else:
+            np.testing.assert_allclose(rows, want_rows, atol=2e-5, rtol=0)
 
 
 def test_a_family_of_kinds_gets_no_prefix_cache_and_names_its_pools():

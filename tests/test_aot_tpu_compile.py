@@ -139,9 +139,11 @@ def _cases(devices):
             return {k.name: i32(rows) if k.per_slot else i32(rows, p_max)
                     for k in fam.page_kinds(cfg)}
         if which == 'step':
+            # the previous step's tokens, the host's, which rows take the
+            # host's, positions, tables, seeds
             return lambda: step.lower(
-                params, pool, i32(slots), i32(slots), table(slots),
-                i32(slots)).compile().as_text()
+                params, pool, i32(slots), i32(slots), S((slots,), jnp.bool_),
+                i32(slots), table(slots), i32(slots)).compile().as_text()
         return lambda: prefill.lower(
             params, pool, i32(1, width or cfg.max_seq_len), i32(1), i32(1),
             table(1), i32(1)).compile().as_text()
@@ -207,7 +209,15 @@ def _cases(devices):
     granite = granite_hybrid.GraniteHybridConfig(max_position_embeddings=2048)
     granite_units = {'kv': 64 * 16 + 1, 'state': 64}
 
+    # benchmark/configs/dots-vlm1-ep16-serve.json (PR 27): the whole step of
+    # the latent family as one chip of sixteen holds it
+    from paddle_tpu.models import latent_moe
+    dots = latent_moe.LatentMoEConfig(
+        num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16160,
+        max_position_embeddings=2048, held=(0, 16))
+
     return {
+        'latent_step': engine_program('step', dots, 64, 1025),
         'granite_step': engine_program('step', granite, 64, granite_units),
         'granite_prefill': engine_program('prefill', granite, 64,
                                           granite_units, width=768),
@@ -363,6 +373,11 @@ def _child():
                 'names': sorted(set(re.findall(
                     r'%((?:paged_attention|flash_fwd)(?:_window)?'
                     r'|ssm_state_update)[.\d]* = ', text))),
+                # the entry parameter a step takes the previous step's
+                # tokens in, where that step left them on the device
+                'fed_back': re.findall(
+                    r'%prev[.\d]* = (s32\[\d+\])\S* parameter\(',
+                    text[text.index('ENTRY '):]),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -537,6 +552,23 @@ def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
     assert compiled[case]['names'] == names
+
+
+@pytest.mark.parametrize('case,slots,copies', [
+    ('gpt_xl_step', 16, 0), ('moe_gpt_step', 16, 0), ('latent_step', 64, 0),
+    ('afmoe_step', 24, 0), ('granite_step', 64, 0),
+    # an int8 bank's two float32 scale planes are moved once a step, as
+    # before (test_int8_kv_step_moves_only_its_scales)
+    ('gpt_xl_step_int8_kv', 16, 4)])
+def test_a_step_takes_the_previous_steps_tokens_where_they_lie(
+        compiled, case, slots, copies):
+    """The decode loop runs one step ahead of its read-back (PR 36): every
+    family's WHOLE step at its cell's widths takes the tokens the previous
+    step sampled as a parameter of its own (``prev``, beside the host's
+    tokens and the mask that chooses between them), and feeding them back
+    re-lays no pool: the step is the program it was."""
+    assert compiled[case].get('fed_back') == [f's32[{slots}]'], compiled[case]
+    assert compiled[case]['pool_copies'] <= copies, compiled[case]
 
 
 @pytest.mark.parametrize('case,name', [

@@ -796,6 +796,211 @@ def test_param_bytes_names_what_a_bf16_engine_holds_of_float32_weights(
 
 
 # ---------------------------------------------------------------------------
+# the decode loop one step ahead of its read-back (PR 36)
+# ---------------------------------------------------------------------------
+
+# case -> (config overrides or None for moe_gpt, engine keywords)
+LOOP_CASES = {
+    'bf16': (dict(dtype='bfloat16'), {}),
+    'kv_cache_int8': (dict(kv_cache_int8=True), {}),
+    'prefix_cache': ({}, dict(prefix_cache=True, prefill_width=24)),
+    'moe_gpt': (None, {}),
+    # five pages for two sequences that want eight: slots are evicted with
+    # a step in flight and regenerate what they had
+    'evicted': ({}, dict(num_pages=6)),
+}
+
+
+def _loop_case(params, case):
+    over, kw = LOOP_CASES[case]
+    if over is None:
+        cfg, weights = _moe_case()
+    else:
+        cfg, weights = gpt.GPTConfig(**{**CFG.__dict__, **over}), params
+    return cfg, weights, kw
+
+
+def _serve_waves(weights, cfg, case, **kw):
+    """Waves of requests on two slots, the first queued before the engine
+    starts (so its steps hold the same rows in every run), each next one
+    sent when the last has ended. -> [(tokens, rows or None) a request],
+    the engine's stats."""
+    first = _prompts([5, 9, 3, 8], seed=53)
+    if case == 'evicted':
+        waves, n_new = [_prompts([9, 9], seed=23)], (lambda i: 16)
+    elif case == 'moe_gpt':
+        # a routed layer groups a step's rows, so a row's last bits follow
+        # its neighbours: the rows are held equal where both orders put
+        # the same rows in every step, which is one wave of a request a
+        # slot (an end by count leaves the row idle in both)
+        waves, n_new = [first[:2]], (lambda i: 6 + 5 * i)
+    else:
+        # slots are re-admitted while their neighbours decode; the second
+        # wave repeats the first's prompts with a tail (what a prefix
+        # cache shares)
+        waves = [first, [np.concatenate([p, _prompts([3], seed=59 + i)[0]])
+                         for i, p in enumerate(first[:3])]]
+        n_new = lambda i: 4 + 3 * (i % 3)                   # noqa: E731
+    eng = _engine(weights, cfg, autostart=False, **kw)
+    out = []
+    try:
+        for wave in waves:
+            futs = [eng.submit(p, max_new_tokens=n_new(i), seed=100 + i,
+                               want_logits=(i % 2 == 0))
+                    for i, p in enumerate(wave)]
+            eng.start()
+            for i, f in enumerate(futs):
+                toks = f.result(timeout=300)
+                out.append((toks, np.stack(f.logits()) if i % 2 == 0
+                            else None))
+        return out, eng.stats()
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize('sampled', [False, True], ids=['greedy', 'sampled'])
+@pytest.mark.parametrize('case', sorted(LOOP_CASES))
+def test_one_step_ahead_serves_what_reading_first_serves(
+        params, case, sampled, read_first):
+    """Step N+1 is dispatched before step N is read, its input tokens fed
+    back on the device: the same tokens, and for a request that asked the
+    same logits rows to the last bit, as a loop that reads every step
+    before it dispatches the next and feeds the host's tokens."""
+    cfg, weights, kw = _loop_case(params, case)
+    if sampled:
+        kw = dict(kw, temperature=0.8, top_k=20)
+    got, stats = _serve_waves(weights, cfg, case, **kw)
+    with read_first():
+        want, base = _serve_waves(weights, cfg, case, **kw)
+    assert base['steps_overlapped'] == 0 and base['rows_discarded'] == 0
+    assert stats['steps_overlapped'] > 0
+    assert stats['traces'] == base['traces'] == 2
+    if case == 'evicted':
+        # the victim's row of the step in flight is dropped unread
+        assert stats['evictions'] >= 1 and stats['rows_discarded'] >= 1
+    if case == 'moe_gpt':
+        assert stats['steps'] == base['steps']      # the same rows a step
+    assert len(got) == len(want) >= 2
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        if want_rows is not None:
+            assert np.array_equal(rows, want_rows)
+    if case == 'prefix_cache':
+        assert stats['prefix']['hits'] >= 3
+
+
+def test_an_end_by_eos_costs_one_discarded_row(params):
+    """The host learns an end by EOS one step late: the row the next step
+    computed for that slot is dropped (no token, no listener), its pages
+    go back to the allocator and the slot takes the queued request. An end
+    by count is known at dispatch and costs nothing."""
+    prompts = _prompts([5, 9, 7], seed=17)
+    n_new, kw = 8, dict(temperature=0.8, top_k=20)
+
+    def serve(**more):
+        heard = [[] for _ in prompts]
+        with _engine(params, **kw, **more) as eng:
+            futs = [eng.submit(p, max_new_tokens=n_new, seed=i)
+                    for i, p in enumerate(prompts)]
+            for f, log in zip(futs, heard):
+                f.subscribe(lambda *ev, log=log: log.append(ev))
+            got = [f.result(timeout=120) for f in futs]
+            return got, heard, eng.stats(), eng._alloc.free_pages
+
+    base, _, stats, _ = serve()
+    assert stats['rows_discarded'] == 0     # every end known by its count
+    # a token the first stream meets first in mid-stream
+    at = next(j for j in range(2, n_new - 1)
+              if base[0][j] not in base[0][:j])
+    eos = base[0][at]
+    got, heard, stats, free = serve(eos_id=eos)
+    want = [s[:s.index(eos) + 1] if eos in s else s for s in base]
+    assert got == want and len(got[0]) == at + 1
+    # one overrun row for every stream that EOS cut after its first token
+    # and before its count would have
+    overrun = sum(1 for s in base if eos in s
+                  and 0 < s.index(eos) < n_new - 1)
+    assert overrun >= 1 and stats['rows_discarded'] == overrun
+    assert stats['tokens'] == sum(len(s) for s in want)
+    assert stats['completed'] == 3          # the third took a freed slot
+    assert free == 4 * 2                    # every page came back
+    for log, toks in zip(heard, want):
+        assert [e[2] for e in log if e[0] == 'token'] == toks
+        assert log[-1] == ('finish', None)
+
+
+def test_a_fault_with_a_step_in_flight_fails_each_sequence_once(params):
+    """``gen.step`` raising at the dispatch that follows an unread step:
+    every active future fails once, the step in flight is dropped unread,
+    the pool is rebuilt and the next request is served."""
+    from paddle_tpu import fault
+    prompts = _prompts([5, 9], seed=61)
+    armed = []
+
+    def arm(kind, *args):
+        if kind == 'token' and args[0] == 2 and not armed:
+            armed.append(True)
+            fault.configure({'gen.step': (1.0, 'raise')}, max_faults=1)
+    try:
+        with _engine(params) as eng:
+            futs = [eng.submit(p, max_new_tokens=12) for p in prompts]
+            futs[0].subscribe(arm)
+            excs = [f.exception(timeout=120) for f in futs]
+            assert all(isinstance(e, fault.InjectedFault) for e in excs)
+            stats = eng.stats()
+            assert stats['failed'] == 2 and stats['completed'] == 0
+            assert eng._inflight is None
+            assert eng._alloc.free_pages == eng.num_pages - 1
+            # both streams stopped short, and nothing came after the fault
+            assert all(2 <= f._count() < 12 for f in futs)
+            again = eng.submit(prompts[1], max_new_tokens=6)
+            assert again.result(timeout=120) == _dense_greedy(
+                params, CFG, prompts[1], 6)
+    finally:
+        fault.configure(None)
+
+
+@pytest.mark.parametrize('thread', [True, False],
+                         ids=['scheduler_thread', 'inline'])
+def test_a_draining_shutdown_reads_the_step_in_flight(params, thread):
+    prompts = _prompts([5, 9, 4], seed=67)
+    want = [_dense_greedy(params, CFG, p, 9) for p in prompts]
+    eng = _engine(params, autostart=thread)
+    futs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+    if thread:
+        next(futs[0].stream(timeout=120))       # decoding has begun
+    eng.shutdown(drain=True)
+    assert [f.result(timeout=10) for f in futs] == want
+    assert eng._inflight is None
+    assert eng.stats()['rows_discarded'] == 0
+
+
+def test_steps_overlap_when_slots_are_full_and_not_for_a_lone_token(params):
+    """``steps_overlapped`` beside ``steps``: with every slot full a step
+    always has a forerunner in flight; a request of one token takes no
+    decode step, one of two tokens a single step with nothing to overlap."""
+    prompts = _prompts([5, 9, 3, 7, 4, 8, 6, 5], seed=71)
+    eng = _engine(params, autostart=False)
+    futs = [eng.submit(p, max_new_tokens=10 + 2 * (i % 4))
+            for i, p in enumerate(prompts)]
+    with eng:
+        for f in futs:
+            f.result(timeout=300)
+        stats = eng.stats()
+    assert stats['steps'] >= 40
+    assert stats['steps_overlapped'] / stats['steps'] > 0.9
+    assert stats['rows_discarded'] == 0
+    # a discarded row is no token and a step counts once: every decoded
+    # token is a row of a counted step
+    assert stats['tokens'] - stats['prefills'] <= stats['steps'] * 2
+    for n_new, steps in ((1, 0), (2, 1)):
+        with _engine(params) as eng:
+            eng.submit(prompts[0], max_new_tokens=n_new).result(timeout=120)
+            stats = eng.stats()
+        assert (stats['steps'], stats['steps_overlapped']) == (steps, 0)
+
+
+# ---------------------------------------------------------------------------
 # decode-fn cache satellite
 # ---------------------------------------------------------------------------
 

@@ -347,6 +347,40 @@ def test_an_evicted_request_regenerates_its_tokens():
     assert [t for t, _ in got] == [t for t, _ in want]
 
 
+@pytest.mark.parametrize('kw,lens,note', [
+    # seven requests on three slots: a slot is filled again while a step
+    # computed for its last occupant is still in flight
+    (dict(num_slots=3, page_size=4, prefill_width=40),
+     (5, 21, 33, 12, 1, 2, 3), 'refilled'),
+    # one slot: every request starts in the row the last one left full
+    (dict(num_slots=1, page_size=4, prefill_width=24), (9, 3, 17), 'alone'),
+    # a pool too small: slots evicted with a step in flight start again
+    (dict(num_slots=3, page_size=4, prefill_width=16, num_pages=11),
+     (7, 6, 5), 'evicted'),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_one_step_ahead_serves_what_reading_first_serves(
+        kw, lens, note, read_first):
+    """The decode loop dispatches step N+1 before it reads step N (PR 36).
+    A step updates EVERY slot's state row, so the step in flight when a
+    slot changes hands writes the old occupant's row once more: the new
+    occupant's prefill, queued behind it, overwrites state and tail before
+    the first step that reads them. Same tokens and the same rows, to the
+    last bit, as a loop that reads each step before it dispatches the
+    next."""
+    shape = tiny_shape()
+    prompts = prompts_of(lens)
+    n_new = 18 if note == 'evicted' else 14
+    _, got, stats = _serve(shape, kw, prompts, n_new, seed=7)
+    with read_first():
+        _, want, base = _serve(shape, kw, prompts, n_new, seed=7)
+    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
+    assert stats['traces'] == 2
+    assert (stats['evictions'] >= 1) is (note == 'evicted')
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        np.testing.assert_array_equal(np.stack(rows), np.stack(want_rows))
+
+
 # ---- the per-slot kind in the engine ---------------------------------------
 
 def test_the_per_slot_kind_gets_no_pages_and_is_counted_as_state():

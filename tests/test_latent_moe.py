@@ -99,6 +99,44 @@ def test_engine_serves_the_references_logits_through_the_latent_pool(
         eng.shutdown()
 
 
+@pytest.mark.parametrize('temperature', [0.0, 0.8],
+                         ids=['greedy', 'sampled'])
+def test_one_step_ahead_serves_what_reading_first_serves(
+        weights, temperature, read_first):
+    """The decode loop dispatches step N+1 before it reads step N (PR 36),
+    and the routed layers' counts ride behind the tokens one dispatch
+    later: the same tokens, the same logits to the last bit and the same
+    counts as a loop that reads each step before it dispatches the next.
+    A request a slot, queued before the engine starts, so that every step
+    holds the same rows in both orders (a routed layer groups them)."""
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, 64, size=n).astype(np.int32) for n in (20, 9)]
+    held = lambda: getattr(obs.find(                        # noqa: E731
+        'moe.rows_held_total', {'phase': 'decode'}), 'value', 0)
+
+    def serve():
+        eng = GenerationEngine(widen(weights), config_of(SHAPE), num_slots=2,
+                               page_size=16, num_pages=9, prefill_width=32,
+                               temperature=temperature, autostart=False)
+        futs = [eng.submit(p, max_new_tokens=6 + 5 * i, seed=i,
+                           want_logits=True) for i, p in enumerate(prompts)]
+        before = held()
+        with eng:
+            out = [(f.result(timeout=300), np.stack(f.logits()))
+                   for f in futs]
+            return out, eng.stats(), held() - before
+
+    got, stats, counted = serve()
+    with read_first():
+        want, base, counted_first = serve()
+    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
+    assert stats['steps'] == base['steps'] and stats['traces'] == 2
+    assert counted == counted_first > 0
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        np.testing.assert_array_equal(rows, want_rows)
+
+
 def test_the_latent_family_refuses_a_prefix_cache(weights):
     with pytest.raises(ValueError, match='prefills from row 0'):
         GenerationEngine(widen(weights), config_of(SHAPE), num_slots=2,

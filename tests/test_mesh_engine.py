@@ -188,6 +188,43 @@ def test_byte_parity_mp1_vs_mp2(mp, temperature):
     assert s2['mesh']['mp'] == mp
 
 
+@pytest.mark.parametrize('temperature', [0.0, 0.8],
+                         ids=['greedy', 'sampled'])
+def test_mp2_one_step_ahead_serves_what_reading_first_serves(
+        temperature, read_first):
+    """The decode loop dispatches step N+1 before it reads step N (PR 36):
+    under a mesh the fed-back tokens stay replicated over it, so the step
+    is still ONE executable (two traces), and six requests on four slots
+    are served the tokens and the rows, to the last bit, that a loop
+    reading each step before it dispatches the next serves."""
+    cfg = tiny_cfg()
+    params = tiny_params(cfg)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 96, size=n).astype(np.int32)
+               for n in (5, 11, 3, 8, 13, 6)]
+
+    def serve():
+        eng = gen_engine(params, cfg, 2, temperature=temperature)
+        try:
+            futs = [eng.submit(p, max_new_tokens=6 + 3 * (i % 3), seed=i,
+                               want_logits=True)
+                    for i, p in enumerate(prompts)]
+            return ([(f.result(timeout=300), np.stack(f.logits()))
+                     for f in futs], eng.stats())
+        finally:
+            eng.shutdown()
+
+    got, stats = serve()
+    with read_first():
+        want, base = serve()
+    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
+    assert stats['traces'] == base['traces'] == 2
+    assert stats['mesh']['mp'] == 2 and stats['rows_discarded'] == 0
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        assert toks == want_toks
+        assert np.array_equal(rows, want_rows)
+
+
 # case -> (mesh degree, config overrides, engine keywords)
 DENSE_CASES = {
     'mp2': (2, {}, {}),

@@ -95,14 +95,17 @@ def serving_audit(mp):
             bad.append('gen_decode: no AOT executable after warmup')
         else:
             args_sh = compiled.input_shardings[0]
-            p_sh, pool_sh, tok_sh, pos_sh, table_sh, seeds_sh = args_sh
+            (p_sh, pool_sh, prev_sh, tok_sh, fresh_sh, pos_sh, table_sh,
+             seeds_sh) = args_sh
             for name, sh in pool_sh.items():
                 subs = sh.items() if isinstance(sh, dict) else [('', sh)]
                 for sub, s in subs:
                     label = (f'gen_decode.pool.{name}.{sub}' if sub
                              else f'gen_decode.pool.{name}')
                     check_pool_plane(label, s)
-            for label, sh in (('tokens', tok_sh), ('positions', pos_sh),
+            for label, sh in (('fed_back_tokens', prev_sh),
+                              ('tokens', tok_sh), ('fresh', fresh_sh),
+                              ('positions', pos_sh),
                               ('page_table', table_sh), ('seeds', seeds_sh)):
                 exec_state[label] = _spec_list(sh)
                 if not _is_replicated(sh):
