@@ -246,7 +246,8 @@ def check_streams(streams, sz):
 
 
 def engine_report(engine):
-    """Counts read off a warmed engine: traces, kernels per executable."""
+    """Counts read off a warmed engine: traces, kernels per executable
+    (the prefill's at the widest of its widths)."""
     st = engine.stats()
     return {'traces': st['traces'], 'evictions': st['evictions'],
             'circuit_state': st['circuit_state'],
@@ -254,8 +255,8 @@ def engine_report(engine):
             'decode_step_ms_p50': st['decode_step_ms_p50'],
             'prefill_ms_p50': st['prefill_ms_p50'],
             'decode_tpu_custom_calls': kernel_calls(engine._aot['gen_decode']),
-            'prefill_tpu_custom_calls':
-                kernel_calls(engine._aot['gen_prefill']),
+            'prefill_tpu_custom_calls': kernel_calls(
+                engine._aot[f'gen_prefill.{engine.prefill_width}']),
             'mesh': st['mesh']}
 
 
@@ -267,8 +268,10 @@ def run_engine(params, cfg, prompts, sz, phase, **engine_kw):
     engine = serving.GenerationEngine(params, cfg, num_slots=sz.slots,
                                       **engine_kw)
     try:
+        # the step, and the prefill at every width a prompt is padded to
+        executables = 1 + len(engine.prefill_widths)
         warm = engine.warmup()
-        assert warm['prebuilt'] == 2, warm
+        assert warm['prebuilt'] == executables, warm
         assert on_tpu((engine._params, engine._pool))
         streams, decoding = serve_requests(engine, prompts, sz)
         check_streams(streams, sz)
@@ -289,7 +292,8 @@ def run_engine(params, cfg, prompts, sz, phase, **engine_kw):
          cache=cache_counters(), **rep)
     assert decoding >= 1, 'late requests were not submitted mid-decode'
     assert again == streams[3], 'same prompt and seed, different stream'
-    assert rep['traces'] == 2, f"trace count {rep['traces']} != 2"
+    assert rep['traces'] == executables, \
+        f"trace count {rep['traces']} != {executables}"
     assert rep['decode_tpu_custom_calls'] > 0, \
         'no paged kernel (tpu_custom_call) in the decode executable'
     assert rep['evictions'] == 0 and rep['circuit_state'] == 'closed', rep

@@ -30,6 +30,7 @@ Rotary dims pair half-split ([x1 | x2]); a checkpoint that interleaves
 them loads with those columns of W_q and W_k permuted.
 """
 import dataclasses
+import functools
 import math
 
 import jax
@@ -253,9 +254,10 @@ MLP_ROWS = 4096     # rows of a long prefill the MLP half takes at a time
 def _mlp_half(lp, x, row_ok, config):
     """x + N_mlp(MLP(N_pre_mlp(x))) over [B, T, H] -> (x, counts or None).
     A prefill longer than ``MLP_ROWS`` goes through in pieces of that many
-    rows (``lax.map``), so that the widest intermediates (a dense layer's
-    [T, 12288] float32 pair, a routed layer's sorted rows) are a piece's
-    and not the whole prompt's: rows do not meet in this half."""
+    rows (``lax.map``) and what is left over as a last, shorter piece, so
+    that the widest intermediates (a dense layer's [T, 12288] float32
+    pair, a routed layer's sorted rows) are a piece's and not the whole
+    prompt's: rows do not meet in this half."""
     c, cdt = config, jnp.dtype(config.dtype)
     b, t, h = x.shape
 
@@ -272,39 +274,44 @@ def _mlp_half(lp, x, row_ok, config):
              + _rms(out, lp['norm_mlp'], c.rms_norm_eps)).astype(cdt)
         return x, counts
     n = b * t
-    if n <= MLP_ROWS or n % MLP_ROWS:
-        x, counts = rows(x.reshape(n, h), row_ok.reshape(n))
+    x, row_ok = x.reshape(n, h), row_ok.reshape(n)
+    if n <= MLP_ROWS:
+        x, counts = rows(x, row_ok)
         return x.reshape(b, t, h), counts
-    x, counts = jax.lax.map(lambda a: rows(*a), (
-        x.reshape(-1, MLP_ROWS, h), row_ok.reshape(-1, MLP_ROWS)))
+    whole = n - n % MLP_ROWS
+    out, counts = jax.lax.map(lambda a: rows(*a), (
+        x[:whole].reshape(-1, MLP_ROWS, h),
+        row_ok[:whole].reshape(-1, MLP_ROWS)))
+    out = out.reshape(whole, h)
+    if whole < n:
+        last, last_counts = rows(x[whole:], row_ok[whole:])
+        out = jnp.concatenate([out, last])
+        if counts is not None:
+            counts = jnp.concatenate([counts, last_counts[None]])
     if counts is not None:                     # [pieces, 5]
         counts = jnp.concatenate([jnp.sum(counts[:, :4], axis=0),
                                   jnp.max(counts[:, 4:], axis=0)])
-    return x.reshape(b, t, h), counts
+    return out.reshape(b, t, h), counts
 
 
-def _block(lp, x, pool, layer, pos_v, tables, valid, row_ok, config):
-    """One layer over [B, T, H]. -> (x, pool, counts or None)."""
+def _block(lp, x, pool, index, pos_v, tables, valid, row_ok, kind, config):
+    """One layer over [B, T, H], the ``index``-th of its ``kind``. ->
+    (x, pool, counts or None)."""
     c, cdt = config, jnp.dtype(config.dtype)
-    kind = KINDS[c.layer_types[layer]]
     window = c.sliding_window if kind == 'window' else None
     names = (f'k_{kind}', f'v_{kind}')
     with jax.named_scope('afmoe.block'):
         with jax.named_scope(f'attn_{kind}'):
             a, planes = _attention(
                 lp, _rms(x, lp['norm_in'], c.rms_norm_eps).astype(cdt),
-                tuple(pool[n] for n in names),
-                c.layers_of(kind).index(layer), window, pos_v, tables[kind],
-                valid, c)
+                tuple(pool[n] for n in names), index, window, pos_v,
+                tables[kind], valid, c)
             pool = dict(pool, **dict(zip(names, planes)))
             x = (x.astype(jnp.float32)
                  + _rms(a, lp['norm_attn'], c.rms_norm_eps)).astype(cdt)
         with jax.named_scope('mlp' if 'mlp' in lp else 'moe'):
             x, counts = _mlp_half(lp, x, row_ok, c)
     return x, pool, counts
-
-
-PREFILL_WIDTHS = (1024, 2048, 4096, 8192)   # narrower bodies of a prefill
 
 
 def _attended(pos_v, page_size, config):
@@ -329,10 +336,18 @@ def _decoder(params, tokens, pool, pos_v, tables, valid, config, last_only):
     if c.mup_enabled:
         x = x * math.sqrt(c.hidden_size)
     x = x.astype(cdt)
+    block = functools.partial(_block, config=c)
+    if t > 1:
+        # a prefill is traced and lowered once for every width the engine
+        # may call it at: its layers of one kind and one MLP half (the
+        # three window layers over experts, say) are traced once and
+        # called, the layer's place among its kind an argument
+        block = jax.jit(block, static_argnames=('kind',))
     counted = []
     for layer, lp in enumerate(params['layers']):
-        x, pool, counts = _block(lp, x, pool, layer, pos_v, tables, valid,
-                                 row_ok, c)
+        kind = KINDS[c.layer_types[layer]]
+        x, pool, counts = block(lp, x, pool, c.layers_of(kind).index(layer),
+                                pos_v, tables, valid, row_ok, kind=kind)
         if counts is not None:
             counted.append(counts)
     if last_only:
@@ -360,12 +375,9 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
     a decode step. The cache that comes back holds 'counts': the routed
     layers' ``routed_experts.COUNTS`` summed over the layers (the largest
     group's rows: the largest of any layer; zeros in a model with no
-    routed layer), then ``ATTENDED`` (zeros in a prefill).
-
-    A padded prefill asked for its last row only runs the narrowest of
-    ``PREFILL_WIDTHS`` that holds the longest prompt, chosen inside the one
-    executable by what the call can observe (``lax.switch`` on ``valid``),
-    as models/latent_moe.py does."""
+    routed layer), then ``ATTENDED`` (zeros in a prefill). Rows past
+    ``valid`` are padding at any ``T``: how wide a prompt is padded is the
+    engine's choice (``family.prefill_widths``)."""
     del partitioner     # one chip: no rules table for this family
     b, t = tokens.shape
     pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
@@ -373,23 +385,9 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
     planes = {n: cache[n] for n in cache if n[:2] in ('k_', 'v_')}
     if t > 1 and valid is None:
         valid = jnp.full((b,), t, jnp.int32)
-    widths = [w for w in PREFILL_WIDTHS if w < t] + [t]
-    if last_only and cache.get('valid') is not None and len(widths) > 1:
-        def body(width):
-            def run(pool):
-                logits, pool, counts = _decoder(
-                    params, tokens[:, :width], pool, pos_v, tables, valid,
-                    config, True)
-                return logits, pool, _no_counts(counts)
-            return run
-        longest = jnp.max(valid.astype(jnp.int32))
-        which = sum((longest > w).astype(jnp.int32) for w in widths[:-1])
-        logits, pool, counts = jax.lax.switch(
-            which, [body(w) for w in widths], planes)
-    else:
-        logits, pool, counts = _decoder(
-            params, tokens, planes, pos_v, tables, valid, config, last_only)
-        counts = _no_counts(counts)
+    logits, pool, counts = _decoder(
+        params, tokens, planes, pos_v, tables, valid, config, last_only)
+    counts = _no_counts(counts)
     page_size = next(iter(planes.values())).shape[3]
     attended = (_attended(pos_v, page_size, config) if t == 1
                 else jnp.zeros((len(ATTENDED),), jnp.int32))

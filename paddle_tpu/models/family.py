@@ -41,6 +41,15 @@ last occupant left there is never read.
 
 The engine never asks what kind of model it serves: it looks the family up
 by the config's class (``family_of``).
+
+How WIDE a prefill runs is the engine's choice, not a family's: a cached
+forward runs its layers over the ``[B, T]`` it is given, rows past ``valid``
+being padding at any ``T``, and the engine pads a prompt to the narrowest
+of ``prefill_widths`` that holds it (one executable a width, all built by
+``engine.warmup()``). The same rows give the same numbers at every width
+in a family whose rows do not meet outside attention; ``moe_gpt``'s rows
+compete for expert capacity, which follows the rows of the call, as they
+do in its decode step.
 """
 import dataclasses
 import typing
@@ -63,6 +72,35 @@ class PageKind:
 
 
 ONE_KIND = (PageKind('kv'),)    # a family that names none
+
+MAX_BODY_STEP = 4096    # rows between two neighbouring prefill widths
+
+
+def prefill_widths(prefill_width, page_size, pages=1):
+    """The widths a prompt of up to ``prefill_width`` rows may be padded
+    to, ascending, the last ``prefill_width`` itself: the powers of two and
+    the midpoints between them, never more than ``MAX_BODY_STEP`` rows
+    apart (past 16,384 that is every 4,096), those that are whole pages
+    (``pages`` of them at a time: ``GenerationFamily.prefill_pages``) and
+    no narrower than an eighth of ``prefill_width``. So a prompt computes
+    at most half as many rows again as it has (a page more where that is
+    less than a page, and the floor's rows for the shortest), and a long
+    one at most 4,095 more. 16,384 rows in pages of 128: 2048, 3072, 4096,
+    6144, 8192, 12288, 16384; 1,024: 128, 256, 384, 512, 768, 1024, and
+    two pages at a time 256, 512, 768, 1024.
+
+    Every width is an executable that every process traces, lowers and
+    loads before traffic, ~3 s each for a five-layer routed model on the
+    chip's host whatever the compile cache holds (PERF.md, PR 39): the
+    floor and the step are where one more width stopped paying for its
+    share of a served cell's set-up."""
+    top, page = int(prefill_width), int(page_size) * int(pages)
+    rungs = sorted({r for k in range(top.bit_length() + 1)
+                    for r in (1 << k, 3 << k >> 1)})
+    steps = [w for lo, hi in zip(rungs, rungs[1:])
+             for w in range(lo, hi, MAX_BODY_STEP)]
+    return tuple(w for w in steps
+                 if w % page == 0 and top <= 8 * w and w < top) + (top,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +130,12 @@ class GenerationFamily:
     tail_prefill: bool = True
     # config -> (PageKind, ...); None: one kind, pool and table as above
     page_kinds: typing.Optional[typing.Callable] = None
+    # a prefill width is a whole number of this many pages
+    # (``prefill_widths``). Every width is an executable that every process
+    # traces, lowers and loads before traffic, whatever the compile cache
+    # holds; a family whose prefill is cheap beside that names more than
+    # one page and has fewer
+    prefill_pages: int = 1
 
 
 _FAMILIES = {}

@@ -805,7 +805,10 @@ _family.register(GPTConfig, _family.GenerationFamily(
     name='gpt', init_pool=init_paged_kv_cache,
     forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
     quantize_decode_params=quantize_decode_params,
-    serve_params=serve_params))
+    serve_params=serve_params,
+    # a 1,024-row prefill at 1.3B is ~20 ms on the chip and a width ~0.3 s
+    # of every process's set-up (PERF.md, PR 39): two pages at a time
+    prefill_pages=2))
 
 
 def _sample(logits, temperature, top_k, top_p=None, key=None):

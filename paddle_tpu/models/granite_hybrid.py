@@ -44,6 +44,7 @@ compiled, not every layer. So the weights are held a period POSITION at a
 time, each leaf stacked over the periods (``stack_periods``).
 """
 import dataclasses
+import functools
 import math
 
 import jax
@@ -63,7 +64,6 @@ MAMBA, ATTENTION = 'mamba', 'attention'
 MATRICES = ('embed', 'in_proj', 'out_proj', 'mlp_in', 'mlp_out',
             'q', 'k', 'v', 'o')
 COUNTS = ('state_rows', 'scan_chunks')     # what a call counts, in order
-PREFILL_WIDTHS = (128, 256, 512)    # narrower bodies of a padded prefill
 
 
 @dataclasses.dataclass
@@ -402,7 +402,7 @@ def _state_space(lp, u, pool, index, slots, valid, config):
         return _dot(y, lp['out_proj'], cdt), left
 
 
-def _layer(lp, x, pool, kind, index, pos_v, tables, valid, config):
+def _layer(lp, x, pool, index, pos_v, tables, valid, kind, config):
     """One layer of ``kind``, the ``index``-th of its kind, over [B, T, H].
     -> (x, what its mixer leaves: a prefill's fresh rows or state, a decode
     step's pool)."""
@@ -445,14 +445,20 @@ def _decoder(params, tokens, pool, pos_v, tables, valid, config, last_only):
     flat = {n: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
             for n, a in pool.items()} if t == 1 else None
 
+    layer_of = functools.partial(_layer, config=c)
+    if t > 1:
+        # a prefill is traced and lowered once for every width the engine
+        # may call it at: a period's nine state-space layers are traced
+        # once and called, the layer's place among its kind an argument
+        layer_of = jax.jit(layer_of, static_argnames=('kind',))
+
     def one_period(carry, step):
         x, flat = carry
         layers, at = step
         seen, left = {MAMBA: 0, ATTENTION: 0}, {MAMBA: [], ATTENTION: []}
         for lp, kind in zip(layers, period):
-            x, out = _layer(lp, x, flat, kind,
-                            at * of_kind[kind] + seen[kind], pos_v, tables,
-                            valid, c)
+            x, out = layer_of(lp, x, flat, at * of_kind[kind] + seen[kind],
+                              pos_v, tables, valid, kind=kind)
             seen[kind] += 1
             if t == 1:
                 flat = out
@@ -499,10 +505,8 @@ def _write_prefill(pool, left, pos_v, tables, valid):
     """What a prefill's layers left, into the pool: every state-space
     layer's state and tail over its sequence's slot's row (what the last
     occupant left there is never read), every attention layer's K and V
-    rows to the pages of its table. Outside the bodies' ``lax.switch``, so
-    that no body takes the pool in or hands it out (a pool that passes
-    through a conditional is copied whole: the compiler's own reading for a
-    described v5e)."""
+    rows to the pages of its table. One write after the layers' scan: a
+    prefill's layers read no pool, so none is carried through them."""
     pool = dict(pool)
     if 'ssm' in left:
         slots = tables['state'].astype(jnp.int32)
@@ -534,42 +538,18 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
     prefill 'valid' [B]) -> (logits, cache). T > 1 is a prefill from row 0
     (whatever ``pos`` says: a recurrence has no tail to start from, so the
     family declines a prefix cache); T == 1 a decode step. The cache that
-    comes back holds 'counts' in the order of ``COUNTS``.
-
-    A padded prefill asked for its last row only runs the narrowest of
-    ``PREFILL_WIDTHS`` that holds the longest prompt, chosen inside the one
-    executable by what the call can observe (``lax.switch`` on ``valid``),
-    as models/latent_moe.py does."""
+    comes back holds 'counts' in the order of ``COUNTS``. Rows past
+    ``valid`` are padding at any ``T``: how wide a prompt is padded is the
+    engine's choice (``family.prefill_widths``)."""
     del partitioner     # one chip: no rules table for this family
     b, t = tokens.shape
     pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     tables, valid = cache['page_table'], cache.get('valid')
     planes = {n: cache[n] for n in ('k', 'v', 'ssm', 'conv') if n in cache}
+    logits, left, counts = _decoder(
+        params, tokens, planes, pos_v, tables, valid, config, last_only)
     if t == 1:
-        logits, pool, counts = _decoder(
-            params, tokens, planes, pos_v, tables, valid, config, last_only)
-        return logits, dict(cache, **pool, counts=counts)
-    widths = [w for w in PREFILL_WIDTHS if w < t] + [t]
-    if last_only and valid is not None and len(widths) > 1:
-        def body(width):
-            def run(_):
-                logits, left, counts = _decoder(
-                    params, tokens[:, :width], planes, pos_v, tables, valid,
-                    config, True)
-                # every body leaves K and V rows of the widest's shape
-                for n in 'kv':
-                    if n in left:
-                        left[n] = jnp.pad(left[n], (
-                            (0, 0), (0, 0), (0, t - width), (0, 0), (0, 0)))
-                return logits, left, counts
-            return run
-        longest = jnp.max(valid.astype(jnp.int32))
-        which = sum((longest > w).astype(jnp.int32) for w in widths[:-1])
-        logits, left, counts = jax.lax.switch(
-            which, [body(w) for w in widths], None)
-    else:
-        logits, left, counts = _decoder(
-            params, tokens, planes, pos_v, tables, valid, config, last_only)
+        return logits, dict(cache, **left, counts=counts)
     pool = _write_prefill(planes, left, pos_v, tables, valid)
     return logits, dict(cache, **pool, counts=counts)
 

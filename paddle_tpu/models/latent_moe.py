@@ -26,6 +26,7 @@ Rotary dims pair half-split ([x1 | x2]); a checkpoint that interleaves
 them loads with those columns of W_qb and W_kva permuted.
 """
 import dataclasses
+import functools
 import math
 
 import jax
@@ -311,9 +312,6 @@ def _block(lp, x, pool, layer, pos_v, page_table, valid, row_ok, config):
         return x + out.reshape(b, t, h), pool, counts
 
 
-PREFILL_WIDTHS = (256, 512)     # narrower bodies of a padded prefill
-
-
 def _decoder(params, tokens, pool, pos_v, page_table, valid, config,
              last_only):
     """The layers and the head over [B, T] tokens. -> (logits, pool, counts
@@ -323,10 +321,17 @@ def _decoder(params, tokens, pool, pos_v, page_table, valid, config,
     row_ok = (jnp.ones((b, t), bool) if valid is None else
               jnp.arange(t)[None, :] < valid.astype(jnp.int32)[:, None])
     x = jnp.take(params['embed'], tokens, axis=0).astype(cdt)
+    block = functools.partial(_block, config=c)
+    if t > 1:
+        # a prefill is traced and lowered once for every width the engine
+        # may call it at: its layers of one make (the expert layers, say)
+        # are traced once and called, the layer's number an argument (a
+        # decode step's kernel takes the number static)
+        block = jax.jit(block)
     counted = []
     for layer, lp in enumerate(params['layers']):
-        x, pool, counts = _block(lp, x, pool, layer, pos_v, page_table,
-                                 valid, row_ok, c)
+        x, pool, counts = block(lp, x, pool, layer, pos_v, page_table,
+                                valid, row_ok)
         if counts is not None:
             counted.append(counts)
     if last_only:
@@ -353,35 +358,18 @@ def forward_with_cache(params, tokens, cache, pos, config, last_only=False,
     a prefill 'valid' [B]) -> (logits, cache). T > 1 is a prefill from row
     0; T == 1 a decode step. The cache that comes back holds 'counts': the
     routed layers' ``routed_experts.COUNTS``, summed over the layers (the
-    largest group's rows: the largest of any layer).
-
-    A prefill that is padded (``valid`` given) and asked for its last row
-    only, which is how the engine calls it, computes no more of the padding
-    than it must: the one executable holds a body for each of
-    ``PREFILL_WIDTHS`` beside the full one and runs the narrowest that
-    holds the longest prompt (``lax.switch`` on what the call can observe,
-    ``valid``); every body gives the same rows, for the rows past ``valid``
-    are padding in all of them."""
+    largest group's rows: the largest of any layer). Rows past ``valid``
+    are padding at any ``T``: how wide a prompt is padded is the engine's
+    choice (``family.prefill_widths``)."""
     del partitioner     # attention is replicated; the pool has no heads
     b, t = tokens.shape
     pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     page_table, valid = cache['page_table'], cache.get('valid')
     if t > 1 and valid is None:
         valid = jnp.full((b,), t, jnp.int32)
-    widths = [w for w in PREFILL_WIDTHS if w < t] + [t]
-    if last_only and cache.get('valid') is not None and len(widths) > 1:
-        def body(width):
-            return lambda pool: _decoder(
-                params, tokens[:, :width], pool, pos_v, page_table, valid,
-                config, True)
-        longest = jnp.max(valid.astype(jnp.int32))
-        which = sum((longest > w).astype(jnp.int32) for w in widths[:-1])
-        logits, pool, counts = jax.lax.switch(
-            which, [body(w) for w in widths], cache['latent'])
-    else:
-        logits, pool, counts = _decoder(
-            params, tokens, cache['latent'], pos_v, page_table, valid,
-            config, last_only)
+    logits, pool, counts = _decoder(
+        params, tokens, cache['latent'], pos_v, page_table, valid, config,
+        last_only)
     out = dict(cache, latent=pool)
     if counts is not None:
         out['counts'] = counts

@@ -249,7 +249,10 @@ def loss_fn(params, tokens, targets, config, dropout_key=None,
 # every token full expert capacity, while a long training/prefill sequence
 # COMPETES for capacity_factor-bounded slots — decode equals the full
 # forward exactly whenever no token is dropped (generous capacity), and is
-# otherwise slightly BETTER-routed than training saw)
+# otherwise slightly BETTER-routed than training saw. The same holds of a
+# served prefill's width: capacity follows the rows of the call, padding
+# among them, so the engine's narrower prefill widths serve what the widest
+# serves whenever no token is dropped)
 # ---------------------------------------------------------------------------
 
 def quantize_decode_params(params):
@@ -346,7 +349,7 @@ _family.register(MoEConfig, _family.GenerationFamily(
     name='moe_gpt', init_pool=init_paged_kv_cache,
     forward_with_cache=forward_with_cache, logical_axes=LOGICAL_AXES,
     quantize_decode_params=quantize_decode_params,
-    serve_params=serve_params))
+    serve_params=serve_params, prefill_pages=2))     # as gpt's
 
 
 def make_decode_fns(config):

@@ -2,8 +2,9 @@
 
 A fleet replica used to be a single-chip engine, so the largest servable
 model was whatever fit one chip's HBM. This module supplies the glue that
-lets the SAME two GenerationEngine executables (padded batch-1 prefill +
-fixed-slot decode step) — and the InferenceEngine bucket executables —
+lets the SAME two GenerationEngine functions (the padded batch-1 prefill,
+an executable a width, + the fixed-slot decode step) — and the
+InferenceEngine bucket executables —
 run as ONE SPMD program over an mp=N device mesh:
 
  - ``MeshContext`` owns the mesh (a dedicated ``HybridTopology`` over
@@ -23,9 +24,10 @@ run as ONE SPMD program over an mp=N device mesh:
    executable compiled before traffic expects exactly the placements the
    live engine passes (zero retraces, zero resharding).
 
-The engine executables stay *uniform* across mesh sizes: trace count is
-still exactly 2, warmth cloning/snapshotting copies the same ``_aot``
-dict, and the fleet/host control planes cannot tell mp=4 from mp=1.
+The engine executables stay *uniform* across mesh sizes: the trace count
+is what it is on one chip (the step and a prefill a width), warmth
+cloning/snapshotting copies the same ``_aot`` dict, and the fleet/host
+control planes cannot tell mp=4 from mp=1.
 """
 import jax
 import numpy as np

@@ -7,12 +7,15 @@ blocks every sequence on the longest one. This engine schedules at the
 *iteration* level (the Orca discipline, PAPERS.md arxiv 2309.06180 /
 2604.15464): a fixed number of decode **slots** runs ONE compiled decode
 step per iteration, and the host scheduler admits new sequences into free
-slots and retires finished ones *between* steps. Two executables serve
-the whole workload:
+slots and retires finished ones *between* steps. Two jitted functions
+serve the whole workload:
 
- - ``prefill``: batch-1, prompts padded to a fixed ``prefill_width`` —
-   one program for every prompt length (pad rows are routed to the paged
-   pool's trash page and the last REAL row's logits sample token 0);
+ - ``prefill``: batch-1, a prompt padded to the narrowest of
+   ``prefill_widths`` that holds it (``models/family.prefill_widths``:
+   the powers of two and their midpoints from an eighth of
+   ``prefill_width`` up), ONE jitted function and one executable a width,
+   all built by ``warmup()``; pad rows are routed to the paged pool's
+   trash page and the last REAL row's logits sample token 0;
  - ``step``: all ``num_slots`` rows advance one token — inactive slots
    decode garbage into the trash page and their sample is discarded.
 
@@ -57,15 +60,16 @@ Robustness / telemetry reuse the serving stack: bounded admission queue
 (``QueueFullError``), per-request deadlines (``DeadlineExceededError``),
 a ``fault.CircuitBreaker`` + ``gen.step`` chaos point around device
 calls, ``gen.*`` metrics in the observability registry, and warmup
-manifest capture (``gen_prefill`` / ``gen_decode`` entries) so a new
-process prebuilds both executables before traffic.
+manifest capture (a ``gen_prefill`` entry a width and the ``gen_decode``
+entry) so a new process prebuilds every executable before traffic.
 
 With ``prefix_cache=True`` the engine indexes finished sequences' pages
 in a :class:`~.prefix_cache.PrefixCache` (tenant-namespaced trie over
 page-aligned chunks): a later request with a cached prefix is admitted
 with those pages pre-mapped and prefills only the uncached tail through
-the SAME prefill executable (the tail start position is a traced
-argument — zero new traces, provable via ``_trace_count``); an exact
+the SAME prefill function, at the width its TAIL needs (the tail start
+position is a traced argument — zero new traces once that width has run
+or ``warmup()`` built it, provable via ``_trace_count``); an exact
 ``(prompt, seed)`` repeat skips the prefill device call entirely and
 replays the recorded first token (near-zero TTFT). Shared pages are
 refcounted by the allocator; mid-page divergence copies the page
@@ -435,6 +439,9 @@ class GenerationEngine:
             raise ValueError(
                 f'prefill_width {self.prefill_width} outside '
                 f'[1, {s_max}]')
+        # what a prompt is padded to: the narrowest of these that holds it
+        self.prefill_widths = _family.prefill_widths(
+            self.prefill_width, ps, family.prefill_pages)
         # the family's kinds of plane; one ('kv') unless it names them.
         # A slot holds at most ``_held_max[kind]`` pages of a kind: the
         # table's width, or what a window spans. A kind that is a row a
@@ -523,7 +530,9 @@ class GenerationEngine:
         self._start_t = self._clock()
         self._n = {k: 0 for k in ('submitted', 'completed', 'rejected',
                                   'expired', 'failed', 'evictions',
-                                  'tokens', 'prefills', 'steps',
+                                  'tokens', 'prefills',
+                                  'prefill_rows_asked',
+                                  'prefill_rows_computed', 'steps',
                                   'steps_overlapped', 'rows_discarded',
                                   'prefix_hits', 'prefix_misses',
                                   'prefix_full_hits', 'prefix_tokens_saved',
@@ -582,7 +591,7 @@ class GenerationEngine:
             depth = len(self._queue)
             closed = self._closed
         warm = (self._warmed or self._fns is not None
-                or ('gen_prefill' in self._aot and 'gen_decode' in self._aot))
+                or all(k in self._aot for k in self._aot_names()))
         breaker = self._breaker.state
         ready = (warm and breaker == 'closed'
                  and depth < self.queue_capacity and not closed)
@@ -620,6 +629,11 @@ class GenerationEngine:
                     'failed')}
         self._c['evictions'] = mk_c('gen.evictions')
         self._c['tokens'] = mk_c('gen.tokens')
+        # a prefill's rows: what the prompts asked (their uncached tails)
+        # and what the bodies they were padded to computed
+        for rows in ('asked', 'computed'):
+            self._c[f'prefill_rows_{rows}'] = mk_c('gen.prefill_rows_total',
+                                                   rows=rows)
         # the decode loop one step ahead of its read-back: steps dispatched
         # while their forerunner was unread (beside stats()['steps']), and
         # rows computed for a slot that had ended or been evicted meanwhile
@@ -706,9 +720,10 @@ class GenerationEngine:
             # 'tail': True (a STATIC pytree key — the dict never crosses a
             # jit boundary) routes T>1 attention through the paged kernel
             # so rows past ``start`` attend prefix pages written by an
-            # earlier sequence. ONE executable serves cold prefills
-            # (start=0) and cached-prefix tails alike: start is traced,
-            # so prefix-cache hits never trace or compile anything new.
+            # earlier sequence. ONE executable a width serves cold
+            # prefills (start=0) and cached-prefix tails alike: start is
+            # traced, so prefix-cache hits never trace or compile anything
+            # new.
             cache = dict(pool, page_table=page_table, valid=valid,
                          tail=True)
             pos0 = start.astype(jnp.int32)
@@ -771,14 +786,20 @@ class GenerationEngine:
             self._fns = self._build_fns()
         return self._fns
 
+    def _aot_names(self):
+        """``_aot``'s keys once warm: the step and a prefill a width."""
+        return ['gen_decode'] + [f'gen_prefill.{w}'
+                                 for w in self.prefill_widths]
+
     def _manifest_entries(self):
         from ..warmup.manifest import generation_entry
         geom = dict(slots=self.num_slots, page_size=self.page_size,
                     num_pages=self.num_pages,
                     prefill_width=self.prefill_width,
                     table_width=self.p_max)
-        return [generation_entry('gen_prefill', **geom),
-                generation_entry('gen_decode', **geom)]
+        return [generation_entry('gen_prefill', body=w, **geom)
+                for w in self.prefill_widths] + [
+                    generation_entry('gen_decode', **geom)]
 
     def _maybe_record(self):
         wm = sys.modules.get('paddle_tpu.warmup.manifest')
@@ -787,9 +808,10 @@ class GenerationEngine:
                 wm.record(e)
 
     def warmup(self):
-        """AOT-compile the prefill and decode executables before traffic
-        (zero cold-start: a live call after this neither retraces nor
-        recompiles). Returns the prebuild report dict."""
+        """AOT-compile the decode step and the prefill at every one of
+        ``prefill_widths`` before traffic (zero cold-start: a live call
+        after this neither retraces nor recompiles, whatever the prompt's
+        length). Returns the prebuild report dict."""
         from .. import warmup as _warmup_mod
         man = _warmup_mod.Manifest()
         for e in self._manifest_entries():
@@ -1106,7 +1128,10 @@ class GenerationEngine:
             return
         start = slot.start
         tail = t0 - start               # uncached rows to prefill
-        prompt = np.zeros((1, self.prefill_width), np.int32)
+        # the narrowest body that holds them: rows past ``valid`` are
+        # padding at any width, so the width is the host's to choose
+        body = next(w for w in self.prefill_widths if w >= tail)
+        prompt = np.zeros((1, body), np.int32)
         prompt[0, :tail] = req.prompt[start:]
         startv = np.asarray([start], np.int32)
         valid = np.asarray([tail], np.int32)
@@ -1114,7 +1139,7 @@ class GenerationEngine:
                              slots=np.asarray([idx], np.int32))
         seed = np.asarray([req.seed], np.uint32)
         self._maybe_record()
-        pf = self._aot.get('gen_prefill') or self._fns_pair()[0]
+        pf = self._aot.get(f'gen_prefill.{body}') or self._fns_pair()[0]
         ahead = self._inflight
 
         def dev():
@@ -1136,7 +1161,8 @@ class GenerationEngine:
             tok = np.asarray(tok)
             return int(tok[0]), row, pool, tok[1:], wall0
 
-        req.rec.note('prefill', slot=idx, prompt_len=t0, start=start)
+        req.rec.note('prefill', slot=idx, prompt_len=t0, start=start,
+                     body=body)
         try:
             with _obs.span('gen.prefill', slot=idx, prompt_len=t0,
                            req_id=req.rec.rid):
@@ -1148,6 +1174,8 @@ class GenerationEngine:
         self._note_counts(counts, 'prefill')
         self._h['prefill'].observe(1e3 * (time.perf_counter() - wall0))
         self._n['prefills'] += 1
+        self._note('prefill_rows_asked', tail)
+        self._note('prefill_rows_computed', body)
         with self._cv:
             if self._slots[idx] is not slot:    # shut down meanwhile
                 return
@@ -1568,6 +1596,7 @@ class GenerationEngine:
             'page_bytes': page_bytes,
             'state_bytes_per_slot': self._state_bytes_per_slot,
             'prefill_width': self.prefill_width,
+            'prefill_widths': self.prefill_widths,
             'traces': self._trace_count,
             'tokens_per_sec': round(self._n['tokens'] / elapsed, 2),
             'prefill_ms_p50': pct(self._h['prefill'], 50),

@@ -79,18 +79,23 @@ def predictor_entry(shapes_key, precision='float32'):
 
 
 def generation_entry(kind, *, slots, page_size, num_pages, prefill_width,
-                     table_width):
+                     table_width, body=None):
     """One GenerationEngine executable (``gen_prefill`` or ``gen_decode``):
     the geometry fields pin the batch-independent shapes of the continuous-
     batching prefill/step programs, so prebuild can verify the replaying
-    engine was built with the same slot/page layout."""
+    engine was built with the same slot/page layout. A prefill's ``body``
+    is the width its prompt is padded to, one of the engine's
+    ``prefill_widths`` (unnamed: ``prefill_width``, the widest)."""
     if kind not in ('gen_prefill', 'gen_decode'):
         raise ValueError(f'kind must be gen_prefill or gen_decode, '
                          f'got {kind!r}')
-    return {'kind': kind, 'slots': int(slots), 'page_size': int(page_size),
-            'num_pages': int(num_pages),
-            'prefill_width': int(prefill_width),
-            'table_width': int(table_width)}
+    entry = {'kind': kind, 'slots': int(slots), 'page_size': int(page_size),
+             'num_pages': int(num_pages),
+             'prefill_width': int(prefill_width),
+             'table_width': int(table_width)}
+    if kind == 'gen_prefill':
+        entry['body'] = int(prefill_width if body is None else body)
+    return entry
 
 
 class Manifest:

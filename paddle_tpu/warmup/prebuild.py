@@ -21,9 +21,10 @@ Entry dispatch:
 - ``predictor`` → ``predictor=``: compile the padded-feed executable and
   seed ``Predictor._compiled``.
 - ``gen_prefill`` / ``gen_decode`` → ``generation=``: compile the
-  continuous-batching GenerationEngine's two executables (fixed-slot
-  decode step + padded batch-1 prefill) after verifying the manifest's
-  slot/page geometry matches the live engine.
+  continuous-batching GenerationEngine's executables (the fixed-slot
+  decode step, and the padded batch-1 prefill at the entry's ``body``
+  width) after verifying the manifest's slot/page geometry matches the
+  live engine.
 
 Entries with no matching target are counted ``untargeted`` and skipped;
 stale entries (shapes the current network can no longer trace) are warned
@@ -31,6 +32,7 @@ about and skipped — a manifest from last week must never crash today's
 deploy. Telemetry: ``warmup.prebuild_ms`` histogram,
 ``warmup.prebuilt_total`` / ``warmup.prebuild_skipped`` counters.
 """
+import concurrent.futures
 import os
 import time
 import warnings
@@ -40,6 +42,9 @@ import numpy as np
 
 from .. import observability as _obs
 from .manifest import Manifest, _sig_from_json, serving_bucket_entry
+
+
+COMPILE_THREADS = 4     # a generation engine's executables side by side
 
 
 def _struct(shape, dtype):
@@ -163,7 +168,8 @@ def _prebuild_generation(engine, entry):
     """AOT-compile one GenerationEngine executable (gen_prefill/gen_decode).
     The manifest's geometry must match the live engine — a mismatched
     entry is stale (caught by the strict/skip machinery), never silently
-    compiled at the wrong shapes."""
+    compiled at the wrong shapes. -> False where the engine holds it
+    already, else its compile, still to run, as a callable."""
     kind = entry['kind']
     geom = {'slots': engine.num_slots, 'page_size': engine.page_size,
             'num_pages': engine.num_pages,
@@ -175,36 +181,49 @@ def _prebuild_generation(engine, entry):
             raise ValueError(
                 f'generation entry {k}={got} does not match the live '
                 f'engine ({k}={v})')
-    if kind in engine._aot:
-        return False
     pf, st = engine._fns_pair()
-    params = _tree_structs(engine._params)
-    pool = _tree_structs(engine._pool)
     if kind == 'gen_prefill':
-        compiled = pf.lower(
-            params, pool,
-            _struct((1, engine.prefill_width), np.int32),
+        # the width this entry's prompts are padded to (an entry written
+        # before the engine chose among widths names none: the widest)
+        body = int(entry.get('body', engine.prefill_width))
+        if body not in engine.prefill_widths:
+            raise ValueError(
+                f'generation entry body={body} is none of the live '
+                f"engine's prefill widths {engine.prefill_widths}")
+        name, label, fn = f'gen_prefill.{body}', f'gen.prefill.{body}', pf
+        shapes = (
+            _struct((1, body), np.int32),
             _struct((1,), np.int32),    # start (prefix-cache tail offset)
             _struct((1,), np.int32),    # valid
             _tree_structs(engine._tables(1)),
-            _struct((1,), np.uint32)).compile()
-        _perf_analyze('gen.prefill', compiled)
+            _struct((1,), np.uint32))
     else:
         s = engine.num_slots
-        compiled = st.lower(
-            params, pool,
+        name, label, fn = kind, 'gen.decode', st
+        shapes = (
             _tree_structs(engine._no_prev),     # the previous step's tokens
             _struct((s,), np.int32),    # the host's tokens ...
             _struct((s,), np.bool_),    # ... and which rows take them
             _struct((s,), np.int32),
             _tree_structs(engine._tables(s)),
-            _struct((s,), np.uint32)).compile()
-        _perf_analyze('gen.decode', compiled)
-    # hand the AOT executable to the engine's live path: jit's own call
-    # cache would rebuild the executable on the first real invocation
-    # even with the trace warm, costing one full XLA compile per fn
-    engine._aot[kind] = compiled
-    return True
+            _struct((s,), np.uint32))
+    if name in engine._aot:
+        return False
+    # traced and lowered here; the compiles (or the reads from the
+    # persistent cache) of an engine's executables, a prefill a width
+    # among them, run side by side once all are lowered (``prebuild``)
+    lowered = fn.lower(_tree_structs(engine._params),
+                       _tree_structs(engine._pool), *shapes)
+
+    def compile_it():
+        compiled = lowered.compile()
+        _perf_analyze(label, compiled)
+        # hand the AOT executable to the engine's live path: jit's own call
+        # cache would rebuild the executable on the first real invocation
+        # even with the trace warm, costing one full XLA compile per fn
+        engine._aot[name] = compiled
+        return True
+    return compile_it
 
 
 def _prebuild_predictor(predictor, entry):
@@ -257,7 +276,37 @@ def prebuild(manifest, *, engine=None, model=None, predictor=None,
     report = {'entries': len(manifest), 'prebuilt': 0, 'already_cached': 0,
               'skipped': 0, 'untargeted': 0, 'skips': []}
     t_start = time.perf_counter()
+
+    def settle(kind, t0, build):
+        """One entry's outcome into the report. ``build()`` -> whether it
+        built anything, or the rest of its work as a callable, which is
+        handed back for a worker thread."""
+        try:
+            built = build()
+        except Exception as e:
+            if strict:
+                raise
+            warnings.warn(
+                f'paddle_tpu.warmup: skipping stale manifest entry '
+                f'({kind}): {e!r}', RuntimeWarning, stacklevel=3)
+            _obs.counter('warmup.prebuild_skipped',
+                         {'kind': str(kind)}).inc()
+            report['skipped'] += 1
+            report['skips'].append(f'{kind}: {e}')
+            return None
+        if callable(built):
+            return built
+        if built:
+            elapsed_ms = 1e3 * (time.perf_counter() - t0)
+            _obs.histogram('warmup.prebuild_ms').observe(elapsed_ms)
+            _obs.counter('warmup.prebuilt_total', {'kind': str(kind)}).inc()
+            report['prebuilt'] += 1
+        else:
+            report['already_cached'] += 1
+        return None
+
     try:
+        lowered = []
         for entry in manifest:
             kind = entry.get('kind')
             handler = handlers.get(kind)
@@ -265,27 +314,16 @@ def prebuild(manifest, *, engine=None, model=None, predictor=None,
                 report['untargeted'] += 1
                 continue
             t0 = time.perf_counter()
-            try:
-                built = handler(entry)
-            except Exception as e:
-                if strict:
-                    raise
-                warnings.warn(
-                    f'paddle_tpu.warmup: skipping stale manifest entry '
-                    f'({kind}): {e!r}', RuntimeWarning, stacklevel=2)
-                _obs.counter('warmup.prebuild_skipped',
-                             {'kind': str(kind)}).inc()
-                report['skipped'] += 1
-                report['skips'].append(f'{kind}: {e}')
-                continue
-            if built:
-                elapsed_ms = 1e3 * (time.perf_counter() - t0)
-                _obs.histogram('warmup.prebuild_ms').observe(elapsed_ms)
-                _obs.counter('warmup.prebuilt_total',
-                             {'kind': str(kind)}).inc()
-                report['prebuilt'] += 1
-            else:
-                report['already_cached'] += 1
+            rest = settle(kind, t0, lambda: handler(entry))
+            if rest is not None:
+                lowered.append((kind, t0, rest))
+        # every trace and lowering first, on this thread alone (Python's:
+        # a worker beside it slows both, measured); then the compiles, or
+        # the reads from the persistent cache, side by side
+        with concurrent.futures.ThreadPoolExecutor(COMPILE_THREADS) as pool:
+            for kind, t0, future in [(kind, t0, pool.submit(rest))
+                                     for kind, t0, rest in lowered]:
+                settle(kind, t0, future.result)
     finally:
         if model is not None and orig_mode is not None:
             model._enter_mode(orig_mode)

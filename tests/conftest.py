@@ -78,3 +78,37 @@ def read_first():
         finally:
             GenerationEngine._plan_step = plan
     return patched
+
+
+@pytest.fixture(scope='session')
+def full_body():
+    """-> a context manager under which every ``GenerationEngine`` built
+    pads every prompt to ``prefill_width``: the one body an engine had
+    before it chose among ``family.prefill_widths``. A test-local patch of
+    the rule; what the narrow bodies serve is held equal to what this one
+    serves."""
+    import contextlib
+
+    from paddle_tpu.models import family
+
+    @contextlib.contextmanager
+    def patched():
+        rule = family.prefill_widths
+        family.prefill_widths = (
+            lambda width, page_size, pages=1: (int(width),))
+        try:
+            yield
+        finally:
+            family.prefill_widths = rule
+    return patched
+
+
+@pytest.fixture
+def traces_for():
+    """-> ``count(widths, rows)``: the traces an engine of these
+    ``prefill_widths`` has made once it has served prompts of these
+    (uncached) rows with no ``warmup()`` before them: its step, and its
+    prefill at each width one of them was padded to."""
+    def count(widths, rows):
+        return 1 + len({next(w for w in widths if w >= n) for n in rows})
+    return count

@@ -524,10 +524,18 @@ def test_a_step_counts_what_each_kind_of_layer_attended():
     assert list(np.asarray(out['counts'])[len(rex.COUNTS):]) == [0, 0, 0, 0]
 
 
-def test_a_long_prefill_takes_its_mlp_half_in_pieces(monkeypatch):
-    """Past ``MLP_ROWS`` rows the MLP half runs a piece at a time: the same
-    rows, the routed layers' counts summed (the largest group: the largest
-    of any piece)."""
+@pytest.mark.parametrize('piece,calls', [
+    (16, 3),        # 48 rows in three whole pieces
+    (20, 3),        # two whole pieces and a last one of 8 rows
+    (32, 2),        # one whole piece and a last one of 16
+    (40, 2)])       # one whole piece and a last one of 8
+def test_a_long_prefill_takes_its_mlp_half_in_pieces(monkeypatch, piece,
+                                                     calls):
+    """Past ``MLP_ROWS`` rows the MLP half runs a piece at a time, and a
+    width that is no multiple of it (6,144 of the engine's widths over
+    pieces of 4,096) leaves a last, shorter piece:
+    the same rows as the unpieced half, the routed layers' counts summed
+    (the largest group: the largest of any piece)."""
     shape = tiny_shape(max_position_embeddings=64)
     cfg, params = program_config(shape), f32_params(shape)
     tokens = jnp.asarray(np.random.RandomState(0).randint(0, 96, (1, 48)))
@@ -540,12 +548,12 @@ def test_a_long_prefill_takes_its_mlp_half_in_pieces(monkeypatch):
             params, tokens, cache, jnp.zeros((1,), jnp.int32), cfg)
         return np.asarray(logits), np.asarray(out['counts'])
     whole, counts = run()
-    monkeypatch.setattr(afmoe, 'MLP_ROWS', 16)
+    monkeypatch.setattr(afmoe, 'MLP_ROWS', piece)
     pieces, piece_counts = run()
     np.testing.assert_allclose(pieces, whole, atol=1e-5)
     # the same rows offered and held; a piece is a call of its own, which
     # offers the held experts again
     assert list(piece_counts[:2]) == list(counts[:2])
-    assert piece_counts[2] == 3 * counts[2]
-    assert counts[3] <= piece_counts[3] <= 3 * counts[3]
+    assert piece_counts[2] == calls * counts[2]
+    assert counts[3] <= piece_counts[3] <= calls * counts[3]
     assert 0 < piece_counts[4] <= counts[4]

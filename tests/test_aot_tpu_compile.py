@@ -42,7 +42,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # ---------------------------------------------------------------------------
 
 def _cases(devices):
-    """name -> thunk returning the compiled program's text."""
+    """name -> thunk returning the compiled program's text, or the
+    compiled program itself (an engine's: its temporaries are read too)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
     fa = importlib.import_module('paddle_tpu.ops.flash_attention')
@@ -143,10 +144,10 @@ def _cases(devices):
             # host's, positions, tables, seeds
             return lambda: step.lower(
                 params, pool, i32(slots), i32(slots), S((slots,), jnp.bool_),
-                i32(slots), table(slots), i32(slots)).compile().as_text()
+                i32(slots), table(slots), i32(slots)).compile()
         return lambda: prefill.lower(
             params, pool, i32(1, width or cfg.max_seq_len), i32(1), i32(1),
-            table(1), i32(1)).compile().as_text()
+            table(1), i32(1)).compile()
 
     from paddle_tpu.models import gpt, moe_gpt
     # benchmark/configs/gpt-1.3b-serve.json: 24 layers, 129 pages of 128
@@ -224,6 +225,14 @@ def _cases(devices):
         'afmoe_step': engine_program('step', trinity, 24, trinity_pages),
         'afmoe_prefill_1024': engine_program('prefill', trinity, 24,
                                              trinity_pages, width=1024),
+        # widths that are no multiple of the MLP half's 4,096-row pieces,
+        # beside the widest
+        'afmoe_prefill_6144': engine_program('prefill', trinity, 24,
+                                             trinity_pages, width=6144),
+        'afmoe_prefill_14336': engine_program('prefill', trinity, 24,
+                                              trinity_pages, width=14336),
+        'afmoe_prefill_16384': engine_program('prefill', trinity, 24,
+                                              trinity_pages, width=16384),
         'flash_window_s16384_gqa6': windowed_flash(16384, 4096),
         'flash_full_s16384_gqa6': windowed_flash(16384, None),
         'paged_gqa6_window': windowed_paged(4096),
@@ -358,10 +367,13 @@ def _child():
     out = {}
     for name, compile_text in _cases(list(topo.devices)).items():
         try:
-            text = compile_text()
+            program = compile_text()
+            text = program if isinstance(program, str) else program.as_text()
             moved = _pool_copies(text)
             taken, remade = _block_matrices(text)
             out[name] = {
+                'temp_bytes': None if isinstance(program, str) else int(
+                    program.memory_analysis().temp_size_in_bytes),
                 'block_matrices': taken, 'block_matrices_remade': remade,
                 'kernels': text.count('tpu_custom_call'),
                 'pool_copies': len(moved), 'moved': sorted(set(moved)),
@@ -533,11 +545,34 @@ def test_window_and_full_engine_programs_leave_their_pools_where_they_lie(
     assert compiled[case]['names'] == names
 
 
+@pytest.mark.parametrize('case', ['afmoe_prefill_6144',
+                                  'afmoe_prefill_14336'])
+def test_a_width_of_no_whole_pieces_keeps_its_temporaries_a_pieces(
+        compiled, case):
+    """6,144 rows (one of the engine's widths at 16,384) and 14,336 are no
+    multiple of the 4,096 rows the MLP half takes at a time: what is left
+    over goes through as a last, shorter
+    piece, so such a prefill's temporaries stay under the widest body's
+    (1.58 GB, PR 31; a 14,336-row dense layer taken whole would hold a
+    [14336, 12288] float32 pair, 1.4 GB, beside them), its five flash
+    forwards take the width as they take the others, and no pool is
+    copied."""
+    widest = compiled['afmoe_prefill_16384']
+    assert 'error' not in widest and 'error' not in compiled[case], (
+        compiled[case], widest)
+    assert 0 < compiled[case]['temp_bytes'] <= widest['temp_bytes'] < 1.7e9
+    # five flash forwards; four routed layers' three grouped products in
+    # the whole pieces' loop, and again in the last piece
+    assert _summary(compiled[case]) == {
+        'kernels': 5 + 2 * 12, 'pool_copies': 0, 'collectives': []}
+    assert compiled[case]['names'] == ['flash_fwd', 'flash_fwd_window']
+
+
 @pytest.mark.parametrize('case,kernels,names', [
     # a period's body, compiled once: nine state updates and the paged call
     ('granite_step', 9 + 1, ['paged_attention', 'ssm_state_update']),
-    # four prefill bodies (128, 256, 512, 768 rows), a flash forward each
-    ('granite_prefill', 4, ['flash_fwd']),
+    # the widest prefill (768 rows): a period's one flash forward
+    ('granite_prefill', 1, ['flash_fwd']),
 ])
 def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
         compiled, case, kernels, names):
@@ -545,10 +580,11 @@ def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
     published widths, all 40 layers: the state a slot (4.8 GB), the
     convolution's tails and the K and V pages are carried through the
     scan over periods and written in place (a decode step's state by the
-    kernel whose result is its operand's buffer), and a prefill's bodies
-    leave what they made for ONE write outside their ``lax.switch``: a
-    pool that passed through the conditional was copied whole (18 GB
-    asked of 16: the compiler refused the program)."""
+    kernel whose result is its operand's buffer), and a prefill's layers
+    read no pool: they leave what they made for ONE write after the scan
+    (a pool that passed through a conditional was copied whole, 18 GB
+    asked of 16, when the widths were bodies of a ``lax.switch``; the
+    engine now calls the prefill at the width it chose)."""
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
     assert compiled[case]['names'] == names

@@ -30,6 +30,9 @@ VARIANTS = {
 PROMPTS = [np.random.RandomState(s).randint(1, 97, size=n).astype(np.int32)
            for s, n in ((1, 5), (2, 17), (3, 11))]
 NEW = 6
+# the step, and the prefill at the two widths of KW (two pages of 8 rows at
+# a time: 16 and 24) that PROMPTS' rows are padded to
+TRACES = 1 + 2
 
 
 def _engine(variant, **over):
@@ -61,7 +64,7 @@ def test_each_rows_argmax_is_the_token_at_temperature_zero(variant):
                 assert row.shape == (BASE['vocab_size'],)
                 assert row.dtype == np.float32
                 assert int(np.argmax(row)) == tok
-        assert engine.stats()['traces'] == 2
+        assert engine.stats()['traces'] == TRACES
     finally:
         engine.shutdown(drain=False)
 
@@ -79,7 +82,7 @@ def test_tokens_do_not_depend_on_who_asked_for_logits(variant):
         assert all(len(f.logits()) == NEW for f in futs)
         if variant == 'prefix_cache':
             assert engine.stats()['prefix']['hits'] == len(PROMPTS)
-        assert engine.stats()['traces'] == 2
+        assert engine.stats()['traces'] == TRACES
     finally:
         engine.shutdown(drain=False)
 
@@ -200,7 +203,7 @@ def _held_engine(variant):
 def _rows_and_tokens(engine):
     try:
         tokens, futs = _serve(engine, want=True)
-        assert engine.stats()['traces'] == 2
+        assert engine.stats()['traces'] == TRACES
         return tokens, [np.stack(f.logits()) for f in futs]
     finally:
         engine.shutdown(drain=False)

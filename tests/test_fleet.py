@@ -249,10 +249,12 @@ def test_autoscaler_scales_up_warm_then_back_down(params):
     rs = ReplicaSet(lambda: _gen_engine(params, num_slots=1),
                     initial=1, min_replicas=1, max_replicas=3)
     # a clone is as warm as its template: spawn() copies the executables
-    # warmup() built, so the template holds both before the burst, whenever
-    # the breach is first seen (a template compiled by live traffic has
-    # none to copy, and its clone would trace on its first request)
-    assert rs.snapshot()[0].engine.warmup()['prebuilt'] == 2
+    # warmup() built, so the template holds them all (the step and a
+    # prefill a width) before the burst, whenever the breach is first seen
+    # (a template compiled by live traffic has none to copy, and its clone
+    # would trace on its first request)
+    template = rs.snapshot()[0].engine
+    assert template.warmup()['prebuilt'] == 1 + len(template.prefill_widths)
     asc = Autoscaler(qwait_p99_ms=1.0, idle_s=0.4, cooldown_s=0.2,
                      debounce=1)
     router = FleetRouter(rs, autoscaler=asc, tick_s=0.01)

@@ -613,12 +613,16 @@ def test_streaming_matches_result(params):
 
 
 def test_warmup_two_traces_and_zero_retrace(params):
+    """Two jitted functions: the step, and the prefill at each of the
+    engine's widths (one here: the family's widths are two pages of 8 rows
+    at a time, tests/test_prefill_widths.py serves several)."""
     eng = _engine(params, autostart=False)
+    assert eng.prefill_widths == (16,)
     report = eng.warmup()
     assert report['prebuilt'] == 2
     assert eng._trace_count == 2
-    assert set(eng._aot) == {'gen_prefill', 'gen_decode'}
-    # a second warmup finds both executables already built
+    assert set(eng._aot) == {'gen_prefill.16', 'gen_decode'}
+    # a second warmup finds every executable already built
     assert eng.warmup()['already_cached'] == 2
     with eng:
         futs = [eng.submit(p, max_new_tokens=4)
@@ -639,6 +643,9 @@ def test_manifest_capture_records_generation_entries(params):
         entry = next(e for e in man if e['kind'] == 'gen_decode')
         assert entry['slots'] == eng.num_slots
         assert entry['page_size'] == eng.page_size
+        # a prefill entry a width, whatever width the traffic ran
+        assert sorted(e['body'] for e in man
+                      if e['kind'] == 'gen_prefill') == [16]
         # a fresh engine of the same geometry prebuilds from the capture
         eng2 = _engine(params, autostart=False)
         report = warmup.prebuild(man, generation=eng2)
@@ -874,7 +881,9 @@ def test_one_step_ahead_serves_what_reading_first_serves(
         want, base = _serve_waves(weights, cfg, case, **kw)
     assert base['steps_overlapped'] == 0 and base['rows_discarded'] == 0
     assert stats['steps_overlapped'] > 0
-    assert stats['traces'] == base['traces'] == 2
+    # the step, and the prefill at each width a prompt's rows needed
+    assert (2 <= stats['traces'] == base['traces']
+            <= 1 + len(stats['prefill_widths']))
     if case == 'evicted':
         # the victim's row of the step in flight is dropped unread
         assert stats['evictions'] >= 1 and stats['rows_discarded'] >= 1

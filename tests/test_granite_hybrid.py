@@ -113,7 +113,8 @@ def _held_to_reference(shape, layers, prompts, served, max_new, tol):
 
 # ---- served rows against the plain reference -------------------------------
 
-def test_engine_serves_the_reference_rows_through_state_and_pages():
+def test_engine_serves_the_reference_rows_through_state_and_pages(
+        traces_for):
     """The jnp paths: prompts of 1, 2 and 3 rows (a convolution's tail
     that reaches before row 0) among longer ones, 20 tokens each through
     the slots' state rows and the pages, seven requests on three slots: the
@@ -124,7 +125,9 @@ def test_engine_serves_the_reference_rows_through_state_and_pages():
     layers, served, stats = _serve(
         shape, dict(num_slots=3, page_size=4, prefill_width=40), prompts, 20)
     _held_to_reference(shape, layers, prompts, served, 20, 2e-5)
-    assert stats['evictions'] == 0 and stats['traces'] == 2
+    assert stats['evictions'] == 0
+    assert stats['traces'] == traces_for(stats['prefill_widths'],
+                                         map(len, prompts)) == 1 + 4
     assert stats['free_pages'] == stats['num_pages'] - 1    # the trash page
 
 
@@ -359,7 +362,7 @@ def test_an_evicted_request_regenerates_its_tokens():
      (7, 6, 5), 'evicted'),
 ], ids=lambda x: x if isinstance(x, str) else None)
 def test_one_step_ahead_serves_what_reading_first_serves(
-        kw, lens, note, read_first):
+        kw, lens, note, read_first, traces_for):
     """The decode loop dispatches step N+1 before it reads step N (PR 36).
     A step updates EVERY slot's state row, so the step in flight when a
     slot changes hands writes the old occupant's row once more: the new
@@ -374,7 +377,8 @@ def test_one_step_ahead_serves_what_reading_first_serves(
     with read_first():
         _, want, base = _serve(shape, kw, prompts, n_new, seed=7)
     assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
-    assert stats['traces'] == 2
+    assert stats['traces'] == base['traces'] == traces_for(
+        stats['prefill_widths'], lens)
     assert (stats['evictions'] >= 1) is (note == 'evicted')
     for (toks, rows), (want_toks, want_rows) in zip(got, want):
         assert toks == want_toks
@@ -462,7 +466,7 @@ def test_the_counters_count_what_a_step_served():
                          prompts_of((5, 11)), 4)
     rows, chunks, decoded = (a - b for a, b in zip(read(), before))
     assert rows == 5 + 11
-    assert chunks == 2 * 3                  # 24 rows in chunks of 8, twice
+    assert chunks == 1 + 2      # bodies of 8 and 12 rows in chunks of 8
     assert decoded == 2 * stats['steps']    # both slots, busy or idle
 
 

@@ -94,7 +94,7 @@ def test_engine_serves_the_references_logits_through_the_latent_pool(
             assert rows.shape == want.shape
             np.testing.assert_allclose(rows, want, atol=2e-5)
             assert toks == list(np.argmax(rows, axis=-1))
-        assert eng.stats()['traces'] == 2
+        assert eng.stats()['traces'] == 2       # both prompts in 32 rows
     finally:
         eng.shutdown()
 
@@ -130,7 +130,8 @@ def test_one_step_ahead_serves_what_reading_first_serves(
     with read_first():
         want, base, counted_first = serve()
     assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
-    assert stats['steps'] == base['steps'] and stats['traces'] == 2
+    # the step and the prefill at 32 and at 16 rows
+    assert stats['steps'] == base['steps'] and stats['traces'] == 3
     assert counted == counted_first > 0
     for (toks, rows), (want_toks, want_rows) in zip(got, want):
         assert toks == want_toks
@@ -161,38 +162,45 @@ def test_absorbed_form_equals_expanded_form(weights):
     np.testing.assert_allclose(row[:, 0], full[:, 23], atol=2e-5)
 
 
-@pytest.mark.parametrize('rows', [20, 256, 257, 300])
+@pytest.mark.parametrize('rows,body', [(20, 128), (256, 256), (257, 320),
+                                       (300, 320)])
 def test_a_padded_prefill_runs_the_narrowest_body_that_holds_it(
-        weights, rows):
-    """The engine's prefill call (padded, last row only): the executable
-    holds a 256-row body beside the full one; either gives the reference's
-    row and writes the same cache rows."""
-    cfg, params = config_of(SHAPE), widen(weights)
-    width = 320
-    rng = np.random.RandomState(rows)
-    toks = np.zeros((1, width), np.int32)
-    toks[0, :rows] = rng.randint(0, 64, size=rows)
-    pool = latent_moe.init_pool(cfg, 4, 128)
-    cache = dict(pool, page_table=jnp.asarray([[1, 2, 3]], jnp.int32),
-                 valid=jnp.asarray([rows], jnp.int32), tail=True)
-    call = jax.jit(lambda t, c: latent_moe.forward_with_cache(
-        params, t, c, jnp.zeros((1,), jnp.int32), cfg, last_only=True))
-    text = call.lower(jnp.asarray(toks), cache).as_text()
-    assert 'case' in text or 'conditional' in text      # both bodies inside
-    logits, out = call(jnp.asarray(toks), cache)
-    want = ref.forward(weights, jnp.asarray(toks[:, :rows]), SHAPE)
-    assert logits.shape == (1, 1, 64)
-    np.testing.assert_allclose(logits[0, 0], want[0, -1], atol=2e-5)
-    # the rows written are the full-width forward's rows
-    full, whole = latent_moe.forward_with_cache(
-        params, jnp.asarray(toks), dict(pool, page_table=cache['page_table'],
-                                        valid=cache['valid']),
-        jnp.zeros((1,), jnp.int32), cfg)
-    written = np.asarray(out['latent'][:, 1:4]).reshape(3, -1, 256)[:, :rows]
-    np.testing.assert_allclose(
-        written, np.asarray(whole['latent'][:, 1:4]).reshape(
-            3, -1, 256)[:, :rows], atol=2e-5)
-    assert int(out['counts'][0]) == 2 * 4 * rows    # padding routed nowhere
+        weights, rows, body):
+    """The engine pads a prompt to the narrowest of its widths that holds
+    it (128, 256 and the full 320 rows here) and runs the prefill at that
+    shape; the family's forward gives the reference's row and writes the
+    same cache rows at either width, the padding routed nowhere."""
+    shape = dict(SHAPE, max_position_embeddings=384)
+    cfg, params = config_of(shape), widen(weights)
+    prompt = np.random.RandomState(rows).randint(0, 64, size=rows).astype(
+        np.int32)
+    with GenerationEngine(params, cfg, num_slots=1, page_size=128,
+                          num_pages=4, prefill_width=320) as eng:
+        assert eng.prefill_widths == (128, 256, 320)
+        fut = eng.submit(prompt, max_new_tokens=1, want_logits=True)
+        fut.result(timeout=300)
+        stats = eng.stats()
+    assert stats['prefill_rows_asked'] == rows
+    assert stats['prefill_rows_computed'] == body
+    want = ref.forward(weights, jnp.asarray(prompt)[None], shape)
+    np.testing.assert_allclose(fut.logits()[0], want[0, -1], atol=2e-5)
+
+    def prefill(width):
+        toks = np.zeros((1, width), np.int32)
+        toks[0, :rows] = prompt
+        cache = dict(latent_moe.init_pool(cfg, 4, 128),
+                     page_table=jnp.asarray([[1, 2, 3]], jnp.int32),
+                     valid=jnp.asarray([rows], jnp.int32))
+        logits, out = latent_moe.forward_with_cache(
+            params, jnp.asarray(toks), cache, jnp.zeros((1,), jnp.int32),
+            cfg, last_only=True)
+        written = np.asarray(out['latent'][:, 1:4]).reshape(3, -1, 256)
+        return np.asarray(logits[0, 0]), written[:, :rows], out['counts']
+    (row, written, counts), (full_row, full, _) = prefill(body), prefill(320)
+    np.testing.assert_allclose(row, want[0, -1], atol=2e-5)
+    np.testing.assert_allclose(row, full_row, atol=2e-5)
+    np.testing.assert_allclose(written, full, atol=2e-5)
+    assert int(counts[0]) == 2 * 4 * rows       # padding routed nowhere
 
 
 def test_the_programs_weights_have_the_references_structure():

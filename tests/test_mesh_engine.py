@@ -271,6 +271,8 @@ def test_sharded_engine_rows_against_the_dense_cache(case):
         stats = engine.stats()
     finally:
         engine.shutdown()
+    # the step and the prefill (one width, two pages of 16 rows): a mesh
+    # changes neither the functions nor how many shapes they meet
     assert stats['traces'] == 2 and stats['mesh']['mp'] == mp
     if kw.get('prefix_cache'):
         assert stats['prefix_tokens_saved'] == len(first)    # ends mid-page
@@ -297,12 +299,14 @@ def test_mesh_gauge_and_uniform_labels():
 
 
 def test_warmup_then_traffic_keeps_two_traces():
+    """Two functions under a mesh as without one: the step, and the
+    prefill at each of the engine's widths (one here)."""
     cfg = tiny_cfg()
     eng = gen_engine(tiny_params(cfg), cfg, 2)
     try:
         eng.warmup()
-        assert eng._trace_count == 2
-        assert set(eng._aot) >= {'gen_prefill', 'gen_decode'}
+        assert eng._trace_count == 1 + len(eng.prefill_widths) == 2
+        assert set(eng._aot) == {'gen_prefill.32', 'gen_decode'}
         list(eng.submit([3, 1, 4], max_new_tokens=6).result(timeout=120))
         assert eng._trace_count == 2
     finally:

@@ -123,7 +123,11 @@ def test_partial_hit_shared_prefix_matches_cold(params):
         st = eng.stats()
         assert st['prefix']['hits'] >= 1
         assert st['prefix_tokens_saved'] >= PS
-        assert eng._trace_count == 2          # tail reuses the executable
+        # the tail reuses the executable (one width here; a tail picks
+        # a narrower one where the engine has it: test_prefill_widths.py)
+        assert eng._trace_count == 2
+        assert st['prefill_rows_asked'] == len(a) + len(b) - PS
+        assert st['prefill_rows_computed'] == 16 + 16
     assert got_b == want_b
 
 
