@@ -37,12 +37,12 @@ import jax
 import jax.numpy as jnp
 
 from .. import observability as _obs
+from ..ops.dense import dot as _dot, rms as _rms
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import paged_attention
 from ..ops.paged_kv import paged_write
 from ..parallel import routed_experts as _re
 from . import family as _family
-from .latent_moe import _dot, _rms
 
 FULL, WINDOW = 'full_attention', 'sliding_attention'
 KINDS = {FULL: 'full', WINDOW: 'window'}     # layer type -> page kind

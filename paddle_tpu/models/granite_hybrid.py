@@ -52,11 +52,11 @@ import jax.numpy as jnp
 
 from .. import observability as _obs
 from ..ops import ssm as _rec
+from ..ops.dense import dot as _dot, rms as _rms
 from ..ops.flash_attention import flash_attention
 from ..ops.paged_attention import paged_attention
 from ..ops.paged_kv import paged_write
 from . import family as _family
-from .latent_moe import _dot, _rms
 
 MAMBA, ATTENTION = 'mamba', 'attention'
 # leaves that are the right-hand operand of a product (held in the compute

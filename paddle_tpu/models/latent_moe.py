@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import observability as _obs
+from ..ops.dense import dot as _dot, rms as _rms
 from ..ops.paged_kv import paged_write
 from ..ops.paged_latent_attention import (latent_prefill_attention,
                                           paged_latent_attention)
@@ -152,18 +153,6 @@ def _rope(x, cos, sin):
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1)
-
-
-def _rms(x, g, eps):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                             + eps) * g.astype(jnp.float32)
-
-
-def _dot(a, w, cdt):
-    """-> float32 (the caller rounds where the next product needs it)."""
-    return jnp.dot(a.astype(cdt), w.astype(cdt),
-                   preferred_element_type=jnp.float32)
 
 
 # ---- weights ---------------------------------------------------------------

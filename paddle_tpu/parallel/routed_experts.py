@@ -7,13 +7,29 @@ what the other experts would add is the other chips' (on one chip: left
 out). ``parallel/moe.py``'s ``top2_gating`` is another layer: two experts a
 token, a capacity that drops rows, one-hot dispatch.
 
-    route          sigmoid scores over all experts, a correction bias that
+WHO chooses is a family's own: a router is the family's code, and the layer
+proper starts from its answer, ``(chosen [T, k], weights [T, k])``.
+
+    route          ONE family of routers (``latent_moe``, ``afmoe``):
+                   sigmoid scores over all experts, a correction bias that
                    moves the CHOICE and not the weights, group-limited
-                   top-k ('noaux_tc'), weights normalised over all chosen
+                   top-k ('noaux_tc'), weights normalised over all chosen.
+                   A family with another router (``zaya``: a softmax over
+                   an MLP's outputs, one expert a token) calls none of it
     plan           which chosen experts are held: rows sorted by expert
                    into tiles of ``tm`` rows, each group padded to a tile
-    routed_experts the grouped product over the held experts only
-                   (ops/expert_grouped_matmul.py), SwiGLU, combine
+    held_experts   the layer from a router's answer: the grouped product
+                   over the held experts only
+                   (ops/expert_grouped_matmul.py), SwiGLU, the weighted
+                   combine, and a shared expert where the layer's weights
+                   have one
+    routed_experts ``route`` and then ``held_experts``: the whole layer of
+                   the families whose router ``route`` is
+
+A row may meet NO expert: a choice outside the held range (another chip's
+expert, or an index past the router's experts that a family uses for "skip
+the experts") is sorted nowhere, costs no row of the product and adds
+nothing; what such a row gets instead is the family's.
 
 No capacity, no dropped row: the sorted array is sized for the worst case
 (every choice of every row held here) and the kernel skips what is not in
@@ -101,37 +117,55 @@ def swiglu(p, h, cdt):
 
 def routed_experts(lp, h, row_ok, *, held, top_k, n_group, topk_group,
                    scale, normalise=True):
-    """The held experts' part of the layer and the shared expert's.
-
-    lp: 'router' [E_all, H], 'router_bias' [E_all], 'experts' {'gate',
-    'up' [count, H, F], 'down' [count, F, H]}, 'shared' {'gate', 'up',
-    'down'}; h [T, H] in the compute dtype; row_ok [T] bool.
+    """The whole layer of a family whose router is ``route``: lp 'router'
+    [E_all, H], 'router_bias' [E_all] beside what ``held_experts`` takes.
     -> (y [T, H], counts [5] i32 in the order of ``COUNTS``)."""
-    cdt = h.dtype
-    t = h.shape[0]
     with jax.named_scope('router'):
         chosen, w = route(h, lp['router'], lp['router_bias'], top_k=top_k,
                           n_group=n_group, topk_group=topk_group,
                           scale=scale, normalise=normalise)
+    return held_experts(lp, h, row_ok, chosen, w, held=held)
+
+
+def held_experts(lp, h, row_ok, chosen, w, *, held, at=None):
+    """The held experts' part of the layer for a router's answer, and the
+    shared expert's where there is one.
+
+    lp: 'experts' {'gate', 'up' [count, H, F], 'down' [count, F, H]} and,
+    optionally, 'shared' {'gate', 'up', 'down'}; h [T, H] in the compute
+    dtype; row_ok [T] bool; chosen [T, k] i32 and w [T, k] f32 from the
+    family's router. ``at``: the experts' leaves are a STACK of layers',
+    ``[layers * count, ...]``, and this layer's lie from ``at * count`` on
+    (a stack scanned over its layers hands the kernel the whole stack and
+    an offset, never a layer's slice: that would be a copy of it).
+    -> (y [T, H], counts [5] i32 in the order of ``COUNTS``)."""
+    cdt = h.dtype
+    t, top_k = chosen.shape
     tm = tile_rows(t * top_k)
     with jax.named_scope('dispatch'):
         pl_ = plan(chosen, row_ok, held, tm)
         rows = jnp.take(h, pl_['src'], axis=0)                   # [M, H]
     with jax.named_scope('experts'):
+        tile_expert = pl_['tile_expert']
+        if at is not None:
+            tile_expert = tile_expert + jnp.int32(held[1]) * at
         gmm = lambda x, wt: expert_grouped_matmul(
-            x, wt.astype(cdt), pl_['tile_expert'], pl_['n_tiles'], tm=tm)
+            x, wt.astype(cdt), tile_expert, pl_['n_tiles'], tm=tm)
         ex = lp['experts']
         act = (jax.nn.silu(gmm(rows, ex['gate']).astype(jnp.float32))
                * gmm(rows, ex['up']).astype(jnp.float32)).astype(cdt)
         out = gmm(act, ex['down'])                               # [M, H]
-    with jax.named_scope('shared'):
-        y = swiglu(lp['shared'], h, cdt)
+    y = None
+    if 'shared' in lp:
+        with jax.named_scope('shared'):
+            y = swiglu(lp['shared'], h, cdt)
     with jax.named_scope('combine'):
         m = out.shape[0]
         picked = jnp.take(out, jnp.minimum(pl_['dest'], m - 1), axis=0)
         w_held = jnp.where(pl_['is_held'], w, 0.0).astype(cdt)   # [T, k]
-        y = y + jnp.einsum('tk,tkh->th', w_held, picked,
-                           preferred_element_type=jnp.float32).astype(cdt)
+        routed = jnp.einsum('tk,tkh->th', w_held, picked,
+                            preferred_element_type=jnp.float32).astype(cdt)
+        y = routed if y is None else y + routed
     sizes = pl_['group_sizes']
     counts = jnp.stack([
         jnp.sum(row_ok.astype(jnp.int32)) * top_k,

@@ -128,6 +128,7 @@ class GenerationFuture:
     def __init__(self, want_logits=False):
         self._cv = threading.Condition()
         self._tokens = []
+        # a token's (logits row as computed, the family's note on the row)
         self._logits = [] if want_logits else None
         self._done = False
         self._exc = None
@@ -209,12 +210,29 @@ class GenerationFuture:
         """One float32 ``[vocab]`` row per token emitted so far, aligned
         with the tokens: the logits each token was chosen from, as the
         engine's executable computed them (before temperature and top-k).
-        Only for a request submitted with ``want_logits=True``."""
+        Only for a request submitted with ``want_logits=True``. The engine
+        keeps a row in the type its executable computed it in; it is
+        widened here, once, on the caller's thread and not the
+        scheduler's."""
         with self._cv:
             if self._logits is None:
                 raise ValueError('this request did not ask for logits: '
                                  'submit(..., want_logits=True)')
-            return list(self._logits)
+            for i, (row, note) in enumerate(self._logits):
+                if row.dtype != np.float32:
+                    self._logits[i] = (row.astype(np.float32), note)
+            return [row for row, _ in self._logits]
+
+    def row_notes(self):
+        """What the family said of each emitted token's row beside its
+        logits (``forward_with_cache``'s 'row_notes': one float32 vector a
+        row), aligned with the tokens; None a row for a family that says
+        nothing. Only for a request submitted with ``want_logits=True``."""
+        with self._cv:
+            if self._logits is None:
+                raise ValueError('this request did not ask for logits: '
+                                 'submit(..., want_logits=True)')
+            return [note for _, note in self._logits]
 
     def stream(self, timeout=None):
         """Generator of tokens in emission order; returns at EOS/limit,
@@ -300,7 +318,35 @@ class _Step:
                                         # step's input, never read
         self.out = None                 # the tokens with a family's counts
                                         # behind them: the one host read
-        self.lg = None                  # [slots, vocab] logits
+        self.lg = None                  # ``_kept_rows``: [slots, vocab] logits
+
+
+ASK_ROWS = 8    # logits rows a gather hands the host at a time
+
+
+@jax.jit
+def _take_rows(lg, idx):
+    """Rows ``idx`` of a step's logits ``[slots, vocab]`` (and of a
+    family's notes on them, where ``lg`` is the pair), on the device: a
+    step's rows are read by the host only for the slots whose request asked
+    (``want_logits``), and a row is the whole vocabulary wide."""
+    return jax.tree_util.tree_map(lambda a: jnp.take(a, idx, axis=0), lg)
+
+
+def _kept_rows(logits, cache):
+    """What a call leaves on the device for the rows whose request asked:
+    the logits, and beside them what the family notes of each row
+    (``cache['row_notes']`` [rows, n] float32) where it notes anything."""
+    notes = cache.get('row_notes')
+    return logits if notes is None else (logits, notes)
+
+
+def _host_rows(kept):
+    """``_kept_rows``' result read -> (logits [rows, vocab] in the type
+    they were computed in, notes [rows, n] or None)."""
+    if isinstance(kept, tuple):
+        return np.asarray(kept[0]), np.asarray(kept[1])
+    return np.asarray(kept), None
 
 
 def _with_counts(tokens, counts):
@@ -735,9 +781,10 @@ class GenerationEngine:
             tok = sample_rows(row, seed, pos0 + valid.astype(jnp.int32) - 1)
             # the logits the token was chosen from stay on the device in
             # the compute dtype; the host reads them, and widens them to
-            # float32, only for a request that asked (want_logits)
-            return (_with_counts(tok, cache.get('counts')), row,
-                    {k: cache[k] for k in pool})
+            # float32 when it is asked for them (``logits()``), only for a
+            # request that asked (want_logits)
+            return (_with_counts(tok, cache.get('counts')),
+                    _kept_rows(row, cache), {k: cache[k] for k in pool})
 
         def step(params, pool, prev, tok, fresh, pos, page_table, seeds):
             self._trace_count += 1
@@ -756,8 +803,9 @@ class GenerationEngine:
                 # it comes back in as ``prev``: placed as ``_no_prev`` is
                 nxt = jax.lax.with_sharding_constraint(
                     nxt, self._mesh_ctx.replicated())
-            return (_with_counts(nxt, cache.get('counts')), rows,
-                    {k: cache[k] for k in pool}, nxt)
+            return (_with_counts(nxt, cache.get('counts')),
+                    _kept_rows(rows, cache), {k: cache[k] for k in pool},
+                    nxt)
 
         # under a mesh the paged kernel shards over it (ops/mesh_kernel)
         from ..ops import mesh_kernel
@@ -817,6 +865,12 @@ class GenerationEngine:
         for e in self._manifest_entries():
             man.add(e)
         report = _warmup_mod.prebuild(man, generation=self)
+        # and the gather that hands the host the asking slots' logits rows
+        if 'gen_decode' in self._aot:
+            kept = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                self._aot['gen_decode'].out_info[1])
+            _take_rows(kept, jnp.zeros((ASK_ROWS,), jnp.int32))
         if self._prefix is not None:
             # pre-compile the COW copy executable too — a trash-page
             # self-copy is a no-op on real data, and without it the first
@@ -1156,8 +1210,10 @@ class GenerationEngine:
             if ahead is not None and not ahead.toks.is_ready():
                 ahead.toks.block_until_ready()
                 wall0 = time.perf_counter()
-            row = (np.asarray(lg)[0].astype(np.float32)
-                   if req.want_logits else None)
+            row = None
+            if req.want_logits:
+                row, note = _host_rows(lg)
+                row = (row[0], None if note is None else note[0])
             tok = np.asarray(tok)
             return int(tok[0]), row, pool, tok[1:], wall0
 
@@ -1207,9 +1263,10 @@ class GenerationEngine:
         (a prefill's token) for any other. A slot whose end the host knows
         (its count, the context) gets no row: its last step is in flight
         already. Then, outside the lock, it dispatches step N+1, reads
-        step N (tokens with a family's counts in one read; every slot's
-        logits only if a row's request asked), and under the lock again
-        emits a token a row, finishes and frees slots. A row whose slot is
+        step N (tokens with a family's counts in one read; the logits
+        rows of the slots whose request asked, gathered on the device),
+        and under the lock again emits a token a row, finishes and frees
+        slots. A row whose slot is
         no longer the one it was computed for (ended by EOS one step ago,
         evicted, shut down) is dropped: no token, no listener,
         ``rows_discarded``. On return ``self._inflight`` is step N+1, or
@@ -1229,6 +1286,10 @@ class GenerationEngine:
 
         def dev():
             fault.inject('gen.step')
+            # the asking slots' rows are gathered on the device BEFORE the
+            # successor is dispatched, so the gather runs right behind the
+            # step it reads and its read lies under the successor
+            asked = self._asked_rows(unread) if unread is not None else None
             if ahead is not None:
                 tok, fresh, pos, table, seeds = ahead.inputs
                 ahead.inputs = None
@@ -1242,9 +1303,15 @@ class GenerationEngine:
                 return None, None
             # ONE host readback per step for every slot (a family's
             # counts ride behind the tokens in it); the logits follow only
-            # when a request in a row asked for them
-            return (np.asarray(unread.out),
-                    np.asarray(unread.lg) if unread.want else None)
+            # for the rows whose request asked for them
+            out, rows = np.asarray(unread.out), {}
+            for part, at in asked or ():
+                lg, notes = _host_rows(part)
+                for j, i in enumerate(at):
+                    # copies: a view would keep all ASK_ROWS rows alive
+                    rows[i] = (lg[j].copy(),
+                               None if notes is None else notes[j].copy())
+            return out, rows
 
         try:
             with (_obs.span('gen.decode_step',
@@ -1277,14 +1344,32 @@ class GenerationEngine:
                 slot.ahead -= 1
                 slot.last_tok = t
                 slot.req.rec.note_decode(slot.pos - slot.ahead)
-                # astype copies: a view would keep every slot's rows alive
                 self._emit_locked(
-                    slot, t, rows[i].astype(np.float32)
-                    if slot.req.want_logits else None)
+                    slot, t, rows[i] if slot.req.want_logits else None)
                 if self._slot_finished(slot, t):
                     self._finish_slot_locked(i)
             self._update_gauges_locked()
             self._cv.notify_all()
+
+    def _asked_rows(self, step):
+        """The logits rows of ``step`` that the host is to read, still on
+        the device: [(rows [ASK_ROWS, vocab], the slots they are)], None
+        where no row's request asked. A row is the whole vocabulary wide
+        (a step's rows are 25 MB at 48 slots of 262,272 logits), so what
+        comes to the host is the asking slots' rows alone, ``ASK_ROWS`` at
+        a time through one small executable (``_take_rows``; a count that
+        followed the askers would compile in the middle of traffic)."""
+        if not step.want:
+            return None
+        at = [i for i, slot in enumerate(step.rows)
+              if slot is not None and slot.req.want_logits]
+        parts = []
+        for lo in range(0, len(at), ASK_ROWS):
+            some = at[lo:lo + ASK_ROWS]
+            idx = np.zeros((ASK_ROWS,), np.int32)
+            idx[:len(some)] = some
+            parts.append((_take_rows(step.lg, jnp.asarray(idx)), some))
+        return parts
 
     def _plan_step(self, unread):
         """The next decode step from what the host knows, with ``unread``

@@ -217,7 +217,19 @@ def _cases(devices):
         num_hidden_layers=5, first_k_dense_replace=1, vocab_size=16160,
         max_position_embeddings=2048, held=(0, 16))
 
+    # benchmark/configs/zaya1-8b-pp2-serve.json (PR 40): 20 layers of
+    # compressed convolutional attention and 16 experts, one a token, 48
+    # slots of 3,072 rows, the tails a slot beside K and V pages, the
+    # stack scanned with the router's state in the carry
+    from paddle_tpu.models import zaya
+    zaya1 = zaya.ZayaConfig(num_hidden_layers=20,
+                            max_position_embeddings=3072)
+    zaya_units = {'kv': 48 * 24 + 1, 'tail': 48}
+
     return {
+        'zaya_step': engine_program('step', zaya1, 48, zaya_units),
+        'zaya_prefill': engine_program('prefill', zaya1, 48, zaya_units,
+                                       width=1024),
         'latent_step': engine_program('step', dots, 64, 1025),
         'granite_step': engine_program('step', granite, 64, granite_units),
         'granite_prefill': engine_program('prefill', granite, 64,
@@ -299,7 +311,11 @@ _POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
          # granite-4.0-h-micro-serve: the state and the convolution's tail
          # a slot, K and V pages of two heads a row; whole and flat
          r'|36,64,128,32,128|2304,128,32,128|36,64,13056|2304,13056'
-         r'|4,1025,4,128,128|4100,4,128,128|1025,4,128,128)\]')
+         r'|4,1025,4,128,128|4100,4,128,128|1025,4,128,128'
+         # zaya1-8b-pp2-serve: K and V pages of two heads of 128, whole
+         # and flat (the tails a slot, 5 MB of all layers', are scanned:
+         # every slot's row is rewritten in every step anyway)
+         r'|20,1153,2,128,128|23060,2,128,128|1153,2,128,128)\]')
 
 
 def _pool_copies(text):
@@ -385,6 +401,12 @@ def _child():
                 'names': sorted(set(re.findall(
                     r'%((?:paged_attention|flash_fwd)(?:_window)?'
                     r'|ssm_state_update)[.\d]* = ', text))),
+                # what a program makes of a scanned stack's experts beside
+                # views of them
+                'expert_stacks_moved': sorted(set(re.findall(
+                    r'= \w+\[(?:320|20,16|16),2048,2048\]\S* '
+                    r'(?!parameter|bitcast|get-tuple-element)([\w\-]+)\(',
+                    text))),
                 # the entry parameter a step takes the previous step's
                 # tokens in, where that step left them on the device
                 'fed_back': re.findall(
@@ -590,9 +612,40 @@ def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
     assert compiled[case]['names'] == names
 
 
+@pytest.mark.parametrize('case,kernels,names', [
+    # a layer's body, compiled once: the paged call and the grouped
+    # product's three
+    ('zaya_step', 1 + 3, ['paged_attention']),
+    # the widest prefill (1,024 rows): a layer's flash forward and the same
+    ('zaya_prefill', 1 + 3, ['flash_fwd']),
+])
+def test_tails_beside_pages_engine_programs_leave_pool_and_experts_where_they_lie(
+        compiled, case, kernels, names):
+    """``zaya1-8b-pp2-serve``'s WHOLE decode step and prefill at the
+    published widths, 20 layers scanned with the router's state in the
+    carry: the K and V pages (3 GB) are carried through the scan and
+    written in place, and the experts' stacks (8 GB) reach the grouped
+    product as they lie: the kernel takes the whole stack and the layer as
+    an offset, so no operation's result is a layer's experts or all of
+    them (a layer's slice handed to a custom call is a copy of it: 400 MB
+    a layer a step)."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+    assert compiled[case]['names'] == names
+    assert compiled[case]['expert_stacks_moved'] == []
+
+
+def test_a_whole_step_of_twenty_layers_needs_no_temporary_worth_naming(
+        compiled):
+    """12.4 GB of arguments on a chip of 16: what a step and the widest
+    prefill allocate beside them stays under 0.1 GB."""
+    for case in ('zaya_step', 'zaya_prefill'):
+        assert compiled[case]['temp_bytes'] < 1e8, compiled[case]
+
+
 @pytest.mark.parametrize('case,slots,copies', [
     ('gpt_xl_step', 16, 0), ('moe_gpt_step', 16, 0), ('latent_step', 64, 0),
-    ('afmoe_step', 24, 0), ('granite_step', 64, 0),
+    ('afmoe_step', 24, 0), ('granite_step', 64, 0), ('zaya_step', 48, 0),
     # an int8 bank's two float32 scale planes are moved once a step, as
     # before (test_int8_kv_step_moves_only_its_scales)
     ('gpt_xl_step_int8_kv', 16, 4)])

@@ -64,6 +64,9 @@ def test_each_rows_argmax_is_the_token_at_temperature_zero(variant):
                 assert row.shape == (BASE['vocab_size'],)
                 assert row.dtype == np.float32
                 assert int(np.argmax(row)) == tok
+            # widened once, when first asked for; this family notes nothing
+            assert all(a is b for a, b in zip(rows, fut.logits()))
+            assert fut.row_notes() == [None] * NEW
         assert engine.stats()['traces'] == TRACES
     finally:
         engine.shutdown(drain=False)
@@ -300,3 +303,49 @@ def test_subscribe_is_public_replays_and_reports_the_finish():
         assert got[-1] == ('finish', None)
     finally:
         engine.shutdown(drain=False)
+
+
+@pytest.mark.parametrize('askers', [1, 5, 11], ids=lambda n: f'{n}_of_11')
+def test_a_step_hands_the_host_the_asking_slots_rows_alone(askers,
+                                                           monkeypatch):
+    """A row is the whole vocabulary wide (1 MB at 262,272 logits), so a
+    step's logits stay on the device and the host reads a gather of the
+    asking slots' rows, ``ASK_ROWS`` at a time through ONE executable built
+    by ``warmup()``: never the whole ``[slots, vocab]`` array, and the rows
+    and tokens are those of an engine that serves each request alone."""
+    from paddle_tpu.serving import generation
+    cfg = gpt.GPTConfig(**BASE)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [np.random.RandomState(s).randint(1, 97, size=4 + s % 9)
+               .astype(np.int32) for s in range(11)]
+    read = []
+    take = generation._take_rows
+    monkeypatch.setattr(generation, '_take_rows',
+                        lambda lg, idx: read.append(lg.shape) or take(lg, idx))
+    with GenerationEngine(params, cfg, num_slots=11, page_size=8,
+                          prefill_width=16, autostart=False) as eng:
+        eng.warmup()
+        built = take._cache_size()
+        del read[:]
+        futs = [eng.submit(p, max_new_tokens=NEW, want_logits=i < askers)
+                for i, p in enumerate(prompts)]
+        eng.start()
+        tokens = [f.result(timeout=300) for f in futs]
+        stats = eng.stats()
+    assert take._cache_size() == built          # no compile under traffic
+    assert set(read) == {(11, 97)}
+    # a gather for every ASK_ROWS askers of a step, none for the others
+    per_step = -(-askers // generation.ASK_ROWS)
+    assert per_step <= len(read) <= per_step * stats['steps']
+    for i, (p, fut) in enumerate(zip(prompts, futs)):
+        if i >= askers:
+            with pytest.raises(ValueError):
+                fut.logits()
+            continue
+        with GenerationEngine(params, cfg, num_slots=1, page_size=8,
+                              prefill_width=16) as alone:
+            one = alone.submit(p, max_new_tokens=NEW, want_logits=True)
+            assert one.result(timeout=300) == tokens[i]
+            np.testing.assert_allclose(np.stack(fut.logits()),
+                                       np.stack(one.logits()), atol=1e-5)
+        assert tokens[i] == [int(np.argmax(r)) for r in fut.logits()]
