@@ -34,16 +34,26 @@ Conventions shared by every consumer:
    out of it: the layers' loop carries the pool whole, viewed as
    ``[L * N_pages, ...]`` with layer ``l``'s page table offset by
    ``l * N_pages`` (models/gpt.paged_forward_with_cache), and a write
-   updates the carried buffer in place.
+   updates the carried buffer in place: a prefill's rows a page at a time
+   (``_write_pages``), a decode step's row by the tile it falls in
+   (``_row_write``, the Pallas call ``paged_row_write``).
  - int8-KV pools reuse the ``{'int8', 'scale'}`` bank layout of
    ops/weight_only (per-row scales), so the +32% int8 decode win composes.
 """
+import importlib
 import threading
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from . import mesh_kernel
 from .weight_only import init_kv_bank, is_weight_only, quantize_kv
+
+# the module, not the function ``ops/__init__`` rebinds the name to
+# (ops/paged_attention.py says why); ``_fa._INTERPRET`` stays late-bound
+_fa = importlib.import_module('paddle_tpu.ops.flash_attention')
 
 TRASH_PAGE = 0   # reserved; see module docstring
 
@@ -224,6 +234,139 @@ def _write_pages(plane, rows, page_table, pos, valid):
     return plane.at[phys].set(jnp.where(ok, fresh, old))
 
 
+# ---- a decode step's one row a sequence: the tile it falls in, in place ----
+
+_LANES = 128
+_TILE_BYTES = 32       # a sublane tile's rows x itemsize: 8 float32, 16 bf16
+# what a grid step of the row kernel holds in fast memory (a tile a
+# sequence, and the sequences' rows with both of the pipeline's buffers)
+# stays far inside what the compiler grants unasked
+_ROW_WRITE_VMEM = 6 << 20
+
+
+def _rows_a_step(b, h, d):
+    """Sequences a grid step of the row kernel takes: the most that divide
+    ``b`` and keep the step inside ``_ROW_WRITE_VMEM`` (a sequence holds a
+    tile, and its row padded to a tile in each of two buffers)."""
+    fits = [g for g in range(1, b + 1)
+            if b % g == 0 and 3 * g * h * _TILE_BYTES * d <= _ROW_WRITE_VMEM]
+    return max(fits) if fits else None
+
+
+def _row_write_available(plane, rows, valid):
+    """The row kernel's gate, from what the call shows: ONE row a sequence
+    (a decode step; a prefill's rows span whole pages, where a page at a
+    time is right), none of them padding, a head-major float plane whose
+    rows fill whole lanes and whose pages hold whole sublane tiles, a
+    sequence's tile inside the budget; on the chip (or interpreted:
+    ops/flash_attention.set_interpret)."""
+    if (valid is not None or plane.ndim != 4 or rows.ndim != 4
+            or rows.shape[1] != 1
+            or not jnp.issubdtype(plane.dtype, jnp.floating)):
+        return False
+    _, h, ps, d = plane.shape
+    return (ps % (_TILE_BYTES // plane.dtype.itemsize) == 0
+            and d % _LANES == 0
+            and _rows_a_step(rows.shape[0], h, d) is not None
+            and _fa._platform_ok())
+
+
+def _row_write(plane, rows, page_table, pos):
+    """One row a sequence into a head-major plane, where the plane lies.
+
+    ``plane``: [N, H, page_size, D]; ``rows``: [B, 1, H, D]. The call's
+    result is its operand's buffer, left in HBM: for each sequence the
+    kernel copies in the ONE sublane tile of the page its row falls in (16
+    rows of bf16: a packed tile takes no single-row DMA), lays the row over
+    its place and copies the tile back, every sequence's copy in flight at
+    once; nothing else of the pool moves. The page comes straight out of
+    the prefetched table, chosen as ``_write_pages`` chooses it: past the
+    table's end, the trash page.
+
+    The tiles a step writes are its sequences' own, except the trash
+    page's: idle slots and positions past the table's end all name page 0,
+    so several copies of one call may read and write the same tile of it
+    at once. That can only ever corrupt page 0, which holds garbage by
+    definition and which no table of a live sequence names
+    (``PageAllocator`` never hands it out).
+
+    (The form with a BlockSpec tile a grid step, the pipeline fetching and
+    writing back the aliased plane, passed every test of the call alone and
+    halted the chip inside the engine's step, ~340 steps into a run:
+    PERF.md section 6, PR 41.)"""
+    ps, d = (int(x) for x in plane.shape[2:])
+    b, p_max = (int(x) for x in page_table.shape)
+    r = _TILE_BYTES // plane.dtype.itemsize
+
+    def core(table, pos, rows, plane):
+        h = plane.shape[1]                    # this device's heads
+        g = _rows_a_step(b, h, d)
+
+        def kernel(table_ref, pos_ref, rows_ref, plane_ref, out_ref, tiles,
+                   sems):
+            del plane_ref           # the result is the same buffer
+            first = pl.program_id(0) * g
+
+            def tile(i, to_plane):
+                at = pos_ref[first + i]
+                logical = at // ps
+                page = jnp.where(
+                    logical < p_max,
+                    table_ref[(first + i) * p_max
+                              + jnp.minimum(logical, p_max - 1)], TRASH_PAGE)
+                there = out_ref.at[
+                    page, :, pl.ds(pl.multiple_of(at % ps // r * r, r), r), :]
+                src, dst = ((tiles.at[i], there) if to_plane
+                            else (there, tiles.at[i]))
+                return pltpu.make_async_copy(src, dst, sems.at[i])
+
+            def fetch(i, c):
+                tile(i, False).start()
+                return c
+            jax.lax.fori_loop(0, g, fetch, 0)
+            row_of = jax.lax.broadcasted_iota(jnp.int32, (h, r, d), 1)
+
+            def lay(i, c):
+                tile(i, False).wait()
+                tiles[i] = jnp.where(row_of == pos_ref[first + i] % r,
+                                     rows_ref[i], tiles[i])
+                tile(i, True).start()
+                return c
+            jax.lax.fori_loop(0, g, lay, 0)
+
+            def done(i, c):
+                tile(i, True).wait()
+                return c
+            jax.lax.fori_loop(0, g, done, 0)
+
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b // g,),
+                in_specs=[pl.BlockSpec((g, h, 1, d),
+                                       lambda j, *_: (j, 0, 0, 0)),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.VMEM((g, h, r, d), plane.dtype),
+                                pltpu.SemaphoreType.DMA((g,))]),
+            out_shape=jax.ShapeDtypeStruct(plane.shape, plane.dtype),
+            # the plane is operand 3 (the two prefetched arrays come
+            # first) and the result: updated where it lies
+            input_output_aliases={3: 0},
+            interpret=_fa._INTERPRET,
+            name='paged_row_write',
+        )(table, pos, rows, plane)
+
+    # heads over 'mp' as the pool lies; the sequences stay whole on every
+    # device (a plane is whole along 'dp': each copy takes every row)
+    heads = (None, 'heads', None, None)
+    return mesh_kernel.sharded_call(
+        core,
+        (page_table.astype(jnp.int32).reshape(-1), pos.astype(jnp.int32),
+         jnp.moveaxis(rows, 1, 2).astype(plane.dtype), plane),
+        (None, None, heads, heads), heads, batch=b, heads=(plane.shape[1],))
+
+
 def paged_write(pages, rows, page_table, pos, valid=None):
     """Write new KV rows into a page plane, in place where the caller's
     buffer allows it (a donated pool carried through the layers' loop).
@@ -237,6 +380,11 @@ def paged_write(pages, rows, page_table, pos, valid=None):
     (rows past it are padding and reach no page of a sequence). Returns
     the updated plane.
 
+    One algorithm, two forms, chosen by what the call shows
+    (``_row_write_available``): a decode step's ONE row a sequence lies in
+    one sublane tile of one page, and the row kernel moves that tile
+    alone; a prefill's rows span whole pages and go a page at a time.
+
     int8 banks quantize the incoming rows with the same per-row scheme as
     the dense int8 cache (ops/weight_only.quantize_kv), so paged int8
     decode matches dense int8 decode row-for-row."""
@@ -246,6 +394,8 @@ def paged_write(pages, rows, page_table, pos, valid=None):
                                      valid),
                 'scale': _write_pages(pages['scale'], scale, page_table,
                                       pos, valid)}
+    if _row_write_available(pages, rows, valid):
+        return _row_write(pages, rows, page_table, pos)
     if rows.ndim == 4:
         return _write_pages(pages, rows, page_table, pos, valid)
     # a plane without a heads axis (a latent cache's): a row is whole, one

@@ -48,6 +48,7 @@ def _cases(devices):
     from jax.sharding import SingleDeviceSharding
     fa = importlib.import_module('paddle_tpu.ops.flash_attention')
     pa = importlib.import_module('paddle_tpu.ops.paged_attention')
+    paged_kv = importlib.import_module('paddle_tpu.ops.paged_kv')
     mesh_kernel = importlib.import_module('paddle_tpu.ops.mesh_kernel')
     fa._platform_ok = lambda: True       # the kernels' TPU branch
     one = SingleDeviceSharding(devices[0])
@@ -56,11 +57,12 @@ def _cases(devices):
     def S(shape, dtype, sharding=one):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    def text(fn, *args, mesh=None):
+    def text(fn, *args, mesh=None, donate=()):
         def scoped(*a):
             with mesh_kernel.kernel_mesh(mesh):
                 return fn(*a)
-        return jax.jit(scoped).lower(*args).compile().as_text()
+        return jax.jit(scoped, donate_argnums=donate).lower(
+            *args).compile().as_text()
 
     def flash_fwd_bwd(dropout=0.0):
         def f(q, k, v):
@@ -106,6 +108,22 @@ def _cases(devices):
     heads = NamedSharding(mp4, P(None, None, 'mp', None))    # q's heads
     pool_heads = NamedSharding(mp4, P(None, 'mp', None, None))
     rep = NamedSharding(mp4, P())
+
+    # a decode step's row a slot into a plane as a served cell carries it
+    # (PR 41): alone, and under the engine's mesh, where the heads that
+    # split over 'mp' are split and the others stay whole on every chip
+    def row_write(pages, h, slots, p_max, mesh=None):
+        def at(*spec):
+            if mesh is None:
+                return one
+            return NamedSharding(
+                mesh, P(*spec) if h % mesh.shape['mp'] == 0 else P())
+        return lambda: text(
+            paged_kv.paged_write,
+            S((pages, h, 128, 128), bf16, at(None, 'mp', None, None)),
+            S((slots, 1, h, 128), bf16, at(None, None, 'mp', None)),
+            S((slots, p_max), jnp.int32, at()), S((slots,), jnp.int32, at()),
+            mesh=mesh, donate=(0,))     # the plane, as the engine's pool is
 
     # the engine's two WHOLE executables (PR 28), built as the engine
     # builds them (``GenerationEngine._build_fns``: sampling, the logits
@@ -226,7 +244,14 @@ def _cases(devices):
                             max_position_embeddings=3072)
     zaya_units = {'kv': 48 * 24 + 1, 'tail': 48}
 
+    # the four served cells' planes, flat over their layers: GPT-3 XL's,
+    # zaya's, trinity's full layer's, granite's (8 KV heads of 64, two a row)
+    rows = {'gpt_xl': (3096, 16, 16, 8), 'zaya': (23060, 2, 48, 24),
+            'trinity': (3073, 8, 24, 128), 'granite': (4100, 4, 64, 16)}
     return {
+        **{f'row_write_{k}': row_write(*v) for k, v in rows.items()},
+        **{f'row_write_{k}_mp4': row_write(*v, mesh=mp4)
+           for k, v in rows.items()},
         'zaya_step': engine_program('step', zaya1, 48, zaya_units),
         'zaya_prefill': engine_program('prefill', zaya1, 48, zaya_units,
                                        width=1024),
@@ -318,12 +343,19 @@ _POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
          r'|20,1153,2,128,128|23060,2,128,128|1153,2,128,128)\]')
 
 
+# a step's slots and the heads of a page of its planes
+_STEP_PAGES = {'gpt_xl_step': (16, 16), 'moe_gpt_step': (16, 8),
+               'zaya_step': (48, 2), 'afmoe_step': (24, 8),
+               'granite_step': (64, 4)}
+
+
 def _pool_copies(text):
     """The operations of a compiled program whose RESULT is pool-shaped
     and that move it: everything but the parameter, views of it (a
     bitcast, an element of the loop's state) and the update in place (a
     scatter or dynamic-update-slice, or the fusion whose computation ends
-    in one: the compiler aliases that fusion's result to its operand)."""
+    in one: the compiler aliases that fusion's result to its operand; or a
+    kernel whose result IS its operand's buffer, ``paged_row_write``)."""
     result = re.compile(r'(%\S+) = ' + _POOL + r'\S* ([\w\-]+)\(')
     views = ('parameter', 'bitcast', 'get-tuple-element')
     updates = ('scatter', 'dynamic-update-slice')
@@ -342,6 +374,8 @@ def _pool_copies(text):
             continue
         calls = re.search(r'calls=(%[\w.\-]+)', line)
         if m.group(3) == 'fusion' and calls and calls.group(1) in in_place:
+            continue
+        if m.group(3) == 'custom-call' and 'output_to_operand_aliasing' in line:
             continue
         moved.append(f'{m.group(3)} of {m.group(2)}')
     return moved
@@ -401,6 +435,16 @@ def _child():
                 'names': sorted(set(re.findall(
                     r'%((?:paged_attention|flash_fwd)(?:_window)?'
                     r'|ssm_state_update)[.\d]* = ', text))),
+                # the in-place row writes of a decode step (PR 41), and
+                # what the page form made around them: a page a slot read,
+                # rebuilt and scattered back, ``[slots, H, 128, 128]``, and
+                # a page-long buffer a slot, ``[slots, 128, H, 128]``
+                'row_writes': len(re.findall(
+                    r'%paged_row_write[.\d]* = ', text)),
+                'page_buffers': sorted(set(re.findall(
+                    r'= (bf16\[(?:{0},{1},128,128|{0},128,{1},128)\])'.format(
+                        *_STEP_PAGES[name]), text)))
+                if name in _STEP_PAGES else None,
                 # what a program makes of a scanned stack's experts beside
                 # views of them
                 'expert_stacks_moved': sorted(set(re.findall(
@@ -474,9 +518,11 @@ def test_kernel_compiles_for_v5e(compiled, case, kernels):
 
 
 @pytest.mark.parametrize('case,kernels', [
-    ('gpt_xl_step', 1),       # the paged kernel, once in the layers' loop
+    # the paged kernel and the K and V row writes before it (PR 41), once
+    # in the layers' loop
+    ('gpt_xl_step', 1 + 2),
     ('gpt_xl_prefill', 0),    # a tail prefill gathers; no kernel under it
-    ('moe_gpt_step', 1),
+    ('moe_gpt_step', 1 + 2),
 ])
 def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
     """The engine's WHOLE decode and prefill executables at
@@ -548,9 +594,10 @@ def test_int8_kv_step_moves_only_its_scales(compiled):
 
 
 @pytest.mark.parametrize('case,kernels,names', [
-    # a step: five paged calls (four window, one full) and the three
-    # grouped products of each of four routed layers
-    ('afmoe_step', 5 + 12, ['paged_attention', 'paged_attention_window']),
+    # a step: five paged calls (four window, one full), each behind its K
+    # and V row writes (PR 41), and the three grouped products of each of
+    # four routed layers
+    ('afmoe_step', 5 + 10 + 12, ['paged_attention', 'paged_attention_window']),
     # one prefill body (1,024 rows): five flash forwards and the products
     ('afmoe_prefill_1024', 5 + 12, ['flash_fwd', 'flash_fwd_window']),
 ])
@@ -591,8 +638,9 @@ def test_a_width_of_no_whole_pieces_keeps_its_temporaries_a_pieces(
 
 
 @pytest.mark.parametrize('case,kernels,names', [
-    # a period's body, compiled once: nine state updates and the paged call
-    ('granite_step', 9 + 1, ['paged_attention', 'ssm_state_update']),
+    # a period's body, compiled once: nine state updates, the paged call and
+    # the K and V row writes before it (PR 41)
+    ('granite_step', 9 + 1 + 2, ['paged_attention', 'ssm_state_update']),
     # the widest prefill (768 rows): a period's one flash forward
     ('granite_prefill', 1, ['flash_fwd']),
 ])
@@ -613,9 +661,9 @@ def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
 
 
 @pytest.mark.parametrize('case,kernels,names', [
-    # a layer's body, compiled once: the paged call and the grouped
-    # product's three
-    ('zaya_step', 1 + 3, ['paged_attention']),
+    # a layer's body, compiled once: the paged call behind its K and V row
+    # writes (PR 41) and the grouped product's three
+    ('zaya_step', 1 + 2 + 3, ['paged_attention']),
     # the widest prefill (1,024 rows): a layer's flash forward and the same
     ('zaya_prefill', 1 + 3, ['flash_fwd']),
 ])
@@ -658,6 +706,46 @@ def test_a_step_takes_the_previous_steps_tokens_where_they_lie(
     re-lays no pool: the step is the program it was."""
     assert compiled[case].get('fed_back') == [f's32[{slots}]'], compiled[case]
     assert compiled[case]['pool_copies'] <= copies, compiled[case]
+
+
+@pytest.mark.parametrize('mesh', ['', '_mp4'], ids=['one_chip', 'mp4'])
+@pytest.mark.parametrize('cell', ['gpt_xl', 'zaya', 'trinity', 'granite'])
+def test_row_write_compiles_for_v5e_at_the_served_cells_shapes(
+        compiled, cell, mesh):
+    """``paged_write`` of a decode step's rows at the four cells' planes
+    (16 heads x 16 slots, 2 x 48, 8 x 24, 4 x 64): ONE Mosaic call whose
+    result is the plane it was handed (an aliased call is no copy), nothing
+    beside it that is shaped like the plane. Under the engine's mesh the
+    call is wrapped (``mesh_kernel.sharded_call``; bare, the partitioner
+    refuses it): heads that split over four chips are split, two heads stay
+    whole on each, and no collective moves a page."""
+    case = compiled[f'row_write_{cell}{mesh}']
+    assert _summary(case) == {'kernels': 1, 'pool_copies': 0,
+                              'collectives': []}, case
+    assert case['row_writes'] == 1, case
+
+
+@pytest.mark.parametrize('case,row_writes', [
+    ('gpt_xl_step', 2), ('moe_gpt_step', 2), ('zaya_step', 2),
+    ('granite_step', 2),
+    ('afmoe_step', 10),         # five layers, unrolled
+    # what the row kernel does not take: an int8 bank, a headless plane
+    ('gpt_xl_step_int8_kv', 0), ('latent_step', 0),
+    # and a prefill's rows, a page at a time as before
+    ('gpt_xl_prefill', 0), ('zaya_prefill', 0), ('granite_prefill', 0),
+    ('afmoe_prefill_1024', 0)])
+def test_a_step_writes_its_rows_in_place_and_builds_no_page_around_them(
+        compiled, case, row_writes):
+    """Every served family with head-major float pages writes a decode
+    step's K and V rows through ``paged_row_write``, chosen by
+    ``paged_write`` from the call's shapes, and NOTHING in its step is
+    shaped like a page a slot any more: before PR 41 a step read the page
+    each row fell in (``[slots, H, 128, 128]``: 8 MB at GPT-3 XL), built a
+    page-long zero buffer around the row (``[slots, 128, H, 128]``), masked,
+    selected and scattered the page back: nine operations a layer, 15-25 %
+    of a served cell's busy time (PERF.md section 6, PR 41)."""
+    assert compiled[case]['row_writes'] == row_writes, compiled[case]
+    assert not compiled[case]['page_buffers'], compiled[case]
 
 
 @pytest.mark.parametrize('case,name', [

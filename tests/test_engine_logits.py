@@ -349,3 +349,53 @@ def test_a_step_hands_the_host_the_asking_slots_rows_alone(askers,
             np.testing.assert_allclose(np.stack(fut.logits()),
                                        np.stack(one.logits()), atol=1e-5)
         assert tokens[i] == [int(np.argmax(r)) for r in fut.logits()]
+
+
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_a_decode_steps_row_written_in_place_serves_the_page_forms_bits(
+        dtype, monkeypatch):
+    """A decode step's K and V rows go through the row kernel
+    (``ops/paged_kv._row_write``: heads of 128, pages of whole sublane
+    tiles, interpreted here), a prefill's through the page form: over a
+    prefill and 40 decode steps, which cross two page boundaries, tokens
+    and kept logits are those of the same engine with the row kernel's
+    gate shut."""
+    import importlib
+    from paddle_tpu.ops import paged_kv
+    fa = importlib.import_module('paddle_tpu.ops.flash_attention')
+    cfg = gpt.GPTConfig(**dict(BASE, hidden_size=256, dtype=dtype))
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+
+    def serve():
+        engine = GenerationEngine(params, cfg, num_slots=3, page_size=16,
+                                  prefill_width=32)
+        try:
+            futs = [engine.submit(p, max_new_tokens=40, seed=i,
+                                  want_logits=True)
+                    for i, p in enumerate(PROMPTS[:2])]
+            return ([f.result(timeout=300) for f in futs],
+                    [np.stack(f.logits()) for f in futs])
+        finally:
+            engine.shutdown(drain=False)
+
+    row_writes = []
+    real = paged_kv._row_write
+
+    def counted(plane, rows, *rest):
+        row_writes.append(rows.shape)
+        return real(plane, rows, *rest)
+    monkeypatch.setattr(paged_kv, '_row_write', counted)
+    fa.set_interpret(True)
+    try:
+        tokens, rows = serve()
+        # K and V in the scanned layers' one body, in the step's one trace
+        assert row_writes == [(3, 1, 2, 128)] * 2
+        monkeypatch.setattr(paged_kv, '_row_write_available',
+                            lambda *a: False)
+        tokens_by_page, rows_by_page = serve()
+    finally:
+        fa.set_interpret(False)
+    assert len(row_writes) == 2
+    assert tokens == tokens_by_page and len(tokens[0]) == 40
+    for a, b in zip(rows, rows_by_page):
+        assert a.shape == (40, BASE['vocab_size']) and np.array_equal(a, b)
