@@ -39,6 +39,15 @@ sequences are: ``[B]`` int32 (a prefill serves one slot, the step all of
 them). A prefill starts from row 0 and overwrites the slot's row: what the
 last occupant left there is never read.
 
+A family may name per-slot kinds ONLY (a stack with no attention layer at
+all: ``models/brumby.py``). The engine then holds no page, no allocator and
+no table: ``init_pool`` is handed ``{kind: num_slots}`` and nothing else,
+``forward_with_cache`` a ``page_table`` of ``{kind: [B] slots}`` alone,
+admission needs a free slot and a prompt within ``prefill_width``, the
+context is bounded by ``max_seq_len`` positions, ``page_size`` is the
+granule of ``prefill_widths`` and nothing else, and a prefix cache is
+refused as for any family that prefills from row 0.
+
 The engine never asks what kind of model it serves: it looks the family up
 by the config's class (``family_of``).
 
@@ -53,6 +62,9 @@ do in its decode step.
 """
 import dataclasses
 import typing
+
+import jax
+import jax.numpy as jnp
 
 from ..ops.paged_kv import POOL_LOGICAL_AXES
 
@@ -101,6 +113,25 @@ def prefill_widths(prefill_width, page_size, pages=1):
              for w in range(lo, hi, MAX_BODY_STEP)]
     return tuple(w for w in steps
                  if w % page == 0 and top <= 8 * w and w < top) + (top,)
+
+
+def stack_layers(n, layer_of):
+    """``layer_of(l)`` -> layer ``l``'s leaves, ``n`` layers -> the stack as
+    a scan over layers takes it: every leaf ``[n, ...]``. A layer is made,
+    written into the stacks where they lie and dropped before the next: two
+    copies of the whole never stand side by side."""
+    put = jax.jit(lambda stack, leaf, l: jax.lax.dynamic_update_index_in_dim(
+        stack, leaf.astype(stack.dtype), l, 0), donate_argnums=0)
+    stacks = None
+    for l in range(n):
+        lp = layer_of(l)
+        if stacks is None:
+            stacks = jax.tree_util.tree_map(
+                lambda a: jnp.zeros((n,) + a.shape, a.dtype), lp)
+        stacks = jax.tree_util.tree_map(
+            lambda s, a: put(s, a, jnp.int32(l)), stacks, lp)
+        del lp
+    return stacks
 
 
 @dataclasses.dataclass(frozen=True)

@@ -213,19 +213,8 @@ def stack_layers(config, layer_of):
     is made, written into the stacks where they lie and dropped before the
     next: two copies of the whole never stand side by side (a layer is 0.4
     GB at the published sizes, the stack 8.3 GB)."""
-    n = config.num_hidden_layers
-    put = jax.jit(lambda stack, leaf, l: jax.lax.dynamic_update_index_in_dim(
-        stack, leaf.astype(stack.dtype), l, 0), donate_argnums=0)
-    stacks = None
-    for l in range(n):
-        lp = _pack(layer_of(l))
-        if stacks is None:
-            stacks = jax.tree_util.tree_map(
-                lambda a: jnp.zeros((n,) + a.shape, a.dtype), lp)
-        stacks = jax.tree_util.tree_map(
-            lambda s, a: put(s, a, jnp.int32(l)), stacks, lp)
-        del lp
-    return stacks
+    return _family.stack_layers(config.num_hidden_layers,
+                                lambda l: _pack(layer_of(l)))
 
 
 def init_params(config, key):

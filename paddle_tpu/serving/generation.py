@@ -46,7 +46,10 @@ kind that is a row a SLOT (``per_slot``: the recurrent state of layers
 that keep no rows) has no allocator and no table: its planes are made with
 one row a slot, a call is told which slots its sequences are, a prefill
 overwrites its slot's row, and ``stats()`` counts the busy slots' rows as
-state held beside the pages in use.
+state held beside the pages in use. A family of per-slot kinds ALONE (no
+layer keeps a row) is served with no page pool at all: no allocator, no
+table, ``num_pages`` 0; admission needs a free slot and nothing else, and
+a sequence's length is bounded by ``max_seq_len`` positions.
 Pages are allocated lazily at each page boundary; on exhaustion the
 most-recently-admitted active slot — possibly the requester itself — is
 evicted (pages freed, request requeued at the queue FRONT), so the oldest
@@ -290,7 +293,9 @@ class _Slot:
         self.last_tok = 0               # the last token the host has read
         self.produced = 0               # tokens read and emitted
         self.tables = tables            # kind -> np [p_max] i32, 0 = none
-        self.table = next(iter(tables.values()))    # the first kind's
+        # the first kind's (what a prefix cache publishes); None with no
+        # paged kind
+        self.table = next(iter(tables.values()), None)
         self.first = first              # kind -> first page still held
         self.admit_seq = admit_seq
         self.start = start              # first prompt row prefill computes
@@ -493,15 +498,14 @@ class GenerationEngine:
         # table's width, or what a window spans. A kind that is a row a
         # slot (recurrent state) has no pages: it is kept apart, and
         # everything below that allocates, tables or releases walks the
-        # paged kinds alone
+        # paged kinds alone. A family may name per-slot kinds ONLY: the
+        # engine then holds no page, no allocator and no table, admits by
+        # free slots, and ``page_size`` is the granule of ``prefill_widths``
+        # and nothing else
         kinds = (family.page_kinds(cfg) if family.page_kinds
                  else _family.ONE_KIND)
         self._kinds = tuple(k for k in kinds if not k.per_slot)
         self._slot_kinds = tuple(k for k in kinds if k.per_slot)
-        if not self._kinds:
-            raise ValueError(
-                f'the {family.name} family names no paged kind: an engine '
-                f'of per-slot state alone is not served yet')
         self._held_max = {
             k.name: (self.p_max if k.window is None else min(
                 self.p_max, _pa.window_pages(k.window, ps)))
@@ -544,7 +548,9 @@ class GenerationEngine:
             self._unit_bytes[k.name] for k in self._slot_kinds)
         self._allocs = {name: _pkv.PageAllocator(n)
                         for name, n in self._num_pages.items()}
-        self._alloc = self._allocs[self._kinds[0].name]
+        # the first paged kind's (what a prefix cache indexes): None for a
+        # family of per-slot state alone, which is refused one below
+        self._alloc = next(iter(self._allocs.values()), None)
         # prefix cache: opt-in (constructor flag, giving it a residency
         # bound, or the env knob) — page accounting changes when finished
         # sequences stay resident, so it is never silently enabled
@@ -1114,20 +1120,19 @@ class GenerationEngine:
             self._queue.popleft()
             tables = {k.name: np.zeros((self.p_max,), np.int32)
                       for k in self._kinds}
-            # the first kind's pages are laid below, beside what a prefix
-            # cache shared (which only a family of one kind has)
-            table, pages = (x[self._kinds[0].name] for x in (tables, got))
-            for k in self._kinds[1:]:
-                tables[k.name][first[k.name]:need] = got[k.name]
-            n_shared = len(shared)
-            table[:n_shared] = shared
             cow = None
-            if cow_src is not None:
-                cow = (cow_src, pages[0])
-                table[n_shared] = pages[0]
-                pages = pages[1:]
-            if pages:
-                table[need - len(pages):need] = pages
+            for name, pages in got.items():
+                table = tables[name]
+                # what a prefix cache shared lies before the fresh pages
+                # (only a family of one kind has a cache: ``shared`` is
+                # empty and ``cow_src`` None for every other)
+                table[:len(shared)] = shared
+                if cow_src is not None:
+                    cow = (cow_src, pages[0])
+                    table[len(shared)] = pages[0]
+                    pages = pages[1:]
+                if pages:
+                    table[need - len(pages):need] = pages
             waited_ms = max(0.0, (now - req.enqueue_t) * 1e3)
             self._h['queue_wait'].observe(waited_ms)
             req.rec.note('admit', slot=free_idx, pages=need,

@@ -244,6 +244,14 @@ def _cases(devices):
                             max_position_embeddings=3072)
     zaya_units = {'kv': 48 * 24 + 1, 'tail': 48}
 
+    # benchmark/configs/brumby-14b-pp5-serve.json (PR 42): 8 layers of power
+    # retention, 16 slots whose matrix state (4.36 GB) is a row a slot, no
+    # page pool at all
+    from paddle_tpu.models import brumby
+    brumby14 = brumby.BrumbyConfig(num_hidden_layers=8,
+                                   max_position_embeddings=3072)
+    brumby_units = {'state': 16}
+
     # the four served cells' planes, flat over their layers: GPT-3 XL's,
     # zaya's, trinity's full layer's, granite's (8 KV heads of 64, two a row)
     rows = {'gpt_xl': (3096, 16, 16, 8), 'zaya': (23060, 2, 48, 24),
@@ -256,6 +264,12 @@ def _cases(devices):
         'zaya_prefill': engine_program('prefill', zaya1, 48, zaya_units,
                                        width=1024),
         'latent_step': engine_program('step', dots, 64, 1025),
+        'brumby_step': engine_program('step', brumby14, 16, brumby_units),
+        'brumby_prefill': engine_program('prefill', brumby14, 16,
+                                         brumby_units, width=1024),
+        # a prompt of two chunks (no cell's engine is that wide yet)
+        'brumby_prefill_2048': engine_program('prefill', brumby14, 16,
+                                              brumby_units, width=2048),
         'granite_step': engine_program('step', granite, 64, granite_units),
         'granite_prefill': engine_program('prefill', granite, 64,
                                           granite_units, width=768),
@@ -340,7 +354,11 @@ _POOL = (r'(bf16|s8|f32)\[(?:5,1025,128,\d+|24,129,16,128(?:,128)?'
          # zaya1-8b-pp2-serve: K and V pages of two heads of 128, whole
          # and flat (the tails a slot, 5 MB of all layers', are scanned:
          # every slot's row is rewritten in every step anyway)
-         r'|20,1153,2,128,128|23060,2,128,128|1153,2,128,128)\]')
+         r'|20,1153,2,128,128|23060,2,128,128|1153,2,128,128'
+         # brumby-14b-pp5-serve: the matrix state a slot, whole and flat
+         # over its layers (4.40 GB; the normaliser's plane beside it is
+         # 34 MB, and the compiler takes that one through fast memory)
+         r'|8,16,8,128,8320|128,8,128,8320)\]')
 
 
 # a step's slots and the heads of a page of its planes
@@ -434,7 +452,8 @@ def _child():
                                            text)),
                 'names': sorted(set(re.findall(
                     r'%((?:paged_attention|flash_fwd)(?:_window)?'
-                    r'|ssm_state_update)[.\d]* = ', text))),
+                    r'|ssm_state_update|retention_state_update)[.\d]* = ',
+                    text))),
                 # the in-place row writes of a decode step (PR 41), and
                 # what the page form made around them: a page a slot read,
                 # rebuilt and scattered back, ``[slots, H, 128, 128]``, and
@@ -658,6 +677,33 @@ def test_state_beside_pages_engine_programs_leave_their_pools_where_they_lie(
     assert _summary(compiled[case]) == {
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
     assert compiled[case]['names'] == names
+
+
+@pytest.mark.parametrize('case,kernels,names,temp', [
+    # the scanned layer's body, compiled once: the one state update
+    ('brumby_step', 1, ['retention_state_update'], 1.5e9),
+    # the widest prefill (1,024 rows): ONE chunk, the attention form and the
+    # state it leaves, all plain products (1.13 GB of temporaries)
+    ('brumby_prefill', 0, [], 1.5e9),
+    # two chunks: the second reads the state the first left, a query head
+    # of every group at a time (2.09 GB: 14.9 GB in all)
+    ('brumby_prefill_2048', 0, [], 2.5e9),
+])
+def test_state_alone_engine_programs_never_copy_the_state(
+        compiled, case, kernels, names, temp):
+    """``brumby-14b-pp5-serve``'s WHOLE decode step and a prefill at the
+    published widths, 8 layers, 16 slots and NO page pool: the matrix state
+    (4.40 GB beside 8.40 GB of weights: one copy and the cell does not
+    fit) is carried through the scan over layers and updated in place by
+    the kernel whose results are its operands' buffers; a prefill's layers
+    read no pool and leave what they made for ONE write after the scan."""
+    assert _summary(compiled[case]) == {
+        'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
+    assert compiled[case]['names'] == names
+    # all of a call's temporaries beside 12.8 GB of arguments (a chunk's
+    # scores and phi(k) and the eight layers' states it leaves among them)
+    # keep it inside the chip's 16 GB
+    assert compiled[case]['temp_bytes'] < temp
 
 
 @pytest.mark.parametrize('case,kernels,names', [
