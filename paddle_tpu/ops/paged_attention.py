@@ -4,23 +4,29 @@ The continuous-batching engine (serving/generation.py) stores each slot's
 KV rows in non-contiguous fixed-size pages (ops/paged_kv.py). This module
 attends q rows to that paged cache two ways:
 
- - a Pallas TPU kernel (``_paged_decode_kernel``): grid (slots, blocks of
-   KV heads, P_max) with the flattened page table + per-slot positions
-   riding scalar prefetch, so each grid step DMAs exactly the page the
-   table points at — the kernel never materializes the gathered cache,
-   and its wrapper never re-lays the pool: a page is stored head-major
-   (ops/paged_kv.py), so every head of it is ONE contiguous block,
-   ``[H_kv, page_size, D]``, which a step takes whole (``decode_plan``
-   says how many heads where that is too much for the fast memory). The
-   query heads of a KV group, and a tail call's T rows, are stacked as
-   rows against their group's K block, padded to one sublane tile (16
-   rows in bf16), so a grouped model reads a page once. A step past the
-   pages a slot holds (``ceil((pos + T) / page_size)``; one for an idle
-   slot) names the slot's last page again: the pipeline fetches no block
-   twice in a row, so such a step moves no byte and computes nothing —
-   it costs the step itself, ~0.25 us, 128 a call at 16 slots x 8 pages.
+ - a Pallas TPU kernel (``_paged_decode_kernel``): grid (blocks of KV
+   heads, the (slot, page) pairs that hold a key this call attends), with
+   the flattened page table, the per-slot positions and that list of
+   pairs (``page_schedule``) riding scalar prefetch, so each grid step
+   DMAs exactly the page the table points at — the kernel never
+   materializes the gathered cache, and its wrapper never re-lays the
+   pool: a page is stored head-major (ops/paged_kv.py), so every head of
+   it is ONE contiguous block, ``[H_kv, page_size, D]``, which a step
+   takes whole (``decode_plan`` says how many heads where that is too much
+   for the fast memory). The query heads of a KV group, and a tail call's
+   T rows, are stacked as rows against their group's K block, padded to
+   one sublane tile (16 rows in bf16), so a grouped model reads a page
+   once. The grid walks the pages slots HOLD
+   (``ceil((pos + T) / page_size)``; one for an idle slot, the trash
+   page), not the pages they may hold: its bound is the list's length, a
+   scalar read on the device, so one executable serves every depth and a
+   call whose slots are all full walks ``slots x P_max`` steps, the dense
+   grid. (Until PR 43 the grid WAS ``slots x P_max``, and a step past a
+   slot's pages fetched and computed nothing but cost ~0.27 us: two
+   thirds of a call at 48 slots that hold 7 of their 24 pages.)
    Online-softmax state (acc/m/l, a heads axis in front) lives in VMEM
-   scratch and persists across the sequential page dimension, the
+   scratch and persists across the sequential step dimension: zeroed on a
+   slot's first page, written out on its last, the
    "Ragged Paged Attention" structure (PAPERS.md arxiv 2604.15464) but
    for who fetches: that paper's kernel copies its own pages out of HBM,
    which Mosaic here refuses for D 64 (PERF.md section 6, PR 30). An
@@ -39,9 +45,9 @@ virtual positions <= pos[b] + j. Inference only (no vjp).
 With a ``window`` q row j attends only the last ``window`` of those
 positions (``pos[b] + j - window < key``): the same kernel body walks from
 the first page that holds such a key (``_pages_first``) and not from page
-0, its grid is as deep as a window spans pages
-(``window_pages(window + T - 1, page_size)``) and not as the table is
-wide, and the rows of the first page that lie before the window are
+0, a slot takes at most as many steps as a window spans pages
+(``window_pages(window + T - 1, page_size)``) however wide the table,
+and the rows of the first page that lie before the window are
 masked. Pages before the first are never named, so a slot may have given
 them back (serving/generation.py frees what leaves the window); the call
 is named ``paged_attention_window``, so a trace tells it from the full
@@ -156,24 +162,75 @@ def _pages_first(pos, ps, window):
     return jnp.maximum(pos - jnp.int32(window - 1), 0) // jnp.int32(ps)
 
 
+def _pages_walked(pos, t, ps, p_max, window):
+    """-> (first, held): pages ``first .. held - 1`` of a slot hold the
+    keys its q rows attend, in a window or not; at least one."""
+    held = _pages_held(pos, t, ps, p_max)
+    if window is None:
+        return jnp.zeros_like(held), held
+    return jnp.minimum(_pages_first(pos, ps, window), held - 1), held
+
+
 def window_pages(rows, page_size):
     """The most pages ``rows`` consecutive rows span, wherever they start:
-    what a slot holds of a window layer, and the depth of that layer's
-    grid."""
+    what a slot holds of a window layer, and the most steps a slot takes of
+    that layer's grid."""
     return (int(rows) + int(page_size) - 2) // int(page_size) + 1
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
-                         ps, t, p_max, depth, window=None):
-    """Grid (slots, blocks of KV heads, pages: P_max of them, or as many
-    as a window spans, counted from the slot's first); the page dim is
-    sequential
-    so the online-softmax scratch carries across the pages of one slot. A
-    step holds ``[heads, ps, D]`` of K and of V — every head of the page
-    where the plan allows, one contiguous piece of the pool — and the q
-    rows of a KV group stacked against their group's K block. Steps past
-    the pages a slot holds name its last page again, which the pipeline
-    does not fetch twice, and compute nothing.
+def page_schedule(pos, t, ps, p_max, window=None):
+    """The grid's page axis for q rows at ``pos [B]``..+t-1: the (slot,
+    page) pairs that hold a key they attend, slot after slot and each
+    slot's pages in order, from its first (``_pages_first``; 0 without a
+    window) to the last it holds (``_pages_held``; an idle slot's one, the
+    trash page). -> ``step_slot``, ``step_page`` (the slot's OWN page
+    number: the table's column) of the static length a call of full slots
+    walks, and ``total``, how many of them this call walks. Entries past
+    ``total`` are zeros: no step that runs reads them, the pipeline's
+    look-ahead past the grid's end does (slot 0's first page, a page that
+    exists). From the positions alone: every layer of a step shares one
+    schedule."""
+    pos = jnp.asarray(pos, jnp.int32).reshape(-1)
+    depth = p_max if window is None else min(
+        p_max, window_pages(window + t - 1, ps))
+    first, held = _pages_walked(pos, t, ps, p_max, window)
+    count = held - first
+    slots = jnp.arange(pos.shape[0], dtype=jnp.int32)
+    # a running sum as a compare-and-reduce over [B, B]: one fusion, where
+    # ``cumsum`` is a copy, a reduce-window and a reduce in every layer
+    ends = jnp.sum(jnp.where(slots <= slots[:, None], count, 0), axis=1)
+    starts = ends - count
+    s = jnp.arange(pos.shape[0] * depth, dtype=jnp.int32)[:, None]
+    mine = (starts <= s) & (s < ends)                  # [B * depth, B]
+    step_slot = jnp.sum(jnp.where(mine, slots, 0), axis=1)
+    step_page = jnp.sum(jnp.where(mine, first + s - starts, 0), axis=1)
+    return step_slot, step_page, ends[-1]
+
+
+def schedule_index_maps(steps, p_max):
+    """The two pieces every block's index map is made of, over the scalar
+    prefetch ``(pt, pos, step_slot, step_page)``: -> ``slot_of(s, *pre)``,
+    the slot of grid step ``s``, and ``page_of(s, *pre)``, the id of its
+    page out of the flat table. Mosaic's pipeline names steps PAST the
+    grid's end too (it looks ahead of the last step it runs), so the step
+    is clamped to the schedule's last entry (``steps`` of them): a word
+    read behind the arrays is a wild page id, and the chip halts on it
+    (PERF.md section 6, PR 43)."""
+    at = lambda s: jnp.minimum(s, steps - 1)
+    slot_of = lambda s, pt, pos, slot, pg: slot[at(s)]
+    page_of = lambda s, pt, pos, slot, pg: pt[slot[at(s)] * p_max + pg[at(s)]]
+    return slot_of, page_of
+
+
+def _paged_decode_kernel(pt_ref, pos_ref, slot_ref, page_ref, q_ref, k_ref,
+                         v_ref, *refs, scale, ps, t, p_max, window=None):
+    """Grid (blocks of KV heads, the steps of ``page_schedule``); the step
+    dim is sequential, a slot's pages in order, so the online-softmax
+    scratch carries across the pages of one slot: zeroed on its first
+    page, written out on its last. A step holds ``[heads, ps, D]`` of K
+    and of V — every head of the page where the plan allows, one
+    contiguous piece of the pool — and the q rows of a KV group stacked
+    against their group's K block.
 
     refs: for int8 pages the page's scales ``[H_kv, ps]`` of K and of V
     (k scale on score columns, v scale folded into probability rows, as
@@ -181,60 +238,58 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs, scale,
     acc / m / l."""
     scales, (o_ref, acc_ref, m_ref, l_ref) = refs[:-4], refs[-4:]
     heads, rows = q_ref.shape[1:3]
-    i, j, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    pos = pos_ref[i]
-    held = _pages_held(pos, t, ps, p_max)
-    page = p if window is None else _pages_first(pos, ps, window) + p
+    j, step = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[slot_ref[step]]
+    page = page_ref[step]
+    first, held = _pages_walked(pos, t, ps, p_max, window)
 
-    @pl.when(p == 0)
+    @pl.when(page == first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(page < held)
-    def _compute():
-        # row r of a KV head is query head r // t of its group, q row r % t
-        k_pos = page * jnp.int32(ps) + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, ps), 1)
-        q_pos = pos
-        if t > 1:
-            q_pos = pos + jax.lax.rem(jax.lax.broadcasted_iota(
-                jnp.int32, (rows, ps), 0), jnp.int32(t))
-        visible = k_pos <= q_pos
-        if window is not None:
-            visible = visible & (k_pos > q_pos - jnp.int32(window))
-        for hd in range(heads):
-            q = q_ref[0, hd]                               # [rows, D] native
-            kblk, vblk = k_ref[0, hd], v_ref[0, hd]        # [ps, D]
-            if scales:
-                row = pl.ds(j * heads + hd, 1)
-                kblk, vblk = kblk.astype(q.dtype), vblk.astype(q.dtype)
-            s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32
-                                    ) * _np.float32(scale)        # [rows, ps]
-            if scales:
-                s = s * scales[0][0, row, :]               # [1, ps] f32
-            s = jnp.where(visible, s, _NEG_INF)
-            # m, l and alpha stay as they are stored, one value a row in
-            # every lane: taking lane 0 and spreading it again costs a
-            # lane permute each, and those were most of a head's time
-            m_prev, l_prev = m_ref[hd], l_ref[hd]          # [rows, 128]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            pr = jnp.exp(s - _fa._lanes(m_new, ps))
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-            if scales:
-                pr = pr * scales[1][0, row, :]
-            acc_ref[hd] = (acc_ref[hd] * _fa._lanes(alpha, acc_ref.shape[2])
-                           + jax.lax.dot_general(
-                               pr.astype(vblk.dtype), vblk,
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32))
-            m_ref[hd] = m_new
-            l_ref[hd] = l_new
+    # row r of a KV head is query head r // t of its group, q row r % t
+    k_pos = page * jnp.int32(ps) + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, ps), 1)
+    q_pos = pos
+    if t > 1:
+        q_pos = pos + jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, ps), 0), jnp.int32(t))
+    visible = k_pos <= q_pos
+    if window is not None:
+        visible = visible & (k_pos > q_pos - jnp.int32(window))
+    for hd in range(heads):
+        q = q_ref[0, hd]                               # [rows, D] native
+        kblk, vblk = k_ref[0, hd], v_ref[0, hd]        # [ps, D]
+        if scales:
+            row = pl.ds(j * heads + hd, 1)
+            kblk, vblk = kblk.astype(q.dtype), vblk.astype(q.dtype)
+        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32
+                                ) * _np.float32(scale)        # [rows, ps]
+        if scales:
+            s = s * scales[0][0, row, :]               # [1, ps] f32
+        s = jnp.where(visible, s, _NEG_INF)
+        # m, l and alpha stay as they are stored, one value a row in
+        # every lane: taking lane 0 and spreading it again costs a
+        # lane permute each, and those were most of a head's time
+        m_prev, l_prev = m_ref[hd], l_ref[hd]          # [rows, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pr = jnp.exp(s - _fa._lanes(m_new, ps))
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+        if scales:
+            pr = pr * scales[1][0, row, :]
+        acc_ref[hd] = (acc_ref[hd] * _fa._lanes(alpha, acc_ref.shape[2])
+                       + jax.lax.dot_general(
+                           pr.astype(vblk.dtype), vblk,
+                           (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32))
+        m_ref[hd] = m_new
+        l_ref[hd] = l_new
 
-    @pl.when(p == depth - 1)
+    @pl.when(page == held - 1)
     def _emit():
         for hd in range(heads):
             o_ref[0, hd] = (acc_ref[hd] / _fa._lanes(
@@ -252,8 +307,6 @@ def _kernel_call(q, page_table, pos, pools, window=None):
     b, t, h, d = q.shape
     h_kv, ps = (int(x) for x in pools[0].shape[1:3])
     p_max = int(page_table.shape[1])
-    depth = p_max if window is None else min(
-        p_max, window_pages(window + t - 1, ps))
 
     def core(q, page_table, pos, *pools):
         b, _, h, _ = q.shape                  # this device's slots / heads
@@ -271,21 +324,17 @@ def _kernel_call(q, page_table, pos, pools, window=None):
 
         # nothing is re-laid: the heads of a page are one block of the pool
         # as it is stored, and a bank's scales come a page at a time, every
-        # head's. The page id comes straight out of the prefetched table,
-        # counted from the slot's first page (0 without a window), and
-        # past the pages a slot holds it stays the last one's
-        def page_id(i, p, pt, pos):
-            if window is not None:
-                p = _pages_first(pos[i], ps, window) + p
-            return pt[i * p_max
-                      + jnp.minimum(p, _pages_held(pos[i], t, ps, p_max) - 1)]
-        page = lambda i, j, p, pt, pos: (page_id(i, p, pt, pos), j, 0, 0)
-        scales = lambda i, j, p, pt, pos: (page_id(i, p, pt, pos), 0, 0)
+        # head's. The grid is as long as the pages this device's slots
+        # hold; a step's page id comes straight out of the prefetched table
+        step_slot, step_page, total = page_schedule(pos, t, ps, p_max, window)
+        slot_of, page_of = schedule_index_maps(step_slot.shape[0], p_max)
+        page = lambda j, s, *pre: (page_of(s, *pre), j, 0, 0)
+        scales = lambda j, s, *pre: (page_of(s, *pre), 0, 0)
         block = pl.BlockSpec((1, heads, rows, d),
-                             lambda i, j, p, *_: (i, j, 0, 0))
+                             lambda j, s, *pre: (slot_of(s, *pre), j, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h_kv // heads, depth),
+            num_scalar_prefetch=4,
+            grid=(h_kv // heads, total),
             in_specs=[block] + [
                 pl.BlockSpec((1, heads) + x.shape[2:], page) if x.ndim == 4
                 else pl.BlockSpec((1,) + x.shape[1:], scales) for x in pools],
@@ -298,14 +347,13 @@ def _kernel_call(q, page_table, pos, pools, window=None):
         )
         out = pl.pallas_call(
             functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(d),
-                              ps=ps, t=t, p_max=p_max, depth=depth,
-                              window=window),
+                              ps=ps, t=t, p_max=p_max, window=window),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
             interpret=_fa._INTERPRET,
             name='paged_attention' if window is None
             else 'paged_attention_window',
-        )(page_table.reshape(-1), pos, qt, *pools)
+        )(page_table.reshape(-1), pos, step_slot, step_page, qt, *pools)
         return out[:, :, :(h // h_kv) * t].reshape(b, h, t, d).transpose(
             0, 2, 1, 3)
 

@@ -8,10 +8,12 @@ and a V a head. Two paths read it:
    query is carried into the latent space (``q~ = q_nope W_kvb^K``), so a
    slot's heads are the rows of ONE matrix operand ``[H, rank + rope]``
    against a page ``[page_size, rank + rope]``; the value is the row's first
-   ``rank`` columns. A Pallas kernel over grid (slots, P_max) with the page
-   table and the positions in scalar prefetch: the block index of a page
-   past the slot's last is clamped to the last, so it is neither fetched nor
-   computed. The pool is handed over whole (``[L * N, page_size, W]``, a
+   ``rank`` columns. A Pallas kernel whose grid is the (slot, page) pairs
+   that hold a row this call attends (``paged_attention.page_schedule``:
+   the pages slots hold, not the ``slots x P_max`` they may hold; the
+   bound is the list's length, read on the device), with the page table,
+   the positions and that list in scalar prefetch. The pool is handed over
+   whole (``[L * N, page_size, W]``, a
    reshape) and the layer's offset is added to the table: no layer's plane
    is sliced out or copied.
  - ``latent_prefill_attention``: prefill over the fresh rows, in the
@@ -35,6 +37,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import mesh_kernel
+from .paged_attention import (_pages_walked, page_schedule,
+                              schedule_index_maps)
 
 # the submodule, not ops/__init__'s same-named function (paged_attention.py)
 _fa = importlib.import_module('paddle_tpu.ops.flash_attention')
@@ -54,43 +58,43 @@ def paged_latent_attention_available(q, pool):
             and q.dtype in (jnp.float32, jnp.bfloat16))
 
 
-def _latent_kernel(pt_ref, pos_ref, q_ref, page_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, scale, ps, p_max, rank):
-    """Grid (slots, P_max), pages in order: the online-softmax state of a
-    slot's heads is carried from page to page in scratch."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    pos = pos_ref[b]
+def _latent_kernel(pt_ref, pos_ref, slot_ref, page_ref, q_ref, pg_ref, o_ref,
+                   acc_ref, m_ref, l_ref, *, scale, ps, p_max, rank):
+    """Grid (the steps of ``page_schedule``), a slot's pages in order: the
+    online-softmax state of a slot's heads is carried from page to page in
+    scratch, zeroed on its first page and written out on its last."""
+    step = pl.program_id(0)
+    pos = pos_ref[slot_ref[step]]
+    p = page_ref[step]
+    first, held = _pages_walked(pos, 1, ps, p_max, None)
 
-    @pl.when(p == 0)
+    @pl.when(p == first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(p * jnp.int32(ps) <= pos)       # the page holds a row <= pos
-    def _compute():
-        q = q_ref[0]                                   # [H, W]
-        page = page_ref[0]                             # [ps, W]
-        s = jax.lax.dot_general(q, page, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32
-                                ) * _np.float32(scale)            # [H, ps]
-        k_pos = p * jnp.int32(ps) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos <= pos, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pr = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pr.astype(page.dtype), page[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                   # [H, rank]
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    q = q_ref[0]                                   # [H, W]
+    page = pg_ref[0]                               # [ps, W]
+    s = jax.lax.dot_general(q, page, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32
+                            ) * _np.float32(scale)            # [H, ps]
+    k_pos = p * jnp.int32(ps) + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
+    s = jnp.where(k_pos <= pos, s, _NEG_INF)
+    m_prev = m_ref[:, :1]
+    l_prev = l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    pr = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(pr, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        pr.astype(page.dtype), page[:, :rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [H, rank]
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(p == p_max - 1)
+    @pl.when(p == held - 1)
     def _emit():
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[:, :1], _EPS)).astype(o_ref.dtype)
@@ -105,16 +109,17 @@ def _latent_decode(q, pool, page_table, pos, layer, scale, rank):
 
     def core(q, table, pos, pages):
         b = q.shape[0]                        # this device's slots
-        # a page past the slot's last takes the last one's block index:
-        # the pipeline fetches nothing for an index that does not change
-        page = lambda i, p, pt, ps_: (
-            pt[i * p_max + jnp.minimum(p, ps_[i] // jnp.int32(ps))], 0, 0)
+        # the grid is as long as the pages this device's slots hold
+        step_slot, step_page, total = page_schedule(pos, 1, ps, p_max)
+        slot_of, page_of = schedule_index_maps(step_slot.shape[0], p_max)
+        page = lambda s, *pre: (page_of(s, *pre), 0, 0)
+        rows = lambda s, *pre: (slot_of(s, *pre), 0, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, p_max),
-            in_specs=[pl.BlockSpec((1, h, w), lambda i, p, *_: (i, 0, 0)),
+            num_scalar_prefetch=4,
+            grid=(total,),
+            in_specs=[pl.BlockSpec((1, h, w), rows),
                       pl.BlockSpec((1, ps, w), page)],
-            out_specs=pl.BlockSpec((1, h, rank), lambda i, p, *_: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, h, rank), rows),
             scratch_shapes=[
                 pltpu.VMEM((h, rank), jnp.float32),       # acc
                 pltpu.VMEM((h, _LANES), jnp.float32),     # m (lane-bcast)
@@ -128,7 +133,7 @@ def _latent_decode(q, pool, page_table, pos, layer, scale, rank):
             out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
             interpret=_fa._INTERPRET,
             name='paged_latent_attention',
-        )(table.reshape(-1), pos, q, pages)
+        )(table.reshape(-1), pos, step_slot, step_page, q, pages)
 
     # slots over 'dp'; the pool has no heads axis to split, so every device
     # of 'mp' holds it whole and runs all heads

@@ -401,7 +401,11 @@ class GenerationEngine:
     ``steps_overlapped`` (steps dispatched while their forerunner was
     unread) beside ``steps``, and ``rows_discarded`` (rows computed for a
     slot that had ended by EOS or been evicted before they were read; such
-    a row is no token and reaches no future).
+    a row is no token and reaches no future), and, for a family with
+    pages, ``paged_steps_walked`` beside ``paged_steps_dense``: the grid
+    steps a full layer's paged decode kernel walks for the steps planned
+    (the pages the slots hold, one for an idle slot) and the steps of a
+    grid over every slot's whole table.
 
     ``precision=None`` (or ``'float32'``: "not the int8 snapshot") holds
     the family's product operands (``family.serve_params``: for ``gpt`` the
@@ -586,6 +590,7 @@ class GenerationEngine:
                                   'prefill_rows_asked',
                                   'prefill_rows_computed', 'steps',
                                   'steps_overlapped', 'rows_discarded',
+                                  'paged_steps_walked', 'paged_steps_dense',
                                   'prefix_hits', 'prefix_misses',
                                   'prefix_full_hits', 'prefix_tokens_saved',
                                   'prefix_evictions')}
@@ -1412,6 +1417,13 @@ class GenerationEngine:
                     rids.append(slot.req.rec.rid)
         if all(r is None for r in rows):
             return None
+        if self._kinds:
+            # the grid steps a full layer's paged kernel walks for these
+            # rows (ops/paged_attention.page_schedule: the pages a slot
+            # holds, an idle slot's one), and a grid of every slot's table
+            self._n['paged_steps_walked'] += int(np.minimum(
+                pos // self.page_size + 1, self.p_max).sum())
+            self._n['paged_steps_dense'] += s * self.p_max
         table = self._tables(s, tables.__getitem__,
                              slots=np.arange(s, dtype=np.int32))
         return _Step(rows, want, rids, overlapped=unread is not None,

@@ -241,18 +241,32 @@ def test_windowed_paged_kernel_matches_brute_force(interpret, positions,
                                np.asarray(want)[live], atol=2e-6, rtol=0)
 
 
-def test_the_window_call_walks_a_window_of_pages_not_the_table(interpret):
-    """Its grid is as deep as a window spans pages, whatever the table's
-    width, and the call carries a name of its own."""
+@pytest.mark.parametrize('window,walked,longest', [
+    # slot 0 at row 1,000: pages 5, 6, 7 hold its last 300 rows, pages
+    # 0..7 all of them; slot 1 at row 5 holds one page either way
+    (300, [(0, 5), (0, 6), (0, 7), (1, 0)], 2 * 4),
+    (None, [(0, p) for p in range(8)] + [(1, 0)], 2 * 16),
+])
+def test_the_window_call_walks_a_window_of_pages_not_the_table(
+        interpret, window, walked, longest):
+    """A window call walks at most ``window_pages`` a slot, from the first
+    page that holds a key of the window, a full call what the slot holds;
+    neither walks the table's width. The grid's bound is the schedule's
+    length, read on the device, and the window call carries a name of its
+    own."""
     assert pa.window_pages(4096, 128) == 33 and pa.window_pages(8, 4) == 3
     assert pa.window_pages(300, 128) == 4 and pa.window_pages(256, 128) == 3
     q, k, v, table, pos, _ = _paged_case([1000, 5], 300, p_max=16)
+    slot, page, total = pa.page_schedule(pos, 1, 128, 16, window)
+    assert slot.shape == page.shape == (longest,)
+    assert int(total) == len(walked)
+    assert list(zip(np.asarray(slot)[:int(total)].tolist(),
+                    np.asarray(page)[:int(total)].tolist())) == walked
     text = str(jax.make_jaxpr(lambda *a: pa.paged_flash_decode(
-        *a, window=300))(q, k, v, table, pos))
-    assert 'name=paged_attention_window' in text and '(2, 1, 4)' in text
-    text = str(jax.make_jaxpr(pa.paged_flash_decode)(q, k, v, table, pos))
-    assert 'name=paged_attention' in text and '(2, 1, 16)' in text
-    assert 'paged_attention_window' not in text
+        *a, window=window))(q, k, v, table, pos))
+    assert 'grid=(1, DynamicGridDim)' in text
+    assert ('name=paged_attention_window' in text) == (window is not None)
+    assert 'name=paged_attention' in text
 
 
 # ---- the pool's two kinds of plane -----------------------------------------

@@ -93,6 +93,12 @@ def _cases(devices):
         return lambda: text(kernel, q, pool(d, int8), pool(d, int8),
                             S((8, 8), jnp.int32), S((8,), jnp.int32))
 
+    def paged_at(slots, heads, kv_heads, p_max, pages):
+        planes = S((pages, kv_heads, 128, 128), bf16)
+        return lambda: text(
+            pa.paged_flash_decode, S((slots, 1, heads, 128), bf16), planes,
+            planes, S((slots, p_max), jnp.int32), S((slots,), jnp.int32))
+
     def decode(int8):
         q, cache = S((8, 1, 16, 64), bf16), (8, 1024, 16, 64)
         bank = ({'int8': S(cache, jnp.int8), 'scale': S(cache[:3],
@@ -325,6 +331,10 @@ def _cases(devices):
         # them (the q row of a score is its row modulo T), 128 rows a head
         'paged_gqa4_tail5': paged(128, False, t=5, heads=64),
         'paged_int8_tail128': paged(64, True, t=128),
+        # what PR 43 makes new: a grid whose bound is read on the device,
+        # at the shape it pays most: zaya1-8b-pp2-serve's 48 slots of 24
+        # pages, 8 query heads on 2 KV heads of 128, one layer's plane
+        'paged_zaya_48x24': paged_at(48, 8, 2, 24, 1153),
         'decode_bf16': decode(False),
         'decode_int8': decode(True),
         'flash_dropout_dp2_mp2': lambda: text(flash_fwd_bwd(0.1), qm, qm, qm,
@@ -397,6 +407,53 @@ def _pool_copies(text):
             continue
         moved.append(f'{m.group(3)} of {m.group(2)}')
     return moved
+
+
+def _schedule(text):
+    """Where the paged calls of a compiled program get their schedule
+    (``ops/paged_attention.page_schedule``: the grid's bound, operand 0 of
+    the call, and the steps' slots and pages, operands 3 and 4, behind the
+    table and the positions) -> ``{'calls': paged calls in the program,
+    'in_loop': whether one lies in a ``while`` body, 'ops': the distinct
+    instructions, views and copies between memories aside, between those
+    operands and their computation's parameters}``: what is computed in EVERY pass of the
+    body a call lies in. A schedule handed in through the loop's state
+    reads 0."""
+    bodies = set(re.findall(r'body=(%[\w.\-]+)', text))
+    calls, ops, in_loop, defs, computation = 0, set(), False, {}, None
+    pending = []
+    for line in text.splitlines():
+        head = re.match(r'(?:ENTRY )?(%\S+) \(.*\{$', line)
+        if head:
+            computation, defs, pending = head.group(1), {}, []
+            continue
+        if line.startswith('}'):
+            for operands in pending:
+                calls += 1
+                in_loop = in_loop or computation in bodies
+                todo = [operands[i] for i in (0, 3, 4)]
+                while todo:
+                    name = todo.pop()
+                    op, args = defs.get(name, ('parameter', []))
+                    if op in ('parameter', 'constant') or (
+                            computation, name) in ops:
+                        continue
+                    if op not in ('get-tuple-element', 'bitcast', 'copy',
+                                  'copy-start', 'copy-done'):
+                        ops.add((computation, name))
+                    todo += args
+            pending = []
+            continue
+        m = re.match(r'\s*(?:ROOT )?(%[\w.\-]+) = .*? ([a-z][\w\-]*)\(([^)]*)\)',
+                     line)
+        if not m:
+            continue
+        args = re.findall(r'%[\w.\-]+', m.group(3))
+        defs[m.group(1)] = (m.group(2), args)
+        if re.match(r'%paged_(latent_)?attention(_window)?[.\d]*$',
+                    m.group(1)) and m.group(2) == 'custom-call':
+            pending.append(args)
+    return {'calls': calls, 'in_loop': in_loop, 'ops': len(ops)}
 
 
 def _block_matrices(text):
@@ -475,6 +532,7 @@ def _child():
                 'fed_back': re.findall(
                     r'%prev[.\d]* = (s32\[\d+\])\S* parameter\(',
                     text[text.index('ENTRY '):]),
+                'schedule': _schedule(text),
                 'collectives': [c for c in (
                     'all-reduce', 'all-gather', 'all-to-all',
                     'collective-permute') if c in text]}
@@ -520,6 +578,7 @@ def _summary(case):
     ('paged_bf16_d64', 1), ('paged_int8_d64', 1),
     ('paged_bf16_d128', 1), ('paged_int8_d128', 1),
     ('paged_gqa4_tail5', 1), ('paged_int8_tail128', 1),
+    ('paged_zaya_48x24', 1),
     ('decode_bf16', 1), ('decode_int8', 1),
 ])
 def test_kernel_compiles_for_v5e(compiled, case, kernels):
@@ -752,6 +811,27 @@ def test_a_step_takes_the_previous_steps_tokens_where_they_lie(
     re-lays no pool: the step is the program it was."""
     assert compiled[case].get('fed_back') == [f's32[{slots}]'], compiled[case]
     assert compiled[case]['pool_copies'] <= copies, compiled[case]
+
+
+@pytest.mark.parametrize('case,calls,in_loop,ops', [
+    # a scanned stack: one call in the layers' ``while`` body
+    ('gpt_xl_step', 1, True, 5), ('zaya_step', 1, True, 5),
+    ('granite_step', 1, True, 5),
+    # layers unrolled: five calls share one schedule (trinity two: the
+    # window layers' and the full layer's, over one count of pages held)
+    ('latent_step', 5, False, 5), ('afmoe_step', 5, False, 9)])
+def test_where_a_steps_paged_calls_get_their_schedule(compiled, case, calls,
+                                                      in_loop, ops):
+    """``page_schedule`` hangs on the positions alone, so it is the same
+    in every layer of a step (PR 43, item 3). Where the layers are
+    unrolled the compiler keeps ONE: five small operations a step (the
+    pages held, their running sum, the starts, the steps' slots and pages,
+    the bound). Where they are a ``while`` it does NOT lift them out of the
+    body: the same five run in every layer, beside the call that reads
+    them (PERF.md section 6, PR 43, says what they cost on the chip). A
+    schedule handed in through the loop's state would read 0 here."""
+    assert compiled[case]['schedule'] == {
+        'calls': calls, 'in_loop': in_loop, 'ops': ops}, compiled[case]
 
 
 @pytest.mark.parametrize('mesh', ['', '_mp4'], ids=['one_chip', 'mp4'])

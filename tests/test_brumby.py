@@ -246,6 +246,15 @@ def test_a_request_admitted_while_others_decode_serves_what_it_serves_alone():
                                atol=1e-6, rtol=0)
 
 
+def test_an_engine_with_no_page_kind_counts_no_paged_step():
+    """``paged_steps_walked`` / ``paged_steps_dense`` (PR 43) count the
+    paged decode kernel's grid: this family calls no such kernel."""
+    shape = tiny_shape()
+    _, _, stats = _serve(shape, ENGINE, prompts_of((5, 12)), 6)
+    assert stats['steps'] > 0
+    assert stats['paged_steps_walked'] == stats['paged_steps_dense'] == 0
+
+
 @pytest.mark.parametrize('kw,lens', [
     (ENGINE, PROMPTS),
     (dict(num_slots=1, page_size=8, prefill_width=24), (9, 3, 17)),

@@ -199,13 +199,14 @@ def test_paged_vs_dense_parity_int8_kv(params):
 # Pallas paged-attention kernel (interpret mode)
 # ---------------------------------------------------------------------------
 
-def _kernel_setup(int8=False, seed=0):
+def _kernel_setup(int8=False, seed=0, pos=(130, 200)):
+    """Slots of two pages each at ``pos``; a slot at 0 is idle: its table
+    names the trash page alone."""
     rng = np.random.RandomState(seed)
-    b, t, h, d, ps, p_max = 2, 1, 2, 64, 128, 2
+    b, t, h, d, ps, p_max = len(pos), 1, 2, 64, 128, 2
     n = b * p_max + 1
     q = jnp.asarray(rng.randn(b, t, h, d), jnp.float32) * 0.3
-    pos = jnp.asarray([130, 200], jnp.int32)
-    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    table = jnp.arange(1, n, dtype=jnp.int32).reshape(b, p_max)
     kv = [jnp.asarray(rng.randn(b, 256, h, d), jnp.float32) * 0.3
           for _ in range(2)]
     pools = []
@@ -216,12 +217,20 @@ def _kernel_setup(int8=False, seed=0):
                     'scale': jnp.zeros((n, h, ps), jnp.float32)}
         pools.append(paged_kv.paged_write(pool, rows, table,
                                           jnp.zeros((b,), jnp.int32)))
+    pos = jnp.asarray(pos, jnp.int32)
+    table = jnp.where((pos > 0)[:, None], table, paged_kv.TRASH_PAGE)
     return q, pools[0], pools[1], table, pos
 
 
+@pytest.mark.parametrize('pos', [
+    (130, 200),
+    (200, 0, 130),          # an idle slot between two busy ones
+    (127, 128),             # a page's last row, the next page's first
+    (0, 128, 0, 127, 0),
+], ids=lambda pos: '_'.join(map(str, pos)))
 @pytest.mark.parametrize('int8', [False, True])
-def test_paged_kernel_interpret_parity(int8):
-    q, kp, vp, table, pos = _kernel_setup(int8=int8)
+def test_paged_kernel_interpret_parity(int8, pos):
+    q, kp, vp, table, pos = _kernel_setup(int8=int8, pos=pos)
     k_arr = kp['int8'] if int8 else kp
     fa.set_interpret(True)
     try:
@@ -242,8 +251,9 @@ def test_paged_kernel_interpret_parity(int8):
 # where it lies, for every head size; the pool comes whole (every layer's
 # pages) with the table offset to one layer's. What PR 30 changed: a grid
 # step takes every head of a page (fewer where ``decode_plan`` says they
-# do not fit), a KV group's query heads are rows against one K block, and
-# a step past the pages a slot holds names its last page again
+# do not fit) and a KV group's query heads are rows against one K block.
+# What PR 43 changed: the grid walks the pages slots hold, slot after slot
+# (``page_schedule``), and no step past them
 def _case(d, h, h_kv, t=1, layers=1, pos=(130, 200), p_max=2, budget=None):
     return dict(d=d, h=h, h_kv=h_kv, t=t, layers=layers, pos=pos,
                 p_max=p_max, budget=budget)
@@ -264,6 +274,15 @@ _KERNEL_SHAPES = {
     # a slot's rows end on a page's last row, or begin the next page
     'page_edges': _case(64, 2, 2, pos=(127, 128, 255)),
     'held_1_3_8_of_8': _case(128, 2, 1, pos=(100, 300, 1000), p_max=8),
+    # the schedule's seams: a slot's one step over the trash page between
+    # two slots' pages, at the grid's two ends, and nothing but such steps
+    'idle_between_busy': _case(128, 2, 2, pos=(300, 0, 130), p_max=4),
+    'idle_between_edges_gqa': _case(64, 4, 2, pos=(127, 0, 128, 0, 383),
+                                    p_max=3),
+    'idle_at_both_ends': _case(128, 2, 1, pos=(0, 0, 511, 0), p_max=4),
+    'all_idle': _case(128, 2, 2, pos=(0, 0, 0), p_max=4),
+    'tail_5_idle_between': _case(128, 4, 2, t=5, pos=(250, 0, 124),
+                                 p_max=3),
     'tail_4': _case(128, 2, 2, t=4),
     'tail_5_gqa': _case(64, 4, 2, t=5, pos=(130, 3)),
     'tail_16': _case(128, 2, 2, t=16, pos=(127, 240)),
@@ -320,6 +339,150 @@ def test_paged_kernel_reads_pages_where_they_lie(case, monkeypatch):
     tol = 2e-2 if int8 else 2e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize('window', [None, 200])
+def test_paged_kernel_under_a_mesh_walks_each_devices_own_slots(window):
+    """Slots split over 'dp', heads over 'mp': ``page_schedule`` is built
+    inside the per-device call from the device's own positions, so the two
+    halves of the batch walk grids of different lengths (3 + 1 steps and
+    1 + 2 without a window) and each slot still gets its own pages."""
+    from jax.sharding import Mesh
+    from paddle_tpu.ops import mesh_kernel
+    rng = np.random.RandomState(11)
+    b, h, d, ps, p_max = 4, 4, 128, 128, 3
+    pos = jnp.asarray([300, 0, 5, 250], jnp.int32)
+    n = 1 + b * p_max
+    table = np.arange(1, n, dtype=np.int32).reshape(b, p_max)
+    table[1] = paged_kv.TRASH_PAGE
+    table = jnp.asarray(table)
+    q = jnp.asarray(rng.randn(b, 1, h, d), jnp.float32) * 0.3
+    kp, vp = (jnp.asarray(rng.randn(n, h, ps, d), jnp.float32) * 0.3
+              for _ in range(2))
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ('dp', 'mp'))
+    call = mesh_kernel.jit(
+        lambda *a: pa.paged_flash_decode(*a, window=window), mesh)
+    fa.set_interpret(True)
+    try:
+        assert 'shard_map' in str(jax.make_jaxpr(call)(q, kp, vp, table, pos))
+        got = call(q, kp, vp, table, pos)
+    finally:
+        fa.set_interpret(False)
+    want = pa.paged_attention_fallback(q, kp, vp, table, pos, jnp.float32,
+                                       window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize('kernel', ['paged_attention',
+                                    'paged_attention_window',
+                                    'paged_latent_attention'])
+def test_every_index_map_clamps_the_step_to_the_schedules_last_entry(kernel):
+    """Mosaic's pipeline names steps PAST a dynamic grid's bound (it looks
+    ahead of the last step it runs), and an index map that read a word
+    behind ``step_slot`` / ``step_page`` halted the chip with every slot
+    at, or one page under, full depth (PERF.md section 6, PR 43). No run
+    off the chip sees that: interpret mode evaluates no step it does not
+    run. So the jaxpr is read: every block's index map takes the minimum
+    of the step and the schedule's last index before it reads anything."""
+    pla = importlib.import_module('paddle_tpu.ops.paged_latent_attention')
+    b, p_max, ps = 3, 4, 128
+    table = jnp.zeros((b, p_max), jnp.int32)
+    pos = jnp.zeros((b,), jnp.int32)
+    fa.set_interpret(True)
+    try:
+        if kernel == 'paged_latent_attention':
+            jaxpr = jax.make_jaxpr(lambda q, pool: pla.paged_latent_attention(
+                q, pool, table, pos, 0, scale=0.1, rank=128))(
+                    jnp.zeros((b, 8, 256)), jnp.zeros((1, 5, ps, 256)))
+            steps = b * p_max
+        else:
+            window = 200 if kernel.endswith('window') else None
+            jaxpr = jax.make_jaxpr(lambda q, k: pa.paged_flash_decode(
+                q, k, k, table, pos, window=window))(
+                    jnp.zeros((b, 1, 2, 128)), jnp.zeros((5, 2, ps, 128)))
+            steps = b * (pa.window_pages(200, ps) if window else p_max)
+    finally:
+        fa.set_interpret(False)
+    call, = [e for e in jaxpr.eqns if e.primitive.name == 'pallas_call']
+    assert call.params['name'] == kernel
+    maps = call.params['grid_mapping'].block_mappings
+    assert len(maps) >= 3
+    for m in maps:
+        mins = [e for e in m.index_map_jaxpr.jaxpr.eqns
+                if e.primitive.name == 'min']
+        assert mins, m
+        assert all(int(e.invars[1].val) == steps - 1 for e in mins), mins
+
+
+def _schedule_by_hand(pos, t, ps, p_max, window):
+    """(slot, page) pairs in the grid's order, from the module's words."""
+    steps = []
+    for i, p in enumerate(pos):
+        held = min(max(-(-(p + t) // ps), 1), p_max)
+        first = 0 if window is None else min(
+            max(p - window + 1, 0) // ps, held - 1)
+        steps += [(i, page) for page in range(first, held)]
+    return steps
+
+
+_SCHEDULES = {
+    'two_slots': dict(pos=(130, 200), p_max=2),
+    'idle_between_busy': dict(pos=(300, 0, 130), p_max=4),
+    'all_idle': dict(pos=(0, 0, 0, 0), p_max=8),
+    'page_edges': dict(pos=(127, 128, 255, 256), p_max=4),
+    'every_slot_full': dict(pos=(1023, 1023, 1023), p_max=8),
+    'past_the_table': dict(pos=(2000, 5), p_max=8),
+    'tail_rows_reach_a_page': dict(pos=(120, 0, 250), p_max=4, t=16),
+    'one_slot': dict(pos=(700,), p_max=8),
+    'zaya_48_slots': dict(
+        pos=tuple(int(x) for x in np.minimum(
+            200 + np.random.RandomState(3).randint(0, 2048, 48), 3071)),
+        p_max=24),
+    'window_first_page_not_0': dict(pos=(1000, 5, 427, 0), p_max=16,
+                                    window=300),
+    'window_of_whole_pages': dict(pos=(900, 1023, 511, 255), p_max=8,
+                                  window=256),
+    'window_wider_than_the_table': dict(pos=(500, 100), p_max=4,
+                                        window=4096),
+    'window_tail_rows': dict(pos=(500, 130, 700, 3), p_max=8, window=300,
+                             t=5),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_SCHEDULES))
+def test_page_schedule_lists_the_pages_slots_hold(case):
+    """The grid's page axis: ``total`` is the sum of what the slots hold,
+    every slot's pages come in order behind the slot's before it, an idle
+    slot takes its one step over the trash page, a window's first page is
+    the first that holds a key of it, and slots at full depth give the
+    dense grid, slot-major."""
+    c = {'t': 1, 'window': None, **_SCHEDULES[case]}
+    pos, t, p_max, window = c['pos'], c['t'], c['p_max'], c['window']
+    ps = 128
+    slot, page, total = jax.jit(
+        lambda pos: pa.page_schedule(pos, t, ps, p_max, window))(
+            jnp.asarray(pos, jnp.int32))
+    depth = p_max if window is None else min(
+        p_max, pa.window_pages(window + t - 1, ps))
+    assert slot.shape == page.shape == (len(pos) * depth,)
+    assert slot.dtype == page.dtype == jnp.int32
+    want = _schedule_by_hand(pos, t, ps, p_max, window)
+    total = int(total)
+    assert total == len(want) <= len(pos) * depth
+    got = list(zip(np.asarray(slot)[:total].tolist(),
+                   np.asarray(page)[:total].tolist()))
+    assert got == want
+    # past the steps a call walks nothing is named that is out of bounds
+    assert not np.asarray(slot)[total:].any()
+    assert not np.asarray(page)[total:].any()
+    if case == 'every_slot_full':
+        assert want == [(i, p) for i in range(len(pos))
+                        for p in range(p_max)]
+    if case == 'all_idle':
+        assert want == [(i, 0) for i in range(len(pos))]
+    if case == 'window_first_page_not_0':
+        assert want[:3] == [(0, 5), (0, 6), (0, 7)] and want[3] == (1, 0)
 
 
 # every shape a configuration or a test hands the kernel: head sizes 64 /
@@ -1007,6 +1170,35 @@ def test_steps_overlap_when_slots_are_full_and_not_for_a_lone_token(params):
             eng.submit(prompts[0], max_new_tokens=n_new).result(timeout=120)
             stats = eng.stats()
         assert (stats['steps'], stats['steps_overlapped']) == (steps, 0)
+
+
+@pytest.mark.parametrize('num_slots,lens', [(4, (11,)), (3, (5, 14)),
+                                            (1, (9,))])
+def test_stats_say_what_share_of_the_dense_grid_the_steps_walked(
+        params, num_slots, lens):
+    """``paged_steps_walked`` beside ``paged_steps_dense``: every decode
+    step counts the pages its busy slots hold at the row they write, one
+    for each idle slot, against ``num_slots * p_max``: what the paged
+    kernel's grid walks a full layer (ops/paged_attention.page_schedule)
+    and what it walked before PR 43."""
+    n_new = 12
+    prompts = _prompts(list(lens), seed=5)
+    eng = _engine(params, num_slots=num_slots, autostart=False)
+    futs = [eng.submit(p, max_new_tokens=n_new) for p in prompts]
+    with eng:
+        for f in futs:
+            f.result(timeout=300)
+        stats = eng.stats()
+    # all admitted before the first step: each of the n_new - 1 steps
+    # writes row len + k of every busy slot
+    steps = n_new - 1
+    walked = sum(min((n + k) // PS + 1, eng.p_max)
+                 for n in lens for k in range(steps))
+    walked += steps * (num_slots - len(lens))
+    assert stats['steps'] == steps
+    assert stats['paged_steps_walked'] == walked
+    assert stats['paged_steps_dense'] == steps * num_slots * eng.p_max
+    assert 0 < walked <= stats['paged_steps_dense']
 
 
 # ---------------------------------------------------------------------------

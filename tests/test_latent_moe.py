@@ -363,18 +363,32 @@ def test_no_row_is_dropped_when_every_row_goes_to_one_expert(t):
 
 # ---- the kernels, interpreted, against jax.numpy ---------------------------
 
+@pytest.mark.parametrize('pos', [
+    # one row past a page, a page's last row, deep in the third page, an
+    # idle slot (its table all trash, its one step over the trash page)
+    (200, 127, 300, 0),
+    (300, 0, 130),              # an idle slot between two busy ones
+    (127, 128, 0, 255, 256),    # rows on a page's edge
+    (383, 383),                 # every slot full: the dense grid
+    (0, 0, 0),                  # no slot busy
+], ids=lambda pos: '_'.join(map(str, pos)))
 @pytest.mark.parametrize('dtype,tol', [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 2e-2)])
 def test_paged_latent_kernel_matches_the_gathered_attention(interpret, dtype,
-                                                            tol):
+                                                            tol, pos):
     rng = np.random.RandomState(0)
-    layers, n, ps, w, rank, heads, slots, p_max = 2, 9, 128, 256, 128, 8, 4, 3
+    layers, ps, w, rank, heads, p_max = 2, 128, 256, 128, 8, 3
+    slots = len(pos)
+    n = 1 + slots * p_max
     pool = jnp.asarray(rng.randn(layers, n, ps, w), dtype)
     q = jnp.asarray(rng.randn(slots, heads, w), dtype)
-    table = jnp.asarray([[3, 1, 0], [2, 0, 0], [4, 5, 6], [0, 0, 0]],
-                        jnp.int32)
-    # one row, a page's last row, deep in the third page, an idle slot
-    pos = jnp.asarray([200, 127, 300, 0], jnp.int32)
+    # the pages a slot holds, in any order; the rest of its row is trash
+    free = iter(rng.permutation(np.arange(1, n)))
+    table = np.zeros((slots, p_max), np.int32)
+    for i, p in enumerate(pos):
+        if p:
+            table[i, :p // ps + 1] = [next(free) for _ in range(p // ps + 1)]
+    table, pos = jnp.asarray(table), jnp.asarray(pos, jnp.int32)
     assert pla.paged_latent_attention_available(q, pool)
     for layer in range(layers):
         got = pla.paged_latent_attention(q, pool, table, pos, layer,
