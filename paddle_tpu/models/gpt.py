@@ -293,19 +293,48 @@ def _mm(y, w, cdt, fp8_meta=None):
     return _fp8.fp8_matmul(y, w.astype(cdt), fp8_meta)
 
 
-def _block_qkv(bp, y, nh, hd, cdt, kvh=None, fp8_meta=None):
-    """Fused QKV projection shared by the train block and the KV-cache
-    decode block. Packing is per KV HEAD: [q_0..q_{g-1}|k|v] (g = query
+def _qkv_product(bp, y, cdt, fp8_meta=None):
+    """The fused QKV projection: ``[B, S, h] x [h, kvh * (g + 2) * hd]``,
+    bias added. Packing is per KV HEAD: [q_0..q_{g-1}|k|v] (g = query
     group size; g=1 is classic head-major MHA) — an 'mp' column shard is
     then exactly that rank's kv heads with their query groups (contiguous
     [Q|K|V] thirds would hand each rank a mix of Q and K columns)."""
-    B, S, _ = y.shape
-    kvh = nh if kvh is None else kvh
+    return _mm(y, bp['qkv_w'], cdt, fp8_meta) + bp['qkv_b'].astype(cdt)
+
+
+def _qkv_split(qkv, nh, hd, kvh):
+    """q ``[B, S, nh, hd]``, k and v ``[B, S, kvh, hd]`` out of the
+    product's columns (``_qkv_product``'s packing)."""
+    B, S, _ = qkv.shape
     g = nh // kvh
-    qkv = _mm(y, bp['qkv_w'], cdt, fp8_meta) + bp['qkv_b'].astype(cdt)
     qkv = qkv.reshape(B, S, kvh, g + 2, hd)
     q = qkv[..., :g, :].reshape(B, S, nh, hd)
     return q, qkv[..., g, :], qkv[..., g + 1, :]
+
+
+def _block_qkv(bp, y, nh, hd, cdt, kvh=None, fp8_meta=None):
+    """q, k, v of the train block: the product and the split with nothing
+    between them (the compiler may fold one into the other, and the
+    backward with them)."""
+    return _qkv_split(_qkv_product(bp, y, cdt, fp8_meta), nh, hd,
+                      nh if kvh is None else kvh)
+
+
+def _cached_qkv(bp, y, config, cdt):
+    """q, k, v of a KV-cache block (this module's and moe_gpt's), the head
+    split kept OUT of the product. Left to fold the split in, the TPU
+    compiler makes the product a convolution over the ``kvh x (g + 2)``
+    window, which wants its weight with the contraction axis minor: every
+    layer of every call it then slices its ``qkv_w`` out of the stack into
+    fast memory and transposes the slice, twice the matrix's own bandwidth
+    price for nothing (a quarter of a GPT-3 XL decode step's busy time,
+    PERF.md section 6, PR 44). Behind the barrier the product is a plain
+    ``[rows, h] x [h, n]`` one that reads its layer where the stack holds
+    it, as the other three block matrices' products do
+    (tests/test_aot_tpu_compile.py reads the compiled programs for it)."""
+    qkv = jax.lax.optimization_barrier(_qkv_product(bp, y, cdt))
+    return _qkv_split(qkv, config.num_heads, config.head_dim,
+                      config.kv_heads)
 
 
 def _block_mlp(bp, y, cdt, fp8_fc=None, fp8_out=None):
@@ -662,8 +691,7 @@ def _cached_block(bp, x, k_cache, v_cache, pos, config, page_table=None,
     with jax.named_scope('gpt.block'):      # as block_fn names its halves
         with jax.named_scope('attn'):
             y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
-            q, k, v = _block_qkv(bp, y, config.num_heads, config.head_dim,
-                                 cdt, config.kv_heads)
+            q, k, v = _cached_qkv(bp, y, config, cdt)
             x, k_cache, v_cache = cached_attention(
                 x, q, k, v, k_cache, v_cache, pos, bp['proj_w'],
                 bp['proj_b'], cdt, page_table=page_table, valid=valid,

@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..ops.weight_only import is_weight_only, wo_lm_head, wo_matmul, wo_take
 from ..parallel.moe import moe_ffn
 from . import family as _family
-from .gpt import (_layer_norm, _attention, _block_qkv, _mm,
+from .gpt import (_layer_norm, _attention, _block_qkv, _cached_qkv, _mm,
                   cached_attention, init_paged_kv_cache,
                   serve_params as _gpt_serve_params, validate_gqa)
 
@@ -284,10 +284,8 @@ def init_kv_cache(config: 'MoEConfig', batch):
 def _cached_block(bp, x, k_cache, v_cache, pos, config, page_table=None,
                   valid=None, tail=False):
     cdt = jnp.dtype(config.dtype)
-    B, T, h = x.shape
-    nh, hd = config.num_heads, config.head_dim
     y = _layer_norm(x, bp['ln1_g'], bp['ln1_b']).astype(cdt)
-    q, k, v = _block_qkv(bp, y, nh, hd, cdt, config.kv_heads)
+    q, k, v = _cached_qkv(bp, y, config, cdt)
     x, k_cache, v_cache = cached_attention(
         x, q, k, v, k_cache, v_cache, pos, bp['proj_w'], bp['proj_b'], cdt,
         page_table=page_table, valid=valid, tail=tail)

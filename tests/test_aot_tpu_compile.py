@@ -174,6 +174,21 @@ def _cases(devices):
             table(1), i32(1)).compile()
 
     from paddle_tpu.models import gpt, moe_gpt
+
+    def split_folded(program):
+        """``program`` with a cached block's q, k, v made as the train
+        block makes them, and as it made them before PR 44: the head
+        split left for the compiler to fold into the product."""
+        def compile_it():
+            kept = gpt._cached_qkv
+            gpt._cached_qkv = lambda bp, y, cfg, cdt: gpt._block_qkv(
+                bp, y, cfg.num_heads, cfg.head_dim, cdt, cfg.kv_heads)
+            try:
+                return program()
+            finally:
+                gpt._cached_qkv = kept
+        return compile_it
+
     # benchmark/configs/gpt-1.3b-serve.json: 24 layers, 129 pages of 128
     # rows, 16 heads of 128, 16 slots, bf16 over float32 weights
     xl = dict(vocab_size=50304, hidden_size=2048, num_layers=24,
@@ -300,6 +315,8 @@ def _cases(devices):
             'step', gpt.GPTConfig(kv_cache_int8=True, **xl), 16, 129),
         'gpt_xl_step_params_as_given': engine_program(
             'step', gpt.GPTConfig(**xl), 16, 129, as_given=True),
+        'gpt_xl_step_split_folded': split_folded(engine_program(
+            'step', gpt.GPTConfig(**xl), 16, 129)),
         'moe_gpt_step': engine_program('step', moe, 16, 129),
         'latent_decode_w640': latent(640),
         'latent_decode_w576': latent(576),
@@ -377,6 +394,10 @@ _STEP_PAGES = {'gpt_xl_step': (16, 16), 'moe_gpt_step': (16, 8),
                'granite_step': (64, 4)}
 
 
+# the line a computation of a compiled program's text opens with
+_COMPUTATION = re.compile(r'(?:ENTRY )?(%\S+) \(.*\{$')
+
+
 def _pool_copies(text):
     """The operations of a compiled program whose RESULT is pool-shaped
     and that move it: everything but the parameter, views of it (a
@@ -389,7 +410,7 @@ def _pool_copies(text):
     updates = ('scatter', 'dynamic-update-slice')
     in_place, computation = set(), None
     for line in text.splitlines():
-        head = re.match(r'(?:ENTRY )?(%\S+) \(.*\{$', line)
+        head = _COMPUTATION.match(line)
         if head:
             computation = head.group(1)
         m = result.search(line)
@@ -423,7 +444,7 @@ def _schedule(text):
     calls, ops, in_loop, defs, computation = 0, set(), False, {}, None
     pending = []
     for line in text.splitlines():
-        head = re.match(r'(?:ENTRY )?(%\S+) \(.*\{$', line)
+        head = _COMPUTATION.match(line)
         if head:
             computation, defs, pending = head.group(1), {}, []
             continue
@@ -458,11 +479,19 @@ def _schedule(text):
 
 def _block_matrices(text):
     """-> ({stacked block matrix: the dtype the program takes it in},
-    [the operations that re-make one]). A stacked matrix is an entry
-    parameter ``params['blocks'][<name ending in _w, w_in, w_out>]``; an
-    operation re-makes it if its result has the matrix's shape, in any
-    dtype, and is no view of it: before PR 32 a ``convert`` of each of the
-    float32 stacks to bfloat16, in every call."""
+    [the operations that re-make one], [those that re-make ONE LAYER of
+    one]). A stacked matrix is an entry parameter
+    ``params['blocks'][<name ending in _w, w_in, w_out>]``; an operation
+    re-makes it if its result has the matrix's shape, in any dtype, and is
+    no view of it: before PR 32 a ``convert`` of each of the float32 stacks
+    to bfloat16, in every call. It re-makes a layer if its result is
+    ``[1, <the layer's axes, the last two in either order>]`` in any
+    layout, is no view, and is an instruction of the entry or of a loop's
+    body: before PR 44 a slice of ``qkv_w`` into fast memory and a
+    transposing copy of it, in every layer of every call. A
+    ``dynamic-slice`` INSIDE a fused computation is the wanted form, the
+    product reading its layer where the stack holds it, and does not
+    count."""
     entry = text[text.index('ENTRY '):]
     taken = {}
     for name, dtype, dims in re.findall(
@@ -471,12 +500,31 @@ def _block_matrices(text):
         if name.endswith('_w') or name in ('w_in', 'w_out'):
             taken[name] = (dtype, dims)
     if not taken:       # a kernel's program: no model under it
-        return {}, []
-    shapes = '|'.join(sorted({re.escape(dims) for _, dims in taken.values()}))
-    remade = [f'{op} of {dtype}[{dims}]' for dtype, dims, op in re.findall(
-        r'= (\w+)\[(' + shapes + r')\]\S* ([\w\-]+)\(', text)
-        if op not in ('parameter', 'bitcast', 'get-tuple-element')]
-    return {k: dtype for k, (dtype, _) in taken.items()}, sorted(remade)
+        return {}, [], []
+    views = ('parameter', 'bitcast', 'get-tuple-element')
+
+    def results(shapes, lines):
+        made = re.compile(r'= (\w+)\[(' + '|'.join(sorted(map(
+            re.escape, shapes))) + r')\]\S* ([\w\-]+)\(')
+        return sorted(f'{op} of {dtype}[{dims}]' for line in lines
+                      for dtype, dims, op in made.findall(line)
+                      if op not in views)
+
+    layers = set()
+    for _, dims in taken.values():
+        *lead, a, b = dims.split(',')[1:]
+        layers |= {','.join(['1', *lead, a, b]), ','.join(['1', *lead, b, a])}
+    fused = set(re.findall(r' fusion\(.*?calls=(%[\w.\-]+)', text))
+    unfused, computation = [], None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+        elif computation not in fused:
+            unfused.append(line)
+    return ({k: dtype for k, (dtype, _) in taken.items()},
+            results({dims for _, dims in taken.values()}, text.splitlines()),
+            results(layers, unfused))
 
 
 def _child():
@@ -495,11 +543,12 @@ def _child():
             program = compile_text()
             text = program if isinstance(program, str) else program.as_text()
             moved = _pool_copies(text)
-            taken, remade = _block_matrices(text)
+            taken, remade, layers_remade = _block_matrices(text)
             out[name] = {
                 'temp_bytes': None if isinstance(program, str) else int(
                     program.memory_analysis().temp_size_in_bytes),
                 'block_matrices': taken, 'block_matrices_remade': remade,
+                'block_layers_remade': layers_remade,
                 'kernels': text.count('tpu_custom_call'),
                 'pool_copies': len(moved), 'moved': sorted(set(moved)),
                 # q as the kernel took it before PR 30: 16 slots x 16
@@ -613,6 +662,9 @@ def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
         'kernels': kernels, 'pool_copies': 0, 'collectives': []}, compiled[case]
 
 
+@pytest.mark.parametrize('remade', ['block_matrices_remade',
+                                    'block_layers_remade'],
+                         ids=['no_stack_remade', 'no_layer_remade'])
 @pytest.mark.parametrize('case,matrices', [
     ('gpt_xl_step', ('fc_w', 'out_w', 'proj_w', 'qkv_w')),
     ('gpt_xl_prefill', ('fc_w', 'out_w', 'proj_w', 'qkv_w')),
@@ -620,14 +672,34 @@ def test_engine_program_never_remakes_its_pool(compiled, case, kernels):
     ('moe_gpt_step', ('gate_w', 'proj_w', 'qkv_w', 'w_in', 'w_out')),
 ])
 def test_engine_program_takes_its_block_matrices_in_the_compute_dtype(
-        compiled, case, matrices):
+        compiled, case, matrices, remade):
     """The engine hands its executables the family's product operands in
     bfloat16 (``family.serve_params``, cast once when the engine is built),
     and no operation of the program has a stacked matrix's shape but the
-    parameter and views of it: nothing re-makes the weights in a call."""
+    parameter and views of it: nothing re-makes the weights in a call. Nor
+    (PR 44) does an instruction of the layers' loop have ONE LAYER's shape:
+    every product takes the stack and the layer's index and reads its
+    matrix where the stack holds it."""
     assert compiled[case]['block_matrices'] == dict.fromkeys(
         matrices, 'bf16'), compiled[case]
-    assert compiled[case]['block_matrices_remade'] == [], compiled[case]
+    assert compiled[case][remade] == [], compiled[case]
+
+
+def test_a_head_split_folded_into_the_qkv_product_slices_and_transposes_its_layer(
+        compiled):
+    """What PR 44 took out, and that the reading above can see it: left to
+    fold ``reshape(B, T, kvh, g + 2, hd)`` into the product, the compiler
+    makes a convolution over the ``16 x 3`` window that wants its weight
+    ``[16, 3, 128, 2048]``, contraction axis minor; the stack holds
+    ``[24, 2048, 6144]``, so every layer of every step copies its 25 MB
+    out into fast memory and transposes the copy (25 % of chat's busy
+    time, 15 % of doc's: ledger, PR 43). Behind
+    ``jax.lax.optimization_barrier`` the product's result is
+    ``[16, 1, 6144]`` and neither is there."""
+    case = compiled['gpt_xl_step_split_folded']
+    assert case['block_matrices_remade'] == [], case
+    assert case['block_layers_remade'] == [
+        'copy of bf16[1,2048,6144]', 'fusion of bf16[1,2048,6144]'], case
 
 
 def test_a_step_over_float32_matrices_converts_every_one_in_every_call(

@@ -636,6 +636,50 @@ def test_engine_tokens_and_rows_against_the_dense_cache(params, case):
         assert toks == [int(np.argmax(r)) for r in rows]
 
 
+def _uncached_rows(params, cfg, prompt, n_new, forward):
+    """Reference: greedy decode of ONE sequence with no cache at all, the
+    whole sequence run anew for every token -> (tokens, the rows)."""
+    toks, rows = list(prompt), []
+    for _ in range(n_new):
+        lg = forward(params, jnp.asarray([toks], jnp.int32), cfg)
+        lg = lg[0] if isinstance(lg, tuple) else lg     # moe_gpt: (rows, aux)
+        rows.append(np.asarray(lg[0, -1], np.float32))
+        toks.append(int(np.argmax(rows[-1])))
+    return toks[len(prompt):], np.stack(rows)
+
+
+@pytest.mark.parametrize('form', ['dense_cache', 'engine'])
+@pytest.mark.parametrize('family', ['gpt', 'moe_gpt'])
+def test_a_cached_decode_of_grouped_heads_serves_the_uncached_forwards_rows(
+        family, form):
+    """Two query heads a KV head (``g = 2``): a cached block makes q, k, v
+    by the product and THEN the split (``gpt._cached_qkv``, PR 44), the
+    uncached forward by ``_block_qkv`` as training does. Both forms of the
+    cache, both families: the same tokens and the same rows."""
+    model, make, extra = ((gpt, gpt.GPTConfig, {}) if family == 'gpt' else (
+        moe_gpt, moe_gpt.MoEConfig, dict(n_experts=4, capacity_factor=8.0)))
+    cfg = make(vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
+               num_kv_heads=2, max_seq_len=32, dtype='float32', remat=False,
+               use_flash=False, **extra)
+    weights = model.init_params(cfg, jax.random.PRNGKey(4))
+    assert weights['blocks']['qkv_w'].shape[-1] == (4 + 2 * 2) * 16
+    prompts, n_new = _prompts([5, 9, 12], seed=13), 6
+    want = [_uncached_rows(weights, cfg, p, n_new, model.forward)
+            for p in prompts]
+    if form == 'dense_cache':
+        got = [_dense_rows(weights, cfg, p, n_new, model.forward_with_cache)
+               for p in prompts]
+    else:
+        with _engine(weights, cfg) as eng:
+            futs = [eng.submit(p, max_new_tokens=n_new, want_logits=True)
+                    for p in prompts]
+            got = [(f.result(timeout=300), np.stack(f.logits()))
+                   for f in futs]
+    for (toks, rows), (want_toks, want_rows) in zip(got, want):
+        np.testing.assert_allclose(rows, want_rows, rtol=2e-5, atol=2e-5)
+        assert toks == want_toks
+
+
 @pytest.mark.parametrize('shared', [8, 11, 16],
                          ids=['page_boundary', 'mid_page', 'two_pages'])
 def test_prefix_tail_prefill_against_the_dense_cache(params, shared):
