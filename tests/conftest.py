@@ -56,59 +56,18 @@ def cpu_mesh():
 
 @pytest.fixture
 def read_first():
-    """-> a context manager under which every ``GenerationEngine`` reads a
-    decode step before it plans the next one: the order the engine had
-    before its loop ran one step ahead of its read-back (PR 36). A
-    test-local patch of ``_plan_step``, which gives no step a successor
-    while its tokens are unread; so every row's input token is the host's
-    and ``steps_overlapped`` stays 0. What the pipelined loop serves is
-    held equal to what this one serves."""
-    import contextlib
-
-    from paddle_tpu.serving import GenerationEngine
-
-    @contextlib.contextmanager
-    def patched():
-        plan = GenerationEngine._plan_step
-        GenerationEngine._plan_step = (
-            lambda self, unread: None if unread is not None
-            else plan(self, unread))
-        try:
-            yield
-        finally:
-            GenerationEngine._plan_step = plan
-    return patched
-
-
-@pytest.fixture(scope='session')
-def full_body():
-    """-> a context manager under which every ``GenerationEngine`` built
-    pads every prompt to ``prefill_width``: the one body an engine had
-    before it chose among ``family.prefill_widths``. A test-local patch of
-    the rule; what the narrow bodies serve is held equal to what this one
+    """-> ``family_contract.reading_first``: a context manager under which
+    every ``GenerationEngine`` reads a decode step before it plans the next
+    one. What the pipelined loop serves is held equal to what this one
     serves."""
-    import contextlib
-
-    from paddle_tpu.models import family
-
-    @contextlib.contextmanager
-    def patched():
-        rule = family.prefill_widths
-        family.prefill_widths = (
-            lambda width, page_size, pages=1: (int(width),))
-        try:
-            yield
-        finally:
-            family.prefill_widths = rule
-    return patched
+    import family_contract
+    return family_contract.reading_first
 
 
-@pytest.fixture
-def traces_for():
-    """-> ``count(widths, rows)``: the traces an engine of these
-    ``prefill_widths`` has made once it has served prompts of these
-    (uncached) rows with no ``warmup()`` before them: its step, and its
-    prefill at each width one of them was padded to."""
-    def count(widths, rows):
-        return 1 + len({next(w for w in widths if w >= n) for n in rows})
-    return count
+def pytest_generate_tests(metafunc):
+    """``refusal``: one case for each shape the bound row of
+    tests/served_families.py says its family refuses."""
+    row = getattr(metafunc.cls, 'row', None)
+    if row is not None and 'refusal' in metafunc.fixturenames:
+        metafunc.parametrize('refusal', row.refused, ids=[
+            '_'.join(over) for over, _ in row.refused])

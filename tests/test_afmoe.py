@@ -3,10 +3,10 @@ forced: a window in the flash forward and in the paged decode kernel, a
 page pool of two kinds of plane whose window kind gives back what left the
 window (serving/generation.py), the routed layer told which experts it
 holds. Small sizes on the CPU: a window of 8 rows over pages of 4 through
-the jnp paths, and the kernels themselves through the Pallas interpreter."""
+the jnp paths, and the kernels themselves through the Pallas interpreter.
+The engine's contract is tests/family_contract.py's, bound to this family's
+row of tests/served_families.py; what stays here is the family's own."""
 import importlib
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
@@ -14,58 +14,32 @@ import numpy as np
 import pytest
 
 from paddle_tpu import observability as obs
-from paddle_tpu.models import afmoe, family, gpt, latent_moe, moe_gpt
+from paddle_tpu.models import afmoe
 from paddle_tpu.parallel import routed_experts as rex
 from paddle_tpu.serving import GenerationEngine
+
+from family_contract import Contract, borrow, served_of
+from served_families import FAMILIES, FULL, SLIDING
 
 pytestmark = pytest.mark.gen
 fa = importlib.import_module('paddle_tpu.ops.flash_attention')
 pa = importlib.import_module('paddle_tpu.ops.paged_attention')
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+row = FAMILIES['afmoe']
+served = served_of(row)
+ref = row.ref
+tiny_shape, program_config = row.shape, row.config
+ENGINE = row.engine
 
 
-def _reference():
-    """benchmark/reference/trinity_large.py: plain jnp, imports nothing of
-    the program."""
-    path = os.path.join(REPO, 'benchmark', 'reference', 'trinity_large.py')
-    spec = importlib.util.spec_from_file_location('ref_trinity_large', path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def f32_params(shape):
+    """The reference's float32 weights, which the program takes as they
+    are: the shared ones for the row's own shape."""
+    return (served.stacked if shape == tiny_shape()
+            else row.weights(shape)[1])
 
 
-ref = _reference()
-SLIDING, FULL = 'sliding_attention', 'full_attention'
-
-
-def tiny_shape(**over):
-    shape = dict(
-        vocab_size=96, hidden_size=32, intermediate_size=64,
-        moe_intermediate_size=16, num_hidden_layers=5, num_dense_layers=1,
-        num_attention_heads=6, num_key_value_heads=1, head_dim=8,
-        sliding_window=8, layer_types=[SLIDING] * 4 + [FULL],
-        num_experts=4, num_experts_per_tok=2, num_shared_experts=1,
-        route_scale=2.448, route_norm=True, rms_norm_eps=1e-5,
-        rope_theta=10000, mup_enabled=True, max_position_embeddings=64,
-        held_first=0, router_width=8)
-    shape.update(over)
-    return shape
-
-
-def program_config(shape, **over):
-    own = {k: v for k, v in shape.items()
-           if k in afmoe.AfmoeConfig.__dataclass_fields__}
-    own.update(num_experts=shape['router_width'],
-               held=(shape['held_first'], shape['num_experts']),
-               dtype='float32', param_dtype='float32')
-    own.update(over)
-    return afmoe.AfmoeConfig(**own)
-
-
-def f32_params(shape, seed=3):
-    return jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.float32),
-        ref.init_params(shape, jax.random.PRNGKey(seed)))
+class TestAfmoeContract(Contract):
+    row = FAMILIES['afmoe']
 
 
 @pytest.fixture
@@ -73,54 +47,6 @@ def interpret():
     fa.set_interpret(True)
     yield
     fa.set_interpret(False)
-
-
-# ---- served logits against the plain reference -----------------------------
-
-def _serve_and_compare(shape, engine_kw, prompt_lens, max_new, tol):
-    cfg, params = program_config(shape), f32_params(shape)
-    rng = np.random.RandomState(1)
-    prompts = [rng.randint(0, shape['vocab_size'], size=n).astype(np.int32)
-               for n in prompt_lens]
-    with GenerationEngine(params, cfg, **engine_kw) as eng:
-        # more requests than slots: the last are admitted while the first
-        # decode, into slots and pages others gave back
-        futs = [eng.submit(p, max_new_tokens=max_new, want_logits=True)
-                for p in prompts]
-        served = [(f.result(timeout=600), f.logits()) for f in futs]
-        stats = eng.stats()
-    assert stats['free_pages'] == stats['num_pages'] - 2    # two trash pages
-    for p, (toks, rows) in zip(prompts, served):
-        seq = np.concatenate([p, np.asarray(toks[:-1], np.int32)])
-        want = np.asarray(ref.forward(params, jnp.asarray(seq)[None],
-                                      shape)[0])[len(p) - 1:]
-        assert len(toks) == max_new == len(rows)
-        np.testing.assert_allclose(np.stack(rows), want, atol=tol, rtol=0)
-        assert toks == [int(np.argmax(r)) for r in rows]
-    return stats
-
-
-def test_engine_serves_the_reference_rows_several_windows_deep():
-    """Window 8 over pages of 4 (the jnp paths): prompts from inside one
-    window to four windows deep, 20 tokens each through the pool, four
-    requests on three slots."""
-    stats = _serve_and_compare(
-        tiny_shape(), dict(num_slots=3, page_size=4, prefill_width=40),
-        (5, 21, 33, 12), 20, 2e-5)
-    assert stats['evictions'] == 0
-
-
-def test_engine_serves_the_reference_rows_through_the_kernels(interpret):
-    """The same through the Pallas interpreter: the windowed flash forward
-    in the prefills and the windowed paged kernel in the steps (window 200
-    over pages of 128, heads of 64), a request past the window among
-    them."""
-    shape = tiny_shape(head_dim=64, sliding_window=200, num_hidden_layers=2,
-                       layer_types=[SLIDING, FULL],
-                       max_position_embeddings=512)
-    _serve_and_compare(
-        shape, dict(num_slots=2, page_size=128, prefill_width=384),
-        (300, 140, 380), 6, 5e-5)
 
 
 # ---- the windowed flash forward --------------------------------------------
@@ -275,8 +201,11 @@ def _watched_engine(shape, **kw):
     """An engine whose every decode step first checks the allocator's
     invariants: a slot holds at most ``window_pages`` pages of the window
     kind, exactly those its next step reads, and a kind's allocator counts
-    what the slots' tables name."""
+    what the slots' tables name. Of the standard geometry it calls the
+    standard run's executables."""
     eng = GenerationEngine(f32_params(shape), program_config(shape), **kw)
+    if {k: v for k, v in kw.items() if k != 'autostart'} == ENGINE:
+        borrow(eng, served.standard.engine)
     seen = {'most': 0, 'steps': 0}
     ensure = eng._ensure_pages_locked
     ps, w = eng.page_size, shape['sliding_window']
@@ -320,8 +249,8 @@ def test_a_window_kind_holds_a_windows_pages_and_gives_back_the_rest(
     rng = np.random.RandomState(5)
     prompts = [rng.randint(0, 96, size=n).astype(np.int32)
                for n in (30, 3, 38, 2, 26)]
-    eng, seen = _watched_engine(shape, num_slots=3, page_size=4,
-                                prefill_width=40, num_pages=pages)
+    eng, seen = _watched_engine(shape, **ENGINE, **(
+        {'num_pages': pages} if pages else {}))
     assert eng._held_max == {'full': 16, 'window': 3}
     released = obs.find('kv.pages_released_total',
                         {**eng.labels, 'kind': 'window'})
@@ -341,9 +270,8 @@ def test_a_window_kind_holds_a_windows_pages_and_gives_back_the_rest(
     # eviction and requeue change no token: the same as from a pool that
     # never runs short
     if evicts:
-        with GenerationEngine(f32_params(shape), program_config(shape),
-                              num_slots=3, page_size=4,
-                              prefill_width=40) as roomy:
+        roomy, _ = _watched_engine(shape, **ENGINE)
+        with roomy:
             want = [roomy.submit(p, max_new_tokens=24).result(timeout=600)
                     for p in prompts]
         assert got == want
@@ -357,21 +285,21 @@ def test_a_window_kind_holds_a_windows_pages_and_gives_back_the_rest(
     # flight, and a neighbour's row then meets another group by a step
     ((30, 3, 38, 2, 26), 3, False),
 ], ids=['a_request_a_slot', 'slots_refilled'])
-def test_one_step_ahead_serves_what_reading_first_serves(
+def test_one_step_ahead_gives_back_the_pages_reading_first_gives_back(
         lens, slots, rows_equal, read_first):
     """The decode loop dispatches step N+1 before it reads step N (PR 36):
     a window kind gives back the pages that step N+1 no longer reads while
     step N, which still reads them, is in flight, and whoever takes them
-    writes them behind it. Same tokens (and, where the steps hold the same
-    rows, the same logits to the last bit) as a loop that reads each step
-    before it dispatches the next."""
+    writes them behind it. Beside the contract's case (the same tokens and
+    rows in both orders): the allocator's invariants hold at every step of
+    both, and both give back the same pages."""
     shape = tiny_shape()
     rng = np.random.RandomState(5)
     prompts = [rng.randint(0, 96, size=n).astype(np.int32) for n in lens]
 
     def serve():
-        eng, seen = _watched_engine(shape, num_slots=slots, page_size=4,
-                                    prefill_width=40, autostart=False)
+        eng, seen = _watched_engine(shape, **dict(ENGINE, num_slots=slots),
+                                    autostart=False)
         released = obs.find('kv.pages_released_total',
                             {**eng.labels, 'kind': 'window'})
         futs = [eng.submit(p, max_new_tokens=12 + 4 * i, want_logits=True)
@@ -410,46 +338,6 @@ def test_a_family_of_kinds_gets_no_prefix_cache_and_names_its_pools():
         'k_full': (1, 33, 1, 4, 8), 'v_full': (1, 33, 1, 4, 8),
         'k_window': (4, 7, 1, 4, 8), 'v_window': (4, 7, 1, 4, 8)}
     assert set(eng._tables(2)) == {'full', 'window'}
-    eng.shutdown()
-
-
-def _older_family(name):
-    if name == 'gpt':
-        cfg = gpt.GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
-                            num_heads=2, max_seq_len=32)
-        return cfg, gpt.init_params(cfg, jax.random.PRNGKey(0))
-    if name == 'moe_gpt':
-        cfg = moe_gpt.MoEConfig(vocab_size=64, hidden_size=32, num_layers=2,
-                                num_heads=2, n_experts=2, max_seq_len=32)
-        return cfg, moe_gpt.init_params(cfg, jax.random.PRNGKey(0))
-    cfg = latent_moe.LatentMoEConfig(
-        vocab_size=64, hidden_size=32, intermediate_size=64,
-        moe_intermediate_size=16, num_hidden_layers=2,
-        first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=16,
-        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
-        v_head_dim=8, n_routed_experts=4, num_experts_per_tok=2, n_group=2,
-        topk_group=1, max_position_embeddings=32, rope_scaling=None,
-        dtype='float32', param_dtype='float32')
-    return cfg, latent_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-@pytest.mark.parametrize('name', ['gpt', 'moe_gpt', 'latent_moe'])
-def test_the_older_families_keep_one_kind_one_table_one_allocator(name):
-    cfg, params = _older_family(name)
-    fam = family.family_of(cfg)
-    assert fam.name == name and fam.page_kinds is None
-    eng = GenerationEngine(params, cfg, num_slots=2, page_size=8,
-                           autostart=False)
-    assert eng._kinds == family.ONE_KIND and eng._held_max == {'kv': 4}
-    assert eng.num_pages == 2 * 4 + 1 and eng._num_pages == {'kv': 9}
-    assert list(eng._allocs.values()) == [eng._alloc]
-    table = eng._tables(2)              # an array, as the executables take
-    assert table.shape == (2, 4) and table.dtype == np.int32
-    # and it serves as it did: the step's table is that array
-    out = eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=6)
-    eng.start()
-    assert len(out.result(timeout=300)) == 6
-    assert eng.stats()['free_pages'] == 8
     eng.shutdown()
 
 

@@ -105,7 +105,7 @@ def test_ppyoloe_train_decreasing_loss():
                                 parameters=net.parameters())
     x, gb, gl, gm = _synth_batch()
     losses = []
-    for _ in range(8):
+    for _ in range(6):      # the loss is under 0.9 of its start from step 3 on
         loss = net.loss(net(x), gb, gl, gm)
         loss.backward()
         opt.step()
@@ -174,7 +174,7 @@ def test_svtr_ctc_train_decreasing_loss():
     in_len = paddle.to_tensor(np.asarray([16, 16], 'i8'))
     lab_len = paddle.to_tensor(np.asarray([5, 5], 'i8'))
     losses = []
-    for _ in range(6):
+    for _ in range(4):      # it falls from the first step on
         logits = net(x)                                  # [N, T, C]
         lp = paddle.transpose(logits, [1, 0, 2])         # CTC wants [T,N,C]
         loss = ctc(lp, labels, in_len, lab_len)

@@ -3,12 +3,11 @@ what it forced: a kind of pool plane that is a row a SLOT (models/family.py,
 serving/generation.py), the recurrence over a sequence and over one token
 (ops/ssm.py), two KV heads of 64 sharing a 128-lane pool row. Small sizes
 on the CPU: two periods of [mamba, mamba, attention, mamba] at hidden 64
-through the jnp paths, and the kernels through the Pallas interpreter."""
+through the jnp paths, and the kernels through the Pallas interpreter. The
+engine's contract is tests/family_contract.py's, bound to this family's row
+of tests/served_families.py; what stays here is the family's own."""
 import importlib
-import importlib.util
-import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,68 +18,21 @@ from paddle_tpu.models import granite_hybrid as gh
 from paddle_tpu.ops import ssm
 from paddle_tpu.serving import GenerationEngine
 
+from family_contract import Contract, borrow, served_of
+from served_families import FAMILIES
+
 pytestmark = pytest.mark.gen
 fa = importlib.import_module('paddle_tpu.ops.flash_attention')
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 M, A = gh.MAMBA, gh.ATTENTION
+row = FAMILIES['granite_hybrid']
+served = served_of(row)
+tiny_shape, program_config = row.shape, row.config
+prompts_of = served.prompts
+ENGINE = row.engine
 
 
-def _reference():
-    """benchmark/reference/granite_hybrid.py: plain jnp, a token-by-token
-    recurrence, imports nothing of the program."""
-    path = os.path.join(REPO, 'benchmark', 'reference', 'granite_hybrid.py')
-    spec = importlib.util.spec_from_file_location('ref_granite_hybrid', path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-ref = _reference()
-
-
-def tiny_shape(**over):
-    shape = dict(
-        vocab_size=96, hidden_size=64, shared_intermediate_size=96,
-        num_hidden_layers=8, layer_types=[M, M, A, M] * 2,
-        num_attention_heads=4, num_key_value_heads=2, mamba_n_heads=4,
-        mamba_d_head=32, mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
-        mamba_n_groups=1, mamba_chunk_size=8, attention_multiplier=0.0625,
-        embedding_multiplier=12.0, residual_multiplier=0.22,
-        logits_scaling=8.0, rms_norm_eps=1e-5, max_position_embeddings=64)
-    shape.update(over)
-    return shape
-
-
-def kernel_shape():
-    """Heads of 64 over pages of 128 rows: what the kernels take."""
-    return tiny_shape(hidden_size=128, num_attention_heads=2,
-                      num_key_value_heads=2, mamba_d_head=64,
-                      max_position_embeddings=512)
-
-
-def program_config(shape, **over):
-    own = {k: v for k, v in shape.items()
-           if k in gh.GraniteHybridConfig.__dataclass_fields__}
-    own.update(dtype='float32', param_dtype='float32')
-    own.update(over)
-    return gh.GraniteHybridConfig(**own)
-
-
-def weights(shape, seed=3):
-    """(the reference's float32 weights, the same as the family scans
-    them)."""
-    layers = jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.float32),
-        ref.init_params(shape, jax.random.PRNGKey(seed)))
-    cfg = program_config(shape)
-    return layers, {
-        'embed': layers['embed'], 'norm_f': layers['norm_f'],
-        'periods': gh.stack_periods(cfg, lambda l: layers['layers'][l])}
-
-
-def prompts_of(lens, vocab=96, seed=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, vocab, size=n).astype(np.int32) for n in lens]
+class TestGraniteHybridContract(Contract):
+    row = FAMILIES['granite_hybrid']
 
 
 @pytest.fixture
@@ -88,70 +40,6 @@ def interpret():
     fa.set_interpret(True)
     yield
     fa.set_interpret(False)
-
-
-def _serve(shape, engine_kw, prompts, max_new, params=None, **submit_kw):
-    layers, stacked = weights(shape)
-    with GenerationEngine(params or stacked, program_config(shape),
-                          **engine_kw) as eng:
-        futs = [eng.submit(p, max_new_tokens=max_new, want_logits=True,
-                           **submit_kw) for p in prompts]
-        served = [(f.result(timeout=600), f.logits()) for f in futs]
-        stats = eng.stats()
-    return layers, served, stats
-
-
-def _held_to_reference(shape, layers, prompts, served, max_new, tol):
-    for p, (toks, rows) in zip(prompts, served):
-        seq = np.concatenate([p, np.asarray(toks[:-1], np.int32)])
-        want = np.asarray(ref.forward(layers, jnp.asarray(seq)[None],
-                                      shape)[0])[len(p) - 1:]
-        assert len(toks) == max_new == len(rows)
-        np.testing.assert_allclose(np.stack(rows), want, atol=tol, rtol=0)
-        assert toks == [int(np.argmax(r)) for r in rows]
-
-
-# ---- served rows against the plain reference -------------------------------
-
-def test_engine_serves_the_reference_rows_through_state_and_pages(
-        traces_for):
-    """The jnp paths: prompts of 1, 2 and 3 rows (a convolution's tail
-    that reaches before row 0) among longer ones, 20 tokens each through
-    the slots' state rows and the pages, seven requests on three slots: the
-    later ones are admitted while the first decode, into slots and pages
-    that others left."""
-    shape = tiny_shape()
-    prompts = prompts_of((5, 21, 33, 12, 1, 2, 3))
-    layers, served, stats = _serve(
-        shape, dict(num_slots=3, page_size=4, prefill_width=40), prompts, 20)
-    _held_to_reference(shape, layers, prompts, served, 20, 2e-5)
-    assert stats['evictions'] == 0
-    assert stats['traces'] == traces_for(stats['prefill_widths'],
-                                         map(len, prompts)) == 1 + 4
-    assert stats['free_pages'] == stats['num_pages'] - 1    # the trash page
-
-
-def test_engine_serves_the_reference_rows_through_the_kernels(interpret):
-    """The same through the Pallas interpreter: the flash forward in the
-    prefills (three bodies: 128, 256 and 384 rows), the paged kernel over
-    rows that hold two KV heads of 64 side by side, and the state update's
-    kernel in the steps."""
-    shape = kernel_shape()
-    assert gh._pack(program_config(shape)) == 2
-    prompts = prompts_of((300, 140, 380, 100))
-    layers, served, _ = _serve(
-        shape, dict(num_slots=2, page_size=128, prefill_width=384), prompts,
-        6)
-    _held_to_reference(shape, layers, prompts, served, 6, 5e-5)
-
-
-def test_the_whole_forward_is_the_references():
-    shape = tiny_shape()
-    layers, stacked = weights(shape)
-    tokens = jnp.asarray(np.stack(prompts_of((21, 21))))
-    np.testing.assert_allclose(
-        gh.forward(stacked, tokens, program_config(shape)),
-        ref.forward(layers, tokens, shape), atol=2e-6, rtol=0)
 
 
 # ---- the recurrence, twice -------------------------------------------------
@@ -232,9 +120,7 @@ def test_a_padded_prefill_leaves_the_state_and_tail_of_its_last_real_row(
     slot what the same prompt unpadded writes: ``S_{valid-1}`` and the
     last three input rows before ``valid`` in every state-space layer, and
     the same last row's logits."""
-    shape = tiny_shape()
-    cfg = program_config(shape)
-    _, stacked = weights(shape)
+    cfg, stacked = served.config, served.stacked
     prompt = prompts_of((16,))[0]
 
     def prefill(tokens, n_valid):
@@ -301,112 +187,22 @@ def test_the_pool_layout_round_trips():
     np.testing.assert_array_equal(ssm.from_lanes(lanes, 4), s)
 
 
-# ---- slots: filled again, filled while others decode, evicted --------------
-
-def test_a_slot_filled_a_second_time_serves_what_a_fresh_engine_serves():
-    """One slot, three requests one after another: each starts from a zero
-    state and a zero tail in a row the last occupant left full, and serves
-    exactly what an engine that never held another serves."""
-    shape = tiny_shape()
-    prompts = prompts_of((9, 3, 17))
-    kw = dict(num_slots=1, page_size=4, prefill_width=24)
-    _, again, _ = _serve(shape, kw, prompts, 10)
-    for p, (toks, rows) in zip(prompts, again):
-        _, fresh, _ = _serve(shape, kw, [p], 10)
-        assert toks == fresh[0][0]
-        np.testing.assert_array_equal(np.stack(rows), np.stack(fresh[0][1]))
-
-
-def test_a_request_admitted_while_others_decode_serves_what_it_serves_alone():
-    shape = tiny_shape()
-    layers, stacked = weights(shape)
-    first, late = prompts_of((11, 6))
-    kw = dict(num_slots=2, page_size=4, prefill_width=24)
-    _, alone, _ = _serve(shape, kw, [late], 12)
-    with GenerationEngine(stacked, program_config(shape), **kw) as eng:
-        running = eng.submit(first, max_new_tokens=30)
-        stream = running.stream(timeout=300)
-        for _ in range(5):                  # the first is five tokens deep
-            next(stream)
-        fut = eng.submit(late, max_new_tokens=12, want_logits=True)
-        toks, rows = fut.result(timeout=300), fut.logits()
-        assert not running.done()           # and still decoding
-        assert len(running.result(timeout=300)) == 30
-    assert toks == alone[0][0]
-    np.testing.assert_allclose(np.stack(rows), np.stack(alone[0][1]),
-                               atol=1e-6, rtol=0)
-
-
-def test_an_evicted_request_regenerates_its_tokens():
-    """A pool too small for three growing sequences: the engine evicts,
-    the evicted restart from row 0 (their state is rebuilt with their
-    pages) and every request's tokens are an unconstrained engine's."""
-    shape = tiny_shape()
-    prompts = prompts_of((7, 6, 5))
-    wide = dict(num_slots=3, page_size=4, prefill_width=16)
-    _, want, _ = _serve(shape, wide, prompts, 18)
-    _, got, stats = _serve(shape, dict(wide, num_pages=11), prompts, 18)
-    assert stats['evictions'] >= 1
-    assert [t for t, _ in got] == [t for t, _ in want]
-
-
-@pytest.mark.parametrize('kw,lens,note', [
-    # seven requests on three slots: a slot is filled again while a step
-    # computed for its last occupant is still in flight
-    (dict(num_slots=3, page_size=4, prefill_width=40),
-     (5, 21, 33, 12, 1, 2, 3), 'refilled'),
-    # one slot: every request starts in the row the last one left full
-    (dict(num_slots=1, page_size=4, prefill_width=24), (9, 3, 17), 'alone'),
-    # a pool too small: slots evicted with a step in flight start again
-    (dict(num_slots=3, page_size=4, prefill_width=16, num_pages=11),
-     (7, 6, 5), 'evicted'),
-], ids=lambda x: x if isinstance(x, str) else None)
-def test_one_step_ahead_serves_what_reading_first_serves(
-        kw, lens, note, read_first, traces_for):
-    """The decode loop dispatches step N+1 before it reads step N (PR 36).
-    A step updates EVERY slot's state row, so the step in flight when a
-    slot changes hands writes the old occupant's row once more: the new
-    occupant's prefill, queued behind it, overwrites state and tail before
-    the first step that reads them. Same tokens and the same rows, to the
-    last bit, as a loop that reads each step before it dispatches the
-    next."""
-    shape = tiny_shape()
-    prompts = prompts_of(lens)
-    n_new = 18 if note == 'evicted' else 14
-    _, got, stats = _serve(shape, kw, prompts, n_new, seed=7)
-    with read_first():
-        _, want, base = _serve(shape, kw, prompts, n_new, seed=7)
-    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
-    assert stats['traces'] == base['traces'] == traces_for(
-        stats['prefill_widths'], lens)
-    assert (stats['evictions'] >= 1) is (note == 'evicted')
-    for (toks, rows), (want_toks, want_rows) in zip(got, want):
-        assert toks == want_toks
-        np.testing.assert_array_equal(np.stack(rows), np.stack(want_rows))
-
-
 # ---- the per-slot kind in the engine ---------------------------------------
 
 def test_the_per_slot_kind_gets_no_pages_and_is_counted_as_state():
-    shape = tiny_shape()
-    cfg = program_config(shape)
-    _, stacked = weights(shape)
+    cfg, stacked = served.config, served.stacked
     kinds = family.family_of(cfg).page_kinds(cfg)
     assert [(k.name, k.per_slot) for k in kinds] == [('kv', False),
                                                      ('state', True)]
-    eng = GenerationEngine(stacked, cfg, num_slots=2, page_size=4,
-                           prefill_width=16, autostart=False)
-    # pages, an allocator and a table for the paged kind alone
-    assert [k.name for k in eng._kinds] == ['kv']
-    assert [k.name for k in eng._slot_kinds] == ['state']
-    assert list(eng._allocs) == ['kv'] and eng._num_pages == {'kv': 33}
-    assert eng.num_pages == 33 and eng._c_released == {}
-    # its planes have a row a slot, and a call is told which slots
-    assert eng._pool['ssm'].shape == (6, 2, 16, 1, 128)
-    assert eng._pool['conv'].shape == (6, 2, 3 * (128 + 32))
-    tables = eng._tables(2, slots=np.asarray([1, 0], np.int32))
-    assert tables['kv'].shape == (2, 16) and list(tables['state']) == [1, 0]
-    assert list(eng._tables(1)['state']) == [0]         # what warmup lowers
+    eng = GenerationEngine(stacked, cfg, autostart=False, **ENGINE)
+    borrow(eng, served.standard.engine)     # the same geometry
+    # pages, an allocator and a table for the paged kind alone (the
+    # contract's case); nothing to release: no window
+    assert eng._num_pages == {'kv': 49}
+    assert eng.num_pages == 49 and eng._c_released == {}
+    # its planes have a row a slot
+    assert eng._pool['ssm'].shape == (6, 3, 16, 1, 128)
+    assert eng._pool['conv'].shape == (6, 3, 3 * (128 + 32))
     per_slot = 6 * (16 * 128 * 4 + 3 * 160 * 4)
     assert eng.stats()['state_bytes_per_slot'] == per_slot
     assert eng.stats()['state_bytes'] == 0 == eng.stats()['page_bytes']
@@ -426,27 +222,17 @@ def test_the_per_slot_kind_gets_no_pages_and_is_counted_as_state():
     fut.result(timeout=300)
     done = eng.stats()
     assert done['state_bytes'] == 0 == done['page_bytes']
-    assert done['free_pages'] == 32
+    assert done['free_pages'] == 48
     eng.shutdown()
 
 
-def test_the_family_declines_a_prefix_cache():
-    shape = tiny_shape()
-    _, stacked = weights(shape)
-    with pytest.raises(ValueError, match='no prefix cache'):
-        GenerationEngine(stacked, program_config(shape), num_slots=2,
-                         page_size=4, prefix_cache=True, autostart=False)
-
-
 def test_num_pages_names_the_paged_kinds_alone():
-    shape = tiny_shape()
-    _, stacked = weights(shape)
+    cfg, stacked = served.config, served.stacked
     with pytest.raises(ValueError, match='num_pages names'):
-        GenerationEngine(stacked, program_config(shape), num_slots=2,
-                         page_size=4, num_pages={'kv': 9, 'state': 2},
-                         autostart=False)
-    eng = GenerationEngine(stacked, program_config(shape), num_slots=2,
-                           page_size=4, num_pages={'kv': 9}, autostart=False)
+        GenerationEngine(stacked, cfg, num_slots=2, page_size=4,
+                         num_pages={'kv': 9, 'state': 2}, autostart=False)
+    eng = GenerationEngine(stacked, cfg, num_slots=2, page_size=4,
+                           num_pages={'kv': 9}, autostart=False)
     assert eng._pool['k'].shape[1] == 9 and eng._pool['ssm'].shape[1] == 2
     eng.shutdown()
 
@@ -459,15 +245,11 @@ def test_the_counters_count_what_a_step_served():
         return (get('ssm.state_rows_total', 'prefill'),
                 get('ssm.scan_chunks_total', 'prefill'),
                 get('ssm.state_rows_total', 'decode'))
-    shape = tiny_shape()
-    before = read()
-    _, _, stats = _serve(shape, dict(num_slots=2, page_size=4,
-                                     prefill_width=24),
-                         prompts_of((5, 11)), 4)
-    rows, chunks, decoded = (a - b for a, b in zip(read(), before))
+    delta, stats = served.counted(lambda: dict(enumerate(read())))
+    rows, chunks, decoded = (delta[i] for i in range(3))
     assert rows == 5 + 11
     assert chunks == 1 + 2      # bodies of 8 and 12 rows in chunks of 8
-    assert decoded == 2 * stats['steps']    # both slots, busy or idle
+    assert decoded == 3 * stats['steps']    # every slot, busy or idle
 
 
 # ---- the configuration -----------------------------------------------------
@@ -489,85 +271,5 @@ def test_the_published_defaults_are_the_catalog_rows():
     assert (cfg.d_inner, cfg.conv_dim, cfg.head_dim) == (4096, 4352, 64)
     assert gh._pack(cfg) == 2
     assert cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads == 8512
-
-
-@pytest.mark.parametrize('over,match', [
-    (dict(layer_types=[M, 'window'] * 4), 'layer_types'),
-    (dict(num_key_value_heads=3), 'num_key_value_heads'),
-    (dict(mamba_n_groups=2), 'one group'),
-    (dict(mamba_n_heads=3), 'mamba_expand'),
-    (dict(hidden_size=48, mamba_n_heads=3, num_attention_heads=2,
-          num_key_value_heads=2), '128')])
-def test_a_shape_the_family_does_not_write_is_refused(over, match):
-    with pytest.raises(ValueError, match=match):
-        program_config(tiny_shape(**over))
-
-
-def test_an_engine_holds_matrices_in_the_compute_type_and_scalars_float32():
-    shape = tiny_shape()
-    cfg = program_config(shape, dtype='bfloat16')
-    _, stacked = weights(shape)
-    held = gh.serve_params(stacked, cfg)
-    mamba, attn = held['periods'][0], held['periods'][2]
-    for name in ('in_proj', 'out_proj', 'mlp_in', 'mlp_out'):
-        assert mamba[name].dtype == jnp.bfloat16
-    for name in ('a_log', 'd', 'dt_bias', 'conv_w', 'conv_b', 'norm_gate',
-                 'norm_in', 'norm_mlp'):
-        assert mamba[name].dtype == jnp.float32
-    assert {attn[n].dtype for n in 'qkvo'} == {jnp.dtype('bfloat16')}
-    assert held['embed'].dtype == jnp.bfloat16
-    assert held['norm_f'].dtype == jnp.float32
-    # leaves already as wanted are handed back themselves
-    again = gh.serve_params(held, cfg)
-    assert again['embed'] is held['embed']
-    assert again['periods'][0]['a_log'] is mamba['a_log']
-
-
-def _family_case(name):
-    from paddle_tpu.models import afmoe, gpt
-    if name == 'gpt':
-        cfg = gpt.GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
-                            num_heads=2, max_seq_len=32)
-        return cfg, gpt.init_params(cfg, jax.random.PRNGKey(0))
-    if name == 'afmoe':
-        cfg = afmoe.AfmoeConfig(
-            vocab_size=64, hidden_size=32, intermediate_size=64,
-            moe_intermediate_size=16, num_hidden_layers=2,
-            num_dense_layers=1, num_attention_heads=2,
-            num_key_value_heads=1, head_dim=8, sliding_window=8,
-            layer_types=('sliding_attention', 'full_attention'),
-            num_experts=4, num_experts_per_tok=2,
-            max_position_embeddings=32, dtype='float32',
-            param_dtype='float32')
-        return cfg, afmoe.init_params(cfg, jax.random.PRNGKey(0))
-    shape = tiny_shape(max_position_embeddings=32)
-    return program_config(shape), weights(shape)[1]
-
-
-@pytest.mark.parametrize('name,paged,per_slot', [
-    ('gpt', ['kv'], []), ('afmoe', ['full', 'window'], []),
-    ('granite_hybrid', ['kv'], ['state'])])
-def test_every_family_keeps_its_kinds_and_only_the_new_one_a_row_a_slot(
-        name, paged, per_slot):
-    """What ``PageKind.per_slot`` added changes nothing for a family that
-    names no such kind: its engine has the allocators and tables it had,
-    no state gauges, and ``state_bytes`` 0."""
-    cfg, params = _family_case(name)
-    eng = GenerationEngine(params, cfg, num_slots=2, page_size=8,
-                           autostart=False)
-    assert [k.name for k in eng._kinds] == paged == list(eng._allocs)
-    assert [k.name for k in eng._slot_kinds] == per_slot
-    assert (eng._g_bytes is not None) == bool(per_slot)
-    tables = eng._tables(2)
-    if name == 'gpt':
-        assert tables.shape == (2, 4)
-    else:
-        assert sorted(tables) == sorted(paged + per_slot)
-        assert all(tables[k].shape == (2, 4) for k in paged)
-        assert all(tables[k].shape == (2,) for k in per_slot)
-    stats = eng.stats()
-    assert stats['state_bytes'] == 0
-    assert (stats['state_bytes_per_slot'] > 0) == bool(per_slot)
-    assert set(eng._unit_bytes) == set(paged + per_slot)
-    assert all(v > 0 for v in eng._unit_bytes.values())
-    eng.shutdown()
+    # and the kernel-sized shape of the interpreter's run packs two too
+    assert gh._pack(program_config(row.kernel()[0])) == 2

@@ -59,7 +59,7 @@ def _infer_factory(**kw):
     return factory
 
 
-def _reference(params, prompt, n_new, seed=0):
+def _served_alone(params, prompt, n_new, seed=0):
     eng = GenerationEngine(params, CFG, num_slots=2, page_size=8,
                            prefill_width=16)
     try:
@@ -75,7 +75,7 @@ def _reference(params, prompt, n_new, seed=0):
 
 def test_host_serves_heterogeneous_models(params):
     prompt = np.array([3, 1, 4, 1, 5])
-    want = _reference(params, prompt, 8, seed=7)
+    want = _served_alone(params, prompt, 8, seed=7)
     with ModelHost(hbm_watermark_bytes=256 * MB, name='hetero') as host:
         host.deploy('chat', _gen_factory(params))
         host.deploy('vision', _infer_factory(),
@@ -121,7 +121,7 @@ def test_admission_refused_over_watermark_without_stripping(params):
 
 def test_lru_eviction_and_zero_trace_swap_in(params):
     prompt = np.array([2, 7, 1, 8])
-    want = _reference(params, prompt, 6, seed=3)
+    want = _served_alone(params, prompt, 6, seed=3)
     with ModelHost(hbm_watermark_bytes=9 * MB, name='lru') as host:
         host.deploy('a', _gen_factory(params), footprint_bytes=4 * MB)
         host.deploy('b', _gen_factory(params), footprint_bytes=4 * MB)
@@ -150,7 +150,7 @@ def test_evict_and_swap_in_mid_traffic_lose_no_interactive_request(params):
     with the single engine's tokens, while a deploy evicts the cold model
     beside it and a later submit swaps that one back in."""
     prompt = np.array([2, 7, 1, 8])
-    want = _reference(params, prompt, 4, seed=3)
+    want = _served_alone(params, prompt, 4, seed=3)
     answers, errors, stop = [], [], threading.Event()
     with ModelHost(hbm_watermark_bytes=13 * MB, name='midtraffic') as host:
         host.deploy('draft', _gen_factory(params), footprint_bytes=4 * MB)
@@ -377,7 +377,7 @@ def test_model_predict_honors_retry_after_hint(monkeypatch):
 
 def test_fleet_router_targets_hosted_model(params):
     prompt = np.array([5, 2, 9])
-    want = _reference(params, prompt, 6, seed=11)
+    want = _served_alone(params, prompt, 6, seed=11)
     with ModelHost(hbm_watermark_bytes=64 * MB, name='behind') as host:
         host.deploy('chat', _gen_factory(params))
         rs = ReplicaSet(replicas=[GenerationEngine(

@@ -1,59 +1,41 @@
 """The latent-attention, routed-expert family (models/latent_moe.py) against
 its plain reference (benchmark/reference/dots_vlm.py), at small sizes on the
-CPU with seeded weights: the engine's served logits, the absorbed form
-against the expanded, the group-limited choice against a brute-force numpy
-choice, the shares of an expert-parallel layer adding up to the uncut one,
-no row dropped under a skewed router, each new kernel interpreted against
-``jax.numpy``, the model-family interface the engine asks through, and the
-``moe.*`` counters."""
+CPU with seeded weights: the absorbed form against the expanded, the
+group-limited choice against a brute-force numpy choice, the shares of an
+expert-parallel layer adding up to the uncut one, no row dropped under a
+skewed router, each new kernel interpreted against ``jax.numpy``, and the
+``moe.*`` counters. The engine's contract (the served logits among it) is
+tests/family_contract.py's, bound to this family's row of
+tests/served_families.py; what stays here is the family's own."""
 import importlib
 import os
-import sys
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+from paddle_tpu import observability as obs
+from paddle_tpu.models import family, latent_moe
+from paddle_tpu.ops import expert_grouped_matmul as gmm
+from paddle_tpu.ops import paged_kv
+from paddle_tpu.ops import paged_latent_attention as pla
+from paddle_tpu.parallel import routed_experts as rexp
+from paddle_tpu.serving import GenerationEngine
 
-from benchmark.harness import manifest  # noqa: E402
-from paddle_tpu import observability as obs  # noqa: E402
-from paddle_tpu.models import family, gpt, latent_moe, moe_gpt  # noqa: E402
-from paddle_tpu.ops import expert_grouped_matmul as gmm  # noqa: E402
-from paddle_tpu.ops import paged_kv  # noqa: E402
-from paddle_tpu.ops import paged_latent_attention as pla  # noqa: E402
-from paddle_tpu.parallel import routed_experts as rexp  # noqa: E402
-from paddle_tpu.serving import GenerationEngine  # noqa: E402
+from family_contract import Contract, borrow, served_of
+from served_families import FAMILIES, REPO
 
 fa = importlib.import_module('paddle_tpu.ops.flash_attention')
-ref = manifest.load_module('reference', 'dots_vlm')
-
-YARN = dict(type='yarn', factor=40, original_max_position_embeddings=16,
-            beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1)
-SHAPE = dict(
-    vocab_size=64, hidden_size=128, intermediate_size=256,
-    moe_intermediate_size=128, num_hidden_layers=3, first_k_dense_replace=1,
-    num_attention_heads=4, q_lora_rank=24, kv_lora_rank=128,
-    qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
-    n_routed_experts=4, router_width=16, held_first=4, n_shared_experts=1,
-    num_experts_per_tok=4, n_group=4, topk_group=2,
-    routed_scaling_factor=2.5, norm_topk_prob=True, rms_norm_eps=1e-6,
-    rope_theta=10000.0, rope_scaling=YARN, max_position_embeddings=256)
+row = FAMILIES['latent_moe']
+served = served_of(row)
+ref = row.ref
+SHAPE = row.shape()
+config_of = row.config
 
 
-def config_of(shape, **kw):
-    own = {k: v for k, v in shape.items()
-           if k in latent_moe.LatentMoEConfig.__dataclass_fields__}
-    own.update(n_routed_experts=shape['router_width'],
-               held=(shape['held_first'], shape['n_routed_experts']))
-    return latent_moe.LatentMoEConfig(**own, **dict(dtype='float32', **kw))
-
-
-def widen(tree):
-    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
+class TestLatentMoeContract(Contract):
+    row = FAMILIES['latent_moe']
 
 
 @pytest.fixture
@@ -65,38 +47,24 @@ def interpret():
 
 @pytest.fixture(scope='module')
 def weights():
-    return ref.init_params(SHAPE, jax.random.PRNGKey(3))
+    """The reference's float32 weights, which the program takes as they
+    are."""
+    return served.stacked
 
 
-# ---- the engine's served rows against the reference ------------------------
-
-def test_engine_serves_the_references_logits_through_the_latent_pool(
-        weights, interpret):
-    """Prefill, then decode through the latent pool with the kernels
-    interpreted; the second request is admitted while the first decodes."""
-    eng = GenerationEngine(widen(weights), config_of(SHAPE), num_slots=2,
-                           page_size=128, num_pages=5, prefill_width=32)
-    try:
-        rng = np.random.RandomState(0)
-        prompts = [rng.randint(0, 64, size=n).astype(np.int32)
-                   for n in (20, 9)]
-        futs = [eng.submit(prompts[0], max_new_tokens=12, want_logits=True)]
-        while eng.stats()['steps'] < 2:
-            time.sleep(0.05)
-        futs.append(eng.submit(prompts[1], max_new_tokens=5,
-                               want_logits=True))
-        for fut, prompt in zip(futs, prompts):
-            toks = fut.result(timeout=300)
-            rows = np.stack(fut.logits())
-            seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
-            want = np.asarray(ref.forward(weights, jnp.asarray(seq)[None],
-                                          SHAPE))[0][len(prompt) - 1:]
-            assert rows.shape == want.shape
-            np.testing.assert_allclose(rows, want, atol=2e-5)
-            assert toks == list(np.argmax(rows, axis=-1))
-        assert eng.stats()['traces'] == 2       # both prompts in 32 rows
-    finally:
-        eng.shutdown()
+def test_the_kernels_admit_a_request_while_another_decodes():
+    """The contract's run through the interpreted kernels (its rows are
+    held to the reference there) is three requests on two slots over pages
+    of 128: the third takes the slot the second leaves after 5 tokens and
+    is prefilled, and decodes its 6 through the latent kernel, while the
+    first is still at its 12. The first's 11 steps hold every other step:
+    an engine that admitted the third after the first would run 11 + 5."""
+    run, _, new, _ = served.kernel
+    assert [len(p) for p in run.prompts] == [20, 9, 13]
+    assert [len(t) for t in run.tokens] == list(new) == [12, 5, 6]
+    assert run.stats['completed'] == 3 == run.stats['prefills']
+    assert run.stats['steps'] == new[0] - 1 < (new[0] - 1) + (new[2] - 1)
+    assert run.stats['traces'] == 2         # all three prompts in 32 rows
 
 
 @pytest.mark.parametrize('temperature', [0.0, 0.8],
@@ -114,10 +82,15 @@ def test_one_step_ahead_serves_what_reading_first_serves(
     held = lambda: getattr(obs.find(                        # noqa: E731
         'moe.rows_held_total', {'phase': 'decode'}), 'value', 0)
 
+    engines = []
+
     def serve():
-        eng = GenerationEngine(widen(weights), config_of(SHAPE), num_slots=2,
+        eng = GenerationEngine(weights, config_of(SHAPE), num_slots=2,
                                page_size=16, num_pages=9, prefill_width=32,
                                temperature=temperature, autostart=False)
+        if engines:     # reading first runs the same executables
+            borrow(eng, engines[0])
+        engines.append(eng)
         futs = [eng.submit(p, max_new_tokens=6 + 5 * i, seed=i,
                            want_logits=True) for i, p in enumerate(prompts)]
         before = held()
@@ -132,23 +105,19 @@ def test_one_step_ahead_serves_what_reading_first_serves(
     assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
     # the step and the prefill at 32 and at 16 rows
     assert stats['steps'] == base['steps'] and stats['traces'] == 3
+    # reading first traced nothing: not of its own and, borrowing, not
+    # where its calls would land a trace, on the first engine
+    assert base['traces'] == 0 and engines[0]._trace_count == 3
     assert counted == counted_first > 0
     for (toks, rows), (want_toks, want_rows) in zip(got, want):
         assert toks == want_toks
         np.testing.assert_array_equal(rows, want_rows)
 
 
-def test_the_latent_family_refuses_a_prefix_cache(weights):
-    with pytest.raises(ValueError, match='prefills from row 0'):
-        GenerationEngine(widen(weights), config_of(SHAPE), num_slots=2,
-                         page_size=16, num_pages=9, prefix_cache=True,
-                         autostart=False)
-
-
 def test_absorbed_form_equals_expanded_form(weights):
     """A decode step over the pool (absorbed) gives the row the prefill
     (expanded) gives for the same token at the same place."""
-    cfg, params = config_of(SHAPE), widen(weights)
+    cfg, params = config_of(SHAPE), weights
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, 64)
     full = latent_moe.forward(params, toks, cfg)
     pool = latent_moe.init_pool(cfg, 5, 16)
@@ -162,26 +131,38 @@ def test_absorbed_form_equals_expanded_form(weights):
     np.testing.assert_allclose(row[:, 0], full[:, 23], atol=2e-5)
 
 
+@pytest.fixture(scope='module')
+def three_bodies(weights):
+    """ONE engine of bodies of 128, 256 and 320 rows for the cases below:
+    a body is traced and compiled by the first case that runs it."""
+    shape = dict(SHAPE, max_position_embeddings=384)
+    with GenerationEngine(weights, config_of(shape), num_slots=1,
+                          page_size=128, num_pages=4,
+                          prefill_width=320) as eng:
+        yield eng
+
+
 @pytest.mark.parametrize('rows,body', [(20, 128), (256, 256), (257, 320),
                                        (300, 320)])
 def test_a_padded_prefill_runs_the_narrowest_body_that_holds_it(
-        weights, rows, body):
+        weights, three_bodies, rows, body):
     """The engine pads a prompt to the narrowest of its widths that holds
     it (128, 256 and the full 320 rows here) and runs the prefill at that
     shape; the family's forward gives the reference's row and writes the
     same cache rows at either width, the padding routed nowhere."""
     shape = dict(SHAPE, max_position_embeddings=384)
-    cfg, params = config_of(shape), widen(weights)
+    cfg, params = config_of(shape), weights
     prompt = np.random.RandomState(rows).randint(0, 64, size=rows).astype(
         np.int32)
-    with GenerationEngine(params, cfg, num_slots=1, page_size=128,
-                          num_pages=4, prefill_width=320) as eng:
-        assert eng.prefill_widths == (128, 256, 320)
-        fut = eng.submit(prompt, max_new_tokens=1, want_logits=True)
-        fut.result(timeout=300)
-        stats = eng.stats()
-    assert stats['prefill_rows_asked'] == rows
-    assert stats['prefill_rows_computed'] == body
+    eng = three_bodies
+    assert eng.prefill_widths == (128, 256, 320)
+    before = eng.stats()
+    fut = eng.submit(prompt, max_new_tokens=1, want_logits=True)
+    fut.result(timeout=300)
+    stats = eng.stats()
+    for key, want in (('prefill_rows_asked', rows),
+                      ('prefill_rows_computed', body)):
+        assert stats[key] - before[key] == want
     want = ref.forward(weights, jnp.asarray(prompt)[None], shape)
     np.testing.assert_allclose(fut.logits()[0], want[0, -1], atol=2e-5)
 
@@ -461,9 +442,7 @@ def test_paged_write_takes_a_plane_without_a_heads_axis():
 
 # ---- the family interface --------------------------------------------------
 
-def test_every_served_family_is_a_pool_maker_and_a_cached_forward():
-    assert family.family_of(gpt.GPTConfig()).name == 'gpt'
-    assert family.family_of(moe_gpt.MoEConfig()).name == 'moe_gpt'
+def test_the_latent_pool_is_one_plane_and_the_engine_names_no_family():
     fam = family.family_of(config_of(SHAPE))
     assert fam.name == 'latent_moe' and not fam.tail_prefill
     pool = fam.init_pool(config_of(SHAPE), 7, 16)
@@ -479,40 +458,12 @@ def test_every_served_family_is_a_pool_maker_and_a_cached_forward():
     assert "'moe' in" not in src and 'init_paged_kv_cache' not in src
 
 
-@pytest.mark.parametrize('which', ['gpt', 'moe_gpt'])
-def test_gpt_families_serve_the_logits_of_their_own_forward(which):
-    """Through the family interface the engine's rows are the model's full
-    forward pass's, as they were."""
-    kw = dict(vocab_size=96, hidden_size=32, num_layers=2, num_heads=2,
-              max_seq_len=64, dtype='float32', use_flash=False)
-    if which == 'gpt':
-        mod, cfg = gpt, gpt.GPTConfig(**kw)
-    else:
-        mod, cfg = moe_gpt, moe_gpt.MoEConfig(n_experts=4,
-                                              capacity_factor=8.0, **kw)
-    params = mod.init_params(cfg, jax.random.PRNGKey(0))
-    eng = GenerationEngine(params, cfg, num_slots=2, page_size=16,
-                           num_pages=9)
-    try:
-        prompt = np.arange(3, 14, dtype=np.int32)
-        fut = eng.submit(prompt, max_new_tokens=5, want_logits=True)
-        toks = fut.result(timeout=300)
-        rows = np.stack(fut.logits())
-    finally:
-        eng.shutdown()
-    seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
-    full = mod.forward(params, jnp.asarray(seq)[None], cfg)
-    full = full[0] if isinstance(full, tuple) else full
-    np.testing.assert_allclose(rows, np.asarray(full)[0][len(prompt) - 1:],
-                               atol=5e-4)
-
-
 # ---- the counters ----------------------------------------------------------
 
 def test_moe_counters_count_what_a_hand_made_batch_routes(weights):
     """Three rows, one of them padding: what the layers count is what the
     reference's own choice says, and ``note_counts`` adds it to moe.*."""
-    cfg, params = config_of(SHAPE), widen(weights)
+    cfg, params = config_of(SHAPE), weights
     toks = jnp.asarray([[5, 9, 0]], jnp.int32)
     pool = latent_moe.init_pool(cfg, 3, 16)
     cache = dict(pool, page_table=jnp.asarray([[1]], jnp.int32),
@@ -525,12 +476,11 @@ def test_moe_counters_count_what_a_hand_made_batch_routes(weights):
     offered = held = touched = biggest = 0
     for lp in weights['layers']:
         if 'router' in lp:
-            lp32 = widen(lp)
             y = ref.rms(x + ref.attention(
-                lp32, ref.rms(x, lp32['attn_norm'], 1e-6), SHAPE),
-                lp32['ffn_norm'], 1e-6)
-            chosen = np.asarray(ref.route(y, lp32['router'],
-                                          lp32['router_bias'], SHAPE)[0])
+                lp, ref.rms(x, lp['attn_norm'], 1e-6), SHAPE),
+                lp['ffn_norm'], 1e-6)
+            chosen = np.asarray(ref.route(y, lp['router'],
+                                          lp['router_bias'], SHAPE)[0])
             local = chosen[(chosen >= 4) & (chosen < 8)]
             sizes = np.bincount(local - 4, minlength=4)
             offered += chosen.size

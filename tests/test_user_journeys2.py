@@ -168,7 +168,7 @@ def test_seq2seq_teacher_forcing_journey():
     tgt_in = paddle.concat([bos, tgt[:, :-1]], axis=1)
 
     losses = []
-    for _ in range(25):
+    for _ in range(18):     # 0.7 of the first loss is passed at step 13
         logits = net(src, tgt_in)
         loss = F.cross_entropy(logits.reshape([-1, V]), tgt.reshape([-1]))
         loss.backward()

@@ -4,10 +4,10 @@ hands ``parallel/routed_experts.py`` ``held_experts`` its answer, a row that mee
 a per-slot kind (the convolutions' tail and the value's) beside ONE paged
 kind in an attention layer itself, a stack scanned with the router's state
 in the carry. Small sizes on the CPU against benchmark/reference/zaya.py,
-and the kernels through the Pallas interpreter."""
+and the kernels through the Pallas interpreter. The engine's contract is
+tests/family_contract.py's, bound to this family's row of
+tests/served_families.py; what stays here is the family's own."""
 import dataclasses
-import importlib
-import importlib.util
 import json
 import os
 
@@ -17,153 +17,28 @@ import numpy as np
 import pytest
 
 from paddle_tpu import observability as obs
-from paddle_tpu.models import afmoe, family, latent_moe, zaya
-from paddle_tpu.ops.expert_grouped_matmul import expert_grouped_matmul
+from paddle_tpu.models import family, zaya
 from paddle_tpu.parallel import routed_experts as re_
 from paddle_tpu.serving import GenerationEngine
 
+from family_contract import Contract, borrow, served_of
+from served_families import FAMILIES, REPO
+
 pytestmark = pytest.mark.gen
-fa = importlib.import_module('paddle_tpu.ops.flash_attention')
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TOL = 2e-5      # float32 program against the float32 'highest' reference
+row = FAMILIES['zaya']
+served = served_of(row)
+ref = row.ref
+tiny_shape, program_config, weights = row.shape, row.config, row.weights
+prompts_of = served.prompts
+TOL = row.tol   # float32 program against the float32 'highest' reference
+ENGINE = row.engine
 
 
-def _reference():
-    """benchmark/reference/zaya.py: plain jnp, imports nothing of the
-    program."""
-    path = os.path.join(REPO, 'benchmark', 'reference', 'zaya.py')
-    spec = importlib.util.spec_from_file_location('ref_zaya', path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+class TestZayaContract(Contract):
+    row = FAMILIES['zaya']
 
 
-ref = _reference()
-
-
-def tiny_shape(**over):
-    shape = dict(
-        vocab_size=96, hidden_size=64, moe_intermediate_size=32,
-        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
-        head_dim=16, cca_time0=2, cca_time1=2, num_experts=4,
-        num_experts_per_tok=1, router_hidden_size=32,
-        partial_rotary_factor=0.5, rope_theta=5000000.0, rms_norm_eps=1e-5,
-        max_position_embeddings=64)
-    shape.update(over)
-    return shape
-
-
-def kernel_shape():
-    """Heads of 128 over pages of 128 rows, widths of whole lanes: what the
-    kernels take."""
-    return tiny_shape(hidden_size=128, moe_intermediate_size=128,
-                      num_hidden_layers=2, num_attention_heads=2,
-                      head_dim=128, max_position_embeddings=512)
-
-
-def program_config(shape, **over):
-    own = {k: v for k, v in shape.items()
-           if k in zaya.ZayaConfig.__dataclass_fields__}
-    own.update(dtype='float32', param_dtype='float32')
-    own.update(over)
-    return zaya.ZayaConfig(**own)
-
-
-def weights(shape, seed=3, edit=None):
-    """(the reference's float32 weights, the same as the family scans
-    them: ``edit(layer's leaves)`` changes what the PROGRAM gets)."""
-    layers = jax.tree_util.tree_map(
-        lambda a: a.astype(jnp.float32),
-        ref.init_params(shape, jax.random.PRNGKey(seed)))
-    cfg = program_config(shape)
-    edit = edit or (lambda lp: lp)
-    return layers, {
-        'embed': layers['embed'], 'norm_f': layers['norm_f'],
-        'layers': zaya.stack_layers(
-            cfg, lambda l: edit(dict(layers['layers'][l])))}
-
-
-def prompts_of(lens, vocab=96, seed=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, vocab, size=n).astype(np.int32) for n in lens]
-
-
-@pytest.fixture
-def interpret():
-    fa.set_interpret(True)
-    yield
-    fa.set_interpret(False)
-
-
-def _serve(shape, engine_kw, prompts, max_new, edit=None, config=None,
-           **submit_kw):
-    layers, stacked = weights(shape, edit=edit)
-    with GenerationEngine(stacked, config or program_config(shape),
-                          **engine_kw) as eng:
-        futs = [eng.submit(p, max_new_tokens=max_new, want_logits=True,
-                           **submit_kw) for p in prompts]
-        served = [(f.result(timeout=600), f.logits()) for f in futs]
-        stats = eng.stats()
-    return layers, served, stats
-
-
-def _held_to_reference(shape, layers, prompts, served, max_new, tol):
-    for p, (toks, rows) in zip(prompts, served):
-        seq = np.concatenate([p, np.asarray(toks[:-1], np.int32)])
-        want = np.asarray(ref.forward(layers, jnp.asarray(seq)[None],
-                                      shape)[0])[len(p) - 1:]
-        assert len(toks) == max_new == len(rows)
-        np.testing.assert_allclose(np.stack(rows), want, atol=tol, rtol=0)
-        assert toks == [int(np.argmax(r)) for r in rows]
-
-
-# ---- served rows against the plain reference -------------------------------
-
-ENGINE = dict(num_slots=3, page_size=4, prefill_width=40)
-PROMPTS = (5, 21, 33, 12, 1, 2, 3)
-
-
-def test_engine_serves_the_reference_rows_through_tails_and_pages(
-        traces_for):
-    """Logits, not tokens: prompts of 1, 2 and 3 rows (a tail that reaches
-    before row 0) among longer ones, each padded to the narrowest of four
-    widths (``valid`` short of it), 20 tokens each through the slots' tails
-    and the pages, seven requests on three slots: the later ones are
-    admitted while the first decode, into slots and pages others left."""
-    shape = tiny_shape()
-    prompts = prompts_of(PROMPTS)
-    layers, served, stats = _serve(shape, ENGINE, prompts, 20)
-    _held_to_reference(shape, layers, prompts, served, 20, TOL)
-    assert stats['evictions'] == 0
-    assert len(stats['prefill_widths']) >= 2
-    assert stats['traces'] == traces_for(stats['prefill_widths'],
-                                         map(len, prompts)) == 1 + 4
-    assert stats['free_pages'] == stats['num_pages'] - 1    # the trash page
-
-
-def test_engine_serves_the_reference_rows_through_the_kernels(interpret):
-    """The same through the Pallas interpreter: the flash forward in the
-    prefills (two widths), the paged kernel over two KV heads of 128 and
-    the grouped expert product with the layer as an offset into the
-    experts' stack."""
-    shape = kernel_shape()
-    prompts = prompts_of((200, 140, 100))
-    layers, served, stats = _serve(
-        shape, dict(num_slots=2, page_size=128, prefill_width=256), prompts,
-        5)
-    assert len({next(w for w in stats['prefill_widths'] if w >= len(p))
-                for p in prompts}) == 2
-    _held_to_reference(shape, layers, prompts, served, 5, 1e-4)
-
-
-def test_the_whole_forward_is_the_references():
-    shape = tiny_shape()
-    layers, stacked = weights(shape)
-    tokens = jnp.asarray(np.stack(prompts_of((21, 21))))
-    np.testing.assert_allclose(
-        zaya.forward(stacked, tokens, program_config(shape)),
-        ref.forward(layers, tokens, shape), atol=5e-6, rtol=0)
-
+# ---- each left-out term fails the comparison the engine passes -------------
 
 def _zeroed(name, at=None):
     """An edit of a layer's leaves: ``name`` (a path a.b) made zero."""
@@ -203,12 +78,15 @@ def test_a_term_left_out_or_a_lower_precision_fails_the_same_comparison(
     own), or run with its router or everything in bfloat16, is refused by
     it."""
     shape = tiny_shape()
-    prompts = prompts_of((5, 21, 2))
-    layers, served, _ = _serve(
-        shape, ENGINE, prompts, 8, edit=edit,
-        config=program_config(shape, **config))
+    # weights with a leaf changed run the standard engine's executables;
+    # another configuration traces its own
+    run = served.serve(
+        ENGINE, prompts_of((5, 21, 2)), 8,
+        stacked=weights(shape, edit=edit)[1] if edit else None,
+        config=program_config(shape, **config),
+        like=served.standard.engine if edit else None)
     with pytest.raises(AssertionError):
-        _held_to_reference(shape, layers, prompts, served, 8, TOL)
+        served.held_to_reference(run, 8, TOL)
 
 
 # ---- the router again, on the program's own rows ---------------------------
@@ -244,21 +122,24 @@ def test_the_routers_notes_tell_its_arithmetic_from_the_streams(router, told):
     whatever the rest of the program computes in, and a bfloat16 router
     stands three orders of magnitude away."""
     shape = tiny_shape()
-    prompts = prompts_of((5, 21, 2))
-    layers, stacked = weights(shape)
-    with GenerationEngine(stacked, program_config(
-            shape, router_dtype=router), **ENGINE) as eng:
-        futs = [eng.submit(p, max_new_tokens=8, want_logits=True)
-                for p in prompts]
-        other = eng.submit(prompts[0], max_new_tokens=8)
-        served = [(f.result(timeout=600), f.row_notes()) for f in futs]
-        other.result(timeout=600)
-    with pytest.raises(ValueError):
-        other.row_notes()
-    for toks, notes in served:
-        assert len(notes) == len(toks) == 8
+    layers = served.weights[0]
+    if told:
+        run = served.serve(ENGINE, prompts_of((5, 21, 2)), 8,
+                           config=program_config(shape, router_dtype=router))
+    else:
+        run = served.standard       # seven requests, 20 rows each
+        eng = GenerationEngine(served.stacked, served.config,
+                               autostart=False, **ENGINE)
+        borrow(eng, run.engine)
+        with eng:
+            other = eng.submit(run.prompts[0], max_new_tokens=8)
+            other.result(timeout=600)
+        with pytest.raises(ValueError):
+            other.row_notes()
+    for toks, notes in zip(run.tokens, run.notes):
+        assert len(notes) == len(toks) >= 8
         got = _routers_again(shape, layers, notes)
-        assert got['state'].shape == (shape['num_hidden_layers'], 8)
+        assert got['state'].shape == (shape['num_hidden_layers'], len(toks))
         if told:
             assert np.median(got['state']) > 1e-3
             assert np.median(got['weight']) > 1e-3
@@ -293,7 +174,7 @@ def test_a_padded_prefill_leaves_the_tails_of_its_last_real_rows(valid):
     1`` of ``u W_v2``, zeros where they lie before row 0, whatever the
     width; the first layer's are the reference's own projections."""
     shape = tiny_shape()
-    layers, stacked = weights(shape)
+    layers, stacked = served.weights
     prompt = prompts_of((valid,))[0]
     narrow = _prefill(shape, stacked, prompt, 8)
     wide = _prefill(shape, stacked, prompt, 16)
@@ -312,84 +193,10 @@ def test_a_padded_prefill_leaves_the_tails_of_its_last_real_rows(valid):
                                atol=1e-5)
 
 
-def test_a_slot_filled_a_second_time_serves_what_a_fresh_engine_serves():
-    """One slot, three requests one after another: each starts from zero
-    tails in a row the last occupant left full (a prompt of 1 and of 2
-    rows among them: their tails reach before row 0, where the last
-    occupant's rows lie), and serves exactly what an engine that never held
-    another serves."""
-    shape = tiny_shape()
-    prompts = prompts_of((9, 1, 2, 17))
-    kw = dict(num_slots=1, page_size=4, prefill_width=24)
-    _, again, _ = _serve(shape, kw, prompts, 10)
-    for p, (toks, rows) in zip(prompts, again):
-        _, fresh, _ = _serve(shape, kw, [p], 10)
-        assert toks == fresh[0][0]
-        np.testing.assert_array_equal(np.stack(rows), np.stack(fresh[0][1]))
-
-
-def test_a_request_admitted_while_others_decode_serves_what_it_serves_alone():
-    shape = tiny_shape()
-    _, stacked = weights(shape)
-    first, late = prompts_of((11, 6))
-    kw = dict(num_slots=2, page_size=4, prefill_width=24)
-    _, alone, _ = _serve(shape, kw, [late], 12)
-    with GenerationEngine(stacked, program_config(shape), **kw) as eng:
-        running = eng.submit(first, max_new_tokens=30)
-        stream = running.stream(timeout=300)
-        for _ in range(5):                  # the first is five tokens deep
-            next(stream)
-        fut = eng.submit(late, max_new_tokens=12, want_logits=True)
-        toks, rows = fut.result(timeout=300), fut.logits()
-        assert not running.done()           # and still decoding
-        assert len(running.result(timeout=300)) == 30
-    assert toks == alone[0][0]
-    np.testing.assert_allclose(np.stack(rows), np.stack(alone[0][1]),
-                               atol=1e-6, rtol=0)
-
-
-def test_an_evicted_request_regenerates_its_tokens():
-    shape = tiny_shape()
-    prompts = prompts_of((7, 6, 5))
-    wide = dict(num_slots=3, page_size=4, prefill_width=16)
-    _, want, _ = _serve(shape, wide, prompts, 18)
-    _, got, stats = _serve(shape, dict(wide, num_pages=11), prompts, 18)
-    assert stats['evictions'] >= 1
-    assert [t for t, _ in got] == [t for t, _ in want]
-
-
-@pytest.mark.parametrize('kw,lens,note', [
-    (ENGINE, PROMPTS, 'refilled'),
-    (dict(num_slots=1, page_size=4, prefill_width=24), (9, 3, 17), 'alone'),
-    (dict(num_slots=3, page_size=4, prefill_width=16, num_pages=11),
-     (7, 6, 5), 'evicted'),
-], ids=lambda x: x if isinstance(x, str) else None)
-def test_one_step_ahead_serves_what_reading_first_serves(
-        kw, lens, note, read_first):
-    """A step rewrites EVERY slot's tails, so the step in flight when a
-    slot changes hands writes the old occupant's row once more: the new
-    occupant's prefill, queued behind it, overwrites both tails before the
-    first step that reads them. Same tokens as a loop that reads each step
-    before it dispatches the next, and the same rows (to rounding: which
-    rows share a step's expert tiles differs between the two orders)."""
-    shape = tiny_shape()
-    prompts = prompts_of(lens)
-    n_new = 18 if note == 'evicted' else 14
-    _, got, stats = _serve(shape, kw, prompts, n_new, seed=7)
-    with read_first():
-        _, want, base = _serve(shape, kw, prompts, n_new, seed=7)
-    assert base['steps_overlapped'] == 0 < stats['steps_overlapped']
-    assert (stats['evictions'] >= 1) is (note == 'evicted')
-    for (toks, rows), (want_toks, want_rows) in zip(got, want):
-        assert toks == want_toks
-        np.testing.assert_allclose(np.stack(rows), np.stack(want_rows),
-                                   atol=1e-6, rtol=0)
-
-
 # ---- the router: its carry, its skip choice, the shares --------------------
 
 def _half_inputs(shape, rows=24, seed=5):
-    layers, _ = weights(shape)
+    layers, _ = served.weights if shape == tiny_shape() else weights(shape)
     lp = layers['layers'][1]
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     u = jax.random.normal(k1, (rows, shape['hidden_size']), jnp.float32)
@@ -436,7 +243,7 @@ def test_the_stack_hands_each_layer_the_router_state_of_the_one_above():
     by what it changes in layers below."""
     shape = tiny_shape()
     tokens = jnp.asarray(np.stack(prompts_of((12,))))
-    layers, stacked = weights(shape)
+    layers, stacked = served.weights
     _, cut = weights(shape, edit=_zeroed('router.gamma'))
     cfg = program_config(shape)
     with_carry = zaya.forward(stacked, tokens, cfg)
@@ -502,88 +309,7 @@ def test_the_shares_add_up_to_the_uncut_half(shares):
     np.testing.assert_allclose(ref_parts + skip, whole, atol=1e-5)
 
 
-# ---- routed_experts after the split ----------------------------------------
-
-def _accepted_layer(lp, h, row_ok, *, held, top_k, n_group, topk_group,
-                    scale, normalise=True):
-    """``parallel/routed_experts.routed_experts`` as PR 39 had it, router
-    and layer in one: what the two accepted families' numbers were made
-    by."""
-    cdt = h.dtype
-    t = h.shape[0]
-    chosen, w = re_.route(h, lp['router'], lp['router_bias'], top_k=top_k,
-                          n_group=n_group, topk_group=topk_group,
-                          scale=scale, normalise=normalise)
-    tm = re_.tile_rows(t * top_k)
-    pl_ = re_.plan(chosen, row_ok, held, tm)
-    rows = jnp.take(h, pl_['src'], axis=0)
-    gmm = lambda x, wt: expert_grouped_matmul(
-        x, wt.astype(cdt), pl_['tile_expert'], pl_['n_tiles'], tm=tm)
-    ex = lp['experts']
-    act = (jax.nn.silu(gmm(rows, ex['gate']).astype(jnp.float32))
-           * gmm(rows, ex['up']).astype(jnp.float32)).astype(cdt)
-    out = gmm(act, ex['down'])
-    y = re_.swiglu(lp['shared'], h, cdt)
-    m = out.shape[0]
-    picked = jnp.take(out, jnp.minimum(pl_['dest'], m - 1), axis=0)
-    w_held = jnp.where(pl_['is_held'], w, 0.0).astype(cdt)
-    return y + jnp.einsum('tk,tkh->th', w_held, picked,
-                          preferred_element_type=jnp.float32).astype(cdt)
-
-
-@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
-@pytest.mark.parametrize('name', ['afmoe', 'latent_moe'])
-def test_the_split_leaves_the_routed_families_their_bits(name, dtype,
-                                                         monkeypatch):
-    """``routed_experts`` is ``route`` and then ``held_experts``: for
-    ``latent_moe`` and ``afmoe``, which call it as they did, the layer's
-    output and the whole forward's are what the one function of PR 39
-    gave, to the bit; and a family that hands ``held_experts`` the same
-    router's answer itself gets the same."""
-    if name == 'afmoe':
-        mod, cfg = afmoe, afmoe.AfmoeConfig(
-            vocab_size=128, hidden_size=64, intermediate_size=128,
-            moe_intermediate_size=32, num_hidden_layers=3,
-            num_dense_layers=1, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=16, sliding_window=8,
-            num_experts=8, num_experts_per_tok=2, held=(2, 4),
-            max_position_embeddings=64, dtype=dtype, param_dtype=dtype)
-        kw = dict(held=cfg.held, top_k=2, n_group=1, topk_group=1,
-                  scale=cfg.route_scale, normalise=cfg.route_norm)
-    else:
-        mod, cfg = latent_moe, latent_moe.LatentMoEConfig(
-            vocab_size=128, hidden_size=64, intermediate_size=128,
-            moe_intermediate_size=32, num_hidden_layers=3,
-            first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=32,
-            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
-            v_head_dim=16, n_routed_experts=8, num_experts_per_tok=2,
-            n_group=2, topk_group=1, held=(0, 4),
-            max_position_embeddings=64, dtype=dtype, param_dtype=dtype)
-        kw = dict(held=cfg.held, top_k=2, n_group=2, topk_group=1,
-                  scale=cfg.routed_scaling_factor,
-                  normalise=cfg.norm_topk_prob)
-    params = mod.init_params(cfg, jax.random.PRNGKey(4))
-    lp = params['layers'][-1]
-    h = jax.random.normal(jax.random.PRNGKey(6), (40, 64)).astype(dtype)
-    row_ok = jnp.arange(40) < 33
-    got, _ = re_.routed_experts(lp, h, row_ok, **kw)
-    np.testing.assert_array_equal(
-        np.asarray(got, np.float32),
-        np.asarray(_accepted_layer(lp, h, row_ok, **kw), np.float32))
-    chosen, w = re_.route(h, lp['router'], lp['router_bias'],
-                          **{k: v for k, v in kw.items() if k != 'held'})
-    again, _ = re_.held_experts(lp, h, row_ok, chosen, w, held=kw['held'])
-    np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(again, np.float32))
-    tokens = jnp.asarray(np.stack(prompts_of((24, 24), vocab=128)))
-    after = np.asarray(mod.forward(params, tokens, cfg), np.float32)
-    monkeypatch.setattr(
-        re_, 'routed_experts',
-        lambda lp, h, row_ok, **kw: (_accepted_layer(lp, h, row_ok, **kw),
-                                     jnp.zeros((5,), jnp.int32)))
-    np.testing.assert_array_equal(
-        after, np.asarray(mod.forward(params, tokens, cfg), np.float32))
-
+# ---- held_experts on its own ------------------------------------------------
 
 def test_a_layer_without_a_shared_expert_and_an_offset_into_a_stack():
     """``held_experts`` with no 'shared' leaf gives the held experts'
@@ -613,23 +339,16 @@ def test_a_layer_without_a_shared_expert_and_an_offset_into_a_stack():
 # ---- the per-slot kind in the engine ---------------------------------------
 
 def test_the_tails_are_a_row_a_slot_beside_one_paged_kind():
-    shape = tiny_shape()
-    cfg = program_config(shape)
-    _, stacked = weights(shape)
+    cfg, stacked = served.config, served.stacked
     kinds = family.family_of(cfg).page_kinds(cfg)
     assert [(k.name, k.per_slot) for k in kinds] == [('kv', False),
                                                      ('tail', True)]
-    eng = GenerationEngine(stacked, cfg, num_slots=2, page_size=4,
-                           prefill_width=16, autostart=False)
-    assert [k.name for k in eng._kinds] == ['kv']
-    assert [k.name for k in eng._slot_kinds] == ['tail']
-    assert list(eng._allocs) == ['kv']
+    eng = GenerationEngine(stacked, cfg, autostart=False, **ENGINE)
+    borrow(eng, served.standard.engine)     # the same geometry
     c = (4 + 2) * 16
     assert eng._pool['k'].shape == (3, eng.num_pages, 2, 4, 16)
-    assert eng._pool['conv'].shape == (3, 2, 2 * c)
-    assert eng._pool['vtail'].shape == (3, 2, 16)
-    tables = eng._tables(2, slots=np.asarray([1, 0], np.int32))
-    assert tables['kv'].shape == (2, 16) and list(tables['tail']) == [1, 0]
+    assert eng._pool['conv'].shape == (3, 3, 2 * c)
+    assert eng._pool['vtail'].shape == (3, 3, 16)
     per_slot = 3 * (2 * c + 16) * 4
     assert eng.stats()['state_bytes_per_slot'] == per_slot
     fut = eng.submit(np.arange(5, dtype=np.int32), max_new_tokens=40)
@@ -644,14 +363,6 @@ def test_the_tails_are_a_row_a_slot_beside_one_paged_kind():
     eng.shutdown()
 
 
-def test_the_family_declines_a_prefix_cache():
-    shape = tiny_shape()
-    _, stacked = weights(shape)
-    with pytest.raises(ValueError, match='no prefix cache'):
-        GenerationEngine(stacked, program_config(shape), num_slots=2,
-                         page_size=4, prefix_cache=True, autostart=False)
-
-
 def test_the_counters_count_what_a_call_served():
     """A prefill counts its real rows, a decode step every slot, a layer
     at a time; a row is held or skipped; the attended keys are the rows a
@@ -662,15 +373,13 @@ def test_the_counters_count_what_a_call_served():
                 for n in ('rows_offered', 'rows_held', 'rows_skipped',
                           'expert_calls')]
     shape = tiny_shape()
-    before = read()
-    _, _, stats = _serve(shape, dict(num_slots=2, page_size=4,
-                                     prefill_width=24),
-                         prompts_of((5, 11)), 4)
+    delta, stats = served.counted(lambda: dict(enumerate(read())))
     p_off, p_held, p_skip, p_calls, d_off, d_held, d_skip, d_calls = (
-        a - b for a, b in zip(read(), before))
+        delta[i] for i in range(8))
     layers, experts = shape['num_hidden_layers'], shape['num_experts']
     assert p_off == (5 + 11) * layers == p_held + p_skip
-    assert d_off == 2 * stats['steps'] * layers == d_held + d_skip
+    # a step counts every slot, busy or idle: 3 a step
+    assert d_off == 3 * stats['steps'] * layers == d_held + d_skip
     assert p_calls == 2 * layers * experts
     assert d_calls == stats['steps'] * layers * experts
     assert obs.find('attn.keys_attended_total', {'kind': 'kv'}).value > 0
@@ -678,53 +387,27 @@ def test_the_counters_count_what_a_call_served():
 
 # ---- the configuration -----------------------------------------------------
 
-def test_the_published_defaults_are_the_catalog_rows():
-    """``ZayaConfig()`` is ZAYA1-8B as its config.json states it, and the
-    benchmark's configuration changes depth and context alone."""
-    path = os.path.join(REPO, 'benchmark', 'configs',
-                        'zaya1-8b-pp2-serve.json')
+def test_the_published_rotary_base_and_the_derived_widths():
+    """Beside the keys the contract reads off the cell's file: the rotary
+    base lies a level down in it, and the benchmark's configuration
+    changes depth and context alone."""
+    path = os.path.join(REPO, 'benchmark', 'configs', row.cell + '.json')
     with open(path) as f:
         doc = json.load(f)
     cfg = zaya.ZayaConfig()
-    same = ('vocab_size', 'hidden_size', 'moe_intermediate_size',
-            'num_attention_heads', 'num_key_value_heads', 'head_dim',
-            'cca_time0', 'cca_time1', 'num_experts', 'num_experts_per_tok',
-            'router_hidden_size', 'partial_rotary_factor', 'rms_norm_eps')
-    for key in same:
-        assert getattr(cfg, key) == doc[key], key
     assert cfg.rope_theta == doc['rope_parameters']['hybrid']['rope_theta']
     assert doc['reduced'] == ['num_hidden_layers', 'max_position_embeddings']
-    for key in doc['reduced']:
-        assert getattr(cfg, key) == doc['published'][key] != doc[key]
     assert cfg.held == (0, 16) and cfg.conv_dim == 1280
 
 
-@pytest.mark.parametrize('over,match', [
-    (dict(held=(8, 9)), 'outside'),
-    (dict(num_attention_heads=3), 'must divide'),
-    (dict(cca_time1=3), 'what is written'),
-    (dict(num_experts_per_tok=2), 'what is written'),
-    (dict(num_key_value_heads=4), 'what is written'),
-])
-def test_a_shape_the_family_does_not_write_is_refused(over, match):
-    with pytest.raises(ValueError, match=match):
-        zaya.ZayaConfig(**over)
-
-
-def test_an_engine_holds_matrices_in_the_compute_type_and_the_router_float32():
-    shape = tiny_shape()
-    _, stacked = weights(shape)
-    cfg = program_config(shape, dtype='bfloat16')
-    held = zaya.serve_params(stacked, cfg)
-    lay = held['layers']
-    for name in ('qkv', 'o', 'conv1'):
-        assert lay[name].dtype == jnp.bfloat16, name
+def test_an_engine_holds_the_router_float32_unless_told_otherwise():
+    """Beside the contract's case (matrices in the compute type, the rest
+    float32): the router's own matrices follow ``router_dtype``."""
+    stacked = served.stacked
+    cfg = program_config(tiny_shape(), dtype='bfloat16')
+    lay = zaya.serve_params(stacked, cfg)['layers']
     assert {a.dtype for a in lay['experts'].values()} == {jnp.dtype(
         'bfloat16')}
-    assert held['embed'].dtype == jnp.bfloat16
-    small = ('norm_attn', 'norm_moe', 'merge_attn', 'merge_moe', 'conv0',
-             'temp')
-    assert {lay[n].dtype for n in small} == {jnp.dtype('float32')}
     assert {a.dtype for a in lay['router'].values()} == {jnp.dtype('float32')}
     lower = zaya.serve_params(stacked, dataclasses.replace(
         cfg, router_dtype='bfloat16'))['layers']['router']
@@ -734,7 +417,7 @@ def test_an_engine_holds_matrices_in_the_compute_type_and_the_router_float32():
 
 def test_the_stack_is_the_layers_packed_and_stacked():
     shape = tiny_shape()
-    layers, stacked = weights(shape)
+    layers, stacked = served.weights
     lay = stacked['layers']
     for l, lp in enumerate(layers['layers']):
         np.testing.assert_array_equal(lay['qkv'][l], np.concatenate(
